@@ -11,15 +11,27 @@ runs as batched JAX/XLA kernels over a device-resident cluster matrix.
 __version__ = "0.1.0"
 
 
-def enable_compilation_cache(path: str = "/tmp/nomad_tpu_jax_cache") -> None:
-    """Opt into JAX's persistent compilation cache.
+def enable_compilation_cache() -> str:
+    """Opt into JAX's persistent compilation cache; returns its directory.
 
     The scheduler's p99 budget assumes warm jit caches; the persistent cache
     makes that true across *processes* too (server restarts, test runs,
     bench warmup). Call before the first kernel invocation.
+
+    One rule for every entry point: where ``JAX_COMPILATION_CACHE_DIR`` is
+    set the directory is JAX's own business and nothing here overrides it;
+    otherwise the cache lives at ``<checkout>/.jax_cache``, derived from
+    this package's location so every process of one checkout shares it.
     """
+    import os
+
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache")
+        )
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax.config.jax_compilation_cache_dir

@@ -27,7 +27,9 @@ def _print(obj) -> None:
     print(json.dumps(obj, indent=2, default=str))
 
 
-def cmd_agent(args) -> int:
+def build_agent(args):
+    """The Agent exactly as ``nomad agent`` boots it, not yet started
+    (chip_smoke.py drives the same construction the operator gets)."""
     from .api.agent import Agent, AgentConfig
     from .api.config_file import apply_config, load_config_files
 
@@ -61,7 +63,17 @@ def cmd_agent(args) -> int:
         ]
     if args.data_dir:
         config.server_config.data_dir = args.data_dir
-    agent = Agent(config)
+    if config.server_enabled:
+        # Server boots compile the scheduling kernels; share the
+        # persistent cache with every other entry point of this checkout.
+        from . import enable_compilation_cache
+
+        enable_compilation_cache()
+    return Agent(config)
+
+
+def cmd_agent(args) -> int:
+    agent = build_agent(args)
     agent.start()
     print(f"agent started; HTTP API at {agent.rpc_addr}")
     try:

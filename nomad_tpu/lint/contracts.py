@@ -5,7 +5,7 @@ entry point, the properties :mod:`nomad_tpu.lint.jaxprpass` proves from
 the *traced program* (not the source text):
 
 * which abstract configuration grid to trace under (two node counts so
-  J102 can assert node-count independence of the device→host tunnel);
+  J102 can assert node-count independence of the device→host fetch);
 * the device→host output-byte budget per launch (``None`` exempts an
   entry whose outputs are deliberately device-resident, e.g. the matrix
   scatter);
@@ -86,7 +86,7 @@ class DeviceContract:
     # count ever collides with it.
     boundary_exempt_shapes: Tuple[Tuple[int, ...], ...] = ()
     # J104: require an explicit input_output_alias in the compiled HLO.
-    # Off for the current entries: on CPU the fused kernel's donated
+    # Off for the current entries: the fused kernel's donated
     # lane operands are scratch-reusable but never output-ALIASED,
     # because no donated aval matches the packed (B, P, 8) output.
     expect_alias: bool = False

@@ -13,7 +13,7 @@ and walking the result:
   megakernel exists to amortize.
 * **J102** — total device→host output bytes per launch within the
   declared budget, and *independent of the node count* (traced at two N
-  values, byte counts must match): the O(B·P)-bytes tunnel contract.
+  values, byte counts must match): the O(B·P)-bytes fetch contract.
 * **J103** — no node-axis-sized value crossing a collective
   (``psum``/``pmax``/``pmin``/``all_gather``/…) or leaving the
   ``shard_map`` boundary, except declared exemptions: nothing
@@ -23,9 +23,8 @@ and walking the result:
   is donated undeclared), and donation survives to the compiled
   executable.  ``expect_alias`` additionally requires an
   ``input_output_alias`` in the HLO — off for the current entries
-  because on CPU no donated lane-operand aval matches the packed
-  (B, P, 8) output, so XLA can reuse the buffers as scratch but never
-  alias them.
+  because no donated lane-operand aval matches the packed (B, P, 8)
+  output, so XLA can reuse the buffers as scratch but never alias them.
 * **J105** — compile-cache cardinality, measured from the real cache:
   the contract's concrete sweep (occupancy fills, pow2 dirty-row
   buckets) may cost at most ``max_compiles`` new cache entries.
@@ -56,13 +55,13 @@ CALLBACK_PRIMS = frozenset(
     {"io_callback", "pure_callback", "debug_callback", "callback"}
 )
 
-# Cross-shard collectives (psum appears as psum2 under shard_map in this
-# jax).  pbroadcast is deliberately absent: it is replication
+# Cross-shard collectives (psum traces as psum_invariant under shard_map's
+# varying-axes typing).  pvary is deliberately absent: it is replication
 # bookkeeping, not data movement.
 COLLECTIVE_PRIMS = frozenset(
     {
         "psum",
-        "psum2",
+        "psum_invariant",
         "pmax",
         "pmin",
         "all_gather",
@@ -295,7 +294,7 @@ def check_contract(c: Any, root: Optional[str] = None) -> List[Finding]:
                     "J102",
                     "device→host bytes depend on the node count "
                     f"({ {n: b for n, b in sorted(by_n.items())} }) — an "
-                    "O(N) value is crossing the tunnel",
+                    "O(N) value is being fetched to the host",
                 )
 
         if c.compile_grid is not None:

@@ -29,10 +29,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.encode import SchedRequest, pow2_bucket
@@ -628,8 +625,15 @@ def _fused_place_batch_local(
         after, fits = jax.lax.scan(p_step, base, l_rows)
         return jnp.where(l_live, after, cum_used), fits
 
+    # The carry starts as this shard's usage slice (varying over 'node'
+    # only) but accumulates lane data gathered over 'batch'; shard_map's
+    # varying-axes check wants the scan carry typed the same going in as
+    # coming out, so the initial value is cast to vary over 'batch' too
+    # (every batch replica holds the same values — a typing formality).
     _, fits_all = jax.lax.scan(
-        lane_step, used, (g_rows, g_ask, g_drows, g_dvals, g_live)
+        lane_step,
+        jax.lax.pcast(used, ("batch",), to="varying"),
+        (g_rows, g_ask, g_drows, g_dvals, g_live),
     )  # (B, P) bool, identical on every node shard only after the pmin:
     verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
 

@@ -1,10 +1,9 @@
 """Bench regression ledger gates: an injected 2x latency regression
-must flag `regress`, noise within 1 MAD must stay `flat`, and the
-committed BENCH_*.json files must ingest without error."""
+must flag `regress`, noise within 1 MAD must stay `flat`, and both
+BENCH_*.json shapes (driver wrapper, flat result) must ingest."""
 
 from __future__ import annotations
 
-import glob
 import json
 import os
 import sys
@@ -13,7 +12,6 @@ import pytest
 
 TOOLS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                      "tools")
-REPO = os.path.dirname(TOOLS)
 sys.path.insert(0, TOOLS)
 
 import bench_history  # noqa: E402
@@ -141,11 +139,36 @@ class TestNormalization:
             "e2e_host_only_phase_ms.plan.apply.p99_ms"] == 2.5
 
 
+def _write_bench_files(tmp_path):
+    """One file per input shape the ledger ingests: two driver wrappers
+    (a parsed run and a crashed one) and a flat bench.py result."""
+    files = {
+        "BENCH_a.json": {
+            "n": 1, "cmd": "python bench.py", "rc": 1,
+            "tail": "Traceback ...", "parsed": None,
+        },
+        "BENCH_b.json": {
+            "n": 2, "cmd": "python bench.py", "rc": 0, "tail": "...",
+            "parsed": {"metric": "eval_throughput", "value": 1245.5,
+                       "p99_ms": 41.2, "platform": "cpu",
+                       "device_kind": "cpu", "device_count": 1},
+        },
+        "BENCH_c.json": {
+            "live_pipeline_evals_per_sec_depth8": 101.4,
+            "live_pipeline_speedup": 6.1, "phase": "live_pipeline",
+        },
+    }
+    paths = []
+    for name, payload in sorted(files.items()):
+        path = tmp_path / name
+        path.write_text(json.dumps(payload))
+        paths.append(str(path))
+    return paths
+
+
 class TestRealFiles:
-    def test_committed_bench_files_ingest(self, tmp_path):
-        files = sorted(glob.glob(os.path.join(REPO, "BENCH_*.json")))
-        files = [f for f in files if not f.endswith("BENCH_LEDGER.jsonl")]
-        assert len(files) >= 5, files
+    def test_bench_files_ingest(self, tmp_path):
+        files = _write_bench_files(tmp_path)
         ledger = tmp_path / "ledger.jsonl"
         rc = bench_history.main(
             ["--ledger", str(ledger), "ingest"] + files)
@@ -153,21 +176,24 @@ class TestRealFiles:
         entries = bench_history.read_ledger(str(ledger))
         assert len(entries) == len(files)
         ok = [e for e in entries if e["ok"]]
-        assert len(ok) == len(files) - 1  # r01 crashed, rest parsed
+        assert len(ok) == len(files) - 1  # the crashed run, rest parsed
         assert all(e["metrics"] for e in ok)
 
-    def test_committed_ledger_parses(self):
-        path = os.path.join(REPO, "BENCH_LEDGER.jsonl")
-        entries = bench_history.read_ledger(path)
-        assert len(entries) >= 6
+    def test_ingested_ledger_parses(self, tmp_path):
+        ledger = str(tmp_path / "ledger.jsonl")
+        bench_history.main(
+            ["--ledger", ledger, "ingest"] + _write_bench_files(tmp_path))
+        entries = bench_history.read_ledger(ledger)
         sources = {e["source"] for e in entries}
-        assert "BENCH_r01.json" in sources
-        assert "BENCH_live_pipeline.json" in sources
+        assert sources == {"BENCH_a.json", "BENCH_b.json", "BENCH_c.json"}
 
-    def test_report_runs_on_committed_ledger(self, capsys):
+    def test_report_runs_on_ingested_ledger(self, tmp_path, capsys):
+        ledger = str(tmp_path / "ledger.jsonl")
+        bench_history.main(
+            ["--ledger", ledger, "ingest"] + _write_bench_files(tmp_path))
+        capsys.readouterr()
         rc = bench_history.main(
-            ["--ledger", os.path.join(REPO, "BENCH_LEDGER.jsonl"),
-             "report", "--last", "10"])
+            ["--ledger", ledger, "report", "--last", "10"])
         assert rc == 0
         out = capsys.readouterr().out
         assert "runs shown" in out

@@ -299,6 +299,47 @@ class TestPipelineWedge:
         assert brief["breaker"] == BREAKER_OPEN
         assert coal.inflight_depth() == 0
 
+    def test_slow_launch_is_not_a_stall(self, monkeypatch):
+        """A jit call compiles synchronously inside the LAUNCH (dispatch
+        thread); the watchdog clocks only the fetch of an already-launched
+        result.  A launch far longer than the wedge bound — a cold XLA
+        compile — must therefore never read as slow or wedged."""
+        from nomad_tpu.ops import kernels
+
+        monkeypatch.setenv("NOMAD_TPU_DEVICE_DEADLINE_MS", "50")
+        monkeypatch.setenv("NOMAD_TPU_DEVICE_COLD_SCALE", "1")
+        m = _matrix(8)
+        coal = DeviceCoalescer(m, max_lanes=2, linger_s=0.0, pipeline_depth=2)
+
+        class Launched:
+            def __array__(self, dtype=None, copy=None):
+                out = np.zeros(
+                    (coal.max_lanes, coal.scan_length,
+                     kernels.FUSED_PACKED_WIDTH), np.float32,
+                )
+                out[:, :, kernels.PACKED_ROW] = -1.0
+                return out
+
+        def compiling_launch(*_operands, **_static):
+            time.sleep(0.4)  # 8x the deadline, 5x the wedge bound
+            return Launched()
+
+        monkeypatch.setattr(
+            kernels, "fused_place_batch_live", compiling_launch
+        )
+        coal.start()
+        try:
+            results = self._drive(
+                coal, [_inputs(m, mock.job()) for _ in range(4)], n_threads=4
+            )
+        finally:
+            coal.stop()
+        assert not any(isinstance(r, BaseException) for r in results), results
+        brief = coal.breaker.brief()
+        assert brief["breaker"] == BREAKER_CLOSED
+        assert brief["wedged"] == 0 and brief["slow"] == 0
+        assert coal.wedged_dispatches == 0
+
     def test_degraded_dispatches_still_place(self, monkeypatch):
         """With the breaker held open, dispatches take the staged host
         path and still produce placements (availability backstop)."""

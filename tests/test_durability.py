@@ -265,8 +265,6 @@ def test_snapshot_every_compacts_log(tmp_path):
 KILL9_CHILD = r"""
 import sys, time, os
 sys.path.insert(0, {repo!r})
-import __graft_entry__
-__graft_entry__._scrub_non_cpu_backends()
 from nomad_tpu import mock
 from nomad_tpu.server.server import Server, ServerConfig
 

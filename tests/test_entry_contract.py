@@ -1,4 +1,5 @@
-"""Driver-contract tests for __graft_entry__.
+"""Driver-contract tests for the entry points: __graft_entry__, bench.py,
+chip_smoke.py and the compile-cache rule they share.
 
 Round-1 postmortem (VERDICT.md Weak #9): nothing exercised the entry
 points the way the driver does — a fresh process with the *default*
@@ -58,7 +59,6 @@ def test_entry_compiles_fresh_process():
     under test is import + build + jit-compile, not the backend.)"""
     code = (
         "import __graft_entry__ as g\n"
-        "g._scrub_non_cpu_backends()\n"
         "import jax, numpy as np\n"
         "fn, args = g.entry()\n"
         "out = jax.jit(fn)(*args)\n"
@@ -115,3 +115,108 @@ def test_bench_smoke_small(tmp_path):
         assert key in out, out
     assert out["value"] > 0
     assert out.get("e2e_evals_per_sec", 0) > 0, out
+
+
+def _cache_dir_seen_by_child(env: dict, cwd: str) -> str:
+    code = (
+        "import nomad_tpu, jax\n"
+        "d = nomad_tpu.enable_compilation_cache()\n"
+        "assert d == jax.config.jax_compilation_cache_dir, d\n"
+        "print(d)\n"
+    )
+    p = subprocess.run(
+        [sys.executable, "-c", code], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert p.returncode == 0, p.stderr
+    return p.stdout.strip()
+
+
+def test_compilation_cache_directory_rule(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the directory is left to JAX;
+    unset it is <checkout>/.jax_cache — the same from any process and any
+    working directory, with no temporary name, pid or time in it."""
+    env = _driver_like_env()
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    assert _cache_dir_seen_by_child(env, REPO) == str(tmp_path / "placed")
+    del env["JAX_COMPILATION_CACHE_DIR"]
+    expected = os.path.join(REPO, ".jax_cache")
+    assert _cache_dir_seen_by_child(env, REPO) == expected
+    assert _cache_dir_seen_by_child(env, str(tmp_path)) == expected
+
+
+def test_bench_exits_nonzero_when_a_phase_raises(monkeypatch, capsys):
+    """A phase that raises still gets its *_error key into the one JSON
+    line, but the run must not exit 0."""
+    import json
+
+    import pytest
+
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+
+    def boom(result):
+        raise RuntimeError("boom")
+
+    monkeypatch.setenv("NOMAD_TPU_BENCH_LEDGER", "0")
+    monkeypatch.setattr(bench, "bench_kernel", lambda r: r.update(value=1.0))
+    monkeypatch.setattr(bench, "bench_sharded", boom)
+    for phase in ("bench_e2e", "bench_host_only", "bench_live_pipeline",
+                  "bench_overload"):
+        monkeypatch.setattr(bench, phase, lambda r: None)
+    for flag in ("SHARDED", "E2E", "HOST_ONLY", "LIVE_PIPELINE", "OVERLOAD"):
+        monkeypatch.setattr(bench, flag, True)
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code not in (0, None)
+    lines = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
+    assert len(lines) == 1, lines
+    out = json.loads(lines[0])
+    assert out["sharded_error"] == "RuntimeError: boom"
+    assert out["platform"] == "cpu"
+    assert out["device_kind"] and out["device_count"] >= 1
+
+
+def test_chip_smoke_refuses_without_an_accelerator(tmp_path):
+    """No accelerator: names the platform it found, exits non-zero and
+    prints no result line."""
+    env = _driver_like_env()
+    env["JAX_PLATFORMS"] = "cpu"
+    p = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert p.returncode != 0
+    assert "platform=cpu" in p.stdout
+    assert "cpu" in p.stderr
+    assert not any(l.startswith("{") for l in p.stdout.splitlines())
+
+
+def test_chip_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
+    """The rehearsal keeps the smoke's own code honest between chip runs:
+    every check passes on a tiny cluster, and the result still says it
+    established nothing (ok stays false)."""
+    import json
+
+    env = _driver_like_env()
+    env.update(JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
+    p = subprocess.run(
+        [sys.executable, "chip_smoke.py", "--cpu-rehearsal"], cwd=REPO,
+        env=env, capture_output=True, text=True, timeout=560,
+    )
+    assert p.returncode == 0, f"stdout={p.stdout[-3000:]}\nstderr={p.stderr[-3000:]}"
+    lines = p.stdout.strip().splitlines()
+    # Last line: the verdict alone, exactly the keys the driver parses.
+    verdict = json.loads(lines[-1])
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is False
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert isinstance(verdict["device"]["kind"], str)
+    assert isinstance(verdict["device"]["count"], int)
+    assert lines[-2].startswith("report: ")
+    out = json.loads(lines[-2][len("report: "):])
+    assert out["ok"] is False and out["rehearsal"] == "tiny"
+    assert out["device"] == verdict["device"]
+    assert "failed" not in out
+    assert out["live"]["counters"]["fused_dispatches"] > 0
+    assert out["live"]["allocs_preempted"] >= 1

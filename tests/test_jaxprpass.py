@@ -114,7 +114,7 @@ def test_j101_injected_io_callback_fires_only_j101():
 def test_j102_full_score_vector_return_fires_only_j102():
     def body(cols, ops, lane_mask):
         # The classic regression: "just return the scores too" — an O(N)
-        # value through the device→host tunnel, on every launch.
+        # value fetched from device to host, on every launch.
         return _mini_body(cols, ops, lane_mask), cols.sum(axis=1)
 
     entry = jax.jit(body, donate_argnums=(1, 2))

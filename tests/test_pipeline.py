@@ -3,7 +3,7 @@ pipeline must change THROUGHPUT only — placements stay identical to the
 serial path (any batching, any chaos timing), stale in-flight reads are
 counted and caught by the applier's re-verify, the sharded mirror stays
 resident (dirty-row scatter, not full re-lay), and depth=4 must beat
-depth=1 by >=2x under 20ms synthetic tunnel latency (the tier-1 floor
+depth=1 by >=2x under 20ms synthetic fetch latency (the tier-1 floor
 for the whole optimisation)."""
 
 from __future__ import annotations
@@ -144,6 +144,69 @@ class TestPipelineParity:
         assert all(o.rows.shape[0] > 0 for o in piped)
 
 
+class TestStagingReuse:
+    def test_operands_untouched_until_their_dispatch_resolves(
+        self, monkeypatch
+    ):
+        """A launch hands the staging buffers to jax, which may read them
+        (async host→device transfer; zero-copy aliasing on CPU) until the
+        result is fetched.  With several dispatches in flight, no staged
+        operand may be rewritten before the dispatch that read it has
+        resolved — a stand-in kernel compares each launch's operands at
+        fetch time with the copy it took at launch."""
+        from nomad_tpu.ops import kernels
+
+        m = _matrix(8)
+        coal = DeviceCoalescer(
+            m, max_lanes=2, linger_s=0.0, pipeline_depth=4
+        )
+        launches = []
+        overlap = []
+
+        class InFlight:
+            def __init__(self, operands):
+                self.live = operands
+                self.at_launch = [np.asarray(a).tobytes() for a in operands]
+                self.intact = None
+
+            def __array__(self, dtype=None, copy=None):
+                time.sleep(0.03)  # hold the fetch: later launches overlap
+                overlap.append(coal.inflight_depth())
+                self.intact = all(
+                    np.asarray(a).tobytes() == b
+                    for a, b in zip(self.live, self.at_launch)
+                )
+                out = np.zeros(
+                    (coal.max_lanes, coal.scan_length,
+                     kernels.FUSED_PACKED_WIDTH), np.float32,
+                )
+                out[:, :, kernels.PACKED_ROW] = -1.0
+                return out
+
+        def stand_in(arrays, used, dr, dv, tg, sc, pen, reqs, ce, hm, lm,
+                     **_static):
+            launches.append(
+                InFlight([dr, dv, tg, sc, pen, ce, hm, lm, *reqs])
+            )
+            return launches[-1]
+
+        monkeypatch.setattr(kernels, "fused_place_batch_live", stand_in)
+        inputs = []
+        for i in range(24):
+            inp = _inputs(m, mock.job())
+            # Distinct staged content per request, so a rewrite shows.
+            inp["tg_count"] = np.full((m.capacity,), i + 1, np.int32)
+            inputs.append(inp)
+        coal.start()
+        try:
+            _drive(coal, inputs, n_threads=8)
+        finally:
+            coal.stop()
+        assert len(launches) >= 12
+        assert max(overlap) > 1, "dispatches never overlapped"
+        assert all(lz.intact for lz in launches)
+
+
 class TestStaleDispatch:
     def test_stale_inflight_dispatch_is_counted(self, monkeypatch):
         """A matrix mutation while a dispatch is in flight bumps
@@ -264,7 +327,7 @@ class TestShardedResidency:
 @pytest.mark.parametrize("latency_ms", [20])
 def test_pipeline_depth4_beats_serial_floor(monkeypatch, latency_ms):
     """Tier-1 floor for the whole optimisation: with a 20ms synthetic
-    tunnel RTT, depth=4 must deliver >=2x the placement rate of the
+    fetch latency, depth=4 must deliver >=2x the placement rate of the
     serial depth=1 loop (theory: 4x — each overlapped dispatch hides a
     full latency window; 2x leaves headroom for loaded CI boxes)."""
     monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE", "1")

@@ -21,8 +21,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SERVER_SCRIPT = r"""
 import sys, time
 sys.path.insert(0, {repo!r})
-import __graft_entry__
-__graft_entry__._scrub_non_cpu_backends()
 from nomad_tpu.api.agent import Agent, AgentConfig
 from nomad_tpu.server.server import ServerConfig
 
@@ -42,8 +40,6 @@ while True:
 CLIENT_SCRIPT = r"""
 import sys, time
 sys.path.insert(0, {repo!r})
-import __graft_entry__
-__graft_entry__._scrub_non_cpu_backends()
 from nomad_tpu.api.agent import Agent, AgentConfig
 from nomad_tpu.client import ClientConfig
 

@@ -1,7 +1,7 @@
 """Profile one score_batch dispatch: cost analysis + component ablation.
 
 Usage: python tools/profile_kernel.py [--hlo] [--ablate]
-Writes nothing; prints findings. Round-4 perf investigation (VERDICT item 1).
+Writes nothing; prints findings.
 """
 
 from __future__ import annotations
@@ -25,8 +25,7 @@ def main() -> None:
     from nomad_tpu.ops.kernels import score_batch
     from nomad_tpu.parallel import build_batch_inputs
 
-    m = bench.build_cluster()
-    shapes = bench.build_requests(m)
+    m, shapes = bench.build_cluster()
     arrays = m.sync()
     inp = build_batch_inputs(m, [shapes[i % len(shapes)] for i in range(BATCH)])
     args = (
