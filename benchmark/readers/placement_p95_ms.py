@@ -1,0 +1,9 @@
+"""placement_p95_ms: client records: due -> placed, 95th percentile (open loop only)."""
+
+import measure
+
+
+def read(run):
+    if run["loop"] != "open":
+        return None
+    return measure.percentile(measure.latencies_ms(run), 0.95)
