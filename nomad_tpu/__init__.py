@@ -32,6 +32,14 @@ def enable_compilation_cache() -> str:
         jax.config.update(
             "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache")
         )
+    # The kernels' stage scopes (jax.named_scope) are metadata, which the
+    # cache key leaves out by default: a profile would then be handed an
+    # executable compiled before a scope existed, and name nothing.  Of the
+    # metadata only the name stack goes into the key: with Python frames in
+    # the locations too, every checkout path and every edit that shifts a
+    # line above a kernel would start the cache from nothing.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return jax.config.jax_compilation_cache_dir

@@ -18,6 +18,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
+from .. import trace
 from ..jobspec import api_to_job, parse_job
 from ..structs.types import DrainStrategy, SchedulerConfiguration
 
@@ -35,6 +36,24 @@ def _dump(obj: Any, exclude: Tuple[str, ...] = ()) -> Any:
     if isinstance(obj, dict):
         return {k: _dump(v, exclude) for k, v in obj.items()}
     return obj
+
+
+_JOB_SCALE = re.compile(r"^/v1/job/.+/scale$")
+_JOB = re.compile(r"^/v1/job/[^/]+$")
+
+
+def _write_route(method: str, path: str) -> Optional[str]:
+    """The job writes that get an ``api.request`` span: register,
+    deregister, scale.  Reads, streams and node RPCs get none."""
+    path = path.split("?", 1)[0]
+    if method in ("PUT", "POST"):
+        if path == "/v1/jobs":
+            return "job.register"
+        if _JOB_SCALE.match(path):
+            return "job.scale"
+    elif method == "DELETE" and _JOB.match(path):
+        return "job.deregister"
+    return None
 
 
 class HTTPError(Exception):
@@ -83,7 +102,28 @@ class HTTPAPIServer:
                 self.end_headers()
                 self.wfile.write(body)
 
+            def send_response(self, code, message=None):
+                self.status = code  # for _handle's api.request span
+                super().send_response(code, message)
+
             def _handle(self, method: str) -> None:
+                route = _write_route(method, self.path)
+                if route is None:
+                    self._serve(method)
+                    return
+                # The API boundary of a write, body read to response
+                # written: the server's side of the client's submit time.
+                t0 = time.time()
+                self.status = 0
+                try:
+                    self._serve(method)
+                finally:
+                    trace.record_span(
+                        "api.request", t0, time.time(),
+                        route=route, status=self.status,
+                    )
+
+            def _serve(self, method: str) -> None:
                 try:
                     parsed = urlparse(self.path)
                     multi = parse_qs(parsed.query)

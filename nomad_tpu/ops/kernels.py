@@ -262,6 +262,7 @@ def port_mask(arrays, req: SchedRequest, enabled: bool = True) -> jnp.ndarray:
     return (~conflict) & dyn_ok
 
 
+@jax.named_scope("feasibility")
 def feasibility_mask(arrays, req: SchedRequest,
                      class_elig: Optional[jnp.ndarray] = None,
                      host_mask: Optional[jnp.ndarray] = None,
@@ -305,6 +306,7 @@ def system_feasible(arrays, used0, req: SchedRequest, class_elig, host_mask):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("binpack")
 def fit_and_binpack(arrays, used, req: SchedRequest):
     """Resource fit + normalized fit score for all nodes.
 
@@ -333,6 +335,7 @@ def fit_and_binpack(arrays, used, req: SchedRequest):
     return fits, score, exhausted
 
 
+@jax.named_scope("affinity_spread")
 def anti_affinity_score(tg_count, req: SchedRequest):
     """(score (N,), appended (N,)) — JobAntiAffinityIterator (rank.go:560-607).
 
@@ -343,11 +346,13 @@ def anti_affinity_score(tg_count, req: SchedRequest):
     return jnp.where(appended, score, 0.0), appended
 
 
+@jax.named_scope("affinity_spread")
 def penalty_score(penalty_mask):
     """NodeReschedulingPenaltyIterator (rank.go:630-646)."""
     return jnp.where(penalty_mask, -1.0, 0.0), penalty_mask
 
 
+@jax.named_scope("affinity_spread")
 def affinity_score(arrays, req: SchedRequest, a_width: int = MAX_AFFINITIES):
     """NodeAffinityIterator (rank.go:698-728): Σ weight·match / Σ|weight|,
     appended only when non-zero. ``a_width`` (static) bounds the stanza loop
@@ -374,6 +379,7 @@ def affinity_score(arrays, req: SchedRequest, a_width: int = MAX_AFFINITIES):
     return jnp.where(appended, norm, 0.0), appended
 
 
+@jax.named_scope("affinity_spread")
 def spread_score(arrays, req: SchedRequest, spread_counts,
                  s_width: int = MAX_SPREADS):
     """SpreadIterator (spread.go:110-257).
@@ -459,6 +465,7 @@ def spread_score(arrays, req: SchedRequest, spread_counts,
     return jnp.where(appended, total, 0.0), appended
 
 
+@jax.named_scope("preemption")
 def preemption_state(arrays, req: SchedRequest):
     """Vectorized preemption candidate math.
 
@@ -722,24 +729,31 @@ def _place_scan(
     def step(carry, _):
         used, tg_cnt, s_hash, s_counts = carry
         req_step = req._replace(s_value_hash=s_hash)
-        res = score_nodes(
-            arrays, used, tg_cnt, s_counts, penalty_mask, req_step,
-            class_elig, host_mask, features,
-        )
-        row = jnp.argmax(res.final).astype(jnp.int32)
-        ok = res.final[row] > NEG_INF / 2
-        row = jnp.where(ok, row, -1)
+        with jax.named_scope("score"):
+            res = score_nodes(
+                arrays, used, tg_cnt, s_counts, penalty_mask, req_step,
+                class_elig, host_mask, features,
+            )
+        with jax.named_scope("pick"):
+            row = jnp.argmax(res.final).astype(jnp.int32)
+            ok = res.final[row] > NEG_INF / 2
+            row = jnp.where(ok, row, -1)
 
-        n_eval = jnp.sum(res.feasible).astype(jnp.int32)
-        n_filtered = jnp.sum(~res.feasible & arrays.eligible).astype(jnp.int32)
-        n_exhausted = jnp.sum(res.feasible & ~res.fits).astype(jnp.int32)
+            n_eval = jnp.sum(res.feasible).astype(jnp.int32)
+            n_filtered = jnp.sum(
+                ~res.feasible & arrays.eligible
+            ).astype(jnp.int32)
+            n_exhausted = jnp.sum(res.feasible & ~res.fits).astype(jnp.int32)
 
-        safe_row = jnp.maximum(row, 0)
-        used2 = jnp.where(ok, used.at[safe_row].add(req.ask), used)
-        tg2 = jnp.where(ok, tg_cnt.at[safe_row].add(1), tg_cnt)
-        new_hash, new_counts = _update_spread_counts(s_counts, req_step, arrays, safe_row)
-        s_hash2 = jnp.where(ok, new_hash, s_hash)
-        s_counts2 = jnp.where(ok, new_counts, s_counts)
+        with jax.named_scope("update"):
+            safe_row = jnp.maximum(row, 0)
+            used2 = jnp.where(ok, used.at[safe_row].add(req.ask), used)
+            tg2 = jnp.where(ok, tg_cnt.at[safe_row].add(1), tg_cnt)
+            new_hash, new_counts = _update_spread_counts(
+                s_counts, req_step, arrays, safe_row
+            )
+            s_hash2 = jnp.where(ok, new_hash, s_hash)
+            s_counts2 = jnp.where(ok, new_counts, s_counts)
 
         out = (
             row,
@@ -753,9 +767,10 @@ def _place_scan(
         return (used2, tg2, s_hash2, s_counts2), out
 
     init = (used0, tg_count, req.s_value_hash, spread_counts)
-    (used_after, tg_after, _, _), outs = lax.scan(
-        step, init, None, length=n_placements
-    )
+    with jax.named_scope("place_scan"):
+        (used_after, tg_after, _, _), outs = lax.scan(
+            step, init, None, length=n_placements
+        )
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh = outs
     return PlacementResult(
         rows=rows,
@@ -939,6 +954,7 @@ FUSED_PACKED_VERIFIED = 7
 FUSED_PACKED_WIDTH = 8
 
 
+@jax.named_scope("pack")
 def pack_fused_lanes(
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified, live
 ):
@@ -1044,9 +1060,10 @@ def _fused_place_batch_impl(
         after, fits = lax.scan(p_step, base, l_rows)
         return jnp.where(l_live, after, cum_used), fits
 
-    _, verified = lax.scan(
-        lane_step, used, (rows, reqs.ask, delta_rows, delta_vals, live)
-    )  # (B, P) bool
+    with jax.named_scope("verify_scan"):
+        _, verified = lax.scan(
+            lane_step, used, (rows, reqs.ask, delta_rows, delta_vals, live)
+        )  # (B, P) bool
 
     return pack_fused_lanes(
         rows, res.scores, res.binpack, res.preempted, res.nodes_evaluated,

@@ -516,57 +516,66 @@ def _fused_place_batch_local(
         def step(carry, _):
             u, tg_cnt, s_hash, s_counts = carry
             req_step = req._replace(s_value_hash=s_hash)
-            res = score_nodes(
-                arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
-                features=features,
-            )
+            with jax.named_scope("score"):
+                res = score_nodes(
+                    arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
+                    features=features,
+                )
             # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
             # then a cross-shard reduce of the implicit (shards, k) table —
             # pmax elects the winning score, pmin the lowest owning row.
-            vals, idxs = jax.lax.top_k(res.final, k)
-            best = jax.lax.pmax(vals[0], "node")
-            ok = best > NEG_INF / 2
-            cand = jnp.where(
-                vals == best, row_offset + idxs.astype(jnp.int32), big
-            )
-            grow = jax.lax.pmin(jnp.min(cand), "node")  # lowest row on ties
-            grow = jnp.where(ok, grow, -1)
-            owner = ok & (grow >= row_offset) & (grow < row_offset + n_local)
-            lwin = jnp.clip(grow - row_offset, 0, n_local - 1)
+            with jax.named_scope("pick"):
+                vals, idxs = jax.lax.top_k(res.final, k)
+                best = jax.lax.pmax(vals[0], "node")
+                ok = best > NEG_INF / 2
+                cand = jnp.where(
+                    vals == best, row_offset + idxs.astype(jnp.int32), big
+                )
+                # lowest row on ties
+                grow = jax.lax.pmin(jnp.min(cand), "node")
+                grow = jnp.where(ok, grow, -1)
+                owner = (
+                    ok & (grow >= row_offset) & (grow < row_offset + n_local)
+                )
+                lwin = jnp.clip(grow - row_offset, 0, n_local - 1)
 
-            n_eval = jax.lax.psum(
-                jnp.sum(res.feasible.astype(jnp.int32)), "node"
-            )
-            n_filt = jax.lax.psum(
-                jnp.sum((~res.feasible & arrays.eligible).astype(jnp.int32)),
-                "node",
-            )
-            n_exh = jax.lax.psum(
-                jnp.sum((res.feasible & ~res.fits).astype(jnp.int32)), "node"
-            )
+                n_eval = jax.lax.psum(
+                    jnp.sum(res.feasible.astype(jnp.int32)), "node"
+                )
+                n_filt = jax.lax.psum(
+                    jnp.sum(
+                        (~res.feasible & arrays.eligible).astype(jnp.int32)
+                    ),
+                    "node",
+                )
+                n_exh = jax.lax.psum(
+                    jnp.sum((res.feasible & ~res.fits).astype(jnp.int32)),
+                    "node",
+                )
 
-            u2 = jnp.where(owner, u.at[lwin].add(req.ask), u)
-            tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
+            with jax.named_scope("update"):
+                u2 = jnp.where(owner, u.at[lwin].add(req.ask), u)
+                tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
 
-            nvals = jnp.where(
-                owner, spread_values_at(arrays, req_step, lwin), 0
-            )
-            nvals = jax.lax.psum(nvals, "node")
-            new_hash, new_counts = apply_spread_values(
-                s_counts, req_step, nvals
-            )
-            s_hash2 = jnp.where(ok, new_hash, s_hash)
-            s_counts2 = jnp.where(ok, new_counts, s_counts)
+                nvals = jnp.where(
+                    owner, spread_values_at(arrays, req_step, lwin), 0
+                )
+                nvals = jax.lax.psum(nvals, "node")
+                new_hash, new_counts = apply_spread_values(
+                    s_counts, req_step, nvals
+                )
+                s_hash2 = jnp.where(ok, new_hash, s_hash)
+                s_counts2 = jnp.where(ok, new_counts, s_counts)
 
-            binp = jax.lax.psum(
-                jnp.where(owner, res.binpack[lwin], 0.0), "node"
-            )
-            pre = jax.lax.pmax(
-                jnp.where(
-                    owner, res.needs_preempt[lwin], False
-                ).astype(jnp.int32),
-                "node",
-            ).astype(bool)
+                binp = jax.lax.psum(
+                    jnp.where(owner, res.binpack[lwin], 0.0), "node"
+                )
+                pre = jax.lax.pmax(
+                    jnp.where(
+                        owner, res.needs_preempt[lwin], False
+                    ).astype(jnp.int32),
+                    "node",
+                ).astype(bool)
             out = (
                 grow,
                 jnp.where(ok, best, 0.0),
@@ -579,7 +588,8 @@ def _fused_place_batch_local(
             return (u2, tg2, s_hash2, s_counts2), out
 
         init = (used0, tg, req.s_value_hash, sc)
-        _, outs = jax.lax.scan(step, init, None, length=n_placements)
+        with jax.named_scope("place_scan"):
+            _, outs = jax.lax.scan(step, init, None, length=n_placements)
         return outs  # each (P,)
 
     rows, scores, binpack, pre, ne, nf, nx = jax.vmap(one)(
@@ -630,12 +640,13 @@ def _fused_place_batch_local(
     # varying-axes check wants the scan carry typed the same going in as
     # coming out, so the initial value is cast to vary over 'batch' too
     # (every batch replica holds the same values — a typing formality).
-    _, fits_all = jax.lax.scan(
-        lane_step,
-        jax.lax.pcast(used, ("batch",), to="varying"),
-        (g_rows, g_ask, g_drows, g_dvals, g_live),
-    )  # (B, P) bool, identical on every node shard only after the pmin:
-    verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
+    with jax.named_scope("verify_scan"):
+        _, fits_all = jax.lax.scan(
+            lane_step,
+            jax.lax.pcast(used, ("batch",), to="varying"),
+            (g_rows, g_ask, g_drows, g_dvals, g_live),
+        )  # (B, P) bool, identical on every node shard only after the pmin:
+        verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
 
     b_local = rows.shape[0]
     b_idx = jax.lax.axis_index("batch")

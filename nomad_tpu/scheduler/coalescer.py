@@ -402,6 +402,13 @@ class DeviceCoalescer:
 
     # ------------------------------------------------------------------
 
+    def _state(self, name: str, **args):
+        """One state of the dispatch or resolver loop as a span: ambient
+        (per launch, not per eval), on the thread that is in that state,
+        and annotated, so a profiler session shows it beside the device's
+        lanes (OBSERVABILITY.md, "Span taxonomy")."""
+        return trace.span(name, metrics=self.metrics, annotate=True, **args)
+
     def _run(self) -> None:
         """Dispatch (producer) loop: build batches, launch, hand tickets to
         the resolver.  Never blocks on a device→host fetch."""
@@ -421,12 +428,9 @@ class DeviceCoalescer:
             # overlapping latency windows (and how stale an in-flight read
             # can get).  Requests arriving during the wait coalesce into
             # the NEXT batch — the batch itself is already sealed.
-            self._depth_sem.acquire()
+            with self._state("coalescer.slot_wait", inflight=self.inflight):
+                self._depth_sem.acquire()
             waited = time.time()
-            if self.metrics is not None:
-                qw = self.metrics.timer("nomad.coalescer.queue_wait")
-                for p in batch:
-                    qw.observe(max(0.0, waited - p.enqueued_at))
             # Stitch each lane's enqueue→launch wait onto its eval trace
             # (carried here from the worker thread on _Pending.trace_ctx).
             for p in batch:
@@ -446,8 +450,7 @@ class DeviceCoalescer:
             if not allowed:
                 self.breaker.note_degraded()
             try:
-                with trace.span("coalescer.launch", lanes=len(batch),
-                                metrics=self.metrics):
+                with self._state("coalescer.launch", lanes=len(batch)):
                     packed, version = self._dispatch(
                         batch, degraded=not allowed
                     )
@@ -558,7 +561,8 @@ class DeviceCoalescer:
                     return
                 op = self._ops.pop(0)
             try:
-                op.result = op.fn()
+                with self._state("coalescer.device_op"):
+                    op.result = op.fn()
             except BaseException as exc:  # noqa: BLE001
                 op.error = exc
             op.done.set()
@@ -572,12 +576,14 @@ class DeviceCoalescer:
                 # guarantees its wake-up even when _resolve raises, so
                 # there is no lost-notify hole left to poll around
                 # (lint rule L004).
-                self._cond.wait_for(
-                    lambda: bool(self._queue)
-                    or bool(self._ops)
-                    or self._stop.is_set(),
-                )
-            if not self._queue:
+                with self._state("coalescer.idle"):
+                    self._cond.wait_for(
+                        lambda: bool(self._queue)
+                        or bool(self._ops)
+                        or self._stop.is_set(),
+                    )
+            queued = len(self._queue)
+            if not queued:
                 return None
         # Linger briefly so concurrent workers land in one dispatch.  The
         # fake-device backend answers synchronously, so lingering would only
@@ -586,7 +592,8 @@ class DeviceCoalescer:
         from ..ops import fake_device
 
         if self.linger_s and not fake_device.enabled():
-            self._stop.wait(self.linger_s)
+            with self._state("coalescer.linger", queued=queued):
+                self._stop.wait(self.linger_s)
         with self._cond:
             batch = self._queue[: self.max_lanes]
             del self._queue[: len(batch)]
@@ -784,6 +791,42 @@ class DeviceCoalescer:
             }
         return st, self._req_slabs[slot]
 
+    def _sync_matrix(self, n_shards: int, degraded: bool):
+        """The snapshot one launch reads: (arrays, sharded arrays, matrix
+        version, node-axis width) — dirty rows go host → device here."""
+        from ..ops import fake_device
+
+        mx = self.matrix
+        rows0, bytes0 = mx.rows_scattered_total, mx.upload_bytes_total
+        arrays = sharded = None
+        with self._state("coalescer.sync"):
+            if n_shards > 1:
+                # Multi-chip: the matrix stays RESIDENT across the mesh —
+                # sync_sharded scatters only dirty rows to the owning
+                # shard instead of re-laying the full matrix per dispatch.
+                with DEVICE_LOCK:
+                    sharded = mx.sync_sharded(self._mesh)
+                    version = mx.version
+                n = int(mx.capacity)
+            elif degraded and not fake_device.enabled():
+                # Breaker open on a real backend: feed the host twin from
+                # the host mirror directly — sync() would build a device
+                # snapshot on the very device the breaker just declared
+                # wedged.
+                arrays = mx.sync_host()
+                version = mx.version
+                n = int(arrays.used.shape[0])
+            else:
+                with DEVICE_LOCK:
+                    arrays = mx.sync()
+                    version = mx.version
+                n = int(arrays.used.shape[0])
+            trace.add_args(
+                rows=mx.rows_scattered_total - rows0,
+                bytes=mx.upload_bytes_total - bytes0,
+            )
+        return arrays, sharded, version, n
+
     def _dispatch(self, batch: List[_Pending], degraded: bool = False):
         """Launch one batched place_batch; returns (unfetched packed result,
         matrix version at launch).  ``degraded`` (breaker open) forces the
@@ -798,28 +841,7 @@ class DeviceCoalescer:
         else:
             n_shards = self._resolve_sharding()
 
-        sharded = None
-        if n_shards > 1:
-            # Multi-chip: the matrix stays RESIDENT across the mesh —
-            # sync_sharded scatters only dirty rows to the owning shard
-            # instead of re-laying the full matrix per dispatch.
-            with DEVICE_LOCK:
-                sharded = self.matrix.sync_sharded(self._mesh)
-                version = self.matrix.version
-            n = int(self.matrix.capacity)
-            arrays = None
-        elif degraded and not fake_device.enabled():
-            # Breaker open on a real backend: feed the host twin from the
-            # host mirror directly — sync() would build a device snapshot
-            # on the very device the breaker just declared wedged.
-            arrays = self.matrix.sync_host()
-            version = self.matrix.version
-            n = int(arrays.used.shape[0])
-        else:
-            with DEVICE_LOCK:
-                arrays = self.matrix.sync()
-                version = self.matrix.version
-            n = int(arrays.used.shape[0])
+        arrays, sharded, version, n = self._sync_matrix(n_shards, degraded)
 
         # Chaos seam: partition an entire matrix shard MID-dispatch — the
         # snapshot above was synced pre-darkening, so this launch still
@@ -849,30 +871,12 @@ class DeviceCoalescer:
             self._lose_shard()
             # The snapshot above was synced pre-evacuation; re-sync so
             # the launch scores the re-homed layout, not freed rows.
-            if degraded and not fake_device.enabled():
-                arrays = self.matrix.sync_host()
-                version = self.matrix.version
-                n = int(arrays.used.shape[0])
-            elif fake:
-                with DEVICE_LOCK:
-                    arrays = self.matrix.sync()
-                    version = self.matrix.version
-                n = int(arrays.used.shape[0])
-            else:
-                # Evacuation dropped the compiled sharded entry points;
-                # re-resolve so this launch runs on the survivor mesh
-                # (or the single-device path when one shard remains).
+            # Evacuation dropped the compiled sharded entry points;
+            # re-resolve so this launch runs on the survivor mesh (or the
+            # single-device path when one shard remains).
+            if not fake:
                 n_shards = self._resolve_sharding()
-                if n_shards > 1:
-                    with DEVICE_LOCK:
-                        sharded = self.matrix.sync_sharded(self._mesh)
-                        version = self.matrix.version
-                    n = int(self.matrix.capacity)
-                else:
-                    with DEVICE_LOCK:
-                        arrays = self.matrix.sync()
-                        version = self.matrix.version
-                    n = int(arrays.used.shape[0])
+            arrays, sharded, version, n = self._sync_matrix(n_shards, degraded)
 
         if fake:
             # Fake-device backend: numpy twins answer synchronously from
@@ -881,55 +885,54 @@ class DeviceCoalescer:
             # Requests built just before a matrix growth carry narrower
             # arrays; pad each by its OWN width (new rows masked off —
             # they were not host-checked).
-            for p in batch:
-                if p.host_mask.shape[0] < n:
-                    p.host_mask = np.concatenate([
-                        p.host_mask,
-                        np.zeros((n - p.host_mask.shape[0],), bool),
-                    ])
-                if p.tg_count.shape[0] < n:
-                    p.tg_count = np.concatenate([
-                        p.tg_count,
-                        np.zeros((n - p.tg_count.shape[0],), np.int32),
-                    ])
-                if p.penalty.shape[0] < n:
-                    p.penalty = np.concatenate([
-                        p.penalty,
-                        np.zeros((n - p.penalty.shape[0],), bool),
-                    ])
-            lane_lists = (
-                [p.delta_rows for p in batch],
-                [p.delta_vals for p in batch],
-                [p.tg_count for p in batch],
-                [p.spread_counts for p in batch],
-                [p.penalty for p in batch],
-                [p.request for p in batch],
-                [p.class_elig for p in batch],
-                [p.host_mask for p in batch],
-            )
-            if self.megabatch:
-                packed = fake_device.fused_place_batch(
-                    arrays,
-                    arrays.used,
-                    *lane_lists,
-                    lane_mask=np.ones((len(batch),), bool),
-                    n_placements=self.scan_length,
-                    live_counts=[
-                        p.n_live or self.scan_length for p in batch
-                    ],
+            with self._state("coalescer.stage", lanes=len(batch)):
+                for p in batch:
+                    if p.host_mask.shape[0] < n:
+                        p.host_mask = np.concatenate([
+                            p.host_mask,
+                            np.zeros((n - p.host_mask.shape[0],), bool),
+                        ])
+                    if p.tg_count.shape[0] < n:
+                        p.tg_count = np.concatenate([
+                            p.tg_count,
+                            np.zeros((n - p.tg_count.shape[0],), np.int32),
+                        ])
+                    if p.penalty.shape[0] < n:
+                        p.penalty = np.concatenate([
+                            p.penalty,
+                            np.zeros((n - p.penalty.shape[0],), bool),
+                        ])
+                lane_lists = (
+                    [p.delta_rows for p in batch],
+                    [p.delta_vals for p in batch],
+                    [p.tg_count for p in batch],
+                    [p.spread_counts for p in batch],
+                    [p.penalty for p in batch],
+                    [p.request for p in batch],
+                    [p.class_elig for p in batch],
+                    [p.host_mask for p in batch],
                 )
-                self.fused_dispatches += 1
-                self.fused_lanes += len(batch)
-            else:
-                packed = fake_device.place_batch(
-                    arrays,
-                    arrays.used,
-                    *lane_lists,
-                    n_placements=self.scan_length,
-                    live_counts=[
-                        p.n_live or self.scan_length for p in batch
-                    ],
-                )
+            live_counts = [p.n_live or self.scan_length for p in batch]
+            with self._state("coalescer.enqueue", lanes=len(batch)):
+                if self.megabatch:
+                    packed = fake_device.fused_place_batch(
+                        arrays,
+                        arrays.used,
+                        *lane_lists,
+                        lane_mask=np.ones((len(batch),), bool),
+                        n_placements=self.scan_length,
+                        live_counts=live_counts,
+                    )
+                    self.fused_dispatches += 1
+                    self.fused_lanes += len(batch)
+                else:
+                    packed = fake_device.place_batch(
+                        arrays,
+                        arrays.used,
+                        *lane_lists,
+                        n_placements=self.scan_length,
+                        live_counts=live_counts,
+                    )
             self.operand_bytes_total += sum(
                 p.host_mask.nbytes + p.tg_count.nbytes + p.penalty.nbytes
                 + p.class_elig.nbytes + p.spread_counts.nbytes
@@ -944,97 +947,110 @@ class DeviceCoalescer:
             return packed, version
 
         k = len(batch)
-        cw = max(p.class_elig.shape[0] for p in batch)
-        sc_shape = batch[0].spread_counts.shape
-        st, slab = self._staging(n, cw, sc_shape)
-        hm, tg = st["host_mask"], st["tg_count"]
-        pen, ce = st["penalty"], st["class_elig"]
-        sc, dr, dv = st["spread_counts"], st["delta_rows"], st["delta_vals"]
-        lm = st["lane_mask"]
-        lm[:k] = True
-        lm[k:] = False
-        for i, p in enumerate(batch):
-            # Requests built just before a matrix growth or a class-count
-            # pow2 crossing carry narrower arrays; the staging row's tail
-            # keeps the inert value (new rows masked off — they were not
-            # host-checked; unknown classes eligible, matching
-            # _class_eligibility's default).
-            w = p.host_mask.shape[0]
-            hm[i, :w] = p.host_mask
-            hm[i, w:] = False
-            w = p.tg_count.shape[0]
-            tg[i, :w] = p.tg_count
-            tg[i, w:] = 0
-            w = p.penalty.shape[0]
-            pen[i, :w] = p.penalty
-            pen[i, w:] = False
-            w = p.class_elig.shape[0]
-            ce[i, :w] = p.class_elig
-            ce[i, w:] = True
-            sc[i] = p.spread_counts
-            dr[i] = p.delta_rows
-            dv[i] = p.delta_vals
-        if k < self.max_lanes:
-            # Pad lanes by memset: an all-False host mask makes every
-            # placement in the lane fail cheaply; whatever the other
-            # staging rows still hold from earlier dispatches only affects
-            # the dead lane's own (discarded) scores.  Deltas are reset so
-            # a stale row id can't scatter into the shared used base.
-            hm[k:] = False
-            dr[k:] = -1
+        with self._state("coalescer.stage", lanes=k):
+            cw = max(p.class_elig.shape[0] for p in batch)
+            sc_shape = batch[0].spread_counts.shape
+            st, slab = self._staging(n, cw, sc_shape)
+            hm, tg = st["host_mask"], st["tg_count"]
+            pen, ce = st["penalty"], st["class_elig"]
+            sc = st["spread_counts"]
+            dr, dv = st["delta_rows"], st["delta_vals"]
+            lm = st["lane_mask"]
+            lm[:k] = True
+            lm[k:] = False
+            for i, p in enumerate(batch):
+                # Requests built just before a matrix growth or a class-count
+                # pow2 crossing carry narrower arrays; the staging row's tail
+                # keeps the inert value (new rows masked off — they were not
+                # host-checked; unknown classes eligible, matching
+                # _class_eligibility's default).
+                w = p.host_mask.shape[0]
+                hm[i, :w] = p.host_mask
+                hm[i, w:] = False
+                w = p.tg_count.shape[0]
+                tg[i, :w] = p.tg_count
+                tg[i, w:] = 0
+                w = p.penalty.shape[0]
+                pen[i, :w] = p.penalty
+                pen[i, w:] = False
+                w = p.class_elig.shape[0]
+                ce[i, :w] = p.class_elig
+                ce[i, w:] = True
+                sc[i] = p.spread_counts
+                dr[i] = p.delta_rows
+                dv[i] = p.delta_vals
+            if k < self.max_lanes:
+                # Pad lanes by memset: an all-False host mask makes every
+                # placement in the lane fail cheaply; whatever the other
+                # staging rows still hold from earlier dispatches only affects
+                # the dead lane's own (discarded) scores.  Deltas are reset so
+                # a stale row id can't scatter into the shared used base.
+                hm[k:] = False
+                dr[k:] = -1
 
-        # Request operands write into the slot's (max_lanes, …) slab;
-        # dead-lane rows keep their previous valid contents (masked off by
-        # lane_mask / the all-False host mask, never decoded into results).
-        for i, p in enumerate(batch):
-            slab.fill(i, p.request)
-        reqs = slab.batch()
-        # Host→device operand traffic for this launch: the staged lane
-        # buffers plus the request slab (cost-attribution gauge; the
-        # resident matrix itself transfers via scatter, counted by
-        # matrix.upload_bytes_total).
-        self.operand_bytes_total += (
-            sum(a.nbytes for a in st.values()) + slab.nbytes()
+            # Request operands write into the slot's (max_lanes, …) slab;
+            # dead-lane rows keep their previous valid contents (masked off by
+            # lane_mask / the all-False host mask, never decoded into results).
+            for i, p in enumerate(batch):
+                slab.fill(i, p.request)
+            reqs = slab.batch()
+            # Host→device operand traffic for this launch: the staged lane
+            # buffers plus the request slab (cost-attribution gauge; the
+            # resident matrix itself transfers via scatter, counted by
+            # matrix.upload_bytes_total).
+            self.operand_bytes_total += (
+                sum(a.nbytes for a in st.values()) + slab.nbytes()
+            )
+        # The jitted call: the fused megakernel covers feasibility → binpack
+        # → spread/affinity → evict-set → the cross-lane AllocsFit
+        # re-verify column in one launch (node-sharded: each mesh shard
+        # scores only its local node slice, the winner comes from the
+        # hierarchical top-k reduce, and the packed (B, P, 8) fetch is the
+        # sole device→host traffic).  A launch that widened the features
+        # ratchet traces, lowers and compiles (or reads from the cache) a
+        # new variant inside the call: that one is named apart.
+        fused = (
+            self._sharded_fused_fn is not None if n_shards > 1
+            else self.megabatch
         )
-        if n_shards > 1:
-            if self._sharded_fused_fn is not None:
-                # Node-sharded fused megakernel: each mesh shard scores
-                # only its local node slice, the winner comes from the
-                # hierarchical top-k reduce, and the AllocsFit verify
-                # column is computed on winner rows only — the packed
-                # (B, P, 8) fetch is the sole device→host traffic.
-                feats = self._ratchet_features(slab, k)
-                self.fused_dispatches += 1
-                self.fused_lanes += k
-                return self._sharded_fused_fn(
-                    sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
-                    hm, lm, features=feats,
-                ), version
-            # Staged sharded fallback (NOMAD_TPU_SHARDED_MEGABATCH=0):
-            # packed result is PACKED_WIDTH wide and _resolve distinguishes
-            # the two by the trailing dimension.
-            return self._sharded_fn(
-                sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce, hm
-            ), version
-        if self.megabatch:
-            # Fused megakernel: one launch covers feasibility → binpack →
-            # spread/affinity → evict-set → the cross-lane AllocsFit
-            # re-verify column.
+        state, args, feats = "coalescer.enqueue", {"lanes": k}, None
+        if fused:
+            variants = self.feature_recompiles
             feats = self._ratchet_features(slab, k)
             self.fused_dispatches += 1
             self.fused_lanes += k
-            return kernels.fused_place_batch_live(
-                arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
-                lm, n_placements=self.scan_length,
-                features=feats,
-            ), version
-        # place_batch_live donates the per-dispatch lane operands (their
-        # device buffers become XLA scratch); `arrays`/`used` stay live —
-        # they are matrix-resident and shared with in-flight dispatches.
-        return kernels.place_batch_live(
-            arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
-            n_placements=self.scan_length,
-        ), version
+            if self.feature_recompiles != variants:
+                state = "coalescer.trace_variant"
+                args["features"] = str(tuple(feats))
+        with self._state(state, **args):
+            if n_shards > 1 and fused:
+                packed = self._sharded_fused_fn(
+                    sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
+                    hm, lm, features=feats,
+                )
+            elif n_shards > 1:
+                # Staged sharded fallback (NOMAD_TPU_SHARDED_MEGABATCH=0):
+                # packed result is PACKED_WIDTH wide and _resolve
+                # distinguishes the two by the trailing dimension.
+                packed = self._sharded_fn(
+                    sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce, hm
+                )
+            elif fused:
+                packed = kernels.fused_place_batch_live(
+                    arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
+                    lm, n_placements=self.scan_length,
+                    features=feats,
+                )
+            else:
+                # place_batch_live donates the per-dispatch lane operands
+                # (their device buffers become XLA scratch); `arrays`/`used`
+                # stay live — they are matrix-resident and shared with
+                # in-flight dispatches.
+                packed = kernels.place_batch_live(
+                    arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
+                    n_placements=self.scan_length,
+                )
+        return packed, version
 
     def _resolve(self, ticket: _Ticket) -> None:
         from ..chaos import inject
@@ -1059,139 +1075,142 @@ class DeviceCoalescer:
             slow is not None and slow.kind == "slow"
         )
 
-        if not seamed and isinstance(packed, np.ndarray):
-            # Fast path: the result is already host-resident (fake-device
-            # twin, no synthetic latency) — no fetch to watchdog, and no
-            # sacrificial thread per ticket.
-            arr = packed
-            brk.record_ok(0.0, canary=ticket.canary)
-        else:
-            def _fetch():
-                if wedge is not None and wedge.kind == "wedge":
-                    # Synthetic wedge: hold the fetch past every watchdog
-                    # bound (duration caps it so abandoned threads die).
-                    time.sleep(
-                        wedge.duration
-                        if wedge.duration > 0
-                        else max(deadline * factor * 4.0, 1.0)
-                    )
-                elif slow is not None and slow.kind == "slow":
-                    # Synthetic slow band: past the deadline, inside the
-                    # wedge bound — the result is late but usable.
-                    time.sleep(
-                        slow.duration
-                        if slow.duration > 0
-                        else deadline * (1.0 + factor) / 2.0
-                    )
-                pk = packed
-                if isinstance(pk, DeferredResult):
-                    pk = pk.result()
-                return np.asarray(pk)  # ONE device→host fetch per dispatch
-
-            try:
-                verdict, arr, elapsed = watchdog_fetch(
-                    _fetch, deadline, factor
-                )
-            except BaseException as exc:  # noqa: BLE001
-                if ticket.canary:
-                    brk.cancel_canary()
-                for p in entries:
-                    p.error = exc
-                    p.done.set()
-                return
-            if verdict == STALL_WEDGED:
-                # The fetch blew through the wedge bound: abandon it, trip
-                # the breaker, and complete every lane with the typed
-                # error — the worker's exception path nacks the eval back
-                # to the broker for redelivery (via the degraded path once
-                # the breaker opens).  Later tickets still resolve in
-                # launch order; the pipeline permit is returned by
-                # _resolve_loop's finally.
-                brk.record_wedge(elapsed, canary=ticket.canary)
-                self.wedged_dispatches += 1
-                trace.event(
-                    "coalescer.wedged_dispatch",
-                    lanes=len(entries),
-                    elapsed_ms=round(elapsed * 1e3, 1),
-                )
-                err = DeviceWedgedError(
-                    f"device fetch wedged after {elapsed * 1e3:.0f}ms "
-                    f"(deadline {deadline * 1e3:.0f}ms)",
-                    elapsed_s=elapsed,
-                    deadline_s=deadline,
-                )
-                for p in entries:
-                    p.error = err
-                    p.done.set()
-                return
-            if verdict == STALL_SLOW:
-                brk.record_slow(elapsed, canary=ticket.canary)
+        with self._state("coalescer.fetch", lanes=len(entries)):
+            if not seamed and isinstance(packed, np.ndarray):
+                # Fast path: the result is already host-resident (fake-device
+                # twin, no synthetic latency) — no fetch to watchdog, and no
+                # sacrificial thread per ticket.
+                arr = packed
+                brk.record_ok(0.0, canary=ticket.canary)
             else:
-                brk.record_ok(elapsed, canary=ticket.canary)
+                def _fetch():
+                    if wedge is not None and wedge.kind == "wedge":
+                        # Synthetic wedge: hold the fetch past every watchdog
+                        # bound (duration caps it so abandoned threads die).
+                        time.sleep(
+                            wedge.duration
+                            if wedge.duration > 0
+                            else max(deadline * factor * 4.0, 1.0)
+                        )
+                    elif slow is not None and slow.kind == "slow":
+                        # Synthetic slow band: past the deadline, inside the
+                        # wedge bound — the result is late but usable.
+                        time.sleep(
+                            slow.duration
+                            if slow.duration > 0
+                            else deadline * (1.0 + factor) / 2.0
+                        )
+                    pk = packed
+                    if isinstance(pk, DeferredResult):
+                        pk = pk.result()
+                    # ONE device→host fetch per dispatch
+                    return np.asarray(pk)
+
+                try:
+                    verdict, arr, elapsed = watchdog_fetch(
+                        _fetch, deadline, factor
+                    )
+                except BaseException as exc:  # noqa: BLE001
+                    if ticket.canary:
+                        brk.cancel_canary()
+                    for p in entries:
+                        p.error = exc
+                        p.done.set()
+                    return
+                if verdict == STALL_WEDGED:
+                    # The fetch blew through the wedge bound: abandon it, trip
+                    # the breaker, and complete every lane with the typed
+                    # error — the worker's exception path nacks the eval back
+                    # to the broker for redelivery (via the degraded path once
+                    # the breaker opens).  Later tickets still resolve in
+                    # launch order; the pipeline permit is returned by
+                    # _resolve_loop's finally.
+                    brk.record_wedge(elapsed, canary=ticket.canary)
+                    self.wedged_dispatches += 1
+                    trace.event(
+                        "coalescer.wedged_dispatch",
+                        lanes=len(entries),
+                        elapsed_ms=round(elapsed * 1e3, 1),
+                    )
+                    err = DeviceWedgedError(
+                        f"device fetch wedged after {elapsed * 1e3:.0f}ms "
+                        f"(deadline {deadline * 1e3:.0f}ms)",
+                        elapsed_s=elapsed,
+                        deadline_s=deadline,
+                    )
+                    for p in entries:
+                        p.error = err
+                        p.done.set()
+                    return
+                if verdict == STALL_SLOW:
+                    brk.record_slow(elapsed, canary=ticket.canary)
+                else:
+                    brk.record_ok(elapsed, canary=ticket.canary)
         resolved_at = time.time()
-        # Result traffic: the packed (lanes, placements, width) fetch is
-        # O(B·P) — winner rows only, never node-axis shaped (lint J005
-        # guards the call sites; the parity test pins this counter).
-        self.topk_host_bytes_total += arr.nbytes
-        # The launch→resolver hop: each lane's device window (launch to
-        # fetched-on-host) recorded here, on the resolver thread, against
-        # the trace context the worker thread captured in place().
-        for p in entries:
-            if p.trace_ctx is not None:
-                trace.record_span(
-                    "coalescer.device",
-                    ticket.launched_at or resolved_at,
-                    resolved_at,
-                    ctx=p.trace_ctx,
-                    metrics=self.metrics,
-                    lanes=len(entries),
+        with self._state("coalescer.unpack", lanes=len(entries)):
+            # Result traffic: the packed (lanes, placements, width) fetch is
+            # O(B·P) — winner rows only, never node-axis shaped (lint J005
+            # guards the call sites; the parity test pins this counter).
+            self.topk_host_bytes_total += arr.nbytes
+            # The launch→resolver hop: each lane's device window (launch to
+            # fetched-on-host) recorded here, on the resolver thread, against
+            # the trace context the worker thread captured in place().
+            for p in entries:
+                if p.trace_ctx is not None:
+                    trace.record_span(
+                        "coalescer.device",
+                        ticket.launched_at or resolved_at,
+                        resolved_at,
+                        ctx=p.trace_ctx,
+                        metrics=self.metrics,
+                        lanes=len(entries),
+                    )
+            if self.matrix.version != ticket.matrix_version:
+                # The matrix moved while this dispatch was in flight: its
+                # placements were scored against a stale snapshot.  They are
+                # still safe to propose — the serialized applier re-verifies
+                # every plan against authoritative state — but the count is
+                # the pipelining tax worth watching (surfaced as a registry
+                # gauge over this attribute by the server).
+                self.stale_dispatches += 1
+                trace.event("coalescer.stale_dispatch")
+            fused = arr.shape[-1] == kernels.FUSED_PACKED_WIDTH
+            for i, p in enumerate(entries):
+                row = arr[i]
+                # Shard-preserving capacity growth relocates rows; a dispatch
+                # that launched pre-growth reports OLD global row ids.  Map
+                # them through the matrix's remap window (no-op when nothing
+                # grew; unmappably old rows become -1 = failed placement).
+                rows_i = self.matrix.translate_rows(
+                    row[:, kernels.PACKED_ROW].astype(np.int32),
+                    ticket.matrix_version,
                 )
-        if self.matrix.version != ticket.matrix_version:
-            # The matrix moved while this dispatch was in flight: its
-            # placements were scored against a stale snapshot.  They are
-            # still safe to propose — the serialized applier re-verifies
-            # every plan against authoritative state — but the count is
-            # the pipelining tax worth watching (surfaced as a registry
-            # gauge over this attribute by the server).
-            self.stale_dispatches += 1
-            trace.event("coalescer.stale_dispatch")
-        fused = arr.shape[-1] == kernels.FUSED_PACKED_WIDTH
-        for i, p in enumerate(entries):
-            row = arr[i]
-            # Shard-preserving capacity growth relocates rows; a dispatch
-            # that launched pre-growth reports OLD global row ids.  Map
-            # them through the matrix's remap window (no-op when nothing
-            # grew; unmappably old rows become -1 = failed placement).
-            rows_i = self.matrix.translate_rows(
-                row[:, kernels.PACKED_ROW].astype(np.int32),
-                ticket.matrix_version,
-            )
-            fit_verified = None
-            if fused:
-                # The device-resident AllocsFit column: a 0.0 on a real
-                # placement means an earlier lane in THIS launch already
-                # claimed the capacity — at an unchanged matrix version the
-                # applier is guaranteed to reject it.  Advisory: the
-                # serialized applier stays authoritative either way.
-                vcol = row[:, kernels.FUSED_PACKED_VERIFIED]
-                placed = rows_i >= 0
-                fit_verified = ~(placed & (vcol == 0.0))
-                self.verify_conflicts += int((~fit_verified).sum())
-            p.outcome = PlaceOutcome(
-                rows=rows_i,
-                scores=row[:, kernels.PACKED_SCORE],
-                binpack=row[:, kernels.PACKED_BINPACK],
-                preempted=row[:, kernels.PACKED_PREEMPT] != 0.0,
-                nodes_evaluated=row[:, kernels.PACKED_EVALUATED].astype(
-                    np.int32
-                ),
-                nodes_filtered=row[:, kernels.PACKED_FILTERED].astype(
-                    np.int32
-                ),
-                nodes_exhausted=row[:, kernels.PACKED_EXHAUSTED].astype(
-                    np.int32
-                ),
-                fit_verified=fit_verified,
-                matrix_version=ticket.matrix_version,
-            )
-            p.done.set()
+                fit_verified = None
+                if fused:
+                    # The device-resident AllocsFit column: a 0.0 on a real
+                    # placement means an earlier lane in THIS launch already
+                    # claimed the capacity — at an unchanged matrix version
+                    # the applier is guaranteed to reject it.  Advisory: the
+                    # serialized applier stays authoritative either way.
+                    vcol = row[:, kernels.FUSED_PACKED_VERIFIED]
+                    placed = rows_i >= 0
+                    fit_verified = ~(placed & (vcol == 0.0))
+                    self.verify_conflicts += int((~fit_verified).sum())
+                p.outcome = PlaceOutcome(
+                    rows=rows_i,
+                    scores=row[:, kernels.PACKED_SCORE],
+                    binpack=row[:, kernels.PACKED_BINPACK],
+                    preempted=row[:, kernels.PACKED_PREEMPT] != 0.0,
+                    nodes_evaluated=row[:, kernels.PACKED_EVALUATED].astype(
+                        np.int32
+                    ),
+                    nodes_filtered=row[:, kernels.PACKED_FILTERED].astype(
+                        np.int32
+                    ),
+                    nodes_exhausted=row[:, kernels.PACKED_EXHAUSTED].astype(
+                        np.int32
+                    ),
+                    fit_verified=fit_verified,
+                    matrix_version=ticket.matrix_version,
+                )
+                p.done.set()

@@ -100,17 +100,26 @@ class PlanApplier:
         outcomes = []
         apply_t0 = time.time()
         spans: List[Tuple[PendingPlan, float, float]] = []
-        with self.server.metrics.timer("nomad.plan.apply").time():
-            with store._write_lock:
-                with store._lock:
-                    for pending in staged:
-                        t0 = time.time()
-                        try:
-                            result, index = self._apply_locked(pending.plan)
-                            outcomes.append((pending, result, index, None))
-                        except Exception as exc:  # noqa: BLE001
-                            outcomes.append((pending, None, 0, exc))
-                        spans.append((pending, t0, time.time()))
+        # The applier's own state, per batch and ambient: what this one
+        # thread was doing while the per-plan records below (stitched onto
+        # each eval's trace afterwards) say what the evals waited for.
+        with trace.span("plan.batch", metrics=self.server.metrics,
+                        annotate=True, plans=len(staged)):
+            with self.server.metrics.timer("nomad.plan.apply").time():
+                with store._write_lock:
+                    with store._lock:
+                        for pending in staged:
+                            t0 = time.time()
+                            try:
+                                result, index = self._apply_locked(
+                                    pending.plan
+                                )
+                                outcomes.append(
+                                    (pending, result, index, None)
+                                )
+                            except Exception as exc:  # noqa: BLE001
+                                outcomes.append((pending, None, 0, exc))
+                            spans.append((pending, t0, time.time()))
         # Trace stitching happens after the store locks are released —
         # per-plan timestamps were collected inside, recorded here onto
         # each plan's carried worker context.
@@ -216,6 +225,7 @@ class PlanApplier:
             # Entirely rejected plan: nothing commits; scheduler refreshes.
             result.refresh_index = self.server.store.latest_index
             self.plans_partial += 1
+            self.server.metrics.incr("nomad.plan.result", outcome="rejected")
             return result, 0
 
         index = self.server.next_index()
@@ -234,6 +244,12 @@ class PlanApplier:
             result.refresh_index = index
             self.plans_partial += 1
         self.plans_applied += 1
+        # One count per plan, where its fate is decided: the herd (evals
+        # racing for the same nodes) shows as rejected, not as partial.
+        self.server.metrics.incr(
+            "nomad.plan.result",
+            outcome="partial" if failed_nodes else "committed",
+        )
         return result, index
 
     # ------------------------------------------------------------------

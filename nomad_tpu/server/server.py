@@ -207,6 +207,7 @@ class Server:
         self._leader = False
         self._reaper: Optional[threading.Thread] = None
         self._shutdown = threading.Event()
+        self._runtime_hooks = False  # trace/runtime.py, held from start()
         self.replicator = None  # set by setup_replication (multi-server)
         self._acl_cache: Dict = {}
 
@@ -379,6 +380,13 @@ class Server:
     # ------------------------------------------------------------------
 
     def start(self) -> None:
+        if not self._runtime_hooks:
+            # Full collections and backend compiles as spans, while any
+            # server of this process runs (trace/runtime.py).
+            from ..trace import runtime
+
+            runtime.install()
+            self._runtime_hooks = True
         if self.replicator is not None:
             # Multi-server: everyone starts following; the election
             # promotes exactly one (monitorLeadership, leader.go:54).
@@ -441,6 +449,11 @@ class Server:
     def shutdown(self) -> None:
         self._shutdown.set()
         self._leader = False
+        if self._runtime_hooks:
+            from ..trace import runtime
+
+            runtime.uninstall()
+            self._runtime_hooks = False
         if self.replicator is not None:
             self.replicator.stop()
         self.deployment_watcher.stop()

@@ -7,6 +7,7 @@ from .core import (
     PHASE_PREFIX,
     FlightRecorder,
     SpanContext,
+    add_args,
     clear,
     config,
     configure,
@@ -26,6 +27,7 @@ __all__ = [
     "PHASE_PREFIX",
     "FlightRecorder",
     "SpanContext",
+    "add_args",
     "auto_dump",
     "chrome_trace",
     "clear",

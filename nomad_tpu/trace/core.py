@@ -43,7 +43,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from ..retry import env_float, env_int
 
@@ -138,6 +138,7 @@ class FlightRecorder:
         self._lock = threading.Lock()
         self._rings: Dict[int, deque] = {}
         self._thread_names: Dict[int, str] = {}
+        self._lanes: Dict[str, int] = {}  # lane name -> synthetic tid (< 0)
         self._tls = threading.local()
 
     def _ring(self) -> deque:
@@ -151,8 +152,20 @@ class FlightRecorder:
                 self._thread_names[t.ident or 0] = t.name
         return ring
 
-    def record(self, rec: Dict[str, Any]) -> None:
-        self._ring().append(rec)
+    def record(self, rec: Dict[str, Any], lane: Optional[str] = None) -> None:
+        (self._ring() if lane is None else self._lane(lane)).append(rec)
+
+    def _lane(self, name: str) -> deque:
+        """A ring that belongs to no thread: what stops the whole process
+        (``runtime.gc_pause``) gets a row of its own in the dump instead
+        of overlapping the spans of whichever thread noticed it."""
+        with self._lock:
+            tid = self._lanes.get(name)
+            if tid is None:
+                tid = self._lanes[name] = -(len(self._lanes) + 1)
+                self._rings[tid] = deque(maxlen=_cfg.ring)
+                self._thread_names[tid] = name
+            return self._rings[tid]
 
     def records(self, limit: Optional[int] = None) -> List[Dict[str, Any]]:
         """Snapshot every thread's ring, globally ordered by start time."""
@@ -219,7 +232,8 @@ def _observe_phase(name: str, dur: float, metrics: Any) -> None:
 _stack_tls = threading.local()
 
 
-def _stack() -> List[SpanContext]:
+def _stack() -> List[Tuple[SpanContext, Dict[str, Any]]]:
+    """Open spans of this thread, innermost last: (context, args)."""
     st = getattr(_stack_tls, "stack", None)
     if st is None:
         st = []
@@ -227,11 +241,19 @@ def _stack() -> List[SpanContext]:
     return st
 
 
+def add_args(**args: Any) -> None:
+    """Attach args to the innermost span open on this thread: what is
+    only known once the work is done (rows synced, bytes moved)."""
+    st = _stack()
+    if st:
+        st[-1][1].update(args)
+
+
 def current() -> Optional[SpanContext]:
     """Context of the innermost span active on *this* thread (what you
     capture before handing work to another thread), or None."""
     st = _stack()
-    return st[-1] if st else None
+    return st[-1][0] if st else None
 
 
 def start_trace(trace_id: str) -> SpanContext:
@@ -248,14 +270,18 @@ def record_span(
     ctx: Optional[SpanContext] = None,
     parent: Optional[int] = None,
     metrics: Any = None,
+    lane: Optional[str] = None,
     **args: Any,
 ) -> None:
     """Retroactively record a finished span — the cross-thread stitch.
     ``ctx`` is the carried context; the recorded span is its *child*
     unless ``parent`` overrides. With no ctx the span is ambient
-    (unparented, fresh trace id from the name)."""
+    (unparented, fresh trace id from the name). ``lane`` files the
+    record under a named row of the dump instead of this thread's."""
     if not _cfg.enabled:
         return
+    if _gc_pauses:
+        _flush_gc_pauses()
     if t1 < t0:
         t1 = t0
     _observe_phase(name, t1 - t0, metrics)
@@ -276,8 +302,29 @@ def record_span(
             "span": next(_span_ids),
             "parent": parent_id,
             "args": args or {},
-        }
+        },
+        lane,
     )
+
+
+# Full collections, as the gc hook of trace/runtime.py saw them: (start,
+# end, objects collected).  The hook runs wherever the collection was
+# triggered — possibly inside a registry or recorder lock — so it only
+# appends here; the next span recorded from ordinary code (or the next
+# dump) turns them into ``runtime.gc_pause`` records.
+_gc_pauses: deque = deque(maxlen=256)
+
+
+def _flush_gc_pauses() -> None:
+    while True:
+        try:
+            t0, t1, collected = _gc_pauses.popleft()
+        except IndexError:
+            return
+        record_span(
+            "runtime.gc_pause", t0, t1, lane="runtime",
+            generation=2, collected=collected,
+        )
 
 
 def event(
@@ -312,6 +359,7 @@ def span(
     ctx: Optional[SpanContext] = None,
     trace_id: Optional[str] = None,
     metrics: Any = None,
+    annotate: bool = False,
     **args: Any,
 ) -> Iterator[Optional[SpanContext]]:
     """Timed span, pushed on this thread's stack for automatic nesting.
@@ -320,10 +368,17 @@ def span(
     its child) > enclosing span on this thread > root. ``trace_id``
     starts a fresh root trace (the worker's ``eval.process`` entry
     point). Yields the span's own context for hand-off to other threads.
+
+    ``annotate`` also enters a ``jax.profiler.TraceAnnotation`` of the
+    same name, so that a profiler session (and only one: it is inert
+    otherwise) shows the span on the profiler's own clock beside the
+    device's lanes. For the per-launch and per-batch spans, not the
+    per-eval ones.
     """
     if not _cfg.enabled:
         yield None
         return
+    ann = _annotation(name, args) if annotate else None
     st = _stack()
     if trace_id is not None:
         parent_id = 0
@@ -332,19 +387,23 @@ def span(
         parent_id = ctx.span_id
         my = ctx.child()
     elif st:
-        parent_id = st[-1].span_id
-        my = st[-1].child()
+        parent_id = st[-1][0].span_id
+        my = st[-1][0].child()
     else:
         parent_id = 0
         my = start_trace("%s#%d" % (name, next(_span_ids)))
-    st.append(my)
+    st.append((my, args))
+    if ann is not None:
+        ann.__enter__()
     t0 = time.time()
     try:
         yield my
     finally:
         t1 = time.time()
+        if ann is not None:
+            ann.__exit__(None, None, None)
         # Pop *our* frame even if a nested span leaked (defensive).
-        while st and st[-1] is not my:
+        while st and st[-1][0] is not my:
             st.pop()
         if st:
             st.pop()
@@ -362,6 +421,20 @@ def span(
                     "args": args or {},
                 }
             )
+        if _gc_pauses:
+            _flush_gc_pauses()
+
+
+_TraceAnnotation = None  # jax.profiler's, imported on first use
+
+
+def _annotation(name: str, args: Dict[str, Any]) -> Any:
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args)
 
 
 # ----------------------------------------------------------------------
@@ -369,6 +442,8 @@ def span(
 
 
 def dump(limit: Optional[int] = None) -> List[Dict[str, Any]]:
+    if _gc_pauses and _cfg.enabled:
+        _flush_gc_pauses()
     return _recorder.records(limit=limit)
 
 

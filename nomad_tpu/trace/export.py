@@ -101,9 +101,10 @@ def dump_flight_record(
     its path. Metadata carries the dump reason and the active chaos seed
     so a postmortem can be replayed (`nomad chaos` / tools/chaos_repro.py).
     """
+    t0 = time.time()
     meta: Dict[str, Any] = {
         "reason": reason,
-        "dumped_at": time.time(),
+        "dumped_at": t0,
         "pid": os.getpid(),
     }
     seed = _chaos_seed()
@@ -125,6 +126,13 @@ def dump_flight_record(
             os.makedirs(parent, exist_ok=True)
     with open(path, "w") as f:
         json.dump(doc, f)
+        size = f.tell()
+    # The dump serializes every ring inside the serving process (the SLO
+    # observatory makes one on a breach): its own duration is a span.
+    core.record_span(
+        "trace.flight_dump", t0, time.time(), reason=reason,
+        records=len(doc["traceEvents"]), bytes=size,
+    )
     return path
 
 

@@ -240,10 +240,22 @@ class TestCrossThreadPropagation:
                     if r["name"] == "eval.process"][0]
             for r in by_trace[tid]:
                 assert r["trace"] == tid
-                if r["name"] == "coalescer.device":
+                if r["name"] in ("coalescer.queue_wait",
+                                 "coalescer.device"):
                     # Parented under the carried context, not another
-                    # request's.
-                    assert r["ts"] >= root["ts"] - 0.001
+                    # request's — by id, not by comparing clocks.
+                    assert r["parent"] == root["span"], (root, r)
+            (wait,) = [r for r in by_trace[tid]
+                       if r["name"] == "coalescer.queue_wait"]
+            (dev,) = [r for r in by_trace[tid]
+                      if r["name"] == "coalescer.device"]
+            # Order, on stamps one thread took in program order: a lane's
+            # wait ends where its launch begins (the same reading), the
+            # fetch ends after the launch, and never before the 10 ms the
+            # fake device was told to take.
+            assert wait["ts"] + wait["dur"] == pytest.approx(
+                dev["ts"], abs=1e-6)
+            assert dev["dur"] >= 0.010
 
 
 class TestHTTPSurfaceAndCLI:

@@ -71,6 +71,41 @@ class TestPerSpanCost:
             f"eval budget at {SPANS_PER_EVAL} spans/eval"
         )
 
+    def test_annotated_span_under_budget(self):
+        """A dispatcher-state span also enters a jax.profiler
+        TraceAnnotation (trace.span(annotate=True)); with no profiler
+        session running it is held to the plain span's ceiling."""
+        reg = MetricsRegistry()
+
+        def burn(n):
+            for i in range(n):
+                with trace.span("bench.state", metrics=reg, annotate=True,
+                                lanes=i):
+                    pass
+
+        burn(500)  # warm: the jax.profiler import, ring, timer
+        per_span = _best_of(5, 2000, burn)
+        assert per_span < CEILING_S, (
+            f"span(annotate=True) costs {per_span * 1e6:.1f}us vs "
+            f"{CEILING_S * 1e6:.1f}us gate"
+        )
+
+    def test_queued_gc_pause_is_filed_by_the_next_span(self):
+        """Every record checks for queued runtime.gc_pause records; a
+        queued one is filed under the runtime lane by the next span."""
+        from nomad_tpu.trace import core
+
+        now = time.time()
+        core._gc_pauses.append((now - 0.25, now, 7))
+        with trace.span("bench.op", trace_id="ev-fixed"):
+            pass
+        (rec,) = [r for r in trace.dump()
+                  if r["name"] == "runtime.gc_pause"]
+        assert rec["thread"] == "runtime"
+        assert rec["dur"] == pytest.approx(0.25)
+        assert rec["args"] == {"generation": 2, "collected": 7}
+        assert not core._gc_pauses
+
     def test_record_span_under_budget(self):
         reg = MetricsRegistry()
         ctx = trace.start_trace("ev-fixed")
