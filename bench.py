@@ -324,13 +324,13 @@ def bench_kernel(result: dict) -> None:
     n = int(np.asarray(arrays.used).shape[0])
     f_dr = jnp.full((BATCH, 1), -1, jnp.int32)
     f_dv = jnp.zeros((BATCH, 1, 3), jnp.float32)
-    f_lm = jnp.ones((BATCH,), bool)
+    f_ls = jnp.ones((BATCH,), jnp.int32)  # every lane live, one step
 
     def dispatch_fused():
         return fused_place_batch(
             arrays, arrays.used, f_dr, f_dv, inp["tg_counts"],
             inp["spread_counts"], inp["penalties"], inp["reqs"],
-            inp["class_eligs"], inp["host_masks"], f_lm,
+            inp["class_eligs"], inp["host_masks"], f_ls,
             n_placements=1, features=feats,
         )
 
@@ -361,7 +361,7 @@ def bench_kernel(result: dict) -> None:
         "tg_count": np.zeros((BATCH, n), np.int32),
         "penalty": np.zeros((BATCH, n), bool),
         "delta_rows": np.full((BATCH, MAX_DELTA_ROWS), -1, np.int32),
-        "lane_mask": np.zeros((BATCH,), bool),
+        "lane_steps": np.zeros((BATCH,), np.int32),
     }
     ones_n = np.ones((n,), bool)
     zeros_n = np.zeros((n,), np.int32)
@@ -374,7 +374,7 @@ def bench_kernel(result: dict) -> None:
         stage["tg_count"][i] = zeros_n
         stage["penalty"][i] = zeros_b
         stage["delta_rows"][i] = drow
-        stage["lane_mask"][i] = True
+        stage["lane_steps"][i] = 1
     host_us = (time.time() - t0) / BATCH * 1e6
 
     result.update(
@@ -455,7 +455,7 @@ def bench_sharded(result: dict) -> None:
     inp = build_batch_inputs(m, [shapes[i % len(shapes)] for i in range(b)])
     dr = jnp.full((b, 1), -1, jnp.int32)
     dv = jnp.zeros((b, 1, 3), jnp.float32)
-    lm = jnp.ones((b,), bool)
+    ls = jnp.full((b,), SHARDED_SCAN, jnp.int32)  # every lane, whole scan
     # Matrix residency: every leaf of the DeviceArrays snapshot; a shard
     # holds 1/s of each node-axis leaf.
     matrix_bytes = int(sum(
@@ -481,7 +481,7 @@ def bench_sharded(result: dict) -> None:
                 return fused_place_batch(
                     arrays, arrays.used, dr, dv, inp["tg_counts"],
                     inp["spread_counts"], inp["penalties"], inp["reqs"],
-                    inp["class_eligs"], inp["host_masks"], lm,
+                    inp["class_eligs"], inp["host_masks"], ls,
                     n_placements=SHARDED_SCAN, features=feats,
                 )
         else:
@@ -493,7 +493,7 @@ def bench_sharded(result: dict) -> None:
                 return fn(
                     arr_s, arr_s.used, dr, dv, inp["tg_counts"],
                     inp["spread_counts"], inp["penalties"], inp["reqs"],
-                    inp["class_eligs"], inp["host_masks"], lm,
+                    inp["class_eligs"], inp["host_masks"], ls,
                     features=feats,
                 )
 

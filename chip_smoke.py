@@ -237,6 +237,12 @@ def library_level(meter: CompileMeter, sizes, seed: int) -> dict:
     hm = np.ones((lanes, n), bool)
     hm[:, nodes:] = False
     lm = np.arange(lanes) < live
+    # Per-lane step counts: every live lane the whole scan (what a launch
+    # costs at most), and the live traffic's mix (1-8 a lane, one dead
+    # lane in between): the loops then stop at the widest lane's count.
+    ls = np.where(lm, scan, 0).astype(np.int32)
+    ls_mixed = np.where(lm, 1 + (np.arange(lanes) * 5) % 8, 0).astype(np.int32)
+    ls_mixed[20] = 0
 
     arrays = m.sync()
     host = m.sync_host()
@@ -261,7 +267,7 @@ def library_level(meter: CompileMeter, sizes, seed: int) -> dict:
     fused = run(
         "fused_place_batch",
         lambda: kernels.fused_place_batch(
-            arrays, arrays.used, dr, dv, tg, sc, pen, stacked, ce, hm, lm,
+            arrays, arrays.used, dr, dv, tg, sc, pen, stacked, ce, hm, ls,
             n_placements=scan, features=feats,
         ),
         lambda: fake_device.fused_place_batch(
@@ -273,6 +279,31 @@ def library_level(meter: CompileMeter, sizes, seed: int) -> dict:
         results["fused_place_batch"]["preempting"] > 0,
         "library: the preempting request never needed preemption",
     )
+    mixed = run(
+        "fused_place_batch_mixed_steps",
+        lambda: kernels.fused_place_batch(
+            arrays, arrays.used, dr, dv, tg, sc, pen, stacked, ce, hm,
+            ls_mixed, n_placements=scan, features=feats,
+        ),
+        lambda: fake_device.fused_place_batch(
+            host, host.used, *lane_lists, lane_mask=lm, n_placements=scan,
+            live_counts=list(ls_mixed),
+        ),
+        fused=True,
+    )
+    check(
+        not results["fused_place_batch_mixed_steps"]["compiles"],
+        "library: step counts compiled the fused kernel again",
+    )
+    for lane, n in enumerate(ls_mixed):
+        # Same work: the steps a lane asked for are bit for bit those of
+        # the full-length launch (the verdict column alone may differ: the
+        # other lanes commit fewer placements before it).
+        check(
+            _same_bits(mixed[lane, :n, :7], fused[lane, :n, :7]),
+            f"library: lane {lane}'s first {n} rows differ from the "
+            "full-length launch",
+        )
     run(
         "place_batch",
         lambda: kernels.place_batch(
@@ -375,15 +406,17 @@ def library_level(meter: CompileMeter, sizes, seed: int) -> dict:
     n_dev = len(jax.devices())
     if n_dev > 1:
         results["sharded_fused_place_batch"] = _library_sharded(
-            meter, m, arrays, (dr, dv, tg, sc, pen, stacked, ce, hm, lm),
-            feats, scan, n_dev,
+            meter, m, arrays, (dr, dv, tg, sc, pen, stacked, ce, hm),
+            (ls_mixed, ls), feats, scan, n_dev,
         )
     return out
 
 
-def _library_sharded(meter, m, arrays, operands, feats, scan, n_dev) -> dict:
+def _library_sharded(meter, m, arrays, operands, steps, feats, scan,
+                     n_dev) -> dict:
     """The node-sharded fused kernel against the unsharded one on the same
-    (post-scatter) matrix: all eight packed columns, bit for bit."""
+    (post-scatter) matrix: all eight packed columns, bit for bit, under
+    each of the per-lane step counts in ``steps`` (mixed first)."""
     import numpy as np
 
     from nomad_tpu.ops import kernels
@@ -398,15 +431,24 @@ def _library_sharded(meter, m, arrays, operands, feats, scan, n_dev) -> dict:
     sharded = m.sync_sharded(mesh)
     fn = sharded_fused_place_batch(mesh, scan)
     log(f"library: sharded_fused_place_batch over {dict(mesh.shape)} (cold)")
-    got, stats = _cold_warm(meter, lambda: fn(
-        sharded, sharded.used, *operands, features=feats
-    ))
-    want = np.asarray(kernels.fused_place_batch(
-        arrays, arrays.used, *operands, n_placements=scan, features=feats
-    ))
+    stats = {}
+    for name, ls in zip(("mixed_steps", "full_length"), steps):
+        got, st = _cold_warm(meter, lambda: fn(
+            sharded, sharded.used, *operands, ls, features=feats
+        ))
+        want = np.asarray(kernels.fused_place_batch(
+            arrays, arrays.used, *operands, ls, n_placements=scan,
+            features=feats,
+        ))
+        check(
+            _same_bits(got, want),
+            f"sharded_fused_place_batch ({name}) differs from the "
+            "unsharded kernel",
+        )
+        stats[name] = st
     check(
-        _same_bits(got, want),
-        "sharded_fused_place_batch differs from the unsharded kernel",
+        not stats["full_length"]["compiles"],
+        "sharded: step counts compiled the fused kernel again",
     )
     stats["mesh"] = {k: int(v) for k, v in mesh.shape.items()}
     stats["resident"] = _residency(sharded.used, n_dev, node_shard_count(mesh))
@@ -765,6 +807,7 @@ def live_level(meter: CompileMeter, sizes, seed: int) -> dict:
             "dispatches": coal.dispatches,
             "fused_dispatches": coal.fused_dispatches,
             "fused_lanes": coal.fused_lanes,
+            "scan_steps_total": coal.scan_steps_total,
             "coalesced_requests": coal.coalesced_requests,
             "solo_ops": coal.solo_ops,
             "stale_dispatches": coal.stale_dispatches,

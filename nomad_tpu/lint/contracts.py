@@ -12,9 +12,9 @@ the *traced program* (not the source text):
 * the donation set — which positional operands the entry declares
   donated, checked against what actually survives ``lower()`` /
   ``compile()``;
-* the compile-cache ratchet — a concrete sweep (occupancy fills,
-  pow2-padded dirty-row counts) plus the max number of distinct cache
-  entries it may cost.
+* the compile-cache ratchet — a concrete sweep (occupancy fills, per-lane
+  step counts, pow2-padded dirty-row counts) plus the max number of
+  distinct cache entries it may cost.
 
 New policy heads (ROADMAP item 4) register a row here instead of a new
 lint rule: add the entry to :func:`table` with its budget/donation/sweep
@@ -38,10 +38,11 @@ class Grid(NamedTuple):
     """One point of the trace/compile configuration grid.
 
     ``live`` is the occupancy (how many of the ``batch`` lanes carry a
-    real eval — the lane-mask fill); ``deltas`` is the in-flight
-    delta-row count K.  ``features`` is the static
-    :class:`nomad_tpu.ops.kernels.Features` bucket (``None`` for entry
-    points that take no feature switch, e.g. the row scatter).
+    real eval); ``steps`` is how many placements each of them asks for
+    (0 = all ``placements``) — together the ``lane_steps`` fill.
+    ``deltas`` is the in-flight delta-row count K.  ``features`` is the
+    static :class:`nomad_tpu.ops.kernels.Features` bucket (``None`` for
+    entry points that take no feature switch, e.g. the row scatter).
     """
 
     nodes: int
@@ -50,6 +51,7 @@ class Grid(NamedTuple):
     deltas: int
     live: int
     features: Any = None
+    steps: int = 0
 
 
 @dataclass(frozen=True)
@@ -174,8 +176,8 @@ def fused_operands(g: Grid) -> Tuple[Any, ...]:
     from ..ops.encode import MAX_SPREAD_VALUES, MAX_SPREADS
 
     n, b, k = g.nodes, g.batch, g.deltas
-    lane_mask = np.zeros((b,), bool)
-    lane_mask[: g.live] = True
+    lane_steps = np.zeros((b,), np.int32)
+    lane_steps[: g.live] = g.steps or g.placements
     return (
         _concrete_arrays(n),
         np.zeros((n, 3), np.float32),  # used
@@ -187,7 +189,7 @@ def fused_operands(g: Grid) -> Tuple[Any, ...]:
         _concrete_reqs(b),
         np.ones((b, 1), bool),  # class_eligs
         np.ones((b, n), bool),  # host_masks
-        lane_mask,
+        lane_steps,
     )
 
 
@@ -211,38 +213,52 @@ def _cache_size(entry: Callable[..., Any]) -> int:
     return int(size()) if callable(size) else 0
 
 
-def occupancy_sweep(entry: Callable[..., Any], c: DeviceContract) -> int:
-    """Call the entry at every lane-mask fill 1..batch (fresh operands
-    per call — donated buffers are consumed) and return how many NEW
-    compile-cache entries the sweep cost.  The contract: occupancy is a
-    runtime value, so ONE compile serves all fills."""
+def _compiles_over(entry: Callable[..., Any], c: DeviceContract, grids) -> int:
+    """Call the entry at each grid point (fresh operands per call —
+    donated buffers are consumed) and return how many NEW compile-cache
+    entries that cost."""
     import jax
 
-    g = c.compile_grid
-    assert g is not None
     before = _cache_size(entry)
-    for k in range(1, g.batch + 1):
-        gk = g._replace(live=k)
-        out = entry(*c.operands(gk), **c.static_kwargs(gk))
+    for g in grids:
+        out = entry(*c.operands(g), **c.static_kwargs(g))
         jax.block_until_ready(out)  # the compile must have really happened
     return _cache_size(entry) - before
+
+
+def occupancy_sweep(entry: Callable[..., Any], c: DeviceContract) -> int:
+    """Every occupancy fill 1..batch.  The contract: occupancy is a
+    runtime value, so ONE compile serves all fills."""
+    g = c.compile_grid
+    assert g is not None
+    return _compiles_over(
+        entry, c, (g._replace(live=k) for k in range(1, g.batch + 1))
+    )
+
+
+def lane_steps_sweep(entry: Callable[..., Any], c: DeviceContract) -> int:
+    """:func:`occupancy_sweep`, then every per-lane step count
+    1..placements.  The contract: the loops' trip counts are read from the
+    ``lane_steps`` operand, so the SAME compile serves all of them — a
+    count that leaked into a static argument shows as one compile each."""
+    g = c.compile_grid
+    assert g is not None
+    return occupancy_sweep(entry, c) + _compiles_over(
+        entry, c, (g._replace(steps=k) for k in range(1, g.placements + 1))
+    )
 
 
 def pow2_rows_sweep(entry: Callable[..., Any], c: DeviceContract) -> int:
     """Scatter sweep: dirty-row counts 1..batch, pow2-padded the way
     ``NodeMatrix._sync_locked`` pads them, so the distinct idx shapes —
     and therefore compiles — stay logarithmic in the row count."""
-    import jax
-
     g = c.compile_grid
     assert g is not None
-    before = _cache_size(entry)
-    for k in range(1, g.batch + 1):
-        padded = 1 << (k - 1).bit_length()
-        gk = g._replace(deltas=padded)
-        out = entry(*c.operands(gk), **c.static_kwargs(gk))
-        jax.block_until_ready(out)
-    return _cache_size(entry) - before
+    return _compiles_over(
+        entry, c,
+        (g._replace(deltas=1 << (k - 1).bit_length())
+         for k in range(1, g.batch + 1)),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +285,8 @@ def _fused_compile_grid() -> Grid:
     from ..ops.kernels import Features
 
     narrow = Features(c_width=0, a_width=0, s_width=0, preempt=False, ports=False)
-    return Grid(nodes=32, batch=4, placements=2, deltas=4, live=4, features=narrow)
+    # The live scan length, so the step-count sweep covers 1..16.
+    return Grid(nodes=32, batch=4, placements=16, deltas=4, live=4, features=narrow)
 
 
 def _fused_budget(g: Grid) -> int:
@@ -320,8 +337,9 @@ def table() -> Tuple[DeviceContract, ...]:
             out_budget=_fused_budget,
             donated_args=tuple(range(2, 11)),  # per-dispatch lane operands
             compile_grid=compile_grid,
-            sweep=occupancy_sweep,
-            max_compiles=1,  # occupancy is runtime data: ONE compile, all fills
+            sweep=lane_steps_sweep,
+            # occupancy and step counts are runtime data: ONE compile
+            max_compiles=1,
         ),
         DeviceContract(
             name="sharded_fused_place_batch",

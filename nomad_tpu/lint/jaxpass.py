@@ -32,7 +32,7 @@ This pass enforces both lexically over ``ops/``, ``parallel/``,
   ``x.shape[...]``).  Either way the "one compile serves every
   occupancy" contract breaks and each distinct batch size pays a full
   XLA compile mid-dispatch.  Preallocate a ``(B, ...)`` operand slab
-  (``ops.encode.RequestSlab``), mask dead lanes with ``lane_mask``, and
+  (``ops.encode.RequestSlab``), mark dead lanes with ``lane_steps`` 0, and
   keep static args bound to configuration constants.
 * **J005 node-axis fetch at a fused/sharded call site** — a function that
   drives the fused or node-sharded dispatch entry points
@@ -404,7 +404,7 @@ def _check_function(
                         f"derived from the live batch (len()/.shape) — "
                         f"each occupancy keys a fresh XLA compile; bind "
                         f"static args to configuration constants and let "
-                        f"lane_mask absorb occupancy",
+                        f"lane_steps absorb occupancy",
                     ))
 
         # J003: mutable value into a static param of a known jitted fn.

@@ -134,7 +134,7 @@ class RequestSlab:
     dispatch — no per-launch allocation, stable shapes for the jit cache.
 
     Rows past the live count keep their previous (valid) contents — dead
-    lanes are masked by ``lane_mask``/``host_mask``, never decoded into
+    lanes are masked by ``lane_steps`` 0 / ``host_mask``, never decoded into
     results — and the whole slab is broadcast-initialized from the first
     request filled so even a cold slab holds well-formed rows.  Buffers are
     rebuilt only if a field's trailing shape shifts (encoder version

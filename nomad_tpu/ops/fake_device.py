@@ -687,10 +687,10 @@ def place_batch(arrays, used, delta_rows: List[np.ndarray],
     lane padding / stacking needed host-side).  Returns (B, P, 7) f32.
 
     ``live_counts[i]`` caps how many scan steps request ``i`` actually
-    computes — callers (stack._select_locked) consume only ``rows[:remaining]``,
-    so the steps past that are dead work under the jax kernel's static
-    shapes.  The uncomputed tail rows are filled with the inert no-placement
-    marker (row=-1); they are shape-filler, not kernel-exact values."""
+    computes — callers (stack._select_locked) consume only ``rows[:remaining]``.
+    The uncomputed tail rows are filled with the inert no-placement marker
+    (row=-1, zeros), as the fused kernel fills them; the staged jax
+    ``place_batch`` has a static scan and still computes them."""
     b = len(reqs)
     out = np.zeros((b, n_placements, 7), np.float32)
     for i in range(b):
@@ -738,16 +738,21 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
     dead lane). ``lane_mask`` marks live lanes explicitly; dead lanes emit
     row=-1 / zeros and touch nothing.
 
-    With ``live_counts`` the uncomputed tail rows are shape-filler
-    (row=-1, verified=1.0) exactly like :func:`place_batch`; kernel-exact
-    parity requires live_counts=None.
+    ``live_counts[i]`` is the kernel's ``lane_steps[i]`` for a live lane
+    (None = all ``n_placements``; 0 = a dead lane): the lane's scan stops
+    after that many steps and its tail rows are inert (row=-1, zeros,
+    verified=1.0, nothing added to the cumulative usage image) —
+    kernel-exact, tests/test_megakernel.py compares all eight columns.
     """
     b = len(reqs)
     lane_mask = np.asarray(lane_mask, bool)
     out = np.zeros((b, n_placements, FUSED_PACKED_WIDTH), np.float32)
     cum_used = np.array(used, np.float32, copy=True)
     for i in range(b):
-        if not lane_mask[i]:
+        steps = n_placements
+        if live_counts is not None:
+            steps = min(n_placements, int(live_counts[i]))
+        if not lane_mask[i] or steps <= 0:
             out[i, :, 0] = -1.0
             out[i, :, FUSED_PACKED_VERIFIED] = -1.0
             continue
@@ -758,9 +763,6 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
         if live.any():
             used0 = used.copy()
             np.add.at(used0, drows[live], dvals[live])
-        steps = n_placements
-        if live_counts is not None:
-            steps = max(1, min(n_placements, int(live_counts[i])))
         out[i, :steps, :7] = _place_scan(
             arrays, reqs[i], used0, tg_counts[i], spread_counts[i],
             penalties[i], class_eligs[i], host_masks[i], steps,

@@ -711,6 +711,42 @@ def _update_spread_counts(spread_counts, req: SchedRequest, arrays, row):
     )
 
 
+def inert_step_outputs(n_placements: int) -> tuple:
+    """The stacked outputs of a placement scan in which no step ran: row
+    -1, zero scores, flags and node counts (what a failed-or-never-asked
+    placement reads, and what the numpy twin fills its tail rows with)."""
+    p = (n_placements,)
+    return (
+        jnp.full(p, -1, jnp.int32),
+        jnp.zeros(p, jnp.float32),
+        jnp.zeros(p, jnp.float32),
+        jnp.zeros(p, bool),
+        jnp.zeros(p, jnp.int32),
+        jnp.zeros(p, jnp.int32),
+        jnp.zeros(p, jnp.int32),
+    )
+
+
+def scan_steps(step, init, outs, trip):
+    """``lax.scan(step, init, jnp.arange(P))`` cut off after ``trip`` steps
+    (traced i32 scalar): step ``i``'s outputs land at index ``i`` of the
+    ``outs`` buffers, the rows never reached keep what ``outs`` held.
+    Shared with the node-sharded step (parallel/sharding.py)."""
+
+    def body(i, state):
+        carry, bufs = state
+        carry, out = step(carry, i)
+        # As lax.scan stacks its outputs: a plain dynamic-update-slice
+        # (``.at[i].set`` adds index wrapping and a bounds check to every
+        # iteration: 0.7 us of the verify loop's 4 us a slot on a v5e).
+        return carry, tuple(
+            lax.dynamic_update_index_in_dim(b, o, i, 0)
+            for b, o in zip(bufs, out)
+        )
+
+    return lax.fori_loop(0, trip, body, (init, tuple(outs)))
+
+
 def _place_scan(
     arrays,
     req: SchedRequest,
@@ -722,11 +758,20 @@ def _place_scan(
     host_mask,
     n_placements: int,
     features: Features = FULL_FEATURES,
+    n_steps=None,
+    trip=None,
 ) -> PlacementResult:
     """Traceable core of the placement scan (shared by the solo
-    ``place_task_group`` jit and the coalesced ``place_batch`` vmap)."""
+    ``place_task_group`` jit and the coalesced ``place_batch`` vmap).
 
-    def step(carry, _):
+    ``n_steps`` (traced i32 scalar; None = all ``n_placements``, a static
+    ``lax.scan``) is how many placements this request asked for: steps
+    past it place nothing and report the inert row (-1, zeros).  ``trip``
+    is the loop bound when a ``vmap`` over requests shares one loop — the
+    largest ``n_steps`` of the batch, computed outside the ``vmap`` so the
+    loop's condition stays one scalar."""
+
+    def step(carry, i):
         used, tg_cnt, s_hash, s_counts = carry
         req_step = req._replace(s_value_hash=s_hash)
         with jax.named_scope("score"):
@@ -737,13 +782,21 @@ def _place_scan(
         with jax.named_scope("pick"):
             row = jnp.argmax(res.final).astype(jnp.int32)
             ok = res.final[row] > NEG_INF / 2
-            row = jnp.where(ok, row, -1)
 
             n_eval = jnp.sum(res.feasible).astype(jnp.int32)
             n_filtered = jnp.sum(
                 ~res.feasible & arrays.eligible
             ).astype(jnp.int32)
             n_exhausted = jnp.sum(res.feasible & ~res.fits).astype(jnp.int32)
+            if n_steps is not None:
+                # A lane that asked for fewer steps than the launch runs
+                # takes no placement here: no usage charged, inert row.
+                active = i < n_steps
+                ok = ok & active
+                n_eval = jnp.where(active, n_eval, 0)
+                n_filtered = jnp.where(active, n_filtered, 0)
+                n_exhausted = jnp.where(active, n_exhausted, 0)
+            row = jnp.where(ok, row, -1)
 
         with jax.named_scope("update"):
             safe_row = jnp.maximum(row, 0)
@@ -768,9 +821,15 @@ def _place_scan(
 
     init = (used0, tg_count, req.s_value_hash, spread_counts)
     with jax.named_scope("place_scan"):
-        (used_after, tg_after, _, _), outs = lax.scan(
-            step, init, None, length=n_placements
-        )
+        if n_steps is None:
+            (used_after, tg_after, _, _), outs = lax.scan(
+                step, init, None, length=n_placements
+            )
+        else:
+            (used_after, tg_after, _, _), outs = scan_steps(
+                step, init, inert_step_outputs(n_placements),
+                n_steps if trip is None else trip,
+            )
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh = outs
     return PlacementResult(
         rows=rows,
@@ -954,6 +1013,21 @@ FUSED_PACKED_VERIFIED = 7
 FUSED_PACKED_WIDTH = 8
 
 
+def fused_trip_counts(lane_steps, n_placements: int):
+    """The two loop bounds of a fused launch, from its per-lane step
+    counts: (largest count, capped at the output's length; index of the
+    last live lane + 1).  Scalars, worked out once outside any ``vmap``."""
+    if not jnp.issubdtype(lane_steps.dtype, jnp.integer):
+        # A bool lane mask would read as "one step a lane".
+        raise TypeError(
+            f"lane_steps must be an integer array, got {lane_steps.dtype}"
+        )
+    trip = jnp.minimum(jnp.max(lane_steps), n_placements).astype(jnp.int32)
+    lanes = jnp.arange(1, lane_steps.shape[0] + 1, dtype=jnp.int32)
+    last_lane = jnp.max(jnp.where(lane_steps > 0, lanes, 0))
+    return trip, last_lane
+
+
 @jax.named_scope("pack")
 def pack_fused_lanes(
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified, live
@@ -992,7 +1066,7 @@ def _fused_place_batch_impl(
     reqs,
     class_eligs,
     host_masks,
-    lane_mask,
+    lane_steps,
     n_placements: int,
     features: Features = FULL_FEATURES,
 ) -> jnp.ndarray:
@@ -1002,10 +1076,17 @@ def _fused_place_batch_impl(
 
     Differences from ``place_batch``:
 
-    * ``lane_mask`` (B,) bool marks live eval slots explicitly. Dead lanes
-      (batch occupancy < B) produce row=-1 / zero outputs and contribute
-      nothing to the verify pass, so one compile serves every occupancy —
-      no host-side request-faking, no shape-polymorphic recompiles.
+    * ``lane_steps`` (B,) i32 says how many placements each eval slot
+      asked for, 0 for a dead slot (batch occupancy < B).  The placement
+      scan and the verify pass run as many iterations as the launch's
+      live lanes asked for (the largest count; the last live lane), read
+      from this operand: ``n_placements`` is only the static length of
+      the output, so one compile serves every occupancy and every mix of
+      counts.  A live lane's rows past its own count are inert (row -1,
+      zeros, VERIFIED 1.0) and charge nothing; its first rows are bit for
+      bit what a full-length launch gives.  Dead lanes produce row=-1 /
+      zero outputs and contribute nothing to the verify pass — no
+      host-side request-faking, no shape-polymorphic recompiles.
     * The packed output grows a VERIFIED column: a device-resident
       sequential AllocsFit re-check of every lane's chosen placements
       against the authoritative matrix usage *plus all earlier lanes'
@@ -1023,46 +1104,57 @@ def _fused_place_batch_impl(
 
     Returns (B, n_placements, FUSED_PACKED_WIDTH) f32 — one fetch.
     """
+    live = lane_steps > 0  # (B,)
+    trip, last_lane = fused_trip_counts(lane_steps, n_placements)
 
-    def one(drows, dvals, tg, sc, pen, req, ce, hm):
+    def one(drows, dvals, tg, sc, pen, req, ce, hm, n_steps):
         safe = jnp.maximum(drows, 0)
         add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
         used0 = used.at[safe].add(add)
         return _place_scan(
-            arrays, req, used0, tg, sc, pen, ce, hm, n_placements, features
+            arrays, req, used0, tg, sc, pen, ce, hm, n_placements, features,
+            n_steps=n_steps, trip=trip,
         )
 
     res = jax.vmap(one)(
         delta_rows, delta_vals, tg_counts, spread_counts, penalties, reqs,
-        class_eligs, host_masks,
+        class_eligs, host_masks, lane_steps,
     )
-    live = lane_mask  # (B,)
     rows = jnp.where(live[:, None], res.rows, -1)  # (B, P)
 
-    # Sequential cross-lane AllocsFit: a scan over lanes carrying the
+    # Sequential cross-lane AllocsFit: a loop over lanes carrying the
     # cumulative proposed usage. Each lane first applies its own in-flight
     # deltas, then commits its placements one by one, checking
     # used ≤ totals on every touched row (funcs.go:97-160 AllocsFit, in
-    # plan-apply order). Work per lane is O(P) row updates on an (N, 3)
-    # carry — negligible next to the ranking itself.
-    def lane_step(cum_used, lane):
-        l_rows, l_ask, l_drows, l_dvals, l_live = lane
-        dadd = jnp.where(((l_drows >= 0) & l_live)[:, None], l_dvals, 0.0)
+    # plan-apply order). Work per lane is O(trip) row updates on an (N, 3)
+    # carry; lanes past the last live one and slots past the launch's
+    # largest count are never visited and read "fits".
+    def lane_step(b, state):
+        cum_used, verified = state
+        l_drows, l_live = delta_rows[b], live[b]
+        l_rows, l_ask = rows[b], reqs.ask[b]
+        dadd = jnp.where(
+            ((l_drows >= 0) & l_live)[:, None], delta_vals[b], 0.0
+        )
         base = cum_used.at[jnp.maximum(l_drows, 0)].add(dadd)
 
-        def p_step(u, row):
+        def p_step(u, p):
+            row = l_rows[p]
             ok_row = (row >= 0) & l_live
             safe_r = jnp.maximum(row, 0)
             u2 = u.at[safe_r].add(jnp.where(ok_row, l_ask, 0.0))
             fit = jnp.all(u2[safe_r] <= arrays.totals[safe_r]) | ~ok_row
-            return u2, fit
+            return u2, (fit,)
 
-        after, fits = lax.scan(p_step, base, l_rows)
-        return jnp.where(l_live, after, cum_used), fits
+        after, (fits,) = scan_steps(p_step, base, (verified[b],), trip)
+        return (
+            jnp.where(l_live, after, cum_used),
+            lax.dynamic_update_index_in_dim(verified, fits, b, 0),
+        )
 
     with jax.named_scope("verify_scan"):
-        _, verified = lax.scan(
-            lane_step, used, (rows, reqs.ask, delta_rows, delta_vals, live)
+        _, verified = lax.fori_loop(
+            0, last_lane, lane_step, (used, jnp.ones(rows.shape, bool))
         )  # (B, P) bool
 
     return pack_fused_lanes(
@@ -1076,7 +1168,7 @@ fused_place_batch = functools.partial(
 )(_fused_place_batch_impl)
 
 # Live entry: per-dispatch lane operands (argnums 2..10, including the lane
-# mask) are donated, mirroring place_batch_live. ``arrays``/``used`` stay
+# step counts) are donated, mirroring place_batch_live. ``arrays``/``used`` stay
 # shared with in-flight pipelined dispatches and are never donated.
 fused_place_batch_live = functools.partial(
     jax.jit,

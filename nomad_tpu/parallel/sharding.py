@@ -37,7 +37,10 @@ from ..ops.kernels import (
     FULL_FEATURES,
     NEG_INF,
     apply_spread_values,
+    fused_trip_counts,
+    inert_step_outputs,
     pack_fused_lanes,
+    scan_steps,
     score_nodes,
     spread_values_at,
 )
@@ -476,7 +479,7 @@ def sharded_place_batch(mesh: Mesh, n_placements: int):
 
 def _fused_place_batch_local(
     arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-    penalties, reqs, class_eligs, host_masks, lane_mask, n_placements,
+    penalties, reqs, class_eligs, host_masks, lane_steps, n_placements,
     features,
 ):
     """Per-shard body of ``kernels.fused_place_batch`` under a
@@ -500,21 +503,38 @@ def _fused_place_batch_local(
     lanes against the LOCAL (n_local, 3) usage slice with non-owned rows
     vacuously fitting, and combines verdicts with a single ``pmin`` over
     the node axis — each row's owner alone decides.
+
+    Both loops run as many iterations as the launch's live lanes asked for
+    (``lane_steps``, as in the single-device kernel).  Every step holds
+    collectives over 'node' and the verify replays all B lanes on every
+    shard, so the trip counts are taken over the WHOLE batch (the counts
+    are all_gathered over 'batch'): one number on every shard.
     """
     n_local = used.shape[0]
     shard = jax.lax.axis_index("node")
     row_offset = shard * n_local
     big = jnp.int32(2 ** 30)
     k = min(TOPK_K, n_local)
+    live = lane_steps > 0  # (b_local,)
+    g_steps = jax.lax.all_gather(lane_steps, "batch", tiled=True)  # (B,)
+    trip, last_lane = fused_trip_counts(g_steps, n_placements)
 
-    def one(drows, dvals, tg, sc, pen, req, ce, hm):
+    def vary(x, axes=("batch",)):
+        # shard_map's varying-axes check wants a loop carry typed the same
+        # going in as coming out: buffers that start as constants (or as
+        # this shard's slice) and take per-lane values are cast to vary
+        # over those axes up front — a typing formality.
+        return jax.lax.pcast(x, axes, to="varying")
+
+    def one(drows, dvals, tg, sc, pen, req, ce, hm, n_steps):
         local = drows - row_offset
         mine = (drows >= 0) & (local >= 0) & (local < n_local)
         safe = jnp.clip(local, 0, n_local - 1)
         used0 = used.at[safe].add(jnp.where(mine[:, None], dvals, 0.0))
 
-        def step(carry, _):
+        def step(carry, i):
             u, tg_cnt, s_hash, s_counts = carry
+            active = i < n_steps
             req_step = req._replace(s_value_hash=s_hash)
             with jax.named_scope("score"):
                 res = score_nodes(
@@ -527,7 +547,7 @@ def _fused_place_batch_local(
             with jax.named_scope("pick"):
                 vals, idxs = jax.lax.top_k(res.final, k)
                 best = jax.lax.pmax(vals[0], "node")
-                ok = best > NEG_INF / 2
+                ok = (best > NEG_INF / 2) & active
                 cand = jnp.where(
                     vals == best, row_offset + idxs.astype(jnp.int32), big
                 )
@@ -581,22 +601,22 @@ def _fused_place_batch_local(
                 jnp.where(ok, best, 0.0),
                 jnp.where(ok, binp, 0.0),
                 pre & ok,
-                n_eval,
-                n_filt,
-                n_exh,
+                jnp.where(active, n_eval, 0),
+                jnp.where(active, n_filt, 0),
+                jnp.where(active, n_exh, 0),
             )
             return (u2, tg2, s_hash2, s_counts2), out
 
         init = (used0, tg, req.s_value_hash, sc)
+        bufs = tuple(vary(o) for o in inert_step_outputs(n_placements))
         with jax.named_scope("place_scan"):
-            _, outs = jax.lax.scan(step, init, None, length=n_placements)
+            _, outs = scan_steps(step, init, bufs, trip)
         return outs  # each (P,)
 
     rows, scores, binpack, pre, ne, nf, nx = jax.vmap(one)(
         delta_rows, delta_vals, tg_counts, spread_counts, penalties, reqs,
-        class_eligs, host_masks,
+        class_eligs, host_masks, lane_steps,
     )
-    live = lane_mask  # (b_local,)
     rows = jnp.where(live[:, None], rows, -1)  # (b_local, P)
 
     # Cross-lane AllocsFit re-verify, sharded: every tensor gathered over
@@ -609,10 +629,12 @@ def _fused_place_batch_local(
     g_ask = jax.lax.all_gather(reqs.ask, "batch", tiled=True)  # (B, 3)
     g_drows = jax.lax.all_gather(delta_rows, "batch", tiled=True)  # (B, K)
     g_dvals = jax.lax.all_gather(delta_vals, "batch", tiled=True)
-    g_live = jax.lax.all_gather(live, "batch", tiled=True)  # (B,)
+    g_live = g_steps > 0  # (B,)
 
-    def lane_step(cum_used, lane):
-        l_rows, l_ask, l_drows, l_dvals, l_live = lane
+    def lane_step(b, state):
+        cum_used, fits_all = state
+        l_rows, l_ask, l_live = g_rows[b], g_ask[b], g_live[b]
+        l_drows, l_dvals = g_drows[b], g_dvals[b]
         l_local = l_drows - row_offset
         l_mine = (
             (l_drows >= 0) & (l_local >= 0) & (l_local < n_local) & l_live
@@ -622,7 +644,8 @@ def _fused_place_batch_local(
             jnp.where(l_mine[:, None], l_dvals, 0.0)
         )
 
-        def p_step(u, row):
+        def p_step(u, p):
+            row = l_rows[p]
             p_local = row - row_offset
             p_mine = (
                 (row >= 0) & (p_local >= 0) & (p_local < n_local) & l_live
@@ -630,21 +653,22 @@ def _fused_place_batch_local(
             p_safe = jnp.clip(p_local, 0, n_local - 1)
             u2 = u.at[p_safe].add(jnp.where(p_mine, l_ask, 0.0))
             fit = jnp.all(u2[p_safe] <= arrays.totals[p_safe]) | ~p_mine
-            return u2, fit
+            return u2, (fit,)
 
-        after, fits = jax.lax.scan(p_step, base, l_rows)
-        return jnp.where(l_live, after, cum_used), fits
+        after, (fits,) = scan_steps(p_step, base, (fits_all[b],), trip)
+        return (
+            jnp.where(l_live, after, cum_used),
+            jax.lax.dynamic_update_index_in_dim(fits_all, fits, b, 0),
+        )
 
     # The carry starts as this shard's usage slice (varying over 'node'
-    # only) but accumulates lane data gathered over 'batch'; shard_map's
-    # varying-axes check wants the scan carry typed the same going in as
-    # coming out, so the initial value is cast to vary over 'batch' too
-    # (every batch replica holds the same values — a typing formality).
+    # only) but accumulates lane data gathered over 'batch' (every batch
+    # replica holds the same values).  Lanes past the last live one and
+    # slots past the largest count are never visited and read "fits".
     with jax.named_scope("verify_scan"):
-        _, fits_all = jax.lax.scan(
-            lane_step,
-            jax.lax.pcast(used, ("batch",), to="varying"),
-            (g_rows, g_ask, g_drows, g_dvals, g_live),
+        _, fits_all = jax.lax.fori_loop(
+            0, last_lane, lane_step,
+            (vary(used), vary(jnp.ones(g_rows.shape, bool), ("batch", "node"))),
         )  # (B, P) bool, identical on every node shard only after the pmin:
         verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
 
@@ -671,7 +695,7 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
 
     def entry(
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-        penalties, reqs, class_eligs, host_masks, lane_mask, *,
+        penalties, reqs, class_eligs, host_masks, lane_steps, *,
         features=FULL_FEATURES,
     ):
         fn = shard_map(
@@ -692,13 +716,13 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
                 _REQS_SPEC,
                 P("batch", None),  # class_eligs
                 P("batch", "node"),  # host_masks
-                P("batch"),  # lane_mask
+                P("batch"),  # lane_steps
             ),
             out_specs=P("batch", None, None),
         )
         return fn(
             arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-            penalties, reqs, class_eligs, host_masks, lane_mask,
+            penalties, reqs, class_eligs, host_masks, lane_steps,
         )
 
     return jax.jit(entry, static_argnames=("features",))

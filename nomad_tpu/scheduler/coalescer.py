@@ -33,9 +33,14 @@ already are.
 Shape discipline (SURVEY.md §7 hard-part e — p99 means no recompiles):
 every dispatch uses the SAME static shapes — ``max_lanes`` lanes (short
 batches padded by memset of the preallocated staging buffers) and a
-``PLACEMENT_CHUNK``-long scan (callers take the first rows they asked
-for) — so exactly one executable serves every batch size. Wasted lanes
-cost ~µs of MXU time; a recompile costs tens of seconds.
+``PLACEMENT_CHUNK``-long output (callers take the first rows they asked
+for) — so one executable per ``Features`` variant serves every batch
+size; a recompile costs seconds.  What the shapes do not fix is the work:
+the fused kernel's two loops take their trip counts from the staged
+``lane_steps`` operand (each live lane's ``n_live``, 0 for a dead lane),
+so a launch scores all nodes once per placement its widest lane asked
+for, not ``PLACEMENT_CHUNK`` times (a full-length launch of 64 lanes x
+10,240 nodes measured 12.2 ms on a v5e, 0.5 ms of it per step: PERF.md).
 
 The reference's analog: many schedulers walk nodes concurrently and the
 plan applier serializes commits (worker.go:49-53, plan_apply.go:49-69).
@@ -87,7 +92,7 @@ def default_pipeline_depth() -> int:
 
 def megabatch_enabled() -> bool:
     """The fused megakernel path (ops.kernels.fused_place_batch): explicit
-    lane masks, occupancy-bucketed compiles, and the device-resident
+    per-lane step counts, occupancy-bucketed compiles, and the device-resident
     AllocsFit re-verify column. Default ON; ``NOMAD_TPU_MEGABATCH=0``
     falls back to the staged place_batch path."""
     return os.environ.get(_MEGABATCH_ENV, "1").lower() not in (
@@ -105,6 +110,12 @@ def sharded_megabatch_enabled() -> bool:
     return os.environ.get(_SHARDED_MEGABATCH_ENV, "1").lower() not in (
         "0", "off", "false",
     )
+
+
+def lane_step_count(n_live: int, scan_length: int) -> int:
+    """Scan steps a lane runs for a caller that consumes ``n_live``
+    placements: 0 (not said) or more than the scan holds mean all of it."""
+    return min(n_live or scan_length, scan_length)
 
 
 @dataclass
@@ -147,8 +158,8 @@ class _Pending:
     class_elig: np.ndarray  # (pad,) bool
     host_mask: np.ndarray  # (N,) bool
     # Placements the caller will actually consume (0 = all scan_length).
-    # The jax kernel ignores it (static shapes); the fake-device twin stops
-    # its scan after this many live steps.
+    # The fused kernel and the fake-device twin stop the lane's scan after
+    # this many steps (lane_step_count); the staged kernels run them all.
     n_live: int = 0
     enqueued_at: float = 0.0
     done: threading.Event = field(default_factory=threading.Event)
@@ -263,6 +274,10 @@ class DeviceCoalescer:
         self.sharded_megabatch = sharded_megabatch_enabled()
         self.fused_dispatches = 0
         self.fused_lanes = 0
+        # Scan steps the fused launches ran: each launch adds its widest
+        # lane's step count, worked out on the host from the batch's
+        # n_live (steps a launch = scan_steps_total / fused_dispatches).
+        self.scan_steps_total = 0
         self.verify_conflicts = 0
         self.feature_recompiles = 0
         self._features = None
@@ -343,7 +358,9 @@ class DeviceCoalescer:
         n_live: int = 0,
     ) -> PlaceOutcome:
         """Submit one placement request; blocks until its batch lands.
-        The scan always runs ``scan_length`` steps — take ``rows[:k]``."""
+        Every output is ``scan_length`` long — take ``rows[:k]``.  On the
+        fused path only the first ``n_live`` steps are computed (0 = all):
+        the rows past them read -1 and charge no usage."""
         p = _Pending(
             request=request,
             delta_rows=delta_rows,
@@ -787,7 +804,7 @@ class DeviceCoalescer:
                 "delta_vals": np.zeros(
                     (lanes, MAX_DELTA_ROWS, 3), np.float32
                 ),
-                "lane_mask": np.zeros((lanes,), bool),
+                "lane_steps": np.zeros((lanes,), np.int32),
             }
         return st, self._req_slabs[slot]
 
@@ -912,7 +929,9 @@ class DeviceCoalescer:
                     [p.class_elig for p in batch],
                     [p.host_mask for p in batch],
                 )
-            live_counts = [p.n_live or self.scan_length for p in batch]
+            live_counts = [
+                lane_step_count(p.n_live, self.scan_length) for p in batch
+            ]
             with self._state("coalescer.enqueue", lanes=len(batch)):
                 if self.megabatch:
                     packed = fake_device.fused_place_batch(
@@ -925,6 +944,7 @@ class DeviceCoalescer:
                     )
                     self.fused_dispatches += 1
                     self.fused_lanes += len(batch)
+                    self.scan_steps_total += max(live_counts)
                 else:
                     packed = fake_device.place_batch(
                         arrays,
@@ -955,10 +975,10 @@ class DeviceCoalescer:
             pen, ce = st["penalty"], st["class_elig"]
             sc = st["spread_counts"]
             dr, dv = st["delta_rows"], st["delta_vals"]
-            lm = st["lane_mask"]
-            lm[:k] = True
-            lm[k:] = False
+            ls = st["lane_steps"]
+            ls[k:] = 0
             for i, p in enumerate(batch):
+                ls[i] = lane_step_count(p.n_live, self.scan_length)
                 # Requests built just before a matrix growth or a class-count
                 # pow2 crossing carry narrower arrays; the staging row's tail
                 # keeps the inert value (new rows masked off — they were not
@@ -990,7 +1010,8 @@ class DeviceCoalescer:
 
             # Request operands write into the slot's (max_lanes, …) slab;
             # dead-lane rows keep their previous valid contents (masked off by
-            # lane_mask / the all-False host mask, never decoded into results).
+            # lane_steps 0 / the all-False host mask, never decoded into
+            # results).
             for i, p in enumerate(batch):
                 slab.fill(i, p.request)
             reqs = slab.batch()
@@ -1019,6 +1040,7 @@ class DeviceCoalescer:
             feats = self._ratchet_features(slab, k)
             self.fused_dispatches += 1
             self.fused_lanes += k
+            self.scan_steps_total += int(ls[:k].max())
             if self.feature_recompiles != variants:
                 state = "coalescer.trace_variant"
                 args["features"] = str(tuple(feats))
@@ -1026,7 +1048,7 @@ class DeviceCoalescer:
             if n_shards > 1 and fused:
                 packed = self._sharded_fused_fn(
                     sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
-                    hm, lm, features=feats,
+                    hm, ls, features=feats,
                 )
             elif n_shards > 1:
                 # Staged sharded fallback (NOMAD_TPU_SHARDED_MEGABATCH=0):
@@ -1038,7 +1060,7 @@ class DeviceCoalescer:
             elif fused:
                 packed = kernels.fused_place_batch_live(
                     arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
-                    lm, n_placements=self.scan_length,
+                    ls, n_placements=self.scan_length,
                     features=feats,
                 )
             else:

@@ -265,6 +265,9 @@ class Server:
         )
         m.gauge_fn("nomad.kernel.fused_lanes", lambda: c.fused_lanes)
         m.gauge_fn(
+            "nomad.kernel.scan_steps_total", lambda: c.scan_steps_total
+        )
+        m.gauge_fn(
             "nomad.kernel.launches_per_eval",
             lambda: round(c.fused_dispatches / (c.fused_lanes or 1), 4),
             path="fused",

@@ -160,12 +160,12 @@ def test_megabatch_throughput_floor():
     penb = jnp.zeros((B, n), bool)
     ceb = jnp.ones((B, 2), bool)
     hmb = jnp.ones((B, n), bool)
-    lm = jnp.ones((B,), bool)
+    ls = jnp.ones((B,), jnp.int32)  # every lane live, its one step
 
     def fused_batch():
         return np.asarray(kernels.fused_place_batch(
             arrays, arrays.used, dr, dv, tgb, scb, penb, reqs, ceb, hmb,
-            lm, n_placements=1, features=feats,
+            ls, n_placements=1, features=feats,
         ))
 
     # Warm both paths out of the timed region (compile + first transfer),

@@ -44,7 +44,7 @@ pytestmark = pytest.mark.skipif(
 
 # ---------------------------------------------------------------------------
 # The mini entry-point family: same contract shape as the fused kernel
-# (node-axis operand, per-lane operands, lane mask, (B, 1) packed result)
+# (node-axis operand, per-lane operands, lane steps, (B, 1) packed result)
 # at a fraction of the trace/compile cost.
 # ---------------------------------------------------------------------------
 
@@ -54,13 +54,13 @@ N1, N2 = 37, 53  # prime markers: collide with no other dimension
 def mini_operands(g: Grid):
     cols = np.ones((g.nodes, 3), np.float32)  # node-axis resident operand
     ops = np.ones((g.batch, 4), np.float32)  # per-lane operand (donated)
-    lane_mask = np.zeros((g.batch,), bool)
-    lane_mask[: g.live] = True
-    return (cols, ops, lane_mask)
+    lane_steps = np.zeros((g.batch,), np.int32)
+    lane_steps[: g.live] = g.steps or g.placements
+    return (cols, ops, lane_steps)
 
 
-def _mini_body(cols, ops, lane_mask):
-    w = jnp.where(lane_mask[:, None], ops, 0.0)
+def _mini_body(cols, ops, lane_steps):
+    w = jnp.where((lane_steps > 0)[:, None], ops, 0.0)
     return w.sum(axis=1, keepdims=True) + 0.0 * cols.sum()  # (B, 1)
 
 
@@ -102,9 +102,9 @@ def test_clean_mini_entry_fires_nothing():
 def test_j101_injected_io_callback_fires_only_j101():
     from jax.experimental import io_callback
 
-    def body(cols, ops, lane_mask):
+    def body(cols, ops, lane_steps):
         io_callback(lambda a: None, None, ops)  # the host round trip
-        return _mini_body(cols, ops, lane_mask)
+        return _mini_body(cols, ops, lane_steps)
 
     entry = jax.jit(body, donate_argnums=(1, 2))
     fs = jaxprpass.check_contract(mini_contract(lambda g: entry))
@@ -112,10 +112,10 @@ def test_j101_injected_io_callback_fires_only_j101():
 
 
 def test_j102_full_score_vector_return_fires_only_j102():
-    def body(cols, ops, lane_mask):
+    def body(cols, ops, lane_steps):
         # The classic regression: "just return the scores too" — an O(N)
         # value fetched from device to host, on every launch.
-        return _mini_body(cols, ops, lane_mask), cols.sum(axis=1)
+        return _mini_body(cols, ops, lane_steps), cols.sum(axis=1)
 
     entry = jax.jit(body, donate_argnums=(1, 2))
     fs = jaxprpass.check_contract(mini_contract(lambda g: entry))
@@ -128,12 +128,12 @@ def test_j102_full_score_vector_return_fires_only_j102():
 def test_j103_node_axis_collective_fires_only_j103():
     mesh = make_mesh(1, batch=1)
 
-    def local(cols, ops, lane_mask):
+    def local(cols, ops, lane_steps):
         # An (n_local,)-shaped value pushed through a collective: the
         # mesh moves O(N) bytes per launch however small the result.
         leak = jax.lax.psum(cols[:, 0], "batch")
         anchor = jax.lax.pmax(leak.sum(), "node")
-        w = jnp.where(lane_mask[:, None], ops, 0.0)
+        w = jnp.where((lane_steps > 0)[:, None], ops, 0.0)
         return w.sum(axis=1, keepdims=True) + 0.0 * anchor
 
     entry = jax.jit(
@@ -166,11 +166,10 @@ def test_j105_occupancy_keyed_static_arg_fires_only_j105():
     @functools.partial(
         jax.jit, static_argnames=("n_live",), donate_argnums=(1, 2)
     )
-    def body(cols, ops, lane_mask, *, n_live):
+    def body(cols, ops, lane_steps, *, n_live):
         # Occupancy in the static key: every fill level recompiles.
         w = ops[:n_live]
-        base = jnp.where(lane_mask[:, None], ops, 0.0)
-        return base.sum(axis=1, keepdims=True) + w.sum() + 0.0 * cols.sum()
+        return _mini_body(cols, ops, lane_steps) + w.sum()
 
     fs = jaxprpass.check_contract(
         mini_contract(
@@ -179,6 +178,27 @@ def test_j105_occupancy_keyed_static_arg_fires_only_j105():
         )
     )
     assert rules(fs) == {"J105"}, [f.render() for f in fs]
+
+
+def test_j105_step_count_keyed_static_arg_fires_only_j105():
+    """The loops' trip count leaked into the static key: the occupancy
+    fills all share one compile, each step count 1..P costs its own."""
+    @functools.partial(
+        jax.jit, static_argnames=("trip",), donate_argnums=(1, 2)
+    )
+    def body(cols, ops, lane_steps, *, trip):
+        return _mini_body(cols, ops, lane_steps) * jnp.arange(trip).sum()
+
+    fs = jaxprpass.check_contract(
+        mini_contract(
+            lambda g: body,
+            static_kwargs=lambda g: {"trip": int(g.steps or g.placements)},
+            compile_grid=COMPILE_GRID._replace(placements=4),
+            sweep=contracts.lane_steps_sweep,
+        )
+    )
+    assert rules(fs) == {"J105"}, [f.render() for f in fs]
+    assert any("cost 4 compile" in f.message for f in fs), fs
 
 
 def test_j103_catches_the_j005_helper_evasion():
@@ -192,8 +212,8 @@ def test_j103_catches_the_j005_helper_evasion():
     def _snapshot(x):  # the one-hop indirection J005 cannot see through
         return x * 2.0
 
-    def local(cols, ops, lane_mask):
-        w = jnp.where(lane_mask[:, None], ops, 0.0)
+    def local(cols, ops, lane_steps):
+        w = jnp.where((lane_steps > 0)[:, None], ops, 0.0)
         verdict = w.sum(axis=1, keepdims=True) + 0.0 * jax.lax.pmax(
             cols.sum(), "node"
         )
@@ -251,6 +271,20 @@ def test_j105_one_compile_serves_all_occupancy_fills():
     entry = c.build(c.compile_grid)
     measured = contracts.occupancy_sweep(entry, c)
     assert measured <= 1, f"occupancy sweep cost {measured} compiles"
+
+
+def test_j105_one_compile_serves_all_step_counts():
+    """Per-lane step counts 1..16 are runtime data too: after the
+    occupancy fills, the whole step sweep of the live fused entry (scan
+    length 16) adds no compile-cache entry."""
+    c = contracts.get("fused_place_batch_live")
+    assert c.sweep is contracts.lane_steps_sweep
+    assert c.compile_grid.placements == 16
+    entry = c.build(c.compile_grid)
+    contracts.occupancy_sweep(entry, c)  # the one compile, if still due
+    before = contracts._cache_size(entry)
+    assert contracts.lane_steps_sweep(entry, c) == 0
+    assert contracts._cache_size(entry) == before
 
 
 def test_contract_table_names_every_registered_entry():

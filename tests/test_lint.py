@@ -353,7 +353,7 @@ class TestJ004FusedRecompile:
                     return kernels.fused_place_batch(
                         arrays, arrays.used,
                         np.stack([p.delta_rows for p in batch]),
-                        self.lane_mask, n_placements=4,
+                        self.lane_steps, n_placements=4,
                     )
                 """
             )
@@ -366,13 +366,13 @@ class TestJ004FusedRecompile:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def bad(self, arrays, batch, lm):
+                def bad(self, arrays, batch, ls):
                     reqs = jax.tree_util.tree_map(
                         lambda *xs: np.stack(xs),
                         *[p.request for p in batch],
                     )
                     return kernels.fused_place_batch_live(
-                        arrays, arrays.used, reqs, lm, n_placements=4,
+                        arrays, arrays.used, reqs, ls, n_placements=4,
                     )
                 """
             )
@@ -383,9 +383,9 @@ class TestJ004FusedRecompile:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def bad(self, arrays, batch, reqs, lm):
+                def bad(self, arrays, batch, reqs, ls):
                     return kernels.fused_place_batch(
-                        arrays, arrays.used, reqs, lm,
+                        arrays, arrays.used, reqs, ls,
                         n_placements=len(batch),
                     )
                 """
@@ -397,10 +397,10 @@ class TestJ004FusedRecompile:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def good(self, arrays, lm):
+                def good(self, arrays, ls):
                     reqs = self._req_slab.batch()
                     return kernels.fused_place_batch_live(
-                        arrays, arrays.used, reqs, lm,
+                        arrays, arrays.used, reqs, ls,
                         n_placements=self.scan_length,
                         features=self._features,
                     )
@@ -433,9 +433,9 @@ class TestJ005NodeAxisFetch:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def bad(self, arrays, dr, dv, reqs, lm):
+                def bad(self, arrays, dr, dv, reqs, ls):
                     packed = self._sharded_fused_fn(
-                        arrays, arrays.used, dr, dv, reqs, lm,
+                        arrays, arrays.used, dr, dv, reqs, ls,
                     )
                     snapshot = np.asarray(arrays.used)
                     return packed, snapshot
@@ -448,11 +448,11 @@ class TestJ005NodeAxisFetch:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def bad(self, arrays, dr, dv, reqs, lm):
+                def bad(self, arrays, dr, dv, reqs, ls):
                     u = arrays.used
                     u.block_until_ready()
                     return kernels.fused_place_batch(
-                        arrays, u, dr, dv, reqs, lm, n_placements=1,
+                        arrays, u, dr, dv, reqs, ls, n_placements=1,
                     )
                 """
             )
@@ -463,8 +463,8 @@ class TestJ005NodeAxisFetch:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def bad(self, arrays, dr, dv, reqs, lm):
-                    res = sharded_place_batch(arrays, reqs, lm)
+                def bad(self, arrays, dr, dv, reqs, ls):
+                    res = sharded_place_batch(arrays, reqs, ls)
                     return np.asarray(res.used_after)
                 """
             )
@@ -477,9 +477,9 @@ class TestJ005NodeAxisFetch:
         fs = jaxpass.analyze_sources({
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
-                def good(self, arrays, dr, dv, reqs, lm):
+                def good(self, arrays, dr, dv, reqs, ls):
                     packed = self._sharded_fused_fn(
-                        arrays, arrays.used, dr, dv, reqs, lm,
+                        arrays, arrays.used, dr, dv, reqs, ls,
                     )
                     return packed
                 """
@@ -521,9 +521,9 @@ class TestJ005NodeAxisFetch:
                 def _snapshot(x):
                     return np.asarray(x)
 
-                def evades(self, arrays, dr, dv, reqs, lm):
+                def evades(self, arrays, dr, dv, reqs, ls):
                     packed = self._sharded_fused_fn(
-                        arrays, arrays.used, dr, dv, reqs, lm,
+                        arrays, arrays.used, dr, dv, reqs, ls,
                     )
                     return packed, _snapshot(arrays.used)
                 """

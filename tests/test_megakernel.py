@@ -13,7 +13,10 @@ one launch. These tests pin it against the staged kernels it replaced:
   where the plan applier would reject, and nowhere else;
 * dead-lane masking: one compile serves every batch occupancy, and dead
   lanes can never perturb live lanes' outputs or verdicts;
-* the fake-device numpy twin is bit-compatible (live_counts=None);
+* per-lane step counts: the loops stop at what the lanes asked for, the
+  steps a lane asked for are bit for bit those of a full-length launch,
+  and the rest is inert;
+* the fake-device numpy twin is kernel-exact, ``live_counts`` included;
 * the occupancy-bucketed ``Features`` fast path scores identically to
   the full decode.
 """
@@ -107,6 +110,12 @@ def lane_operands(b, n, deltas=None, penalties=None, tg_counts=None,
     return drows, dvals, tg, sc, pen, ce, hm
 
 
+def steps_of(lane_mask, scan):
+    """The fused entry's ``lane_steps`` for lanes that all ask for the
+    whole scan: ``scan`` where live, 0 where dead."""
+    return np.where(np.asarray(lane_mask, bool), scan, 0).astype(np.int32)
+
+
 def run_both(m, compiled, scan=SCAN, lane_mask=None, **lanes_kw):
     """Run the staged place_batch and the fused megakernel over the same
     operands; returns (staged (B,P,7), fused (B,P,8)) as numpy."""
@@ -118,12 +127,13 @@ def run_both(m, compiled, scan=SCAN, lane_mask=None, **lanes_kw):
     )
     reqs = stack_requests(compiled)
     lm = np.ones((b,), bool) if lane_mask is None else np.asarray(lane_mask)
+    ls = steps_of(lm, scan)
     staged = np.asarray(place_batch(
         arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
         n_placements=scan,
     ))
     fused = np.asarray(fused_place_batch(
-        arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm, lm,
+        arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm, ls,
         n_placements=scan,
     ))
     return staged, fused
@@ -363,9 +373,9 @@ class TestDeadLaneMasking:
 
 class TestFakeDeviceTwinParity:
     def test_twin_matches_kernel(self, cluster_1k):
-        """The numpy twin (live_counts=None) must be bit-compatible with
-        the jax megakernel across the full lane mix, including a dead
-        lane, in-flight deltas, and the verify column."""
+        """The numpy twin must be bit-compatible with the jax megakernel
+        across the full lane mix at full length, including a dead lane,
+        in-flight deltas, and the verify column."""
         m, _ = cluster_1k
         compiled = compile_lane_mix(m)
         arrays = m.sync()
@@ -380,7 +390,8 @@ class TestFakeDeviceTwinParity:
         )
         kernel = np.asarray(fused_place_batch(
             arrays, arrays.used, drows, dvals, tg, sc, pen,
-            stack_requests(compiled), ce, hm, lm, n_placements=SCAN,
+            stack_requests(compiled), ce, hm, steps_of(lm, SCAN),
+            n_placements=SCAN,
         ))
         arrays_np = type(arrays)(*[np.asarray(x) for x in arrays])
         twin = fake_device.fused_place_batch(
@@ -404,6 +415,152 @@ class TestFakeDeviceTwinParity:
         )
 
 
+FULL = 16  # the live scan length (stack.PLACEMENT_CHUNK)
+
+# Placements each lane's caller consumes (what stack.py hands place() as
+# n_live); None = a dead lane.
+N_LIVE_CASES = {
+    # ROADMAP R4's shape: every lane wants the whole scan — the launch
+    # must give what the static 16-step program gave, bit for bit.
+    "all_16": [16] * 8,
+    "all_1": [1] * 8,
+    "mixed_1_to_8": [1, 3, None, 8, 2, None, 5, None],
+    # "not said" and "more than the scan holds" both mean all of it.
+    "zero_and_40": [0, 40, 2, 0, None, 40, 1, 7],
+}
+
+
+class TestLaneStepCounts:
+    """The fused kernel's loops take their trip counts from ``lane_steps``:
+    same answers for the steps that were asked for, inert rows after."""
+
+    def _launch(self, m, n_live):
+        """(lane_steps, fused kernel as a function of lane_steps, and the
+        twin / the staged kernel over the same operands, both lazy)."""
+        from nomad_tpu.scheduler.coalescer import lane_step_count
+
+        mix = compile_lane_mix(m)
+        compiled = (mix + mix)[: len(n_live)]
+        arrays = m.sync()
+        b = len(compiled)
+        steps = np.array(
+            [0 if k is None else lane_step_count(k, FULL) for k in n_live],
+            np.int32,
+        )
+        drows, dvals, tg, sc, pen, ce, hm = lane_operands(
+            b, arrays.used.shape[0], n_classes=len(m.class_ids),
+            deltas={1: [(7, (900.0, 512.0, 0.0)), (11, (400.0, 0.0, 0.0))],
+                    6: [(272, (300.0, 100.0, 0.0))]},
+            penalties={0: [3, 5]}, tg_counts={4: {2: 1, 9: 2}},
+        )
+        reqs = stack_requests(compiled)
+
+        def kernel(ls):
+            return np.asarray(fused_place_batch(
+                arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce,
+                hm, ls, n_placements=FULL,
+            ))
+
+        def twin():
+            arrays_np = type(arrays)(*[np.asarray(x) for x in arrays])
+            return fake_device.fused_place_batch(
+                arrays_np, arrays_np.used,
+                *[list(a) for a in (drows, dvals, tg, sc, pen)],
+                [c.request for c in compiled], list(ce), list(hm),
+                steps > 0, n_placements=FULL, live_counts=list(steps),
+            )
+
+        def staged():
+            return np.asarray(place_batch(
+                arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
+                n_placements=FULL,
+            ))
+
+        return steps, kernel, twin, staged
+
+    @pytest.mark.parametrize("case", sorted(N_LIVE_CASES))
+    def test_kernel_matches_twin_with_live_counts(self, cluster_1k, case):
+        m, _ = cluster_1k
+        steps, kernel, twin, staged = self._launch(m, N_LIVE_CASES[case])
+        got, twin = kernel(steps), twin()
+        assert got.shape == twin.shape == (len(steps), FULL, FUSED_PACKED_WIDTH)
+        # All eight columns: everything that decides or describes a
+        # placement exactly, the two scores to float32 rounding (numpy and
+        # XLA order their sums differently).
+        for col in (0, 3, 4, 5, 6, FUSED_PACKED_VERIFIED):
+            np.testing.assert_array_equal(
+                got[:, :, col], twin[:, :, col], err_msg=f"column {col}"
+            )
+        np.testing.assert_allclose(
+            got[:, :, 1:3], twin[:, :, 1:3], rtol=1e-5, atol=1e-5
+        )
+        # The case must have teeth: live lanes placed something.
+        assert (got[steps > 0, 0, 0] >= 0).all()
+        for lane, k in enumerate(steps):
+            # Rows past what the lane asked for are inert: row -1, zeros,
+            # "fits" (dead lanes: -1.0, no verdict).
+            tail = got[lane, k:]
+            assert (tail[:, 0] == -1.0).all()
+            assert (tail[:, 1:7] == 0.0).all()
+            assert (
+                tail[:, FUSED_PACKED_VERIFIED] == (1.0 if k else -1.0)
+            ).all()
+        if (steps == FULL).all():
+            # Nothing to cut: the placement columns are bit for bit the
+            # static 16-step scan's (place_batch still runs one).
+            np.testing.assert_array_equal(got[:, :, :7], staged())
+
+    @pytest.mark.parametrize(
+        "case", [c for c in sorted(N_LIVE_CASES) if c != "all_16"]
+    )
+    def test_asked_steps_are_the_full_launch_bitwise(self, cluster_1k, case):
+        """Same work, not less: the first ``n`` rows of a lane are bit for
+        bit that lane's rows in a launch where every lane runs all 16 (the
+        later steps never fed back into the earlier ones).  The verdict
+        column is left out: it reads the OTHER lanes' commits, and those
+        no longer include placements nobody asked for."""
+        m, _ = cluster_1k
+        steps, kernel, _, _ = self._launch(m, N_LIVE_CASES[case])
+        cut = kernel(steps)
+        full = kernel(np.where(steps > 0, FULL, 0).astype(np.int32))
+        for lane, k in enumerate(steps):
+            assert cut[lane, :k, :7].tobytes() == full[lane, :k, :7].tobytes()
+
+    def test_short_lane_is_not_charged_beside_a_wide_one(self):
+        """A lane that asked for 1 beside a lane that asked for 4 takes no
+        phantom placement at steps 2-4: its usage stays out of the
+        cross-lane verify image, so the next lane's verdict is true."""
+        m = NodeMatrix(capacity=16)
+        node = make_node(cpu=1000, mem=1024)
+        m.upsert_node(node)
+        enc = RequestEncoder(m)
+        small = make_job(cpu=300, mem=100)
+        c = enc.compile(small, small.task_groups[0])
+        arrays = m.sync()
+        n = arrays.used.shape[0]
+        drows, dvals, tg, sc, pen, ce, hm = lane_operands(
+            3, n, n_classes=len(m.class_ids)
+        )
+        out = np.asarray(fused_place_batch(
+            arrays, arrays.used, drows, dvals, tg, sc, pen,
+            stack_requests([c, c, c]), ce, hm,
+            np.array([1, 4, 1], np.int32), n_placements=4,
+        ))
+        row = m.row_of[node.id]
+        assert out[0, :, 0].tolist() == [row, -1, -1, -1]
+        assert out[1, :3, 0].tolist() == [row, row, row]  # 3 x 300 fit
+        # Committed before lane 1: lane 0's ONE placement (300), so lane
+        # 1's first two fit (600, 900) and its third does not (1200).
+        assert out[1, :3, FUSED_PACKED_VERIFIED].tolist() == [1.0, 1.0, 0.0]
+
+    def test_bool_mask_is_refused(self, cluster_1k):
+        """A bool lane mask would silently read as one step a lane."""
+        m, _ = cluster_1k
+        _, kernel, _, _ = self._launch(m, [16] * 8)
+        with pytest.raises(TypeError, match="lane_steps"):
+            kernel(np.ones((8,), bool))
+
+
 class TestFeaturesBucketing:
     def test_measured_features_match_full_decode(self, cluster_1k):
         """The occupancy-bucketed slim decode must score identically to
@@ -415,15 +572,15 @@ class TestFeaturesBucketing:
         b = len(compiled)
         drows, dvals, tg, sc, pen, ce, hm = lane_operands(b, n)
         reqs = stack_requests(compiled)
-        lm = np.ones((b,), bool)
+        ls = np.full((b,), SCAN, np.int32)
         feats = kernels.features_of(reqs)
         full = np.asarray(fused_place_batch(
             arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
-            lm, n_placements=SCAN, features=kernels.FULL_FEATURES,
+            ls, n_placements=SCAN, features=kernels.FULL_FEATURES,
         ))
         slim = np.asarray(fused_place_batch(
             arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
-            lm, n_placements=SCAN, features=feats,
+            ls, n_placements=SCAN, features=feats,
         ))
         np.testing.assert_array_equal(
             slim[:, :, 0].astype(np.int32), full[:, :, 0].astype(np.int32)

@@ -300,7 +300,7 @@ class TestShardedFusedParity:
             dvals[i, :3] = rng.uniform(0, 50, (3, 3))
         return drows, dvals
 
-    def _ref_and_sharded(self, m, inp, drows, dvals, lm, scan,
+    def _ref_and_sharded(self, m, inp, drows, dvals, steps, scan,
                          nshards, batch):
         from nomad_tpu.parallel import (
             make_mesh,
@@ -313,7 +313,7 @@ class TestShardedFusedParity:
         ref = kernels.fused_place_batch(
             arrays, arrays.used, drows, dvals, inp["tg_counts"],
             inp["spread_counts"], inp["penalties"], reqs,
-            inp["class_eligs"], inp["host_masks"], jnp.asarray(lm),
+            inp["class_eligs"], inp["host_masks"], steps,
             n_placements=scan,
         )
         mesh = make_mesh(nshards, batch=batch)
@@ -321,7 +321,7 @@ class TestShardedFusedParity:
         out = sharded_fused_place_batch(mesh, scan)(
             sharded, sharded.used, drows, dvals, inp["tg_counts"],
             inp["spread_counts"], inp["penalties"], reqs,
-            inp["class_eligs"], inp["host_masks"], jnp.asarray(lm),
+            inp["class_eligs"], inp["host_masks"], steps,
         )
         return np.asarray(ref), out
 
@@ -340,8 +340,19 @@ class TestShardedFusedParity:
                 err_msg=f"col {col} {where}",
             )
 
+    # Per-lane step counts (0 = a dead lane, which must stay dead across
+    # shardings): every live lane the whole scan, and mixed counts whose
+    # largest sits on one batch shard only — the loops' trip count has to
+    # be one number on every shard all the same.
+    STEPS = {
+        "full": [4, 4, 4, 4, 4, 4, 4, 0],
+        "mixed": [1, 2, 0, 1, 3, 1, 4, 0],
+    }
+
+    @pytest.mark.parametrize("steps", sorted(STEPS))
     @pytest.mark.parametrize("nshards,batch", MESHES)
-    def test_matches_unsharded_fused(self, eight_devices, nshards, batch):
+    def test_matches_unsharded_fused(self, eight_devices, nshards, batch,
+                                     steps):
         m, nodes = _cluster(n_nodes=48, capacity=64)
         job1 = mock.job()
         job2 = mock.job()
@@ -355,16 +366,19 @@ class TestShardedFusedParity:
 
         inp = build_batch_inputs(m, (reqs_list * 4)[:b])
         drows, dvals = self._deltas(b, 48)
-        lm = np.ones((b,), bool)
-        lm[-1] = False  # one dead lane must stay dead across shardings
+        ls = np.array(self.STEPS[steps], np.int32)
         ref, out = self._ref_and_sharded(
-            m, inp, drows, dvals, lm, scan, nshards, batch
+            m, inp, drows, dvals, ls, scan, nshards, batch
         )
+        assert (ref[ls == 0, :, kernels.PACKED_ROW] == -1).all()
+        for lane, k in enumerate(ls):
+            assert (ref[lane, :k, kernels.PACKED_ROW] >= 0).all()
+            assert (ref[lane, k:, kernels.PACKED_ROW] == -1).all()
         # The fetched winner block is node-count independent: (B, P, 8).
         assert np.asarray(out).shape == (
             b, scan, kernels.FUSED_PACKED_WIDTH
         )
-        self._assert_parity(ref, out, f"mesh ({nshards},{batch})")
+        self._assert_parity(ref, out, f"mesh ({nshards},{batch}) {steps}")
 
     @pytest.mark.parametrize("nshards,batch", MESHES)
     def test_cross_lane_conflicts_match(self, eight_devices, nshards,
@@ -383,9 +397,9 @@ class TestShardedFusedParity:
         inp = build_batch_inputs(m, [req] * b)
         drows = np.full((b, 4), -1, np.int32)
         dvals = np.zeros((b, 4, 3), np.float32)
-        lm = np.ones((b,), bool)
+        ls = np.array([2, 1, 2, 2, 1, 2, 2, 2], np.int32)
         ref, out = self._ref_and_sharded(
-            m, inp, drows, dvals, lm, scan, nshards, batch
+            m, inp, drows, dvals, ls, scan, nshards, batch
         )
         assert (ref[:, :, kernels.FUSED_PACKED_VERIFIED] == 0.0).any(), (
             "conflict case produced no rejections — test lost its teeth"

@@ -144,6 +144,32 @@ class TestPipelineParity:
         assert all(o.rows.shape[0] > 0 for o in piped)
 
 
+class TestLaneSteps:
+    @pytest.mark.parametrize("n_live,steps", [(3, 3), (0, 16), (40, 16)])
+    def test_n_live_reaches_the_jitted_kernel(self, n_live, steps):
+        """`place(n_live=k)` through the real jitted fused entry: the scan
+        stops after k steps (rows past them read -1), and the launch adds
+        its largest count to `scan_steps_total`.  0 ("not said") and more
+        than the scan holds both mean all of it."""
+        m = _matrix(8)
+        coal = DeviceCoalescer(m, max_lanes=4, linger_s=0.0)
+        assert coal.scan_length == 16
+        job = mock.job()
+        job.task_groups[0].tasks[0].resources.cpu = 100
+        coal.start()
+        try:
+            out = coal.place(**_inputs(m, job), n_live=n_live)
+        finally:
+            coal.stop()
+        assert out.rows.shape == (16,)
+        assert (out.rows[:steps] >= 0).all()
+        assert (out.rows[steps:] == -1).all()
+        assert (out.scores[steps:] == 0.0).all()
+        assert out.fit_verified.all()
+        assert coal.fused_dispatches == 1
+        assert coal.scan_steps_total == steps
+
+
 class TestStagingReuse:
     def test_operands_untouched_until_their_dispatch_resolves(
         self, monkeypatch
@@ -183,10 +209,10 @@ class TestStagingReuse:
                 out[:, :, kernels.PACKED_ROW] = -1.0
                 return out
 
-        def stand_in(arrays, used, dr, dv, tg, sc, pen, reqs, ce, hm, lm,
+        def stand_in(arrays, used, dr, dv, tg, sc, pen, reqs, ce, hm, ls,
                      **_static):
             launches.append(
-                InFlight([dr, dv, tg, sc, pen, ce, hm, lm, *reqs])
+                InFlight([dr, dv, tg, sc, pen, ce, hm, ls, *reqs])
             )
             return launches[-1]
 
