@@ -6,9 +6,8 @@ deployment size — 10,000 nodes (capacity 10,240) carrying the aggregated
 usage of 2,000,000 allocations, seeded from ``--seed``:
 
 1. **library level, full width** — one call each of ``fused_place_batch``
-   (64 lanes x 10,240 rows x 16 placements), ``place_batch``,
-   ``place_task_group``, ``system_feasible``, ``verify_plan_fit`` and the
-   dirty-row scatter, each compared on the host with its numpy twin in
+   (64 lanes x 10,240 rows x 16 placements), ``place_task_group``,
+   ``system_feasible``, ``verify_plan_fit`` and the dirty-row scatter, each compared on the host with its numpy twin in
    ``ops/fake_device.py`` (rows, preempted flags, VERIFIED column and the
    node counters exactly equal); with several devices visible also
    ``sharded_fused_place_batch`` against the unsharded kernel, bit for bit;
@@ -318,22 +317,11 @@ def library_level(meter: CompileMeter, sizes, seed: int,
             f"library: lane {lane}'s first {n} rows differ from the "
             "full-length launch",
         )
-    run(
-        "place_batch",
-        lambda: kernels.place_batch(
-            arrays, arrays.used, dr, dv, tg, sc, pen, stacked, ce, hm,
-            n_placements=scan, features=feats,
-        ),
-        lambda: fake_device.place_batch(
-            host, host.used, *lane_lists, n_placements=scan
-        ),
-        fused=False,
-    )
 
     # Solo entry: the preempting request alone, the way stack.py calls it.
     solo_feats = kernels.features_of(preempting)
 
-    def pack(r, xp):  # a PlacementResult in place_batch's packed layout
+    def pack(r, xp):  # a PlacementResult in the PACKED_* column layout
         f32 = xp.float32
         return xp.stack([
             r.rows.astype(f32), r.scores, r.binpack, r.preempted.astype(f32),

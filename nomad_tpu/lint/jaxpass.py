@@ -36,7 +36,7 @@ This pass enforces both lexically over ``ops/``, ``parallel/``,
   keep static args bound to configuration constants.
 * **J005 node-axis fetch at a fused/sharded call site** — a function that
   drives the fused or node-sharded dispatch entry points
-  (``fused_place_batch[_live]`` / ``sharded_[fused_]place_batch``) also
+  (``fused_place_batch[_live]`` / ``sharded_fused_place_batch``) also
   fetches a node-axis-shaped value to host: a sync sink
   (``np.asarray``/``.block_until_ready()``/…) applied to a
   ``DeviceArrays`` leaf (``arrays.used``, ``.totals``, ``.attr_hash``,
@@ -65,7 +65,9 @@ SCAN_FILES = (
 # Dotted-prefix patterns whose call results live on device.
 DEVICE_PRODUCER_PREFIXES = ("kernels.", "jnp.", "jax.numpy.")
 DEVICE_PRODUCER_EXACT = {"jax.device_put"}
-DEVICE_PRODUCER_NAMES = {"place_batch_live", "sharded_place_batch"}
+DEVICE_PRODUCER_NAMES = {
+    "fused_place_batch_live", "sharded_fused_place_batch", "_sharded_fused_fn",
+}
 
 # Sinks that force a device→host sync.
 SYNC_CALL_NAMES = {"float", "int", "bool"}
@@ -91,7 +93,6 @@ FUSED_STATIC_PARAMS = ("n_placements", "features")
 # — the production dispatch site invokes the entry through it, so the
 # bound name counts as an entry too.
 SHARDED_ENTRY_NAMES = {
-    "sharded_place_batch",
     "sharded_fused_place_batch",
     "_sharded_fused_fn",
 }

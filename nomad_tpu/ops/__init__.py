@@ -22,7 +22,6 @@ from .kernels import (  # noqa: F401
     fit_and_binpack,
     fused_place_batch,
     fused_place_batch_live,
-    place_batch,
     place_task_group,
     score_nodes,
     verify_plan_fit,

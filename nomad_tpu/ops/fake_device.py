@@ -676,42 +676,6 @@ def place_task_group(arrays, req: SchedRequest, used0, tg_count,
     )
 
 
-def place_batch(arrays, used, delta_rows: List[np.ndarray],
-                delta_vals: List[np.ndarray], tg_counts: List[np.ndarray],
-                spread_counts: List[np.ndarray], penalties: List[np.ndarray],
-                reqs: List[SchedRequest], class_eligs: List[np.ndarray],
-                host_masks: List[np.ndarray],
-                n_placements: int,
-                live_counts: Optional[List[int]] = None) -> np.ndarray:
-    """Batched twin of kernels.place_batch, taking per-request lists (no
-    lane padding / stacking needed host-side).  Returns (B, P, 7) f32.
-
-    ``live_counts[i]`` caps how many scan steps request ``i`` actually
-    computes — callers (stack._select_locked) consume only ``rows[:remaining]``.
-    The uncomputed tail rows are filled with the inert no-placement marker
-    (row=-1, zeros), as the fused kernel fills them; the staged jax
-    ``place_batch`` has a static scan and still computes them."""
-    b = len(reqs)
-    out = np.zeros((b, n_placements, 7), np.float32)
-    for i in range(b):
-        drows = np.asarray(delta_rows[i])
-        live = drows >= 0
-        used0 = used
-        if live.any():
-            used0 = used.copy()
-            np.add.at(used0, drows[live], np.asarray(delta_vals[i])[live])
-        steps = n_placements
-        if live_counts is not None:
-            steps = max(1, min(n_placements, int(live_counts[i])))
-        out[i, :steps] = _place_scan(
-            arrays, reqs[i], used0, tg_counts[i], spread_counts[i],
-            penalties[i], class_eligs[i], host_masks[i], steps,
-        )
-        if steps < n_placements:
-            out[i, steps:, 0] = -1.0
-    return out
-
-
 # Packed-output constants of the fused megakernel, mirrored from
 # ops/kernels.py (this module stays importable without JAX).
 FUSED_PACKED_VERIFIED = 7
@@ -732,7 +696,7 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
     """Twin of kernels.fused_place_batch — (B, P, FUSED_PACKED_WIDTH) f32.
 
     Adds the sequential cross-lane AllocsFit VERIFIED column on top of the
-    staged scans: lanes commit their in-flight deltas and placements to a
+    per-lane scans: lanes commit their in-flight deltas and placements to a
     cumulative usage image in lane order, and each placement is checked
     against it (1.0 fits, 0.0 an earlier lane claimed the capacity, -1.0
     dead lane). ``lane_mask`` marks live lanes explicitly; dead lanes emit

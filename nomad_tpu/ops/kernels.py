@@ -295,7 +295,7 @@ def system_feasible(arrays, used0, req: SchedRequest, class_elig, host_mask):
 
     Returns ONE stacked (2, N) bool array [mask, fits] so the host pays a
     single device→host fetch (each separate fetch is its own synchronous
-    round-trip — see bench.py rtt_floor_ms)."""
+    round-trip)."""
     mask = feasibility_mask(arrays, req, class_elig, host_mask)
     fits, _, _ = fit_and_binpack(arrays, used0, req)
     return jnp.stack([mask, fits])
@@ -591,73 +591,6 @@ def score_nodes(
 
 
 # ---------------------------------------------------------------------------
-# Batched independent evals (the throughput path)
-# ---------------------------------------------------------------------------
-
-
-class BatchScoreResult(NamedTuple):
-    rows: jnp.ndarray  # (B,) i32 argmax node row, -1 = no fit
-    scores: jnp.ndarray  # (B,) f32
-    binpack: jnp.ndarray  # (B,) f32
-    preempted: jnp.ndarray  # (B,) bool
-    nodes_evaluated: jnp.ndarray  # (B,) i32
-    nodes_filtered: jnp.ndarray  # (B,) i32
-    nodes_exhausted: jnp.ndarray  # (B,) i32
-
-
-def _score_and_pick(arrays, used, tg_count, spread_counts, penalty, req,
-                    class_elig, host_mask,
-                    features: Features = FULL_FEATURES) -> tuple:
-    res = score_nodes(
-        arrays, used, tg_count, spread_counts, penalty, req, class_elig,
-        host_mask, features,
-    )
-    row = jnp.argmax(res.final).astype(jnp.int32)
-    ok = res.final[row] > NEG_INF / 2
-    return (
-        jnp.where(ok, row, -1),
-        # Failed placements report 0 score/binpack, matching the placement
-        # scan's convention (place_task_group) so consumers can aggregate
-        # without re-masking.
-        jnp.where(ok, res.final[row], 0.0),
-        jnp.where(ok, res.binpack[row], 0.0),
-        res.needs_preempt[row] & ok,
-        jnp.sum(res.feasible.astype(jnp.int32)),
-        # Filtered counts exclude capacity-padding / ineligible rows, like
-        # the placement scan's n_filtered.
-        jnp.sum((~res.feasible & arrays.eligible).astype(jnp.int32)),
-        jnp.sum((res.feasible & ~res.fits).astype(jnp.int32)),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("features",))
-def score_batch(arrays, used, tg_counts, spread_counts, penalties, reqs,
-                class_eligs, host_masks,
-                features: Features = FULL_FEATURES) -> BatchScoreResult:
-    """B independent evaluations in ONE dispatch: full ranking over every
-    node for each, then per-eval argmax.
-
-    This is where the TPU design earns its keep versus the reference: where
-    Nomad bounds *per-eval* work (shuffle + log₂(n) candidates + po2c,
-    stack.go:78-91) and scales via optimistic worker concurrency, we score
-    all nodes for a whole *batch* of evals as one (B, N) data-parallel
-    program. Conflicting picks are caught by the plan applier's re-verify —
-    the same optimistic-concurrency contract the reference already relies on
-    (plan_apply.go:49-69).
-
-    Batched args lead with a B axis: tg_counts (B,N), spread_counts (B,S,V),
-    penalties (B,N), reqs a stacked SchedRequest pytree, class_eligs (B,K),
-    host_masks (B,N). ``arrays`` and ``used`` are shared.
-    """
-    outs = jax.vmap(
-        lambda tg, sc, pen, req, ce, hm: _score_and_pick(
-            arrays, used, tg, sc, pen, req, ce, hm, features
-        )
-    )(tg_counts, spread_counts, penalties, reqs, class_eligs, host_masks)
-    return BatchScoreResult(*outs)
-
-
-# ---------------------------------------------------------------------------
 # Placement scan
 # ---------------------------------------------------------------------------
 
@@ -762,11 +695,12 @@ def _place_scan(
     trip=None,
 ) -> PlacementResult:
     """Traceable core of the placement scan (shared by the solo
-    ``place_task_group`` jit and the coalesced ``place_batch`` vmap).
+    ``place_task_group`` jit and the fused kernel's ``vmap`` over lanes).
 
     ``n_steps`` (traced i32 scalar; None = all ``n_placements``, a static
-    ``lax.scan``) is how many placements this request asked for: steps
-    past it place nothing and report the inert row (-1, zeros).  ``trip``
+    ``lax.scan``: ``place_task_group`` alone runs that branch) is how many
+    placements this request asked for: steps past it place nothing and
+    report the inert row (-1, zeros).  ``trip``
     is the loop bound when a ``vmap`` over requests shares one loop — the
     largest ``n_steps`` of the batch, computed outside the ``vmap`` so the
     loop's condition stays one scalar."""
@@ -876,7 +810,7 @@ def place_task_group(
     )
 
 
-# Columns of place_batch's packed per-request output (one fetch per
+# Per-placement columns of a batched launch's packed output (one fetch per
 # dispatch; each separate device→host fetch is its own round-trip).
 PACKED_ROW = 0
 PACKED_SCORE = 1
@@ -888,122 +822,12 @@ PACKED_EXHAUSTED = 6
 PACKED_WIDTH = 7
 
 
-def _place_batch_impl(
-    arrays,
-    used,
-    delta_rows,
-    delta_vals,
-    tg_counts,
-    spread_counts,
-    penalties,
-    reqs,
-    class_eligs,
-    host_masks,
-    n_placements: int,
-    features: Features = FULL_FEATURES,
-) -> jnp.ndarray:
-    """B independent placement scans in ONE dispatch — the device side of
-    the dispatch coalescer (scheduler/coalescer.py).
-
-    Where the reference scales scheduling by optimistic worker concurrency
-    (worker.go:49-53) with each worker walking nodes alone, here concurrent
-    workers' selects coalesce into one vmapped scan over the shared matrix;
-    conflicting picks stay the plan applier's job (plan_apply.go:49-69).
-
-    Per-request args lead with a B axis. ``delta_rows``/``delta_vals``
-    ((B, K) i32 / (B, K, 3) f32, row -1 = padding) carry each request's
-    sparse in-flight plan usage deltas — applied to the shared ``used``
-    inside the kernel so the host never materializes a dense per-request
-    usage matrix.
-
-    Returns a packed (B, n_placements, PACKED_WIDTH) f32 array (row ids and
-    counts are exact in f32 up to 2^24) so the host pays ONE fetch.
-    """
-
-    def one(drows, dvals, tg, sc, pen, req, ce, hm):
-        safe = jnp.maximum(drows, 0)
-        add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
-        used0 = used.at[safe].add(add)
-        res = _place_scan(
-            arrays, req, used0, tg, sc, pen, ce, hm, n_placements, features
-        )
-        return jnp.stack(
-            [
-                res.rows.astype(jnp.float32),
-                res.scores,
-                res.binpack,
-                res.preempted.astype(jnp.float32),
-                res.nodes_evaluated.astype(jnp.float32),
-                res.nodes_filtered.astype(jnp.float32),
-                res.nodes_exhausted.astype(jnp.float32),
-            ],
-            axis=1,
-        )  # (P, 7)
-
-    return jax.vmap(one)(
-        delta_rows, delta_vals, tg_counts, spread_counts, penalties, reqs,
-        class_eligs, host_masks,
-    )
-
-
-place_batch = functools.partial(
-    jax.jit, static_argnames=("n_placements", "features")
-)(_place_batch_impl)
-
-# The coalescer's entry point: identical computation, but the per-dispatch
-# lane operands (deltas, tg/spread counts, penalties, stacked requests,
-# class eligibility, host masks — argnums 2..9) are DONATED, so XLA reuses
-# their freshly-transferred device buffers as scratch instead of holding
-# them live alongside the outputs. ``arrays``/``used`` (argnums 0-1) are
-# never donated: they are matrix-resident and shared with other in-flight
-# pipelined dispatches. Kept separate from ``place_batch`` because callers
-# of the un-donated entry (tests, tools) legitimately reuse their input
-# arrays across calls.
-place_batch_live = functools.partial(
-    jax.jit,
-    static_argnames=("n_placements", "features"),
-    donate_argnums=tuple(range(2, 10)),
-)(_place_batch_impl)
-
-
 # ---------------------------------------------------------------------------
 # Fused megakernel (mega-batched eval pipeline + device-resident re-verify)
 # ---------------------------------------------------------------------------
 
-# Escape hatch reserved by the fusion work: if XLA ever stops fusing the
-# sequential binpack/placement scan inside the megakernel (a regression
-# observable as per-step launch overhead returning in the trace), the
-# scan segment gets a hand-written Pallas kernel behind this flag.
-# XLA fuses the whole pipeline into one program, so no Pallas
-# implementation exists and the flag only warns — it must never silently
-# change numerics.
-PALLAS_FLAG = "NOMAD_TPU_PALLAS"
-_pallas_warned = False
-
-
-def pallas_requested() -> bool:
-    """True when NOMAD_TPU_PALLAS opts into the (reserved) Pallas scan.
-
-    Warns once: there is nothing to switch yet, the XLA fusion is the
-    implementation. Callers must not branch numerics on this."""
-    import os
-
-    global _pallas_warned
-    on = os.environ.get(PALLAS_FLAG, "").lower() in ("1", "on", "true", "yes")
-    if on and not _pallas_warned:
-        _pallas_warned = True
-        import warnings
-
-        warnings.warn(
-            f"{PALLAS_FLAG} is set, but the fused scan has no Pallas "
-            f"implementation (XLA fuses it; see ops/kernels.py) — "
-            f"running the XLA path.",
-            stacklevel=2,
-        )
-    return on
-
-# Columns of the fused kernel's packed output. The first PACKED_WIDTH
-# columns are identical to place_batch's; the extra VERIFIED column carries
+# Columns of the fused kernel's packed output: the PACKED_WIDTH
+# per-placement columns above, then the VERIFIED column, which carries
 # the device-resident AllocsFit re-verify verdict per placement:
 #   1.0  placement survives the sequential cross-lane re-check
 #   0.0  placement would be rejected (an earlier lane's plan claims the
@@ -1074,7 +898,11 @@ def _fused_place_batch_impl(
     binpack → spread/affinity → preemption evict-state → placement scan —
     PLUS the ``AllocsFit`` plan re-verify, in ONE launch.
 
-    Differences from ``place_batch``:
+    Per-request args lead with a B axis.  ``delta_rows``/``delta_vals``
+    ((B, K) i32 / (B, K, 3) f32, row -1 = padding) carry each request's
+    sparse in-flight plan usage deltas, applied to the shared ``used``
+    inside the kernel so the host never materializes a dense per-request
+    usage matrix.
 
     * ``lane_steps`` (B,) i32 says how many placements each eval slot
       asked for, 0 for a dead slot (batch occupancy < B).  The placement
@@ -1087,7 +915,7 @@ def _fused_place_batch_impl(
       bit what a full-length launch gives.  Dead lanes produce row=-1 /
       zero outputs and contribute nothing to the verify pass — no
       host-side request-faking, no shape-polymorphic recompiles.
-    * The packed output grows a VERIFIED column: a device-resident
+    * The packed output's VERIFIED column is a device-resident
       sequential AllocsFit re-check of every lane's chosen placements
       against the authoritative matrix usage *plus all earlier lanes'
       deltas and placements*, in lane (= resolve) order. Within one lane a
@@ -1168,8 +996,11 @@ fused_place_batch = functools.partial(
 )(_fused_place_batch_impl)
 
 # Live entry: per-dispatch lane operands (argnums 2..10, including the lane
-# step counts) are donated, mirroring place_batch_live. ``arrays``/``used`` stay
-# shared with in-flight pipelined dispatches and are never donated.
+# step counts) are DONATED, so XLA reuses their freshly-transferred device
+# buffers as scratch instead of holding them live alongside the outputs.
+# ``arrays``/``used`` stay shared with in-flight pipelined dispatches and
+# are never donated.  Kept apart from ``fused_place_batch`` because callers
+# of the un-donated entry (tests, the smoke) reuse their inputs across calls.
 fused_place_batch_live = functools.partial(
     jax.jit,
     static_argnames=("n_placements", "features"),

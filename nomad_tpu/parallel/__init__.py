@@ -8,23 +8,15 @@ and the cross-device argmax/psum reductions ride ICI.
 """
 
 from .sharding import (
-    build_batch_inputs,
     make_mesh,
     mesh_layout,
     shard_matrix_arrays,
     sharded_fused_place_batch,
-    sharded_place_batch,
-    sharded_schedule_step,
-    stack_requests,
 )
 
 __all__ = [
-    "build_batch_inputs",
     "make_mesh",
     "mesh_layout",
     "shard_matrix_arrays",
     "sharded_fused_place_batch",
-    "sharded_place_batch",
-    "sharded_schedule_step",
-    "stack_requests",
 ]

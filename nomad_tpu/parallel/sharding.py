@@ -1,4 +1,4 @@
-"""Sharded scheduling step over a ``jax.sharding.Mesh``.
+"""The batched placement program over a ``jax.sharding.Mesh``.
 
 Mesh axes and their roles (the sharding design the scaling-book recipe
 produces for this workload):
@@ -7,12 +7,12 @@ produces for this workload):
   dims in an ML model. Every (N, ...) array in ``DeviceArrays`` plus the
   usage matrix splits along it. Feasibility/scoring is row-parallel, so each
   shard scores its own nodes with zero communication; only the final
-  *argmax* crosses shards (one ``pmax`` pair over ICI — the analog of a
-  ring-attention score reduction).
+  *argmax* crosses shards (a ``pmax``/``pmin`` pair over ICI — the analog
+  of a ring-attention score reduction).
 - ``batch`` — independent evaluations, sharded like data-parallel batches.
-  Each batch shard picks winners locally; the resulting usage deltas are
-  ``psum``-ed across the batch axis (the gradient-all-reduce analog) so every
-  replica applies the same state update.
+  Each batch shard runs its own lanes' scans; the cross-lane verify
+  ``all_gather``s the winner rows, asks and in-flight deltas over the batch
+  axis so every replica replays all lanes in resolve order.
 
 Reference behaviors preserved: the step scores all nodes per eval (replacing
 stack.go:78-91's candidate sampling), applies proposed usage like
@@ -24,7 +24,7 @@ batched picks are optimistic by design.
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +32,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.encode import SchedRequest, pow2_bucket
+from ..ops.encode import SchedRequest
 from ..ops.kernels import (
     FULL_FEATURES,
     NEG_INF,
@@ -123,67 +123,6 @@ def node_shard_count(mesh: Mesh) -> int:
     return int(dict(zip(mesh.axis_names, mesh.devices.shape))["node"])
 
 
-def stack_requests(reqs: Sequence[SchedRequest]) -> SchedRequest:
-    """Stack B per-eval requests into one batched pytree (leading B axis).
-
-    Trailing padding in the per-predicate dimensions (constraints,
-    affinities, static ports, datacenters) is narrowed to the batch's
-    actual maximum, pow2-bucketed so the jit cache stays bounded.  The
-    per-predicate column gathers are the dominant HBM traffic of a batched
-    dispatch (see kernels._check_predicate); typical jobs use 2-4 of the
-    16 constraint slots, so this cuts the gather volume ~4x.
-    """
-    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *reqs)
-
-    def width(active: np.ndarray, cap: int) -> int:
-        count = int(active.sum(axis=1).max()) if len(active) else 0
-        return min(cap, pow2_bucket(max(1, count)))
-
-    cw = width(stacked.c_slot >= 0, stacked.c_slot.shape[1])
-    aw = width(stacked.a_slot >= 0, stacked.a_slot.shape[1])
-    pw = width(stacked.p_static >= 0, stacked.p_static.shape[1])
-    dw = width(stacked.dc_hash != 0, stacked.dc_hash.shape[1])
-    return stacked._replace(
-        c_slot=stacked.c_slot[:, :cw],
-        c_op=stacked.c_op[:, :cw],
-        c_hash=stacked.c_hash[:, :cw],
-        c_num=stacked.c_num[:, :cw],
-        a_slot=stacked.a_slot[:, :aw],
-        a_op=stacked.a_op[:, :aw],
-        a_hash=stacked.a_hash[:, :aw],
-        a_num=stacked.a_num[:, :aw],
-        a_weight=stacked.a_weight[:, :aw],
-        p_static=stacked.p_static[:, :pw],
-        dc_hash=stacked.dc_hash[:, :dw],
-    )
-
-
-def build_batch_inputs(matrix, requests: Sequence[SchedRequest]) -> dict:
-    """Assemble the batched tensors ``score_batch``/``sharded_schedule_step``
-    consume, for B evals with no in-flight plan state: zero TG counts and
-    spread counts, no penalties, all classes eligible, no host mask.
-
-    Shared by bench.py, __graft_entry__, and tests — the shapes (class-count
-    padding in particular) must stay in sync with the kernel.
-    """
-    reqs = jax.tree_util.tree_map(
-        jnp.asarray, stack_requests(list(requests))
-    )
-    b = len(requests)
-    n = matrix.capacity
-    pad = pow2_bucket(max(1, len(matrix.class_ids)))
-    return dict(
-        reqs=reqs,
-        tg_counts=jnp.zeros((b, n), jnp.int32),
-        spread_counts=jnp.zeros(
-            (b,) + requests[0].s_value_hash.shape, jnp.float32
-        ),
-        penalties=jnp.zeros((b, n), bool),
-        class_eligs=jnp.ones((b, pad), bool),
-        host_masks=jnp.ones((b, n), bool),
-    )
-
-
 # PartitionSpecs for the matrix arrays: every (N, ...) leaf splits on 'node'.
 _ARRAYS_SPEC = DeviceArrays(
     totals=P("node", None),
@@ -268,240 +207,9 @@ def make_sharded_row_scatter(mesh: Mesh):
     return jax.jit(scat, out_shardings=out_shardings)
 
 
-def _step_local(arrays, used, tg_counts, spread_counts, penalties, reqs,
-                class_eligs, host_masks):
-    """Per-shard body. Local shapes: arrays/used are (N/n, ...); batched
-    inputs are (B/b, ...) with node-sized trailing dims already (N/n)."""
-    n_local = used.shape[0]
-    shard = jax.lax.axis_index("node")
-    row_offset = shard * n_local
-
-    def one(tg, sc, pen, req, ce, hm):
-        res = score_nodes(arrays, used, tg, sc, pen, req, ce, hm)
-        local_row = jnp.argmax(res.final).astype(jnp.int32)
-        local_ok = res.final[local_row] > NEG_INF / 2
-
-        # Cross-shard argmax over the node axis: one pmax for the score, one
-        # to elect the owning shard's global row (ties break to highest row).
-        score = jnp.where(local_ok, res.final[local_row], NEG_INF)
-        best = jax.lax.pmax(score, "node")
-        candidate = jnp.where(
-            local_ok & (score == best), row_offset + local_row, -1
-        )
-        row = jax.lax.pmax(candidate, "node")
-        ok = best > NEG_INF / 2
-        row = jnp.where(ok, row, -1)
-        win = (row >= row_offset) & (row < row_offset + n_local)
-        pre = jax.lax.pmax(
-            jnp.where(
-                win & ok, res.needs_preempt[local_row], False
-            ).astype(jnp.int32),
-            "node",
-        ).astype(bool)
-        evaluated = jax.lax.psum(
-            jnp.sum(res.feasible.astype(jnp.int32)), "node"
-        )
-        # Failed placements report score 0.0, matching score_batch /
-        # place_task_group so consumers can aggregate without re-masking.
-        return row, jnp.where(ok, best, 0.0), pre, evaluated, req.ask
-
-    rows, scores, pre, evaluated, asks = jax.vmap(one)(
-        tg_counts, spread_counts, penalties, reqs, class_eligs, host_masks
-    )
-
-    # State update (the "optimizer step"): scatter each winner's ask into
-    # this shard's usage rows, then psum the deltas across the batch axis so
-    # every batch replica applies every pick.
-    local_rows = rows - row_offset
-    mine = (local_rows >= 0) & (local_rows < n_local)
-    safe = jnp.clip(local_rows, 0, n_local - 1)
-    delta = jnp.zeros_like(used).at[safe].add(
-        jnp.where(mine[:, None], asks, 0.0)
-    )
-    delta = jax.lax.psum(delta, "batch")
-    return rows, scores, pre, evaluated, used + delta
-
-
-def sharded_schedule_step(mesh: Mesh):
-    """Build the jitted SPMD scheduling step for ``mesh``.
-
-    Returns ``step(arrays, used, tg_counts, spread_counts, penalties, reqs,
-    class_eligs, host_masks) -> (rows, scores, preempted, nodes_evaluated,
-    used_after)`` — B optimistic placements plus the updated (still sharded)
-    usage matrix.
-    """
-    fn = shard_map(
-        _step_local,
-        mesh=mesh,
-        in_specs=(
-            _ARRAYS_SPEC,
-            P("node", None),  # used
-            P("batch", "node"),  # tg_counts
-            P("batch", None, None),  # spread_counts
-            P("batch", "node"),  # penalties
-            _REQS_SPEC,
-            P("batch", None),  # class_eligs
-            P("batch", "node"),  # host_masks
-        ),
-        out_specs=(
-            P("batch"),
-            P("batch"),
-            P("batch"),
-            P("batch"),
-            P("node", None),
-        ),
-    )
-    return jax.jit(fn)
-
-
 # ---------------------------------------------------------------------------
-# Sharded dispatch-coalescer kernel (the LIVE multi-chip path)
-# ---------------------------------------------------------------------------
-
-
-def _place_batch_local(
-    arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-    penalties, reqs, class_eligs, host_masks, n_placements,
-):
-    """Per-shard body of the coalescer's ``place_batch`` (ops/kernels.py:659)
-    under a ('batch', 'node') mesh: each shard scores its own node rows, the
-    per-placement argmax crosses shards over ICI (pmax score + pmin row, so
-    ties break to the lowest global row exactly like the single-device
-    ``jnp.argmax``), and the winning shard alone applies the usage/tg-count
-    update.  Spread-count updates need the winning node's attribute values,
-    which live on one shard — the owner broadcasts them with a psum.
-    """
-    n_local = used.shape[0]
-    shard = jax.lax.axis_index("node")
-    row_offset = shard * n_local
-    big = jnp.int32(2 ** 30)
-
-    def one(drows, dvals, tg, sc, pen, req, ce, hm):
-        # Sparse in-flight plan deltas arrive as GLOBAL rows; each shard
-        # applies the slice it owns.
-        local = drows - row_offset
-        mine = (drows >= 0) & (local >= 0) & (local < n_local)
-        safe = jnp.clip(local, 0, n_local - 1)
-        used0 = used.at[safe].add(jnp.where(mine[:, None], dvals, 0.0))
-
-        def step(carry, _):
-            u, tg_cnt, s_hash, s_counts = carry
-            req_step = req._replace(s_value_hash=s_hash)
-            res = score_nodes(
-                arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm
-            )
-            lrow = jnp.argmax(res.final).astype(jnp.int32)
-            lok = res.final[lrow] > NEG_INF / 2
-            score = jnp.where(lok, res.final[lrow], NEG_INF)
-            best = jax.lax.pmax(score, "node")
-            cand = jnp.where(
-                lok & (score == best), row_offset + lrow, big
-            )
-            grow = jax.lax.pmin(cand, "node")  # lowest row wins ties
-            ok = best > NEG_INF / 2
-            grow = jnp.where(ok, grow, -1)
-            owner = ok & (grow >= row_offset) & (grow < row_offset + n_local)
-            lwin = jnp.clip(grow - row_offset, 0, n_local - 1)
-
-            n_eval = jax.lax.psum(
-                jnp.sum(res.feasible.astype(jnp.int32)), "node"
-            )
-            n_filt = jax.lax.psum(
-                jnp.sum((~res.feasible & arrays.eligible).astype(jnp.int32)),
-                "node",
-            )
-            n_exh = jax.lax.psum(
-                jnp.sum((res.feasible & ~res.fits).astype(jnp.int32)), "node"
-            )
-
-            u2 = jnp.where(owner, u.at[lwin].add(req.ask), u)
-            tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
-
-            # Winning node's per-stanza attr values: owner computes, psum
-            # broadcasts (hash 0 = "no value", so non-owners contribute 0).
-            nvals = jnp.where(
-                owner, spread_values_at(arrays, req_step, lwin), 0
-            )
-            nvals = jax.lax.psum(nvals, "node")
-            new_hash, new_counts = apply_spread_values(
-                s_counts, req_step, nvals
-            )
-            s_hash2 = jnp.where(ok, new_hash, s_hash)
-            s_counts2 = jnp.where(ok, new_counts, s_counts)
-
-            binp = jax.lax.psum(
-                jnp.where(owner, res.binpack[lwin], 0.0), "node"
-            )
-            pre = jax.lax.pmax(
-                jnp.where(
-                    owner, res.needs_preempt[lwin], False
-                ).astype(jnp.int32),
-                "node",
-            ).astype(bool)
-            out = (
-                grow,
-                jnp.where(ok, best, 0.0),
-                jnp.where(ok, binp, 0.0),
-                pre & ok,
-                n_eval,
-                n_filt,
-                n_exh,
-            )
-            return (u2, tg2, s_hash2, s_counts2), out
-
-        init = (used0, tg, req.s_value_hash, sc)
-        _, outs = jax.lax.scan(step, init, None, length=n_placements)
-        rows, scores, binpack, pre, ne, nf, nx = outs
-        return jnp.stack(
-            [
-                rows.astype(jnp.float32),
-                scores,
-                binpack,
-                pre.astype(jnp.float32),
-                ne.astype(jnp.float32),
-                nf.astype(jnp.float32),
-                nx.astype(jnp.float32),
-            ],
-            axis=1,
-        )  # (P, 7) — kernels.PACKED_* layout
-
-    return jax.vmap(one)(
-        delta_rows, delta_vals, tg_counts, spread_counts, penalties, reqs,
-        class_eligs, host_masks,
-    )
-
-
-def sharded_place_batch(mesh: Mesh, n_placements: int):
-    """Build the jitted SPMD twin of ``kernels.place_batch`` for ``mesh``.
-
-    Same signature and packed (B, P, PACKED_WIDTH) result as the unsharded
-    kernel, so the dispatch coalescer swaps it in transparently when the
-    server runs on a multi-chip slice (scheduler/coalescer.py).  Placement
-    parity with the single-device kernel is exact (tie-breaks included) —
-    tests/test_parallel.py asserts it.
-    """
-    fn = shard_map(
-        functools.partial(_place_batch_local, n_placements=n_placements),
-        mesh=mesh,
-        in_specs=(
-            _ARRAYS_SPEC,
-            P("node", None),  # used
-            P("batch", None),  # delta_rows (global ids, replicated on node)
-            P("batch", None, None),  # delta_vals
-            P("batch", "node"),  # tg_counts
-            P("batch", None, None),  # spread_counts
-            P("batch", "node"),  # penalties
-            _REQS_SPEC,
-            P("batch", None),  # class_eligs
-            P("batch", "node"),  # host_masks
-        ),
-        out_specs=P("batch", None, None),
-    )
-    return jax.jit(fn)
-
-
-# ---------------------------------------------------------------------------
-# Sharded FUSED megakernel (hierarchical top-k + sharded AllocsFit verify)
+# Sharded fused megakernel (hierarchical top-k + sharded AllocsFit verify):
+# the live multi-chip path
 # ---------------------------------------------------------------------------
 
 
@@ -718,8 +426,8 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
 
     Same signature (``features`` keyword-static) and packed
     (B, P, FUSED_PACKED_WIDTH) result as the single-device fused kernel —
-    the dispatch coalescer swaps it in when a mesh is configured and
-    ``NOMAD_TPU_SHARDED_MEGABATCH`` is not disabled.  Placement AND
+    the dispatch coalescer launches it when dispatches span a mesh
+    (scheduler/coalescer.py ``_resolve_sharding``).  Placement AND
     verify-column parity with the unsharded kernel is exact (tie-breaks
     included) — tests/test_parallel.py asserts it across shard counts.
     """

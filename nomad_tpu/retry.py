@@ -29,7 +29,7 @@ from typing import Callable, Optional, Tuple, Type
 
 def env_int(name: str, default: int) -> int:
     """Tolerant integer env knob: unset, empty, or unparsable → default.
-    The one parser for every ``NOMAD_TPU_*``/``BENCH_*`` tuning variable,
+    The one parser for every ``NOMAD_TPU_*`` tuning variable,
     so tools and product code agree on the failure mode (a typo'd knob
     degrades to the default instead of crashing an agent at import)."""
     raw = os.environ.get(name, "")

@@ -2,14 +2,14 @@
 
 If every worker's ``select()`` held the global DEVICE_LOCK across its own
 kernel dispatch and fetched its result buffers one by one, each fetch
-would cost a full synchronous device→host round-trip (bench.py
-``rtt_floor_ms``), workers would serialize behind one another, and the
-batched kernel would sit unused outside the bench.
+would cost a full synchronous device→host round-trip, workers would
+serialize behind one another, and the batched kernel would sit unused.
 
 This module makes the batched kernel THE live path: workers enqueue
 compiled placement requests and block on a future; a dispatch thread
 drains the queue, stacks up to ``max_lanes`` requests, and issues ONE
-``ops.kernels.place_batch`` dispatch whose packed result costs ONE fetch.
+``ops.kernels.fused_place_batch_live`` dispatch (its node-sharded twin
+when dispatches span a mesh) whose packed result costs ONE fetch.
 
 A dispatch thread that performed that fetch itself (``np.asarray`` blocks
 until the device is done) would keep exactly one dispatch in flight, so
@@ -17,8 +17,8 @@ the loop is a producer/consumer pipeline:
 
 * the **dispatch thread** only launches — it relies on JAX async dispatch
   and never calls ``np.asarray``.  Up to ``pipeline_depth`` launches
-  (default 8, env ``NOMAD_TPU_PIPELINE_DEPTH``) overlap; the bounded
-  ticket queue provides backpressure.
+  (``PIPELINE_DEPTH`` unless the constructor says otherwise) overlap; the
+  bounded ticket queue provides backpressure.
 * a **resolver thread** performs the blocking device→host fetch for each
   in-flight ticket and completes the ``_Pending`` futures in launch order.
 
@@ -51,7 +51,6 @@ pick conflicting nodes; the applier's re-verify catches it.
 from __future__ import annotations
 
 import logging
-import os
 import queue
 import threading
 import time
@@ -70,7 +69,6 @@ from ..obs.breaker import (
 )
 from ..ops import kernels
 from ..ops.encode import RequestSlab, SchedRequest
-from ..retry import env_int
 from ..state.matrix import DEVICE_LOCK
 
 log = logging.getLogger(__name__)
@@ -79,37 +77,10 @@ log = logging.getLogger(__name__)
 # fall back to the solo dispatch path.
 MAX_DELTA_ROWS = 32
 
-_DEPTH_ENV = "NOMAD_TPU_PIPELINE_DEPTH"
-_MEGABATCH_ENV = "NOMAD_TPU_MEGABATCH"
-_SHARDED_MEGABATCH_ENV = "NOMAD_TPU_SHARDED_MEGABATCH"
-
-
-def default_pipeline_depth() -> int:
-    """Overlapping dispatches kept in flight (env-tunable, default 8 —
-    bench.py's pipelined phase runs at the same depth)."""
-    return max(1, env_int(_DEPTH_ENV, 8))
-
-
-def megabatch_enabled() -> bool:
-    """The fused megakernel path (ops.kernels.fused_place_batch): explicit
-    per-lane step counts, occupancy-bucketed compiles, and the device-resident
-    AllocsFit re-verify column. Default ON; ``NOMAD_TPU_MEGABATCH=0``
-    falls back to the staged place_batch path."""
-    return os.environ.get(_MEGABATCH_ENV, "1").lower() not in (
-        "0", "off", "false",
-    )
-
-
-def sharded_megabatch_enabled() -> bool:
-    """The node-sharded fused megakernel (parallel/sharding.py
-    sharded_fused_place_batch): hierarchical top-k ranking plus the
-    on-device cross-lane AllocsFit verify, with the node axis split over
-    the mesh.  Default ON when a mesh is configured;
-    ``NOMAD_TPU_SHARDED_MEGABATCH=0`` keeps multi-chip dispatches on the
-    staged sharded_place_batch path (no verify column)."""
-    return os.environ.get(_SHARDED_MEGABATCH_ENV, "1").lower() not in (
-        "0", "off", "false",
-    )
+# Overlapping dispatches kept in flight when the constructor names no
+# depth: the dispatch loop waits for a slot 0.01-0.02 % of a window at this
+# depth (PERF.md section 6, PR 24), so nothing is left for a knob to tune.
+PIPELINE_DEPTH = 8
 
 
 def lane_step_count(n_live: int, scan_length: int) -> int:
@@ -129,14 +100,14 @@ class PlaceOutcome:
     nodes_evaluated: np.ndarray  # (P,) i32
     nodes_filtered: np.ndarray  # (P,) i32
     nodes_exhausted: np.ndarray  # (P,) i32
-    # Fused-path extras: device-resident AllocsFit re-verify verdicts
-    # ((P,) bool — True = placement survives the sequential cross-lane
-    # re-check at `matrix_version`; None on the staged path) and the matrix
-    # version the dispatch was scored against. At an unchanged version a
-    # False verdict is a guaranteed plan-applier rejection; the applier
-    # against live state stays authoritative either way.
-    fit_verified: Optional[np.ndarray] = None
-    matrix_version: int = -1
+    # Device-resident AllocsFit re-verify verdicts ((P,) bool — True =
+    # placement survives the sequential cross-lane re-check at
+    # `matrix_version`) and the matrix version the dispatch was scored
+    # against. At an unchanged version a False verdict is a guaranteed
+    # plan-applier rejection; the applier against live state stays
+    # authoritative either way.
+    fit_verified: np.ndarray
+    matrix_version: int
 
 
 @dataclass
@@ -157,9 +128,8 @@ class _Pending:
     penalty: np.ndarray  # (N,) bool
     class_elig: np.ndarray  # (pad,) bool
     host_mask: np.ndarray  # (N,) bool
-    # Placements the caller will actually consume (0 = all scan_length).
-    # The fused kernel and the fake-device twin stop the lane's scan after
-    # this many steps (lane_step_count); the staged kernels run them all.
+    # Placements the caller will actually consume (0 = all scan_length):
+    # the lane's scan stops after this many steps (lane_step_count).
     n_live: int = 0
     enqueued_at: float = 0.0
     done: threading.Event = field(default_factory=threading.Event)
@@ -204,19 +174,16 @@ class DeviceCoalescer:
         self.max_lanes = max_lanes
         self.scan_length = scan_length or PLACEMENT_CHUNK
         self.linger_s = linger_s
-        self.pipeline_depth = (
-            pipeline_depth if pipeline_depth else default_pipeline_depth()
-        )
-        # Multi-chip: when >1, dispatches go through the SPMD twin of
-        # place_batch (parallel/sharding.py sharded_place_batch) over a
-        # ('batch', 'node') mesh — the live server path the dryrun
+        self.pipeline_depth = pipeline_depth or PIPELINE_DEPTH
+        # Multi-chip: when >1, dispatches go through the SPMD twin of the
+        # fused kernel (parallel/sharding.py sharded_fused_place_batch)
+        # over a ('batch', 'node') mesh — the live server path the dryrun
         # certifies.  None = auto: all visible devices on real
         # accelerators, single-device on CPU (the virtual 8-CPU rig is a
         # test harness, not a deployment; tests opt in explicitly).
         self.n_device_shards = n_device_shards
         self.metrics = metrics  # optional MetricsRegistry (the server's)
         self._mesh = None
-        self._sharded_fn = None
         self._sharded_fused_fn = None
         # Chaos shard.partition bookkeeping: shard -> node ids darkened by
         # the seam (heal_shard_partitions re-lights them).
@@ -226,8 +193,7 @@ class DeviceCoalescer:
         # oversized-delta solo selects) executed on the dispatch thread so
         # the live server has exactly ONE device-LAUNCHING thread
         # (state/matrix.py DEVICE_LOCK note).  The resolver thread only
-        # fetches already-launched results, the same overlap bench.py's
-        # pipelined phase exercises.
+        # fetches already-launched results.
         self._ops: List["_DeviceOp"] = []
         self._cond = threading.Condition()
         self._stop = threading.Event()
@@ -262,19 +228,15 @@ class DeviceCoalescer:
         # traffic staged per batched dispatch.
         self.solo_ops = 0
         self.operand_bytes_total = 0
-        # Fused-megakernel accounting: launches and live lanes through the
-        # fused path (launches-per-eval = fused_dispatches / fused_lanes),
+        # Batched-launch accounting: launches and live lanes
+        # (launches-per-eval = fused_dispatches / fused_lanes),
         # verify-column conflicts (placements an earlier lane's plan will
         # make the applier reject), and the occupancy-features ratchet —
         # a monotone widening union, so each Features variant compiles at
         # most once per process instead of flapping per batch.
-        self.megabatch = megabatch_enabled()
-        if self.megabatch:
-            kernels.pallas_requested()  # warn once if the reserved flag is set
-        self.sharded_megabatch = sharded_megabatch_enabled()
         self.fused_dispatches = 0
         self.fused_lanes = 0
-        # Scan steps the fused launches ran: each launch adds its widest
+        # Scan steps the launches ran: each launch adds its widest
         # lane's step count, worked out on the host from the batch's
         # n_live (steps a launch = scan_steps_total / fused_dispatches).
         self.scan_steps_total = 0
@@ -289,8 +251,8 @@ class DeviceCoalescer:
         self.topk_host_bytes_total = 0
         # Device fault domain (obs/breaker.py): the resolver classifies
         # every fetch ok/slow/wedged under the watchdog deadline; the
-        # breaker gates _dispatch between the device path and the staged
-        # host twin.  Wedged tickets count here (their futures raise
+        # breaker gates _dispatch between the device path and the numpy
+        # twin.  Wedged tickets count here (their futures raise
         # DeviceWedgedError); shard evacuations re-home the matrix onto
         # the surviving shards.
         self.breaker = DeviceBreaker(metrics=metrics)
@@ -358,9 +320,9 @@ class DeviceCoalescer:
         n_live: int = 0,
     ) -> PlaceOutcome:
         """Submit one placement request; blocks until its batch lands.
-        Every output is ``scan_length`` long — take ``rows[:k]``.  On the
-        fused path only the first ``n_live`` steps are computed (0 = all):
-        the rows past them read -1 and charge no usage."""
+        Every output is ``scan_length`` long — take ``rows[:k]``.  Only
+        the first ``n_live`` steps are computed (0 = all): the rows past
+        them read -1 and charge no usage."""
         p = _Pending(
             request=request,
             delta_rows=delta_rows,
@@ -460,7 +422,7 @@ class DeviceCoalescer:
                         metrics=self.metrics,
                     )
             # Device fault domain: while the breaker is open, dispatches
-            # degrade to the staged host twin (placements keep flowing at
+            # degrade to the numpy twin (placements keep flowing at
             # reduced throughput); half-open admits exactly one canary
             # launch whose fetch verdict decides re-admission.
             allowed, canary = self.breaker.allow_device_dispatch()
@@ -632,27 +594,22 @@ class DeviceCoalescer:
                 len(devs) if devs[0].platform != "cpu" and len(devs) > 1
                 else 1
             )
-        if self.n_device_shards > 1 and self._sharded_fn is None:
+        if self.n_device_shards > 1 and self._sharded_fused_fn is None:
             from ..parallel.sharding import (
                 make_mesh,
                 mesh_layout,
                 node_shard_count,
                 sharded_fused_place_batch,
-                sharded_place_batch,
             )
 
             batch, _node = mesh_layout(
                 self.n_device_shards, int(self.matrix.capacity)
             )
             self._mesh = make_mesh(self.n_device_shards, batch=batch)
-            self._sharded_fn = sharded_place_batch(
+            self._sharded_fused_fn = sharded_fused_place_batch(
                 self._mesh, self.scan_length
             )
             node_shards = node_shard_count(self._mesh)
-            if self.megabatch and self.sharded_megabatch:
-                self._sharded_fused_fn = sharded_fused_place_batch(
-                    self._mesh, self.scan_length
-                )
             # Home rows to their mesh shard so claims balance across the
             # node axis and growth never migrates a row between shards.
             # (Skipped while an evacuation is active: the survivor layout
@@ -677,9 +634,8 @@ class DeviceCoalescer:
                             shard=s,
                         )
             log.info(
-                "coalescer: multi-chip dispatch over mesh %s (%s)",
+                "coalescer: multi-chip dispatch over mesh %s",
                 dict(zip(self._mesh.axis_names, self._mesh.devices.shape)),
-                "fused" if self._sharded_fused_fn is not None else "staged",
             )
         return self.n_device_shards
 
@@ -751,7 +707,6 @@ class DeviceCoalescer:
             if self.n_device_shards is not None and self.n_device_shards > 1:
                 self.n_device_shards -= 1
             self._mesh = None
-            self._sharded_fn = None
             self._sharded_fused_fn = None
         self.shard_evacuations += 1
         self.breaker.note_evacuation()
@@ -775,7 +730,6 @@ class DeviceCoalescer:
             self._pre_evac_shards = None
             self.n_device_shards = self._pre_evac_device_shards
             self._mesh = None
-            self._sharded_fn = None
             self._sharded_fused_fn = None
         trace.event("seam.shard.loss.healed", restored=restored)
         return restored
@@ -863,10 +817,13 @@ class DeviceCoalescer:
         return arrays, sharded, version, n
 
     def _dispatch(self, batch: List[_Pending], degraded: bool = False):
-        """Launch one batched place_batch; returns (unfetched packed result,
-        matrix version at launch).  ``degraded`` (breaker open) forces the
-        staged host twin — the fake-device numpy path answers from the
-        host mirror, so placements keep flowing while the device is out."""
+        """Launch one placement batch; returns (unfetched packed result,
+        matrix version at launch).  Three routes, each chosen from what the
+        code observes: the numpy twin (fake backend, or ``degraded`` — the
+        breaker is open — answering from the host mirror, so placements
+        keep flowing while the device is out), the node-sharded program
+        when dispatches span a mesh (``_resolve_sharding``), the
+        one-device program otherwise."""
         from ..chaos import inject
         from ..ops import fake_device
 
@@ -951,26 +908,17 @@ class DeviceCoalescer:
                 lane_step_count(p.n_live, self.scan_length) for p in batch
             ]
             with self._state("coalescer.enqueue", lanes=len(batch)):
-                if self.megabatch:
-                    packed = fake_device.fused_place_batch(
-                        arrays,
-                        arrays.used,
-                        *lane_lists,
-                        lane_mask=np.ones((len(batch),), bool),
-                        n_placements=self.scan_length,
-                        live_counts=live_counts,
-                    )
-                    self.fused_dispatches += 1
-                    self.fused_lanes += len(batch)
-                    self.scan_steps_total += max(live_counts)
-                else:
-                    packed = fake_device.place_batch(
-                        arrays,
-                        arrays.used,
-                        *lane_lists,
-                        n_placements=self.scan_length,
-                        live_counts=live_counts,
-                    )
+                packed = fake_device.fused_place_batch(
+                    arrays,
+                    arrays.used,
+                    *lane_lists,
+                    lane_mask=np.ones((len(batch),), bool),
+                    n_placements=self.scan_length,
+                    live_counts=live_counts,
+                )
+            self.fused_dispatches += 1
+            self.fused_lanes += len(batch)
+            self.scan_steps_total += max(live_counts)
             self.operand_bytes_total += sum(
                 p.host_mask.nbytes + p.tg_count.nbytes + p.penalty.nbytes
                 + p.class_elig.nbytes + p.spread_counts.nbytes
@@ -1048,47 +996,30 @@ class DeviceCoalescer:
         # sole device→host traffic).  A launch that widened the features
         # ratchet traces, lowers and compiles (or reads from the cache) a
         # new variant inside the call: that one is named apart.
-        fused = (
-            self._sharded_fused_fn is not None if n_shards > 1
-            else self.megabatch
-        )
-        state, args, feats = "coalescer.enqueue", {"lanes": k}, None
-        if fused:
-            variants = self.feature_recompiles
-            feats = self._ratchet_features(slab, k)
-            self.fused_dispatches += 1
-            self.fused_lanes += k
-            self.scan_steps_total += int(ls[:k].max())
-            if self.feature_recompiles != variants:
-                state = "coalescer.trace_variant"
-                args["features"] = str(tuple(feats))
+        state, args = "coalescer.enqueue", {"lanes": k}
+        variants = self.feature_recompiles
+        feats = self._ratchet_features(slab, k)
+        self.fused_dispatches += 1
+        self.fused_lanes += k
+        self.scan_steps_total += int(ls[:k].max())
+        if self.feature_recompiles != variants:
+            state = "coalescer.trace_variant"
+            args["features"] = str(tuple(feats))
         with self._state(state, **args):
-            if n_shards > 1 and fused:
+            if n_shards > 1:
                 packed = self._sharded_fused_fn(
                     sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
                     hm, ls, features=feats,
                 )
-            elif n_shards > 1:
-                # Staged sharded fallback (NOMAD_TPU_SHARDED_MEGABATCH=0):
-                # packed result is PACKED_WIDTH wide and _resolve
-                # distinguishes the two by the trailing dimension.
-                packed = self._sharded_fn(
-                    sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce, hm
-                )
-            elif fused:
+            else:
+                # The live entry donates the per-dispatch lane operands
+                # (their device buffers become XLA scratch); `arrays`/`used`
+                # stay live — they are matrix-resident and shared with
+                # in-flight dispatches.
                 packed = kernels.fused_place_batch_live(
                     arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
                     ls, n_placements=self.scan_length,
                     features=feats,
-                )
-            else:
-                # place_batch_live donates the per-dispatch lane operands
-                # (their device buffers become XLA scratch); `arrays`/`used`
-                # stay live — they are matrix-resident and shared with
-                # in-flight dispatches.
-                packed = kernels.place_batch_live(
-                    arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
-                    n_placements=self.scan_length,
                 )
         return packed, version
 
@@ -1214,7 +1145,6 @@ class DeviceCoalescer:
                 # gauge over this attribute by the server).
                 self.stale_dispatches += 1
                 trace.event("coalescer.stale_dispatch")
-            fused = arr.shape[-1] == kernels.FUSED_PACKED_WIDTH
             for i, p in enumerate(entries):
                 row = arr[i]
                 # Shard-preserving capacity growth relocates rows; a dispatch
@@ -1225,17 +1155,15 @@ class DeviceCoalescer:
                     row[:, kernels.PACKED_ROW].astype(np.int32),
                     ticket.matrix_version,
                 )
-                fit_verified = None
-                if fused:
-                    # The device-resident AllocsFit column: a 0.0 on a real
-                    # placement means an earlier lane in THIS launch already
-                    # claimed the capacity — at an unchanged matrix version
-                    # the applier is guaranteed to reject it.  Advisory: the
-                    # serialized applier stays authoritative either way.
-                    vcol = row[:, kernels.FUSED_PACKED_VERIFIED]
-                    placed = rows_i >= 0
-                    fit_verified = ~(placed & (vcol == 0.0))
-                    self.verify_conflicts += int((~fit_verified).sum())
+                # The device-resident AllocsFit column: a 0.0 on a real
+                # placement means an earlier lane in THIS launch already
+                # claimed the capacity — at an unchanged matrix version
+                # the applier is guaranteed to reject it.  Advisory: the
+                # serialized applier stays authoritative either way.
+                vcol = row[:, kernels.FUSED_PACKED_VERIFIED]
+                placed = rows_i >= 0
+                fit_verified = ~(placed & (vcol == 0.0))
+                self.verify_conflicts += int((~fit_verified).sum())
                 p.outcome = PlaceOutcome(
                     rows=rows_i,
                     scores=row[:, kernels.PACKED_SCORE],

@@ -112,10 +112,10 @@ class SelectionOption:
     metric: AllocMetric = field(default_factory=AllocMetric)
     # task -> {label: port} assigned host-side for the chosen node
     assigned_ports: Dict[str, Dict[str, int]] = field(default_factory=dict)
-    # Fused-path advisory: the device's sequential cross-lane AllocsFit
-    # verdict for this placement (False = an earlier lane in the same
-    # launch claimed the capacity — the applier will reject this plan at an
-    # unchanged matrix version).  None on the staged/solo paths.
+    # Coalesced-launch advisory: the device's sequential cross-lane
+    # AllocsFit verdict for this placement (False = an earlier lane in the
+    # same launch claimed the capacity — the applier will reject this plan
+    # at an unchanged matrix version).  None on the solo path.
     fit_verified: Optional[bool] = None
 
 
@@ -524,8 +524,8 @@ class GenericStack:
     ):
         """Run one placement scan; returns host-side arrays (rows, scores,
         binpack, preempted, n_eval, n_filt, n_exh, fit_verified) of scan
-        length ≥ the bucket for ``remaining``.  fit_verified is None unless
-        the fused megakernel path supplied its cross-lane verify column.
+        length ≥ the bucket for ``remaining``.  fit_verified is the
+        coalesced launch's cross-lane verify column, None on the solo path.
 
         With a mesh configured the coalescer routes the batch through the
         node-sharded fused entry (parallel/sharding.py, hierarchical
@@ -533,7 +533,7 @@ class GenericStack:
         translated through any shard-preserving capacity growth that
         happened while the dispatch was in flight (matrix.translate_rows),
         so the node_of lookup below never sees a pre-relocation id."""
-        from .coalescer import MAX_DELTA_ROWS, megabatch_enabled
+        from .coalescer import MAX_DELTA_ROWS
 
         # One consistent width for every per-node array in this request:
         # re-reading matrix.capacity here could disagree with the shapes the
@@ -592,10 +592,6 @@ class GenericStack:
 
             import jax.numpy as jnp
 
-            feats = (
-                _ratchet_features(compiled.request)
-                if megabatch_enabled() else kernels.FULL_FEATURES
-            )
             result = kernels.place_task_group(
                 arrays,
                 compiled.request,
@@ -606,7 +602,7 @@ class GenericStack:
                 jnp.asarray(class_elig),
                 jnp.asarray(_pad_width(_full_mask(n, host_mask), n_dev, False)),
                 n_placements=bucket,
-                features=feats,
+                features=_ratchet_features(compiled.request),
             )
             return (
                 np.asarray(result.rows),

@@ -362,7 +362,7 @@ class PlanApplier:
         # the exact host twin of the verify_plan_fit kernel (pinned together
         # by tests/test_kernels.py::test_host_twin_matches_kernel).  The
         # applier holds the global store lock here, so the device (a
-        # synchronous round-trip, bench.py rtt_floor_ms) is never touched
+        # synchronous round-trip) is never touched
         # on this path; O(k) numpy handles any plan size in microseconds.
         host = matrix.snapshot_host()
         rows_np = np.asarray(rows, np.int32)

@@ -72,7 +72,7 @@ class ServerConfig:
     # Max selects batched into one device dispatch (scheduler/coalescer.py).
     coalescer_lanes: int = 64
     # Overlapping dispatches the coalescer keeps in flight (pipelined
-    # producer/consumer loop). None = env NOMAD_TPU_PIPELINE_DEPTH, default 8.
+    # producer/consumer loop). None = coalescer.PIPELINE_DEPTH (8).
     pipeline_depth: Optional[int] = None
     # Devices the coalescer shards dispatches over (parallel/sharding.py).
     # None = auto: every visible chip on real accelerators, 1 on CPU.

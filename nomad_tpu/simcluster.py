@@ -4,7 +4,7 @@ BASELINE.json's C2M configuration: N nodes in 4 datacenters, 6 node
 classes and 32 racks, a third of them carrying the ``v5p`` accelerator
 attribute and the rest ``v5e``, loaded with the aggregated usage of ~M
 allocations (the matrix carries usage aggregates, the same thing AllocsFit
-recomputes per call in the reference, funcs.go:97-150).  ``bench.py`` and
+recomputes per call in the reference, funcs.go:97-150).  ``benchmark/run.py`` and
 ``chip_smoke.py`` both build their cluster here, always from the seed —
 nothing is read from or written to disk.
 """

@@ -47,8 +47,8 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from ..retry import env_float, env_int
 
-# Phase histograms land in the MetricsRegistry under this prefix; bench.py
-# folds them into the per-phase latency breakdown.
+# Phase histograms land in the MetricsRegistry under this prefix
+# (the per-phase latency breakdown `/v1/metrics` serves).
 PHASE_PREFIX = "nomad.phase."
 
 _span_ids = itertools.count(1)  # process-wide; next() is atomic in CPython

@@ -9,7 +9,7 @@ This must run before jax is imported anywhere.
 import os
 
 # Force CPU even if the environment preset JAX_PLATFORMS: unit tests
-# validate logic + sharding on the virtual mesh; chip_smoke.py and bench.py
+# validate logic + sharding on the virtual mesh; chip_smoke.py and benchmark/run.py
 # are what run on the real chip.
 os.environ["JAX_PLATFORMS"] = "cpu"
 
@@ -34,7 +34,7 @@ def pytest_configure(config):
         "markers",
         "slow: exhaustive chaos sweeps excluded from tier-1 (-m 'not slow')",
     )
-    # place_batch_live donates its lane operands; no output shares their
+    # fused_place_batch_live donates its lane operands; no output shares their
     # shape, so XLA cannot alias them and jax warns once per compile (on
     # every backend).
     config.addinivalue_line(

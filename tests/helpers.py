@@ -48,3 +48,76 @@ def _live(server, job):
         a for a in server.store.allocs_by_job(job.namespace, job.id)
         if not a.terminal_status()
     ]
+
+
+def lane_operands(matrix, requests, deltas=None, penalties=None,
+                  tg_counts=None, max_deltas=4):
+    """The per-lane operands of ``fused_place_batch`` between ``used`` and
+    ``lane_steps``, in the entry's order: (delta_rows, delta_vals,
+    tg_counts, spread_counts, penalties, reqs, class_eligs, host_masks)
+    for ``requests`` with no plan state but what the overrides say.
+
+    ``ops.encode.RequestSlab`` stacks the requests, as the coalescer does;
+    what it cannot give is the other seven operands, whose shapes (the
+    class-count padding in particular) must stay in step with the kernel.
+
+    deltas: {lane: [(row, (cpu, mem, disk)), ...]} in-flight deltas;
+    penalties: {lane: [row, ...]}; tg_counts: {lane: {row: count}}.
+    """
+    import numpy as np
+
+    from nomad_tpu.ops.encode import RequestSlab, pow2_bucket
+
+    b, n = len(requests), int(matrix.capacity)
+    slab = RequestSlab(b)
+    for i, req in enumerate(requests):
+        slab.fill(i, req)
+    drows = np.full((b, max_deltas), -1, np.int32)
+    dvals = np.zeros((b, max_deltas, 3), np.float32)
+    for lane, items in (deltas or {}).items():
+        for j, (row, vals) in enumerate(items):
+            drows[lane, j] = row
+            dvals[lane, j] = vals
+    pen = np.zeros((b, n), bool)
+    for lane, rows in (penalties or {}).items():
+        pen[lane, list(rows)] = True
+    tg = np.zeros((b, n), np.int32)
+    for lane, counts in (tg_counts or {}).items():
+        for row, c in counts.items():
+            tg[lane, row] = c
+    sc = np.zeros((b,) + np.asarray(requests[0].s_value_hash).shape,
+                  np.float32)
+    ce = np.ones((b, max(2, pow2_bucket(len(matrix.class_ids)))), bool)
+    hm = np.ones((b, n), bool)
+    return drows, dvals, tg, sc, pen, slab.batch(), ce, hm
+
+
+def solo_reference(arrays, operands, n_placements, lanes=None):
+    """Each lane (or each of ``lanes``) of ``operands`` (``lane_operands``'
+    tuple) alone through ``place_task_group`` (the static scan over the
+    dense proposed usage), packed (B, P, 7) in the ``PACKED_*`` column
+    order: what a lane of the batched program must read, sparse deltas
+    included."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nomad_tpu.ops import kernels
+
+    drows, dvals, tg, sc, pen, reqs, ce, hm = operands
+    out = []
+    for i in (range(len(drows)) if lanes is None else lanes):
+        live = drows[i] >= 0
+        used0 = arrays.used.at[drows[i][live]].add(dvals[i][live])
+        r = kernels.place_task_group(
+            arrays, jax.tree_util.tree_map(lambda x: x[i], reqs), used0,
+            jnp.asarray(tg[i]), jnp.asarray(sc[i]), jnp.asarray(pen[i]),
+            jnp.asarray(ce[i]), jnp.asarray(hm[i]), n_placements,
+        )
+        out.append(np.stack([
+            np.asarray(c, np.float32) for c in (
+                r.rows, r.scores, r.binpack, r.preempted, r.nodes_evaluated,
+                r.nodes_filtered, r.nodes_exhausted,
+            )
+        ], axis=1))
+    return np.stack(out)

@@ -141,3 +141,36 @@ def test_server_schedules_through_coalescer(tmp_path):
     finally:
         c.shutdown()
         srv.shutdown()
+
+
+@pytest.mark.parametrize("route", ["one_device", "mesh", "breaker_open"])
+def test_every_route_returns_the_verify_column(route, eight_devices):
+    """_dispatch keeps three routes (one device, a mesh, the numpy twin
+    while the breaker is open): on each the outcome carries the cross-lane
+    verify column as an array, and the launch counts as a batched one."""
+    m = NodeMatrix(capacity=16)
+    for _ in range(8):
+        m.upsert_node(mock.node())
+    coal = DeviceCoalescer(
+        m, max_lanes=4, linger_s=0.0,
+        n_device_shards=8 if route == "mesh" else 1,
+    )
+    coal.start()
+    try:
+        if route == "breaker_open":
+            coal.breaker.record_wedge(1.0)
+            assert coal.breaker.brief()["breaker"] == "open"
+        out = coal.place(**_inputs(m, mock.job()), n_live=2)
+    finally:
+        coal.stop()
+    assert isinstance(out.fit_verified, np.ndarray)
+    assert out.fit_verified.shape == out.rows.shape == (coal.scan_length,)
+    assert out.fit_verified.dtype == bool and out.fit_verified.all()
+    assert (out.rows[:2] >= 0).all() and (out.rows[2:] == -1).all()
+    assert out.matrix_version == m.version
+    assert coal.fused_dispatches == coal.dispatches == 1
+    assert coal.fused_lanes == 1 and coal.scan_steps_total == 2
+    assert coal.breaker.brief()["degraded_dispatches"] == (
+        1 if route == "breaker_open" else 0
+    )
+    assert (coal.mesh_shape() != (1, 1)) == (route == "mesh")

@@ -1,4 +1,4 @@
-"""Driver-contract tests for the entry points: __graft_entry__, bench.py,
+"""Driver-contract tests for the entry points: __graft_entry__,
 chip_smoke.py and the compile-cache rule they share.
 
 Round-1 postmortem (VERDICT.md Weak #9): nothing exercised the entry
@@ -61,9 +61,9 @@ def test_entry_compiles_fresh_process():
         "import __graft_entry__ as g\n"
         "import jax, numpy as np\n"
         "fn, args = g.entry()\n"
-        "out = jax.jit(fn)(*args)\n"
-        "rows = np.asarray(out.rows)\n"
-        "assert rows.shape == (4,), rows.shape\n"
+        "out = np.asarray(jax.jit(fn)(*args))\n"
+        "assert out.shape[0] == 4 and out.shape[2] == 8, out.shape\n"
+        "assert (out[:, 0, 0] >= 0).all(), out[:, :, 0]\n"
         "print('entry-contract-ok')\n"
     )
     env = _driver_like_env()
@@ -78,43 +78,6 @@ def test_entry_compiles_fresh_process():
     )
     assert p.returncode == 0, f"stdout={p.stdout}\nstderr={p.stderr}"
     assert "entry-contract-ok" in p.stdout
-
-
-def test_bench_smoke_small(tmp_path):
-    """bench.py end-to-end on a toy cluster: must print exactly one JSON
-    line with the required keys, on whatever platform is available."""
-    import json
-
-    env = _driver_like_env()
-    env.update(
-        JAX_PLATFORMS="cpu",
-        # Toy-cluster numbers must not land in the committed regression
-        # ledger — they'd poison the real baselines.
-        NOMAD_TPU_BENCH_LEDGER=str(tmp_path / "ledger.jsonl"),
-        BENCH_NODES="64",
-        BENCH_ALLOCS="2000",
-        BENCH_BATCH="8",
-        BENCH_DISPATCHES="5",
-        BENCH_E2E_JOBS="4",
-        BENCH_E2E_PROBES="3",
-        BENCH_E2E_WORKERS="2",
-    )
-    p = subprocess.run(
-        [sys.executable, "bench.py"],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=560,
-    )
-    assert p.returncode == 0, f"stdout={p.stdout}\nstderr={p.stderr}"
-    lines = [l for l in p.stdout.strip().splitlines() if l.strip()]
-    assert len(lines) == 1, p.stdout
-    out = json.loads(lines[0])
-    for key in ("metric", "value", "unit", "vs_baseline"):
-        assert key in out, out
-    assert out["value"] > 0
-    assert out.get("e2e_evals_per_sec", 0) > 0, out
 
 
 def _cache_dir_seen_by_child(env: dict, cwd: str) -> str:
@@ -144,38 +107,6 @@ def test_compilation_cache_directory_rule(tmp_path):
     expected = os.path.join(REPO, ".jax_cache")
     assert _cache_dir_seen_by_child(env, REPO) == expected
     assert _cache_dir_seen_by_child(env, str(tmp_path)) == expected
-
-
-def test_bench_exits_nonzero_when_a_phase_raises(monkeypatch, capsys):
-    """A phase that raises still gets its *_error key into the one JSON
-    line, but the run must not exit 0."""
-    import json
-
-    import pytest
-
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-
-    def boom(result):
-        raise RuntimeError("boom")
-
-    monkeypatch.setenv("NOMAD_TPU_BENCH_LEDGER", "0")
-    monkeypatch.setattr(bench, "bench_kernel", lambda r: r.update(value=1.0))
-    monkeypatch.setattr(bench, "bench_sharded", boom)
-    for phase in ("bench_e2e", "bench_host_only", "bench_live_pipeline",
-                  "bench_overload"):
-        monkeypatch.setattr(bench, phase, lambda r: None)
-    for flag in ("SHARDED", "E2E", "HOST_ONLY", "LIVE_PIPELINE", "OVERLOAD"):
-        monkeypatch.setattr(bench, flag, True)
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code not in (0, None)
-    lines = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
-    assert len(lines) == 1, lines
-    out = json.loads(lines[0])
-    assert out["sharded_error"] == "RuntimeError: boom"
-    assert out["platform"] == "cpu"
-    assert out["device_kind"] and out["device_count"] >= 1
 
 
 def test_chip_smoke_refuses_without_an_accelerator(tmp_path):
@@ -218,5 +149,10 @@ def test_chip_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
     assert out["ok"] is False and out["rehearsal"] == "tiny"
     assert out["device"] == verdict["device"]
     assert "failed" not in out
+    assert list(out["library"]["entry_points"]) == [
+        "fused_place_batch", "fused_place_batch_mixed_steps",
+        "place_task_group", "system_feasible", "verify_plan_fit",
+        "row_scatter",
+    ]
     assert out["live"]["counters"]["fused_dispatches"] > 0
     assert out["live"]["allocs_preempted"] >= 1

@@ -259,50 +259,6 @@ class TestPlacementParity:
             assert_same_placement(m, job, count=3)
 
 
-class TestBatchParity:
-    def test_place_batch_matches_kernel(self):
-        nodes = [make_node(cpu=2000 + 500 * i, mem=4096) for i in range(6)]
-        m = setup(nodes)
-        jobs = [make_job(cpu=300 + 100 * i, mem=256) for i in range(3)]
-        enc = RequestEncoder(m)
-        compiled = [enc.compile(j, j.task_groups[0]) for j in jobs]
-        arrays = m.sync()
-        host = host_view(arrays)
-        n = host.used.shape[0]
-
-        scan_len = 4
-        drows = np.full((3, 8), -1, np.int32)
-        dvals = np.zeros((3, 8, 3), np.float32)
-        drows[1, 0] = 5
-        dvals[1, 0] = [1500.0, 0.0, 0.0]
-
-        import jax
-
-        reqs = jax.tree_util.tree_map(
-            lambda *xs: np.stack(xs), *[c.request for c in compiled]
-        )
-        zeros_tg = np.zeros((3, n), np.int32)
-        zeros_sc = np.zeros((3, MAX_SPREADS, MAX_SPREAD_VALUES), np.float32)
-        zeros_pen = np.zeros((3, n), bool)
-        ones_ce = np.ones((3, 2), bool)
-        ones_hm = np.ones((3, n), bool)
-        packed = np.asarray(kernels.place_batch(
-            arrays, arrays.used, drows, dvals, zeros_tg, zeros_sc,
-            zeros_pen, reqs, ones_ce, ones_hm, n_placements=scan_len,
-        ))
-
-        fake = fake_device.place_batch(
-            host, host.used, list(drows), list(dvals), list(zeros_tg),
-            list(zeros_sc), list(zeros_pen), [c.request for c in compiled],
-            list(ones_ce), list(ones_hm), n_placements=scan_len,
-        )
-        assert (packed[:, :, 0].astype(np.int32)
-                == fake[:, :, 0].astype(np.int32)).all()
-        np.testing.assert_allclose(packed[:, :, 1], fake[:, :, 1],
-                                   rtol=1e-4, atol=1e-5)
-        assert (packed[:, :, 3:] == fake[:, :, 3:]).all()
-
-
 class TestSystemAndVerifyParity:
     def test_system_feasible(self):
         nodes = [make_node(cpu=1000 + 700 * i, mem=2048) for i in range(5)]

@@ -372,56 +372,35 @@ class TestVerifyPlanFit:
 
 class TestPlaceBatch:
     def test_matches_solo_scan(self):
-        """place_batch (the coalescer kernel) must equal per-request
-        place_task_group runs, including sparse delta application."""
-        from nomad_tpu.ops.encode import MAX_SPREADS, MAX_SPREAD_VALUES
-        from nomad_tpu.ops.kernels import place_batch
+        """A lane of fused_place_batch (the coalescer kernel) must equal
+        that request's own place_task_group run, including sparse delta
+        application."""
+        from helpers import lane_operands, solo_reference
+        from nomad_tpu.ops.kernels import fused_place_batch
 
         nodes = [make_node(cpu=2000 + 500 * i, mem=4096) for i in range(6)]
         m = setup(nodes)
         jobs = [make_job(cpu=300 + 100 * i, mem=256) for i in range(3)]
         enc = RequestEncoder(m)
-        compiled = [enc.compile(j, j.task_groups[0]) for j in jobs]
+        reqs = [enc.compile(j, j.task_groups[0]).request for j in jobs]
         arrays = m.sync()
-        n = arrays.used.shape[0]
 
         scan_len = 4
-        drows = np.full((3, 8), -1, np.int32)
-        dvals = np.zeros((3, 8, 3), np.float32)
         # Request 1 carries an in-flight delta on row 5.
-        drows[1, 0] = 5
-        dvals[1, 0] = [1500.0, 0.0, 0.0]
-
-        import jax
-
-        reqs = jax.tree_util.tree_map(
-            lambda *xs: np.stack(xs), *[c.request for c in compiled]
+        ops = lane_operands(
+            m, reqs, deltas={1: [(5, (1500.0, 0.0, 0.0))]}, max_deltas=8
         )
-        zeros_tg = np.zeros((3, n), np.int32)
-        zeros_sc = np.zeros((3, MAX_SPREADS, MAX_SPREAD_VALUES), np.float32)
-        zeros_pen = np.zeros((3, n), bool)
-        ones_ce = np.ones((3, 2), bool)
-        ones_hm = np.ones((3, n), bool)
-        packed = np.asarray(place_batch(
-            arrays, arrays.used, drows, dvals, zeros_tg, zeros_sc,
-            zeros_pen, reqs, ones_ce, ones_hm, n_placements=scan_len,
+        packed = np.asarray(fused_place_batch(
+            arrays, arrays.used, *ops, np.full((3,), scan_len, np.int32),
+            n_placements=scan_len,
         ))
-
-        for i, c in enumerate(compiled):
-            used0 = arrays.used
-            if i == 1:
-                used0 = used0.at[5].add(jnp.asarray([1500.0, 0.0, 0.0]))
-            solo = place_task_group(
-                arrays, c.request, used0, jnp.zeros((n,), jnp.int32),
-                jnp.zeros((MAX_SPREADS, MAX_SPREAD_VALUES), jnp.float32),
-                jnp.zeros((n,), bool), jnp.ones((2,), bool),
-                jnp.ones((n,), bool), scan_len,
-            )
-            assert (packed[i, :, 0].astype(np.int32)
-                    == np.asarray(solo.rows)).all()
-            np.testing.assert_allclose(
-                packed[i, :, 1], np.asarray(solo.scores), rtol=1e-5
-            )
+        solo = solo_reference(arrays, ops, scan_len)
+        assert (packed[:, :, 0] >= 0).any()
+        np.testing.assert_array_equal(packed[:, :, 0], solo[:, :, 0])
+        np.testing.assert_allclose(packed[:, :, 1], solo[:, :, 1], rtol=1e-5)
+        # The delta moved lane 1 off what it would pick without it.
+        bare = solo_reference(arrays, lane_operands(m, reqs), scan_len)
+        assert (bare[1, :, 0] != solo[1, :, 0]).any()
 
 
 class TestEncodingEscapes:

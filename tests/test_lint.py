@@ -266,7 +266,7 @@ class TestJaxPass:
             "nomad_tpu/ops/fixture.py": textwrap.dedent(
                 """
                 def bad(arrays):
-                    packed = kernels.place_batch_live(arrays)
+                    packed = fused_place_batch_live(arrays)
                     return np.asarray(packed)
                 """
             )
@@ -464,7 +464,7 @@ class TestJ005NodeAxisFetch:
             "nomad_tpu/scheduler/coalescer.py": textwrap.dedent(
                 """
                 def bad(self, arrays, dr, dv, reqs, ls):
-                    res = sharded_place_batch(arrays, reqs, ls)
+                    res = self._sharded_fused_fn(arrays, reqs, ls)
                     return np.asarray(res.used_after)
                 """
             )

@@ -82,3 +82,37 @@ def test_committed_baseline_is_canonical():
     baseline = load_baseline()
     keys = [(e["rule"], e["path"], e["symbol"]) for e in baseline.entries]
     assert keys == sorted(keys) and len(keys) == len(set(keys))
+
+
+# ----------------------------------------------------------------------
+# The documented variables are the ones the code reads.
+# ----------------------------------------------------------------------
+
+
+def test_documented_variables_are_the_ones_read():
+    """README's tables of ``NOMAD_TPU_*`` variables name exactly the
+    variables the program's source names: a variable deleted from the code
+    leaves the document with it, a new one arrives documented."""
+    import os
+    import re
+
+    root = repo_root()
+    name = re.compile(r"NOMAD_TPU_[A-Z0-9_]+")
+    sources = [os.path.join(root, f)
+               for f in ("chip_smoke.py", "__graft_entry__.py")]
+    for top in ("nomad_tpu", "tools"):
+        for d, _dirs, files in os.walk(os.path.join(root, top)):
+            sources += [os.path.join(d, f) for f in files if f.endswith(".py")]
+    read = set()
+    for path in sources:
+        with open(path, encoding="utf-8") as fh:
+            # ``NOMAD_TPU_DEVICE_*`` in a docstring names a family, not a
+            # variable.
+            read |= {n for n in name.findall(fh.read()) if not n.endswith("_")}
+    with open(os.path.join(root, "README.md"), encoding="utf-8") as fh:
+        rows = re.findall(r"^\| `(NOMAD_TPU_[A-Z0-9_]+)` \|", fh.read(), re.M)
+    assert len(rows) == len(set(rows)), "a variable is documented twice"
+    assert set(rows) == read, (
+        f"documented but not read: {sorted(set(rows) - read)}; "
+        f"read but not documented: {sorted(read - set(rows))}"
+    )

@@ -1,13 +1,14 @@
-"""Fused ranking megakernel vs the staged pipeline.
+"""Fused ranking megakernel vs the solo scan.
 
 The mega-batched fused kernel (ops/kernels.py fused_place_batch) runs B
 eval pipelines — feasibility → binpack → spread/affinity → preemption
 evict-set → placement scan — PLUS the cross-lane AllocsFit re-verify in
-one launch. These tests pin it against the staged kernels it replaced:
+one launch. These tests pin it against each request alone through
+``place_task_group`` (the static scan over the dense proposed usage):
 
-* placement parity with ``place_batch`` on a seeded 1K-node cluster,
-  across constraint/affinity/spread/preemption request shapes and
-  in-flight deltas;
+* placement parity, lane by lane, on a seeded 1K-node cluster, across
+  constraint/affinity/spread/preemption request shapes and in-flight
+  deltas; each of the served traffic's shapes alone in a 64-lane launch;
 * the VERIFIED column: cross-lane capacity conflicts (two lanes claiming
   the same node, an earlier lane's in-flight delta) are flagged exactly
   where the plan applier would reject, and nowhere else;
@@ -24,17 +25,12 @@ one launch. These tests pin it against the staged kernels it replaced:
 import numpy as np
 import pytest
 
-import jax
-import jax.numpy as jnp
-
 from nomad_tpu.ops import RequestEncoder, fake_device
 from nomad_tpu.ops import kernels
-from nomad_tpu.ops.encode import MAX_SPREADS, MAX_SPREAD_VALUES
 from nomad_tpu.ops.kernels import (
     FUSED_PACKED_VERIFIED,
     FUSED_PACKED_WIDTH,
     fused_place_batch,
-    place_batch,
 )
 from nomad_tpu.state import NodeMatrix
 from nomad_tpu.structs import (
@@ -50,6 +46,8 @@ from nomad_tpu.structs import (
     Task,
     TaskGroup,
 )
+
+from helpers import lane_operands, solo_reference
 
 SCAN = 4
 
@@ -78,36 +76,9 @@ def make_job(cpu=500, mem=256, count=1, constraints=None, affinities=None,
     return Job(task_groups=[tg], **kw)
 
 
-def stack_requests(compiled):
-    return jax.tree_util.tree_map(
-        lambda *xs: np.stack(xs), *[c.request for c in compiled]
-    )
-
-
-def lane_operands(b, n, deltas=None, penalties=None, tg_counts=None,
-                  max_deltas=4, n_classes=2):
-    """Dense per-lane operand slab with optional per-lane overrides.
-
-    deltas: {lane: [(row, (cpu, mem, disk)), ...]} in-flight deltas;
-    penalties: {lane: [row, ...]}; tg_counts: {lane: {row: count}}.
-    """
-    drows = np.full((b, max_deltas), -1, np.int32)
-    dvals = np.zeros((b, max_deltas, 3), np.float32)
-    for lane, items in (deltas or {}).items():
-        for j, (row, vals) in enumerate(items):
-            drows[lane, j] = row
-            dvals[lane, j] = vals
-    pen = np.zeros((b, n), bool)
-    for lane, rows in (penalties or {}).items():
-        pen[lane, list(rows)] = True
-    tg = np.zeros((b, n), np.int32)
-    for lane, counts in (tg_counts or {}).items():
-        for row, c in counts.items():
-            tg[lane, row] = c
-    sc = np.zeros((b, MAX_SPREADS, MAX_SPREAD_VALUES), np.float32)
-    ce = np.ones((b, max(2, n_classes)), bool)
-    hm = np.ones((b, n), bool)
-    return drows, dvals, tg, sc, pen, ce, hm
+def host_view(arrays):
+    """The device snapshot as numpy arrays, for the numpy twin."""
+    return type(arrays)(*[np.asarray(x) for x in arrays])
 
 
 def steps_of(lane_mask, scan):
@@ -117,39 +88,30 @@ def steps_of(lane_mask, scan):
 
 
 def run_both(m, compiled, scan=SCAN, lane_mask=None, **lanes_kw):
-    """Run the staged place_batch and the fused megakernel over the same
-    operands; returns (staged (B,P,7), fused (B,P,8)) as numpy."""
+    """Run each request alone (``solo_reference``) and the fused megakernel
+    over the same operands; returns (solo (B,P,7), fused (B,P,8)) as numpy."""
     arrays = m.sync()
-    n = arrays.used.shape[0]
+    ops = lane_operands(m, [c.request for c in compiled], **lanes_kw)
     b = len(compiled)
-    drows, dvals, tg, sc, pen, ce, hm = lane_operands(
-        b, n, n_classes=len(m.class_ids), **lanes_kw
-    )
-    reqs = stack_requests(compiled)
     lm = np.ones((b,), bool) if lane_mask is None else np.asarray(lane_mask)
-    ls = steps_of(lm, scan)
-    staged = np.asarray(place_batch(
-        arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
-        n_placements=scan,
-    ))
+    solo = solo_reference(arrays, ops, scan)
     fused = np.asarray(fused_place_batch(
-        arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm, ls,
-        n_placements=scan,
+        arrays, arrays.used, *ops, steps_of(lm, scan), n_placements=scan,
     ))
-    return staged, fused
+    return solo, fused
 
 
-def assert_staged_columns_match(staged, fused, lane_mask=None):
-    """The fused kernel's first 7 columns must equal the staged kernel's
-    on every live lane — same feasibility, scores, evict decisions."""
-    b = staged.shape[0]
+def assert_solo_columns_match(solo, fused, lane_mask=None):
+    """The fused kernel's first 7 columns must equal the solo scan's on
+    every live lane — same feasibility, scores, evict decisions."""
+    b = solo.shape[0]
     live = np.ones((b,), bool) if lane_mask is None else np.asarray(lane_mask)
-    assert fused.shape == (b, staged.shape[1], FUSED_PACKED_WIDTH)
+    assert fused.shape == (b, solo.shape[1], FUSED_PACKED_WIDTH)
     np.testing.assert_array_equal(
-        fused[live, :, 0].astype(np.int32), staged[live, :, 0].astype(np.int32)
+        fused[live, :, 0].astype(np.int32), solo[live, :, 0].astype(np.int32)
     )
     np.testing.assert_allclose(
-        fused[live, :, 1:7], staged[live, :, 1:7], rtol=1e-6, atol=1e-6
+        fused[live, :, 1:7], solo[live, :, 1:7], rtol=1e-6, atol=1e-6
     )
 
 
@@ -218,17 +180,17 @@ def compile_lane_mix(m):
     return lanes
 
 
-class TestFusedVsStaged1K:
+class TestFusedVsSolo1K:
     def test_parity_on_seeded_cluster(self, cluster_1k):
         m, _ = cluster_1k
         compiled = compile_lane_mix(m)
-        staged, fused = run_both(
+        solo, fused = run_both(
             m, compiled,
             deltas={1: [(7, (900.0, 512.0, 0.0)), (11, (400.0, 0.0, 0.0))]},
             penalties={0: [3, 5], 3: [40]},
             tg_counts={4: {2: 1, 9: 2}},
         )
-        assert_staged_columns_match(staged, fused)
+        assert_solo_columns_match(solo, fused)
         # The mix must actually exercise the pipeline: placements landed...
         assert (fused[:, 0, 0] >= 0).all()
         # ...and every live placement carries a real verify verdict.
@@ -251,7 +213,7 @@ class TestFusedVsStaged1K:
 
 
 class TestPreemptionEvictSets:
-    def test_fused_preempts_like_staged(self):
+    def test_fused_preempts_like_solo(self):
         # Nodes saturated by low-priority work: only the preemption lane
         # can place, by evicting — parity including the preempted column.
         m = NodeMatrix(capacity=16)
@@ -269,8 +231,8 @@ class TestPreemptionEvictSets:
             enc.compile(lo, lo.task_groups[0]),
             enc.compile(hi, hi.task_groups[0], preemption_enabled=True),
         ]
-        staged, fused = run_both(m, compiled, scan=2)
-        assert_staged_columns_match(staged, fused)
+        solo, fused = run_both(m, compiled, scan=2)
+        assert_solo_columns_match(solo, fused)
         assert int(fused[0, 0, 0]) == -1  # no preemption → no room
         assert int(fused[1, 0, 0]) >= 0
         assert fused[1, 0, 3] == 1.0  # placed by evicting
@@ -379,23 +341,21 @@ class TestFakeDeviceTwinParity:
         m, _ = cluster_1k
         compiled = compile_lane_mix(m)
         arrays = m.sync()
-        n = arrays.used.shape[0]
         b = len(compiled)
         lm = np.ones((b,), bool)
         lm[3] = False
         deltas = {1: [(7, (900.0, 512.0, 0.0))]}
-        drows, dvals, tg, sc, pen, ce, hm = lane_operands(
-            b, n, deltas=deltas, penalties={0: [3, 5]},
-            n_classes=len(m.class_ids),
+        drows, dvals, tg, sc, pen, reqs, ce, hm = lane_operands(
+            m, [c.request for c in compiled], deltas=deltas,
+            penalties={0: [3, 5]},
         )
         kernel = np.asarray(fused_place_batch(
-            arrays, arrays.used, drows, dvals, tg, sc, pen,
-            stack_requests(compiled), ce, hm, steps_of(lm, SCAN),
-            n_placements=SCAN,
+            arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
+            steps_of(lm, SCAN), n_placements=SCAN,
         ))
-        arrays_np = type(arrays)(*[np.asarray(x) for x in arrays])
+        arrays_np = host_view(arrays)
         twin = fake_device.fused_place_batch(
-            arrays_np, np.asarray(arrays.used),
+            arrays_np, arrays_np.used,
             [drows[i] for i in range(b)], [dvals[i] for i in range(b)],
             [tg[i] for i in range(b)], [sc[i] for i in range(b)],
             [pen[i] for i in range(b)],
@@ -436,24 +396,23 @@ class TestLaneStepCounts:
 
     def _launch(self, m, n_live):
         """(lane_steps, fused kernel as a function of lane_steps, and the
-        twin / the staged kernel over the same operands, both lazy)."""
+        twin / the solo static scan over the same operands, both lazy)."""
         from nomad_tpu.scheduler.coalescer import lane_step_count
 
         mix = compile_lane_mix(m)
         compiled = (mix + mix)[: len(n_live)]
         arrays = m.sync()
-        b = len(compiled)
         steps = np.array(
             [0 if k is None else lane_step_count(k, FULL) for k in n_live],
             np.int32,
         )
-        drows, dvals, tg, sc, pen, ce, hm = lane_operands(
-            b, arrays.used.shape[0], n_classes=len(m.class_ids),
+        ops = lane_operands(
+            m, [c.request for c in compiled],
             deltas={1: [(7, (900.0, 512.0, 0.0)), (11, (400.0, 0.0, 0.0))],
                     6: [(272, (300.0, 100.0, 0.0))]},
             penalties={0: [3, 5]}, tg_counts={4: {2: 1, 9: 2}},
         )
-        reqs = stack_requests(compiled)
+        drows, dvals, tg, sc, pen, reqs, ce, hm = ops
 
         def kernel(ls):
             return np.asarray(fused_place_batch(
@@ -462,7 +421,7 @@ class TestLaneStepCounts:
             ))
 
         def twin():
-            arrays_np = type(arrays)(*[np.asarray(x) for x in arrays])
+            arrays_np = host_view(arrays)
             return fake_device.fused_place_batch(
                 arrays_np, arrays_np.used,
                 *[list(a) for a in (drows, dvals, tg, sc, pen)],
@@ -470,18 +429,15 @@ class TestLaneStepCounts:
                 steps > 0, n_placements=FULL, live_counts=list(steps),
             )
 
-        def staged():
-            return np.asarray(place_batch(
-                arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
-                n_placements=FULL,
-            ))
+        def solo():
+            return solo_reference(arrays, ops, FULL)
 
-        return steps, kernel, twin, staged
+        return steps, kernel, twin, solo
 
     @pytest.mark.parametrize("case", sorted(N_LIVE_CASES))
     def test_kernel_matches_twin_with_live_counts(self, cluster_1k, case):
         m, _ = cluster_1k
-        steps, kernel, twin, staged = self._launch(m, N_LIVE_CASES[case])
+        steps, kernel, twin, solo = self._launch(m, N_LIVE_CASES[case])
         got, twin = kernel(steps), twin()
         assert got.shape == twin.shape == (len(steps), FULL, FUSED_PACKED_WIDTH)
         # All eight columns: everything that decides or describes a
@@ -507,8 +463,8 @@ class TestLaneStepCounts:
             ).all()
         if (steps == FULL).all():
             # Nothing to cut: the placement columns are bit for bit the
-            # static 16-step scan's (place_batch still runs one).
-            np.testing.assert_array_equal(got[:, :, :7], staged())
+            # static 16-step scan's (place_task_group still runs one).
+            np.testing.assert_array_equal(got[:, :, :7], solo())
 
     @pytest.mark.parametrize(
         "case", [c for c in sorted(N_LIVE_CASES) if c != "all_16"]
@@ -537,13 +493,8 @@ class TestLaneStepCounts:
         small = make_job(cpu=300, mem=100)
         c = enc.compile(small, small.task_groups[0])
         arrays = m.sync()
-        n = arrays.used.shape[0]
-        drows, dvals, tg, sc, pen, ce, hm = lane_operands(
-            3, n, n_classes=len(m.class_ids)
-        )
         out = np.asarray(fused_place_batch(
-            arrays, arrays.used, drows, dvals, tg, sc, pen,
-            stack_requests([c, c, c]), ce, hm,
+            arrays, arrays.used, *lane_operands(m, [c.request] * 3),
             np.array([1, 4, 1], np.int32), n_placements=4,
         ))
         row = m.row_of[node.id]
@@ -561,6 +512,78 @@ class TestLaneStepCounts:
             kernel(np.ones((8,), bool))
 
 
+# ---------------------------------------------------------------------------
+# Each shape of the served traffic alone in a launch of the server's width
+# ---------------------------------------------------------------------------
+
+SERVED_LANES = 64  # ServerConfig.coalescer_lanes
+SERVED_SHAPES = [f"shape{i}" for i in range(8)] + ["preempting"]
+
+
+@pytest.fixture(scope="module")
+def served_shapes():
+    """A seeded simcluster matrix, ``simcluster.build_requests``' eight
+    shapes and one request that has to preempt (built as chip_smoke.py
+    builds its own)."""
+    from nomad_tpu import mock, simcluster
+
+    m = simcluster.build_cluster(480, 512, 96_000, seed=29)
+    shapes = simcluster.build_requests(m)
+    assert len(shapes) == 8
+    pjob = mock.job(priority=90)
+    pjob.task_groups[0].tasks[0].resources.cpu = 1400
+    pjob.task_groups[0].tasks[0].resources.memory_mb = 2600
+    preempting = RequestEncoder(m).compile(
+        pjob, pjob.task_groups[0], preemption_enabled=True
+    ).request
+    return m, dict(zip(SERVED_SHAPES, shapes + [preempting]))
+
+
+@pytest.mark.parametrize("shape", SERVED_SHAPES)
+def test_each_shape_alone_matches_solo(served_shapes, shape):
+    """One live lane among 63 dead ones, at FULL_FEATURES (one compile for
+    all nine): all eight columns are the request's own solo scan and the
+    numpy twin's.  A mix cannot show which shape a disagreement is in."""
+    m, by_name = served_shapes
+    req = by_name[shape]
+    arrays = m.sync()
+    # Lane 0 is the shape under test; the dead lanes hold another shape so
+    # that a leak across lanes would show.
+    other = by_name["shape0" if shape != "shape0" else "shape3"]
+    ops = lane_operands(m, [req] + [other] * (SERVED_LANES - 1))
+    ls = np.zeros((SERVED_LANES,), np.int32)
+    ls[0] = FULL
+    got = np.asarray(fused_place_batch(
+        arrays, arrays.used, *ops, ls, n_placements=FULL,
+        features=kernels.FULL_FEATURES,
+    ))
+    assert got.shape == (SERVED_LANES, FULL, FUSED_PACKED_WIDTH)
+    assert (got[0, :, 0] >= 0).all(), "the shape placed nothing"
+    if shape == "preempting":
+        assert (got[0, :, 3] == 1.0).any(), "never needed preemption"
+
+    solo = solo_reference(arrays, ops, FULL, lanes=[0])
+    np.testing.assert_array_equal(got[0, :, :7], solo[0])
+
+    drows, dvals, tg, sc, pen, _, ce, hm = ops
+    arrays_np = host_view(arrays)
+    twin = fake_device.fused_place_batch(
+        arrays_np, arrays_np.used, [drows[0]], [dvals[0]], [tg[0]], [sc[0]],
+        [pen[0]], [req], [ce[0]], [hm[0]], np.ones((1,), bool),
+        n_placements=FULL,
+    )
+    for col in (0, 3, 4, 5, 6, FUSED_PACKED_VERIFIED):
+        np.testing.assert_array_equal(
+            got[0, :, col], twin[0, :, col], err_msg=f"column {col}"
+        )
+    np.testing.assert_allclose(
+        got[0, :, 1:3], twin[0, :, 1:3], rtol=1e-5, atol=1e-5
+    )
+    # The dead lanes stayed dead.
+    assert (got[1:, :, 0] == -1.0).all()
+    assert (got[1:, :, FUSED_PACKED_VERIFIED] == -1.0).all()
+
+
 class TestFeaturesBucketing:
     def test_measured_features_match_full_decode(self, cluster_1k):
         """The occupancy-bucketed slim decode must score identically to
@@ -568,19 +591,15 @@ class TestFeaturesBucketing:
         m, _ = cluster_1k
         compiled = compile_lane_mix(m)
         arrays = m.sync()
-        n = arrays.used.shape[0]
-        b = len(compiled)
-        drows, dvals, tg, sc, pen, ce, hm = lane_operands(b, n)
-        reqs = stack_requests(compiled)
-        ls = np.full((b,), SCAN, np.int32)
-        feats = kernels.features_of(reqs)
+        ops = lane_operands(m, [c.request for c in compiled])
+        ls = np.full((len(compiled),), SCAN, np.int32)
+        feats = kernels.features_of(ops[5])
         full = np.asarray(fused_place_batch(
-            arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
-            ls, n_placements=SCAN, features=kernels.FULL_FEATURES,
+            arrays, arrays.used, *ops, ls, n_placements=SCAN,
+            features=kernels.FULL_FEATURES,
         ))
         slim = np.asarray(fused_place_batch(
-            arrays, arrays.used, drows, dvals, tg, sc, pen, reqs, ce, hm,
-            ls, n_placements=SCAN, features=feats,
+            arrays, arrays.used, *ops, ls, n_placements=SCAN, features=feats,
         ))
         np.testing.assert_array_equal(
             slim[:, :, 0].astype(np.int32), full[:, :, 0].astype(np.int32)

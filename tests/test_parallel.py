@@ -1,6 +1,6 @@
-"""Batched-eval kernel + multi-chip sharded step: the sharded program must
-agree exactly with the single-device batched program (tier-1 parity testing
-on the 8-device virtual CPU mesh)."""
+"""The batched placement program over a mesh: the sharded program must
+agree exactly with the single-device one (tier-1 parity testing on the
+8-device virtual CPU mesh)."""
 
 import json
 import os
@@ -16,6 +16,8 @@ from nomad_tpu import mock
 from nomad_tpu.ops import kernels
 from nomad_tpu.ops.encode import RequestEncoder
 from nomad_tpu.state.matrix import NodeMatrix
+
+from helpers import lane_operands
 
 
 def _cluster(n_nodes=32, capacity=64, seed=0):
@@ -37,125 +39,7 @@ def _cluster(n_nodes=32, capacity=64, seed=0):
     return m, nodes
 
 
-def _batched_inputs(m, job, b):
-    from nomad_tpu.parallel import build_batch_inputs
-
-    compiled = RequestEncoder(m).compile(job, job.task_groups[0])
-    return build_batch_inputs(m, [compiled.request] * b)
-
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-
-class TestScoreBatch:
-    def test_matches_sequential(self):
-        m, nodes = _cluster()
-        job = mock.job()
-        arrays = m.sync()
-        inp = _batched_inputs(m, job, 4)
-        out = kernels.score_batch(
-            arrays,
-            arrays.used,
-            inp["tg_counts"],
-            inp["spread_counts"],
-            inp["penalties"],
-            jax.tree_util.tree_map(jnp.asarray, inp["reqs"]),
-            inp["class_eligs"],
-            inp["host_masks"],
-        )
-        # Sequential reference: same inputs through score_nodes + argmax.
-        enc = RequestEncoder(m)
-        compiled = enc.compile(job, job.task_groups[0])
-        res = kernels.score_nodes(
-            arrays,
-            arrays.used,
-            inp["tg_counts"][0],
-            inp["spread_counts"][0],
-            inp["penalties"][0],
-            jax.tree_util.tree_map(jnp.asarray, compiled.request),
-            inp["class_eligs"][0],
-            inp["host_masks"][0],
-        )
-        want = int(np.argmax(np.asarray(res.final)))
-        rows = np.asarray(out.rows)
-        assert (rows == want).all()
-        assert np.asarray(out.scores)[0] == pytest.approx(
-            float(np.asarray(res.final)[want])
-        )
-
-    def test_no_fit_returns_minus_one(self):
-        m, _ = _cluster(n_nodes=2, capacity=8)
-        job = mock.job()
-        job.task_groups[0].tasks[0].resources.cpu = 10**9
-        arrays = m.sync()
-        inp = _batched_inputs(m, job, 2)
-        out = kernels.score_batch(
-            arrays,
-            arrays.used,
-            inp["tg_counts"],
-            inp["spread_counts"],
-            inp["penalties"],
-            jax.tree_util.tree_map(jnp.asarray, inp["reqs"]),
-            inp["class_eligs"],
-            inp["host_masks"],
-        )
-        assert (np.asarray(out.rows) == -1).all()
-
-
 class TestShardedStep:
-    def test_sharded_matches_batched(self, eight_devices):
-        from nomad_tpu.parallel import (
-            make_mesh,
-            shard_matrix_arrays,
-            sharded_schedule_step,
-        )
-
-        m, nodes = _cluster(n_nodes=48, capacity=64)
-        job = mock.job()
-        arrays = m.sync()
-        b = 4
-        inp = _batched_inputs(m, job, b)
-        reqs = jax.tree_util.tree_map(jnp.asarray, inp["reqs"])
-
-        ref = kernels.score_batch(
-            arrays,
-            arrays.used,
-            inp["tg_counts"],
-            inp["spread_counts"],
-            inp["penalties"],
-            reqs,
-            inp["class_eligs"],
-            inp["host_masks"],
-        )
-
-        mesh = make_mesh(8, batch=2)
-        sharded = shard_matrix_arrays(mesh, arrays)
-        step = sharded_schedule_step(mesh)
-        rows, scores, pre, evaluated, used_after = step(
-            sharded,
-            sharded.used,
-            inp["tg_counts"],
-            inp["spread_counts"],
-            inp["penalties"],
-            reqs,
-            inp["class_eligs"],
-            inp["host_masks"],
-        )
-        # Same winning score; row may differ only on exact ties.
-        np.testing.assert_allclose(
-            np.asarray(scores), np.asarray(ref.scores), rtol=1e-5
-        )
-        # The usage update accounts every pick exactly once.
-        asks = np.asarray(reqs.ask)
-        expect = np.asarray(arrays.used).copy()
-        for i, r in enumerate(np.asarray(rows)):
-            if r >= 0:
-                expect[r] += asks[i]
-        np.testing.assert_allclose(
-            np.asarray(used_after), expect, rtol=1e-5
-        )
-
     def test_mesh_factoring(self, eight_devices):
         from nomad_tpu.parallel import make_mesh
 
@@ -182,82 +66,6 @@ class TestMeshLayout:
         batch, node = mesh_layout(devices, capacity)
         assert (batch, node) == want
         assert batch * node == devices and capacity % node == 0
-
-
-class TestShardedPlaceBatch:
-    """The SPMD twin of the coalescer kernel must agree EXACTLY with the
-    single-device place_batch — rows included (pmin tie-break mirrors
-    argmax's lowest-index rule)."""
-
-    def _inputs(self, m, jobs, b, scan):
-        from nomad_tpu.parallel import build_batch_inputs, stack_requests
-
-        enc = RequestEncoder(m)
-        reqs = [
-            enc.compile(j, j.task_groups[0]).request
-            for j in jobs
-        ]
-        reqs = (reqs * ((b // len(reqs)) + 1))[:b]
-        inp = build_batch_inputs(m, reqs)
-        rng = np.random.default_rng(3)
-        k = 32
-        delta_rows = np.full((b, k), -1, np.int32)
-        delta_vals = np.zeros((b, k, 3), np.float32)
-        # A few random in-flight deltas per lane.
-        for i in range(b):
-            rows = rng.choice(48, size=3, replace=False)
-            delta_rows[i, :3] = rows
-            delta_vals[i, :3] = rng.uniform(0, 50, (3, 3))
-        return inp, delta_rows, delta_vals
-
-    def test_matches_single_device(self, eight_devices):
-        from nomad_tpu.parallel import make_mesh, shard_matrix_arrays
-        from nomad_tpu.parallel import sharded_place_batch
-
-        m, nodes = _cluster(n_nodes=48, capacity=64)
-        job1 = mock.job()
-        job2 = mock.job()
-        job2.task_groups[0].spreads = []
-        b, scan = 8, 4
-        inp, drows, dvals = self._inputs(m, [job1, job2], b, scan)
-        arrays = m.sync()
-        reqs = jax.tree_util.tree_map(jnp.asarray, inp["reqs"])
-
-        ref = kernels.place_batch(
-            arrays, arrays.used, drows, dvals,
-            inp["tg_counts"], inp["spread_counts"], inp["penalties"],
-            reqs, inp["class_eligs"], inp["host_masks"],
-            n_placements=scan,
-        )
-
-        mesh = make_mesh(8, batch=2)
-        sharded = shard_matrix_arrays(mesh, arrays)
-        fn = sharded_place_batch(mesh, scan)
-        out = fn(
-            sharded, sharded.used, drows, dvals,
-            inp["tg_counts"], inp["spread_counts"], inp["penalties"],
-            reqs, inp["class_eligs"], inp["host_masks"],
-        )
-        ref_np = np.asarray(ref)
-        out_np = np.asarray(out)
-        # Rows/preempt flags/diagnostic counts are exact; scores to fp
-        # tolerance (cross-shard reduction order differs).
-        np.testing.assert_array_equal(
-            out_np[:, :, kernels.PACKED_ROW], ref_np[:, :, kernels.PACKED_ROW]
-        )
-        np.testing.assert_array_equal(
-            out_np[:, :, kernels.PACKED_PREEMPT],
-            ref_np[:, :, kernels.PACKED_PREEMPT],
-        )
-        for col in (kernels.PACKED_EVALUATED, kernels.PACKED_FILTERED,
-                    kernels.PACKED_EXHAUSTED):
-            np.testing.assert_array_equal(
-                out_np[:, :, col], ref_np[:, :, col]
-            )
-        np.testing.assert_allclose(
-            out_np[:, :, kernels.PACKED_SCORE],
-            ref_np[:, :, kernels.PACKED_SCORE], rtol=1e-5, atol=1e-6,
-        )
 
 
 class TestMultichipLiveServer:
@@ -318,17 +126,18 @@ class TestShardedFusedParity:
     MESHES = ((1, 1), (2, 1), (4, 2))
 
     def _deltas(self, b, n_nodes):
+        """A few random in-flight deltas per lane, as GLOBAL rows: each
+        shard has to apply the slice it owns."""
         rng = np.random.default_rng(3)
-        drows = np.full((b, 32), -1, np.int32)
-        dvals = np.zeros((b, 32, 3), np.float32)
-        for i in range(b):
-            rows = rng.choice(n_nodes, size=3, replace=False)
-            drows[i, :3] = rows
-            dvals[i, :3] = rng.uniform(0, 50, (3, 3))
-        return drows, dvals
+        return {
+            i: list(zip(
+                rng.choice(n_nodes, size=3, replace=False).tolist(),
+                rng.uniform(0, 50, (3, 3)).tolist(),
+            ))
+            for i in range(b)
+        }
 
-    def _ref_and_sharded(self, m, inp, drows, dvals, steps, scan,
-                         nshards, batch):
+    def _ref_and_sharded(self, m, ops, steps, scan, nshards, batch):
         from nomad_tpu.parallel import (
             make_mesh,
             shard_matrix_arrays,
@@ -336,19 +145,13 @@ class TestShardedFusedParity:
         )
 
         arrays = m.sync()
-        reqs = jax.tree_util.tree_map(jnp.asarray, inp["reqs"])
         ref = kernels.fused_place_batch(
-            arrays, arrays.used, drows, dvals, inp["tg_counts"],
-            inp["spread_counts"], inp["penalties"], reqs,
-            inp["class_eligs"], inp["host_masks"], steps,
-            n_placements=scan,
+            arrays, arrays.used, *ops, steps, n_placements=scan,
         )
         mesh = make_mesh(nshards, batch=batch)
         sharded = shard_matrix_arrays(mesh, arrays)
         out = sharded_fused_place_batch(mesh, scan)(
-            sharded, sharded.used, drows, dvals, inp["tg_counts"],
-            inp["spread_counts"], inp["penalties"], reqs,
-            inp["class_eligs"], inp["host_masks"], steps,
+            sharded, sharded.used, *ops, steps,
         )
         return np.asarray(ref), out
 
@@ -389,14 +192,12 @@ class TestShardedFusedParity:
         reqs_list = [
             enc.compile(j, j.task_groups[0]).request for j in (job1, job2)
         ]
-        from nomad_tpu.parallel import build_batch_inputs
-
-        inp = build_batch_inputs(m, (reqs_list * 4)[:b])
-        drows, dvals = self._deltas(b, 48)
-        ls = np.array(self.STEPS[steps], np.int32)
-        ref, out = self._ref_and_sharded(
-            m, inp, drows, dvals, ls, scan, nshards, batch
+        ops = lane_operands(
+            m, (reqs_list * 4)[:b], deltas=self._deltas(b, 48),
+            max_deltas=32,
         )
+        ls = np.array(self.STEPS[steps], np.int32)
+        ref, out = self._ref_and_sharded(m, ops, ls, scan, nshards, batch)
         assert (ref[ls == 0, :, kernels.PACKED_ROW] == -1).all()
         for lane, k in enumerate(ls):
             assert (ref[lane, :k, kernels.PACKED_ROW] >= 0).all()
@@ -419,14 +220,9 @@ class TestShardedFusedParity:
         job.task_groups[0].tasks[0].resources.memory_mb = 900
         b, scan = 8, 2
         req = RequestEncoder(m).compile(job, job.task_groups[0]).request
-        from nomad_tpu.parallel import build_batch_inputs
-
-        inp = build_batch_inputs(m, [req] * b)
-        drows = np.full((b, 4), -1, np.int32)
-        dvals = np.zeros((b, 4, 3), np.float32)
         ls = np.array([2, 1, 2, 2, 1, 2, 2, 2], np.int32)
         ref, out = self._ref_and_sharded(
-            m, inp, drows, dvals, ls, scan, nshards, batch
+            m, lane_operands(m, [req] * b), ls, scan, nshards, batch
         )
         assert (ref[:, :, kernels.FUSED_PACKED_VERIFIED] == 0.0).any(), (
             "conflict case produced no rejections — test lost its teeth"

@@ -1,8 +1,10 @@
 #!/usr/bin/env python
 """Bench regression ledger — normalize, baseline, verdict.
 
-Every ``bench.py`` run (and the committed ``BENCH_*.json`` snapshots from
-earlier rounds) is normalized into one line of ``BENCH_LEDGER.jsonl``:
+Any JSON result file (a flat dict of numbers, or a driver wrapper around
+one) is normalized into one line of ``BENCH_LEDGER.jsonl``.  The harness
+that used to feed it is gone; the driver's own record of the benchmark is
+``PERF_LEDGER.jsonl``, which this tool neither reads nor writes:
 
     {"ts": ..., "source": "...", "ok": true,
      "metrics": {"eval_throughput": 969.5, "p99_ms": 266.0, ...},
@@ -14,7 +16,7 @@ Two input shapes are understood:
   is the bench's JSON stdout line (None when the run crashed; the entry
   is kept with ``ok: false`` so the ledger records the failure, but it
   contributes nothing to baselines);
-* a flat result dict straight from ``bench.py`` (numeric leaves become
+* a flat result dict (numeric leaves become
   metrics; a ``{"metric": name, "value": v}`` pair is folded to
   ``name: v``).
 
@@ -40,9 +42,6 @@ CLI:
     python tools/bench_history.py ingest BENCH_*.json   # seed/extend ledger
     python tools/bench_history.py record result.json    # one run + verdicts
     python tools/bench_history.py report [--last N]     # recent verdicts
-
-``bench.py`` calls :func:`record_run` at the end of ``main()`` so the
-ledger and verdict lines ride along with every local run.
 """
 
 from __future__ import annotations
@@ -265,11 +264,11 @@ def format_verdicts(entry: Dict[str, Any]) -> List[str]:
 
 def record_run(
     result: Dict[str, Any],
-    source: str = "bench.py",
+    source: str = "result",
     ledger: str = DEFAULT_LEDGER,
 ) -> Dict[str, Any]:
     """Normalize one run, judge it against the ledger, append, return
-    the entry (with ``verdicts``).  The hook ``bench.py`` calls."""
+    the entry (with ``verdicts``)."""
     history = read_ledger(ledger)
     entry = normalize(result, source=source)
     entry["verdicts"] = judge_entry(entry, history)
