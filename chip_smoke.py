@@ -163,11 +163,20 @@ def _compare_packed(name: str, dev, twin, fused: bool) -> dict:
         ).max()
     )
     check(score_err < 1e-4, f"{name}: scores off by {score_err}")
-    return {
+    stats = {
         "placed": int((dev[..., K.PACKED_ROW] >= 0).sum()),
         "preempting": int((dev[..., K.PACKED_PREEMPT] != 0).sum()),
         "max_score_err": score_err,
     }
+    if fused:
+        # The in-launch resolution at work: picks moved off a node that
+        # earlier lanes had claimed, and conflicts left to the applier.
+        placed = dev[..., K.PACKED_ROW] >= 0
+        stats["repicked"] = int((dev[..., K.FUSED_PACKED_VERIFIED] == 2.0).sum())
+        stats["unresolved"] = int(
+            (placed & (dev[..., K.FUSED_PACKED_VERIFIED] == 0.0)).sum()
+        )
+    return stats
 
 
 def _same_bits(a, b) -> bool:
@@ -308,10 +317,18 @@ def library_level(meter: CompileMeter, sizes, seed: int,
         not results["fused_place_batch_mixed_steps"]["compiles"],
         "library: step counts compiled the fused kernel again",
     )
+    vcol = kernels.FUSED_PACKED_VERIFIED
     for lane, n in enumerate(ls_mixed):
         # Same work: the steps a lane asked for are bit for bit those of
-        # the full-length launch (the verdict column alone may differ: the
-        # other lanes commit fewer placements before it).
+        # the full-length launch, up to the step at which the lane's pick
+        # first depends on the other lanes in either (VERIFIED 2.0: they
+        # had claimed the room its own pick needed, and in the full-length
+        # launch they claim more; 0.0: the same, and the node it moved to
+        # does not verify, as a preempting pick never does).  The verdict
+        # column alone may differ before that.
+        for launch in (mixed, fused):
+            hit = np.flatnonzero(launch[lane, :n, vcol] != 1.0)
+            n = min(n, int(hit[0])) if len(hit) else n
         check(
             _same_bits(mixed[lane, :n, :7], fused[lane, :n, :7]),
             f"library: lane {lane}'s first {n} rows differ from the "
@@ -815,6 +832,7 @@ def live_level(meter: CompileMeter, sizes, seed: int) -> dict:
             "solo_ops": coal.solo_ops,
             "stale_dispatches": coal.stale_dispatches,
             "verify_conflicts": coal.verify_conflicts,
+            "lane_repicks": coal.lane_repicks,
             "feature_recompiles": coal.feature_recompiles,
             "wedged_dispatches": coal.wedged_dispatches,
             "full_uploads": mx.full_uploads,

@@ -505,119 +505,113 @@ class _TotalsView(NamedTuple):
     totals: np.ndarray
 
 
-def _place_scan(arrays, req: SchedRequest, used0, tg_count, spread_counts,
-                penalty_mask, class_elig, host_mask,
-                n_placements: int) -> np.ndarray:
-    """Twin of kernels._place_scan; returns packed (n_placements, 7) f32."""
-    sp = _static_parts(arrays, req, penalty_mask, class_elig, host_mask)
-    used = np.array(used0, np.float32, copy=True)
-    tg = np.array(tg_count, np.int32, copy=True)
-    s_hash = np.array(req.s_value_hash, copy=True)
-    s_counts = np.array(spread_counts, np.float32, copy=True)
+class _LaneScan:
+    """One request's placement scan, a step at a time: ``final`` is the
+    step's score vector, ``commit(row)`` charges the pick and returns the
+    step's seven packed columns.  Twin of the two halves of a kernel step
+    (kernels._score_step / _commit_step); the solo scan takes the arg-max
+    between them, the fused twin resolves the lanes' picks there.
 
-    out = np.zeros((n_placements, 7), np.float32)
-    if not (np.asarray(req.s_slot) >= 0).any():
-        return _place_scan_incremental(arrays, req, sp, used, tg, out)
+    Without spread stanzas every node is scored once and only the placed
+    row is rescored between steps (the carry changes nowhere else; the
+    single-row rescore runs the same float32 expressions on 1-element
+    slices, so the outputs are identical to a full recompute).  Spread
+    stanzas shift every node's score when a placement bumps a value
+    count: full recompute per step."""
 
-    # Spread stanzas shift every node's score when a placement bumps a value
-    # count, so there is no single-row shortcut — full recompute per step.
-    step = 0
-    while step < n_placements:
-        req_step = req._replace(s_value_hash=s_hash)
-        final, needs_pre, binpack, n_eval, n_filt, n_exh = _score_step(
-            arrays, req_step, sp, used, tg, s_counts
+    def __init__(self, arrays, req: SchedRequest, used0, tg_count,
+                 spread_counts, penalty_mask, class_elig, host_mask):
+        self.arrays, self.req = arrays, req
+        self.sp = sp = _static_parts(
+            arrays, req, penalty_mask, class_elig, host_mask
         )
-        row = int(np.argmax(final))
-        ok = final[row] > NEG_INF / 2
-        if not ok:
-            # Failed step leaves the carry unchanged — every remaining step
-            # is byte-identical; replicate instead of recomputing.
-            out[step:, :] = (-1.0, 0.0, 0.0, 0.0, n_eval, n_filt, n_exh)
-            break
-        out[step] = (
+        self.used = np.array(used0, np.float32, copy=True)
+        self.tg = np.array(tg_count, np.int32, copy=True)
+        self.spreads = bool((np.asarray(req.s_slot) >= 0).any())
+        if self.spreads:
+            self.s_hash = np.array(req.s_value_hash, copy=True)
+            self.s_counts = np.array(spread_counts, np.float32, copy=True)
+            self._rescore_all()
+            return
+        f32 = np.float32
+        self.feas = sp.feas & ~(self.tg > 0) if sp.distinct else sp.feas
+        fits, self.binpack, _ = fit_and_binpack(arrays, self.used, req)
+        util = self.used + sp.ask[None, :]
+        fwp = np.all(util - sp.extra_free <= arrays.totals, axis=1)
+        self.needs_pre = ~fits & fwp & sp.pre_usable
+        self.fits_all = fits | self.needs_pre
+        aa_score, aa_app = anti_affinity_score(self.tg, req)
+        pre_component = np.where(self.needs_pre, sp.pre_score, 0.0)
+        total = (
+            self.binpack + aa_score + sp.pen_score + sp.aff_score
+            + pre_component
+        )
+        count = (
+            1.0
+            + aa_app.astype(f32)
+            + sp.pen_app.astype(f32)
+            + sp.aff_app.astype(f32)
+            + self.needs_pre.astype(f32)
+        )
+        self.final = np.where(
+            self.feas & self.fits_all, total / count, NEG_INF
+        ).astype(f32)
+        self.n_eval = int(np.sum(self.feas))
+        self.n_filt = int(np.sum(~self.feas & arrays.eligible))
+        self.n_exh = int(np.sum(self.feas & ~self.fits_all))
+
+    def _rescore_all(self):
+        self.req_step = self.req._replace(s_value_hash=self.s_hash)
+        (self.final, self.needs_pre, self.binpack, self.n_eval, self.n_filt,
+         self.n_exh) = _score_step(
+            self.arrays, self.req_step, self.sp, self.used, self.tg,
+            self.s_counts,
+        )
+
+    def failed_row(self) -> tuple:
+        """The packed columns of a step in which no node can take the
+        request (the carry stays as it is, so every later step reads the
+        same)."""
+        return (-1.0, 0.0, 0.0, 0.0, self.n_eval, self.n_filt, self.n_exh)
+
+    def commit(self, row: int) -> tuple:
+        arrays, req, sp = self.arrays, self.req, self.sp
+        out = (
             row,
-            final[row],
-            binpack[row],
-            1.0 if needs_pre[row] else 0.0,
-            n_eval,
-            n_filt,
-            n_exh,
+            self.final[row],
+            self.binpack[row],
+            1.0 if self.needs_pre[row] else 0.0,
+            self.n_eval,
+            self.n_filt,
+            self.n_exh,
         )
-        used[row] += sp.ask
-        tg[row] += 1
-        nvalues = arrays.attr_hash[
-            row, np.maximum(np.asarray(req_step.s_slot), 0)
-        ]
-        _apply_spread_values(req_step, s_hash, s_counts, nvalues)
-        step += 1
-    return out
+        self.used[row] += sp.ask
+        self.tg[row] += 1
+        if self.spreads:
+            nvalues = arrays.attr_hash[
+                row, np.maximum(np.asarray(self.req_step.s_slot), 0)
+            ]
+            _apply_spread_values(
+                self.req_step, self.s_hash, self.s_counts, nvalues
+            )
+            self._rescore_all()
+            return out
 
-
-def _place_scan_incremental(arrays, req: SchedRequest, sp: _StaticParts,
-                            used, tg, out) -> np.ndarray:
-    """No-spread scan: score every node once, then rescore only the placed
-    row between steps (the carry changes nowhere else).  The single-row
-    rescore runs the same float32 expressions on 1-element slices, so the
-    packed output is identical to the full per-step recompute."""
-    f32 = np.float32
-    feas = sp.feas & ~(tg > 0) if sp.distinct else sp.feas
-    fits, binpack, _ = fit_and_binpack(arrays, used, req)
-    util = used + sp.ask[None, :]
-    fwp = np.all(util - sp.extra_free <= arrays.totals, axis=1)
-    needs_pre = ~fits & fwp & sp.pre_usable
-    fits_all = fits | needs_pre
-    aa_score, aa_app = anti_affinity_score(tg, req)
-    pre_component = np.where(needs_pre, sp.pre_score, 0.0)
-    total = (
-        binpack + aa_score + sp.pen_score + sp.aff_score + pre_component
-    )
-    count = (
-        1.0
-        + aa_app.astype(f32)
-        + sp.pen_app.astype(f32)
-        + sp.aff_app.astype(f32)
-        + needs_pre.astype(f32)
-    )
-    final = np.where(feas & fits_all, total / count, NEG_INF).astype(f32)
-    n_eval = int(np.sum(feas))
-    n_filt = int(np.sum(~feas & arrays.eligible))
-    n_exh = int(np.sum(feas & ~fits_all))
-
-    n_placements = out.shape[0]
-    step = 0
-    while step < n_placements:
-        row = int(np.argmax(final))
-        if not final[row] > NEG_INF / 2:
-            out[step:, :] = (-1.0, 0.0, 0.0, 0.0, n_eval, n_filt, n_exh)
-            break
-        out[step] = (
-            row,
-            final[row],
-            binpack[row],
-            1.0 if needs_pre[row] else 0.0,
-            n_eval,
-            n_filt,
-            n_exh,
-        )
-        step += 1
-        if step >= n_placements:
-            break
-
-        used[row] += sp.ask
-        tg[row] += 1
-        old_feas = bool(feas[row])
-        old_open = old_feas and not bool(fits_all[row])
+        f32 = np.float32
+        old_feas = bool(self.feas[row])
+        old_open = old_feas and not bool(self.fits_all[row])
         if sp.distinct:
-            feas = feas.copy() if feas is sp.feas else feas
-            feas[row] = False
+            if self.feas is sp.feas:
+                self.feas = self.feas.copy()
+            self.feas[row] = False
         r = slice(row, row + 1)
         fits_r, bin_r, _ = fit_and_binpack(_TotalsView(arrays.totals[r]),
-                                           used[r], req)
-        util_r = used[r] + sp.ask[None, :]
+                                           self.used[r], req)
+        util_r = self.used[r] + sp.ask[None, :]
         fwp_r = np.all(util_r - sp.extra_free[r] <= arrays.totals[r], axis=1)
         np_r = ~fits_r & fwp_r & sp.pre_usable[r]
         fa_r = fits_r | np_r
-        aa_r, aa_app_r = anti_affinity_score(tg[r], req)
+        aa_r, aa_app_r = anti_affinity_score(self.tg[r], req)
         pre_r = np.where(np_r, sp.pre_score[r], 0.0)
         tot_r = bin_r + aa_r + sp.pen_score[r] + sp.aff_score[r] + pre_r
         cnt_r = (
@@ -627,18 +621,40 @@ def _place_scan_incremental(arrays, req: SchedRequest, sp: _StaticParts,
             + sp.aff_app[r].astype(f32)
             + np_r.astype(f32)
         )
-        fin_r = np.where(feas[r] & fa_r, tot_r / cnt_r, NEG_INF).astype(f32)
-        binpack[row] = bin_r[0]
-        needs_pre[row] = np_r[0]
-        fits_all[row] = fa_r[0]
-        final[row] = fin_r[0]
+        fin_r = np.where(
+            self.feas[r] & fa_r, tot_r / cnt_r, NEG_INF
+        ).astype(f32)
+        self.binpack[row] = bin_r[0]
+        self.needs_pre[row] = np_r[0]
+        self.fits_all[row] = fa_r[0]
+        self.final[row] = fin_r[0]
 
-        new_feas = bool(feas[row])
+        new_feas = bool(self.feas[row])
         if new_feas != old_feas:
-            n_eval += 1 if new_feas else -1
+            self.n_eval += 1 if new_feas else -1
             if bool(arrays.eligible[row]):
-                n_filt += -1 if new_feas else 1
-        n_exh += int(new_feas and not bool(fits_all[row])) - int(old_open)
+                self.n_filt += -1 if new_feas else 1
+        self.n_exh += (
+            int(new_feas and not bool(self.fits_all[row])) - int(old_open)
+        )
+        return out
+
+
+def _place_scan(arrays, req: SchedRequest, used0, tg_count, spread_counts,
+                penalty_mask, class_elig, host_mask,
+                n_placements: int) -> np.ndarray:
+    """Twin of kernels._place_scan; returns packed (n_placements, 7) f32."""
+    lane = _LaneScan(arrays, req, used0, tg_count, spread_counts,
+                     penalty_mask, class_elig, host_mask)
+    out = np.zeros((n_placements, 7), np.float32)
+    for step in range(n_placements):
+        row = int(np.argmax(lane.final))
+        if not lane.final[row] > NEG_INF / 2:
+            # Failed step leaves the carry unchanged — every remaining step
+            # is byte-identical; replicate instead of recomputing.
+            out[step:, :] = lane.failed_row()
+            break
+        out[step] = lane.commit(row)
     return out
 
 
@@ -695,56 +711,88 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
                       live_counts: Optional[List[int]] = None) -> np.ndarray:
     """Twin of kernels.fused_place_batch — (B, P, FUSED_PACKED_WIDTH) f32.
 
-    Adds the sequential cross-lane AllocsFit VERIFIED column on top of the
-    per-lane scans: lanes commit their in-flight deltas and placements to a
-    cumulative usage image in lane order, and each placement is checked
-    against it (1.0 fits, 0.0 an earlier lane claimed the capacity, -1.0
-    dead lane). ``lane_mask`` marks live lanes explicitly; dead lanes emit
-    row=-1 / zeros and touch nothing.
+    The lanes' scans run in lockstep.  Within a step the live lanes take
+    their picks in lane order against one image of the launch's claims
+    (the shared usage, every live lane's in-flight deltas, every pick so
+    far): the arg-max of the lane's own scores over the nodes where the
+    image has room for its ask (nodes it may take by preempting count as
+    having room), its own arg-max where none has.  Then the sequential
+    cross-lane AllocsFit VERIFIED column: lanes commit their in-flight
+    deltas and placements to a cumulative usage image in lane order, and
+    each placement is checked against it (1.0 fits on the lane's own
+    arg-max, 2.0 fits on a re-picked node, 0.0 an earlier lane claimed the
+    capacity, -1.0 dead lane). ``lane_mask`` marks live lanes explicitly;
+    dead lanes emit row=-1 / zeros and touch nothing.
 
     ``live_counts[i]`` is the kernel's ``lane_steps[i]`` for a live lane
     (None = all ``n_placements``; 0 = a dead lane): the lane's scan stops
     after that many steps and its tail rows are inert (row=-1, zeros,
-    verified=1.0, nothing added to the cumulative usage image) —
+    verified=1.0, nothing added to either usage image) —
     kernel-exact, tests/test_megakernel.py compares all eight columns.
     """
     b = len(reqs)
     lane_mask = np.asarray(lane_mask, bool)
     out = np.zeros((b, n_placements, FUSED_PACKED_WIDTH), np.float32)
-    cum_used = np.array(used, np.float32, copy=True)
-    for i in range(b):
-        steps = n_placements
-        if live_counts is not None:
-            steps = min(n_placements, int(live_counts[i]))
-        if not lane_mask[i] or steps <= 0:
-            out[i, :, 0] = -1.0
-            out[i, :, FUSED_PACKED_VERIFIED] = -1.0
-            continue
+    out[:, :, 0] = -1.0
+    steps = [
+        min(n_placements, int(live_counts[i]))
+        if live_counts is not None else n_placements
+        for i in range(b)
+    ]
+    live = [i for i in range(b) if lane_mask[i] and steps[i] > 0]
+    totals = arrays.totals
+    claims = np.array(used, np.float32, copy=True)
+    scans = {}
+    for i in live:
         drows = np.asarray(delta_rows[i])
         dvals = np.asarray(delta_vals[i])
-        live = drows >= 0
+        valid = drows >= 0
         used0 = used
-        if live.any():
+        if valid.any():
             used0 = used.copy()
-            np.add.at(used0, drows[live], dvals[live])
-        out[i, :steps, :7] = _place_scan(
+            np.add.at(used0, drows[valid], dvals[valid])
+            np.add.at(claims, drows[valid], dvals[valid])
+        scans[i] = _LaneScan(
             arrays, reqs[i], used0, tg_counts[i], spread_counts[i],
-            penalties[i], class_eligs[i], host_masks[i], steps,
+            penalties[i], class_eligs[i], host_masks[i],
         )
-        if steps < n_placements:
-            out[i, steps:, 0] = -1.0
-        # Sequential AllocsFit re-verify against the cumulative image.
-        if live.any():
-            np.add.at(cum_used, drows[live], dvals[live])
-        ask = np.asarray(reqs[i].ask, np.float32)
+    repicked = np.zeros((b, n_placements), bool)
+    for step in range(max((steps[i] for i in live), default=0)):
+        for i in live:
+            if step >= steps[i]:
+                continue
+            lane = scans[i]
+            own = int(np.argmax(lane.final))
+            if not lane.final[own] > NEG_INF / 2:
+                out[i, step, :7] = lane.failed_row()
+                continue
+            ask = lane.sp.ask
+            room = np.all(claims + ask[None, :] <= totals, axis=1)
+            masked = np.where(room | lane.needs_pre, lane.final, NEG_INF)
+            row = int(np.argmax(masked))
+            if not masked[row] > NEG_INF / 2:
+                row = own
+            repicked[i, step] = row != own
+            out[i, step, :7] = lane.commit(row)
+            claims[row] += ask
+    # Sequential AllocsFit re-verify against the cumulative image.
+    cum_used = np.array(used, np.float32, copy=True)
+    out[:, :, FUSED_PACKED_VERIFIED] = -1.0
+    for i in live:
+        drows = np.asarray(delta_rows[i])
+        valid = drows >= 0
+        if valid.any():
+            np.add.at(cum_used, drows[valid], np.asarray(delta_vals[i])[valid])
+        ask = scans[i].sp.ask
         for p in range(n_placements):
             row = int(out[i, p, 0])
             if row < 0:
                 out[i, p, FUSED_PACKED_VERIFIED] = 1.0
                 continue
             cum_used[row] += ask
-            out[i, p, FUSED_PACKED_VERIFIED] = float(
-                np.all(cum_used[row] <= arrays.totals[row])
+            fits = np.all(cum_used[row] <= totals[row])
+            out[i, p, FUSED_PACKED_VERIFIED] = (
+                (2.0 if repicked[i, p] else 1.0) if fits else 0.0
             )
     return out
 

@@ -644,22 +644,6 @@ def _update_spread_counts(spread_counts, req: SchedRequest, arrays, row):
     )
 
 
-def inert_step_outputs(n_placements: int) -> tuple:
-    """The stacked outputs of a placement scan in which no step ran: row
-    -1, zero scores, flags and node counts (what a failed-or-never-asked
-    placement reads, and what the numpy twin fills its tail rows with)."""
-    p = (n_placements,)
-    return (
-        jnp.full(p, -1, jnp.int32),
-        jnp.zeros(p, jnp.float32),
-        jnp.zeros(p, jnp.float32),
-        jnp.zeros(p, bool),
-        jnp.zeros(p, jnp.int32),
-        jnp.zeros(p, jnp.int32),
-        jnp.zeros(p, jnp.int32),
-    )
-
-
 def scan_steps(step, init, outs, trip):
     """``lax.scan(step, init, jnp.arange(P))`` cut off after ``trip`` steps
     (traced i32 scalar): step ``i``'s outputs land at index ``i`` of the
@@ -680,6 +664,50 @@ def scan_steps(step, init, outs, trip):
     return lax.fori_loop(0, trip, body, (init, tuple(outs)))
 
 
+def _score_step(arrays, req: SchedRequest, carry, penalty_mask, class_elig,
+                host_mask, features: Features):
+    """One placement step's scores from the scan's carry: (the request as
+    this step reads it, its ``ScoreResult``, the three node counts)."""
+    used, tg_cnt, s_hash, s_counts = carry
+    req_step = req._replace(s_value_hash=s_hash)
+    with jax.named_scope("score"):
+        res = score_nodes(
+            arrays, used, tg_cnt, s_counts, penalty_mask, req_step,
+            class_elig, host_mask, features,
+        )
+    with jax.named_scope("pick"):
+        counts = (
+            jnp.sum(res.feasible).astype(jnp.int32),
+            jnp.sum(~res.feasible & arrays.eligible).astype(jnp.int32),
+            jnp.sum(res.feasible & ~res.fits).astype(jnp.int32),
+        )
+    return req_step, res, counts
+
+
+def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
+                 counts, row, ok):
+    """Charge a step's pick (``row``; nothing where ``ok`` is false) to the
+    scan's carry; returns (carry, the step's seven output columns)."""
+    used, tg_cnt, s_hash, s_counts = carry
+    with jax.named_scope("update"):
+        safe_row = jnp.maximum(row, 0)
+        used2 = jnp.where(ok, used.at[safe_row].add(req_step.ask), used)
+        tg2 = jnp.where(ok, tg_cnt.at[safe_row].add(1), tg_cnt)
+        new_hash, new_counts = _update_spread_counts(
+            s_counts, req_step, arrays, safe_row
+        )
+        s_hash2 = jnp.where(ok, new_hash, s_hash)
+        s_counts2 = jnp.where(ok, new_counts, s_counts)
+
+    out = (
+        row,
+        jnp.where(ok, res.final[safe_row], 0.0),
+        jnp.where(ok, res.binpack[safe_row], 0.0),
+        ok & res.needs_preempt[safe_row],
+    ) + counts
+    return (used2, tg2, s_hash2, s_counts2), out
+
+
 def _place_scan(
     arrays,
     req: SchedRequest,
@@ -691,79 +719,28 @@ def _place_scan(
     host_mask,
     n_placements: int,
     features: Features = FULL_FEATURES,
-    n_steps=None,
-    trip=None,
 ) -> PlacementResult:
-    """Traceable core of the placement scan (shared by the solo
-    ``place_task_group`` jit and the fused kernel's ``vmap`` over lanes).
+    """Traceable core of the solo placement scan (``place_task_group``): a
+    static ``lax.scan`` of ``n_placements`` steps, each the arg-max of the
+    request's own scores.  The batched program runs the same two halves of
+    a step (``_score_step``, ``_commit_step``) with the lanes' picks
+    resolved between them (``_fused_place_batch_impl``)."""
 
-    ``n_steps`` (traced i32 scalar; None = all ``n_placements``, a static
-    ``lax.scan``: ``place_task_group`` alone runs that branch) is how many
-    placements this request asked for: steps past it place nothing and
-    report the inert row (-1, zeros).  ``trip``
-    is the loop bound when a ``vmap`` over requests shares one loop — the
-    largest ``n_steps`` of the batch, computed outside the ``vmap`` so the
-    loop's condition stays one scalar."""
-
-    def step(carry, i):
-        used, tg_cnt, s_hash, s_counts = carry
-        req_step = req._replace(s_value_hash=s_hash)
-        with jax.named_scope("score"):
-            res = score_nodes(
-                arrays, used, tg_cnt, s_counts, penalty_mask, req_step,
-                class_elig, host_mask, features,
-            )
+    def step(carry, _):
+        req_step, res, counts = _score_step(
+            arrays, req, carry, penalty_mask, class_elig, host_mask, features
+        )
         with jax.named_scope("pick"):
             row = jnp.argmax(res.final).astype(jnp.int32)
             ok = res.final[row] > NEG_INF / 2
-
-            n_eval = jnp.sum(res.feasible).astype(jnp.int32)
-            n_filtered = jnp.sum(
-                ~res.feasible & arrays.eligible
-            ).astype(jnp.int32)
-            n_exhausted = jnp.sum(res.feasible & ~res.fits).astype(jnp.int32)
-            if n_steps is not None:
-                # A lane that asked for fewer steps than the launch runs
-                # takes no placement here: no usage charged, inert row.
-                active = i < n_steps
-                ok = ok & active
-                n_eval = jnp.where(active, n_eval, 0)
-                n_filtered = jnp.where(active, n_filtered, 0)
-                n_exhausted = jnp.where(active, n_exhausted, 0)
             row = jnp.where(ok, row, -1)
-
-        with jax.named_scope("update"):
-            safe_row = jnp.maximum(row, 0)
-            used2 = jnp.where(ok, used.at[safe_row].add(req.ask), used)
-            tg2 = jnp.where(ok, tg_cnt.at[safe_row].add(1), tg_cnt)
-            new_hash, new_counts = _update_spread_counts(
-                s_counts, req_step, arrays, safe_row
-            )
-            s_hash2 = jnp.where(ok, new_hash, s_hash)
-            s_counts2 = jnp.where(ok, new_counts, s_counts)
-
-        out = (
-            row,
-            jnp.where(ok, res.final[safe_row], 0.0),
-            jnp.where(ok, res.binpack[safe_row], 0.0),
-            ok & res.needs_preempt[safe_row],
-            n_eval,
-            n_filtered,
-            n_exhausted,
-        )
-        return (used2, tg2, s_hash2, s_counts2), out
+        return _commit_step(arrays, req_step, carry, res, counts, row, ok)
 
     init = (used0, tg_count, req.s_value_hash, spread_counts)
     with jax.named_scope("place_scan"):
-        if n_steps is None:
-            (used_after, tg_after, _, _), outs = lax.scan(
-                step, init, None, length=n_placements
-            )
-        else:
-            (used_after, tg_after, _, _), outs = scan_steps(
-                step, init, inert_step_outputs(n_placements),
-                n_steps if trip is None else trip,
-            )
+        (used_after, tg_after, _, _), outs = lax.scan(
+            step, init, None, length=n_placements
+        )
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh = outs
     return PlacementResult(
         rows=rows,
@@ -827,11 +804,17 @@ PACKED_WIDTH = 7
 # ---------------------------------------------------------------------------
 
 # Columns of the fused kernel's packed output: the PACKED_WIDTH
-# per-placement columns above, then the VERIFIED column, which carries
-# the device-resident AllocsFit re-verify verdict per placement:
-#   1.0  placement survives the sequential cross-lane re-check
-#   0.0  placement would be rejected (an earlier lane's plan claims the
-#        capacity first, in resolve order — the applier will reject it)
+# per-placement columns above, then the VERIFIED column: the in-launch
+# pick resolution's outcome and the device-resident AllocsFit re-verify
+# verdict per placement:
+#   1.0  the lane's own arg-max, and it survives the sequential cross-lane
+#        re-check (also what an empty or never-asked-for slot reads)
+#   2.0  resolved: an earlier lane of this launch had claimed the room the
+#        lane's own arg-max needed, the lane took its best node that still
+#        fits under the launch's claims, and that survives the re-check
+#   0.0  placement would be rejected (no node fits under the claims, so
+#        the lane kept its unresolved pick, or a preempting pick: the
+#        applier decides)
 #  -1.0  not computed (dead/padded lane)
 FUSED_PACKED_VERIFIED = 7
 FUSED_PACKED_WIDTH = 8
@@ -852,18 +835,44 @@ def fused_trip_counts(lane_steps, n_placements: int):
     return trip, last_lane
 
 
+def claims_image(used, delta_rows, delta_vals, live):
+    """What the launch's lanes have claimed before any of them places: the
+    shared usage plus every live lane's in-flight deltas ((N, 3); the
+    resolution adds each pick's ask to it as the lanes take their turns).
+    With one live lane it is, bit for bit, that lane's own ``used0``."""
+    valid = (delta_rows >= 0) & live[:, None]  # (B, K)
+    add = jnp.where(valid[:, :, None], delta_vals, 0.0)
+    return used.at[jnp.maximum(delta_rows, 0).reshape(-1)].add(
+        add.reshape(-1, 3)
+    )
+
+
+def resolved_pick(final, room, own):
+    """A lane's pick under the launch's claims: the arg-max of its own
+    ``final`` over the rows with ``room``; its unresolved arg-max ``own``
+    where no feasible row has room (never an empty slot: the re-verify
+    then reads 0.0 and the applier decides)."""
+    masked = jnp.where(room, final, NEG_INF)
+    alt = jnp.argmax(masked).astype(jnp.int32)
+    return jnp.where(masked[alt] > NEG_INF / 2, alt, own)
+
+
 @jax.named_scope("pack")
 def pack_fused_lanes(
-    rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified, live
+    rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
+    repicked, live
 ):
     """Stack per-lane placement outputs into the fused (B, P, 8) layout with
-    dead-lane masking: row/-1, VERIFIED/-1.0, zeros elsewhere.  Shared by the
-    single-device fused kernel and the shard_map local body
+    dead-lane masking: row/-1, VERIFIED/-1.0, zeros elsewhere.  VERIFIED
+    reads 2.0 where the placement fits and the lane re-picked.  Shared by
+    the single-device fused kernel and the shard_map local body
     (parallel/sharding.py) so the two paths cannot drift column-wise —
     tests/test_parallel.py asserts bitwise parity across them.
     """
     lv = live[:, None]
-    vcol = jnp.where(lv, verified.astype(jnp.float32), -1.0)
+    fits = verified.astype(bool)
+    vcol = jnp.where(fits, jnp.where(repicked, 2.0, 1.0), 0.0)
+    vcol = jnp.where(lv, vcol, -1.0)
     return jnp.stack(
         [
             rows.astype(jnp.float32),
@@ -877,6 +886,25 @@ def pack_fused_lanes(
         ],
         axis=2,
     )  # (B, P, FUSED_PACKED_WIDTH)
+
+
+def inert_lane_outputs(lanes: int, n_placements: int) -> tuple:
+    """The stacked outputs of a launch in which no step ran, step-major
+    ((P, B): a step's outputs of all lanes land at one index): row -1,
+    zero scores, flags and node counts (what a failed-or-never-asked
+    placement reads, and what the numpy twin fills its tail rows with),
+    and the re-pick flag as an eighth buffer."""
+    shape = (n_placements, lanes)
+    return (
+        jnp.full(shape, -1, jnp.int32),
+        jnp.zeros(shape, jnp.float32),
+        jnp.zeros(shape, jnp.float32),
+        jnp.zeros(shape, bool),
+        jnp.zeros(shape, jnp.int32),
+        jnp.zeros(shape, jnp.int32),
+        jnp.zeros(shape, jnp.int32),
+        jnp.zeros(shape, bool),
+    )
 
 
 def _fused_place_batch_impl(
@@ -896,7 +924,8 @@ def _fused_place_batch_impl(
 ) -> jnp.ndarray:
     """The mega-batched ranking megakernel: B eval pipelines — feasibility →
     binpack → spread/affinity → preemption evict-state → placement scan —
-    PLUS the ``AllocsFit`` plan re-verify, in ONE launch.
+    with the lanes' picks resolved in lane order inside every placement
+    step, PLUS the ``AllocsFit`` plan re-verify, in ONE launch.
 
     Per-request args lead with a B axis.  ``delta_rows``/``delta_vals``
     ((B, K) i32 / (B, K, 3) f32, row -1 = padding) carry each request's
@@ -911,44 +940,109 @@ def _fused_place_batch_impl(
       from this operand: ``n_placements`` is only the static length of
       the output, so one compile serves every occupancy and every mix of
       counts.  A live lane's rows past its own count are inert (row -1,
-      zeros, VERIFIED 1.0) and charge nothing; its first rows are bit for
-      bit what a full-length launch gives.  Dead lanes produce row=-1 /
-      zero outputs and contribute nothing to the verify pass — no
-      host-side request-faking, no shape-polymorphic recompiles.
+      zeros, VERIFIED 1.0) and charge nothing.  Dead lanes produce row=-1 /
+      zero outputs and contribute nothing to the resolution or the verify
+      pass — no host-side request-faking, no shape-polymorphic recompiles.
+    * **In-launch pick resolution.**  Every lane scores the nodes against
+      its own proposed usage, exactly as alone.  Then, within a step, the
+      live lanes take their picks in lane order against one image of the
+      launch's claims (``claims_image``: the shared usage, every live
+      lane's in-flight deltas, every pick so far): a lane takes the
+      arg-max of its own scores over the nodes where that image still has
+      room for its ask, and adds its ask to the image.  So a lane passes
+      over a node only because lanes of this launch took the room it
+      needed, and then takes its best node that is left — exact arg-max,
+      float32 scores, nothing sampled.  If no feasible node has room under
+      the claims, the lane keeps its own arg-max (never an empty slot that
+      its own scores would fill: an empty slot reads "no node can take it"
+      on the host and blocks the eval).  Nodes a lane may only take by
+      preempting are never masked: eviction frees their room at apply
+      time.  A launch whose lanes' picks never overflow a node — one live
+      lane in particular — is bit for bit every lane's solo scan: the
+      behaviour follows from the picks and the claims, there is no switch.
     * The packed output's VERIFIED column is a device-resident
       sequential AllocsFit re-check of every lane's chosen placements
       against the authoritative matrix usage *plus all earlier lanes'
-      deltas and placements*, in lane (= resolve) order. Within one lane a
-      placement always fits its own proposed usage by construction; what
-      the scan cannot see is *other* lanes of the same launch claiming the
-      same capacity — exactly the conflicts the plan applier's
+      deltas and placements*, in lane (= resolve) order, the re-picked
+      ones included: 1.0 fits with the lane's own arg-max, 2.0 fits on
+      the node the lane re-picked, 0.0 would be rejected.  After the
+      resolution only an unresolved pick (no node left under the claims;
+      a pick that preempts; a later lane's pick on a node such a pick
+      overflowed) reads 0.0 — exactly the conflicts the plan applier's
       optimistic-concurrency re-verify (plan_apply.py:_evaluate) rejects
-      one plan-apply round-trip later. The verdicts are advisory (the
-      applier against live state stays authoritative; lanes whose
-      in-flight deltas overlap are re-checked conservatively), but at an
-      unchanged matrix version a 0.0 verdict is a guaranteed applier
-      rejection, surfaced hundreds of microseconds earlier and without a
-      single extra launch.
+      one plan-apply round-trip later.  The applier against live state
+      stays authoritative and serialized: launches in flight do not see
+      one another, and any lane's *stop* is credited to the claims before
+      its plan commits (the verify column, which adds deltas in lane
+      order, credits it to the later lanes only and reads 0.0 otherwise).
 
     Returns (B, n_placements, FUSED_PACKED_WIDTH) f32 — one fetch.
     """
     live = lane_steps > 0  # (B,)
     trip, last_lane = fused_trip_counts(lane_steps, n_placements)
+    lanes = lane_steps.shape[0]
 
-    def one(drows, dvals, tg, sc, pen, req, ce, hm, n_steps):
-        safe = jnp.maximum(drows, 0)
+    def lane_used0(drows, dvals):
         add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
-        used0 = used.at[safe].add(add)
-        return _place_scan(
-            arrays, req, used0, tg, sc, pen, ce, hm, n_placements, features,
-            n_steps=n_steps, trip=trip,
-        )
+        return used.at[jnp.maximum(drows, 0)].add(add)
 
-    res = jax.vmap(one)(
-        delta_rows, delta_vals, tg_counts, spread_counts, penalties, reqs,
-        class_eligs, host_masks, lane_steps,
+    def score(carry, pen, req, ce, hm):
+        req_step, res, counts = _score_step(
+            arrays, req, carry, pen, ce, hm, features
+        )
+        with jax.named_scope("pick"):
+            own = jnp.argmax(res.final).astype(jnp.int32)
+        return req_step, res, counts, own, res.final[own] > NEG_INF / 2
+
+    def commit(carry, req_step, res, counts, row, active):
+        counts = tuple(jnp.where(active, c, 0) for c in counts)
+        return _commit_step(arrays, req_step, carry, res, counts, row, row >= 0)
+
+    def step(state, i):
+        carry, claims = state
+        req_step, res, counts, own, own_ok = jax.vmap(score)(
+            carry, penalties, reqs, class_eligs, host_masks
+        )
+        # A lane that asked for fewer steps than the launch runs takes no
+        # placement here: no usage charged, inert row.
+        active = i < lane_steps  # (B,)
+
+        def take(b, picked):
+            claims, rows = picked
+            ok = own_ok[b] & active[b]
+            ask = reqs.ask[b]
+            room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
+            row = resolved_pick(
+                res.final[b], room | res.needs_preempt[b], own[b]
+            )
+            row = jnp.where(ok, row, -1)
+            return (
+                claims.at[jnp.maximum(row, 0)].add(jnp.where(ok, ask, 0.0)),
+                lax.dynamic_update_index_in_dim(rows, row, b, 0),
+            )
+
+        with jax.named_scope("pick"), jax.named_scope("resolve"):
+            claims, rows = lax.fori_loop(
+                0, last_lane, take,
+                (claims, jnp.full((lanes,), -1, jnp.int32)),
+            )
+        carry, out = jax.vmap(commit)(
+            carry, req_step, res, counts, rows, active
+        )
+        return (carry, claims), out + ((rows >= 0) & (rows != own),)
+
+    init = (
+        jax.vmap(lane_used0)(delta_rows, delta_vals),
+        tg_counts, reqs.s_value_hash, spread_counts,
     )
-    rows = jnp.where(live[:, None], res.rows, -1)  # (B, P)
+    with jax.named_scope("place_scan"):
+        _, outs = scan_steps(
+            step, (init, claims_image(used, delta_rows, delta_vals, live)),
+            inert_lane_outputs(lanes, n_placements), trip,
+        )
+    rows, scores, binpack, preempted, n_eval, n_filt, n_exh, repicked = (
+        o.T for o in outs
+    )  # each (B, P)
 
     # Sequential cross-lane AllocsFit: a loop over lanes carrying the
     # cumulative proposed usage. Each lane first applies its own in-flight
@@ -986,8 +1080,8 @@ def _fused_place_batch_impl(
         )  # (B, P) bool
 
     return pack_fused_lanes(
-        rows, res.scores, res.binpack, res.preempted, res.nodes_evaluated,
-        res.nodes_filtered, res.nodes_exhausted, verified, live,
+        rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
+        repicked, live,
     )
 
 

@@ -10,15 +10,18 @@ produces for this workload):
   *argmax* crosses shards (a ``pmax``/``pmin`` pair over ICI — the analog
   of a ring-attention score reduction).
 - ``batch`` — independent evaluations, sharded like data-parallel batches.
-  Each batch shard runs its own lanes' scans; the cross-lane verify
-  ``all_gather``s the winner rows, asks and in-flight deltas over the batch
-  axis so every replica replays all lanes in resolve order.
+  Each batch shard scores its own lanes; the in-launch pick resolution
+  and the cross-lane verify replay ALL lanes in resolve order on every
+  replica: asks, in-flight deltas and step counts are ``all_gather``ed over
+  the batch axis once a launch, and each lane's winner reaches every shard
+  through the election of its turn (a ``pmax`` / ``pmin`` over both axes).
 
 Reference behaviors preserved: the step scores all nodes per eval (replacing
 stack.go:78-91's candidate sampling), applies proposed usage like
 BinPackIterator's proposed-alloc accounting (rank.go:210-323), and leaves
-conflict resolution to the serialized plan applier (plan_apply.go:49-69) —
-batched picks are optimistic by design.
+conflict resolution ACROSS launches to the serialized plan applier
+(plan_apply.go:49-69) — batched picks are optimistic by design; only the
+lanes of one launch resolve their picks among themselves.
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ from ..ops.kernels import (
     NEG_INF,
     apply_spread_values,
     fused_trip_counts,
-    inert_step_outputs,
+    inert_lane_outputs,
     pack_fused_lanes,
     scan_steps,
     score_nodes,
@@ -219,7 +222,8 @@ def _fused_place_batch_local(
     features,
 ):
     """Per-shard body of ``kernels.fused_place_batch`` under a
-    ('batch', 'node') mesh — the full megakernel (ranking scan + cross-lane
+    ('batch', 'node') mesh — the full megakernel (ranking scan with the
+    lanes' picks resolved in lane order inside every step + cross-lane
     AllocsFit re-verify) with the node axis partitioned.
 
     Ranking is a hierarchical top-k: each shard scores only its local node
@@ -234,17 +238,25 @@ def _fused_place_batch_local(
     per-shard intermediates are (n_local,) and everything crossing the
     interconnect or reaching the host is O(B · P) or (shards, k).
 
-    The cross-lane verify gathers only winner rows + asks + in-flight
-    deltas over the batch axis (all O(B · P), node-shape-free), scans all B
-    lanes against the LOCAL (n_local, 3) usage slice with non-owned rows
-    vacuously fitting, and combines verdicts with a single ``pmin`` over
-    the node axis — each row's owner alone decides.
+    The resolution walks ALL B lanes in lane order on every shard, against
+    this shard's slice of the launch's claims image (every batch replica
+    holds the same slice and makes the same updates).  A lane's scores
+    live on one batch shard, so each turn elects the lane's best row with
+    room across the whole mesh (a ``pmax`` and a ``pmin`` over both axes;
+    the other batch shards offer nothing) — the winners every shard needs
+    for the verify are thereby already everywhere, and only asks, deltas
+    and step counts are gathered over 'batch', once, up front.
+
+    The cross-lane verify scans all B lanes against the LOCAL (n_local, 3)
+    usage slice with non-owned rows vacuously fitting, and combines
+    verdicts with a single ``pmin`` over the node axis — each row's owner
+    alone decides.
 
     Both loops run as many iterations as the launch's live lanes asked for
     (``lane_steps``, as in the single-device kernel).  Every step holds
-    collectives over 'node' and the verify replays all B lanes on every
-    shard, so the trip counts are taken over the WHOLE batch (the counts
-    are all_gathered over 'batch'): one number on every shard.
+    collectives and the walk and the verify visit all B lanes on every
+    shard, so the trip counts are taken over the WHOLE batch: one number
+    on every shard.
     """
     n_local = used.shape[0]
     shard = jax.lax.axis_index("node")
@@ -252,10 +264,17 @@ def _fused_place_batch_local(
     big = jnp.int32(2 ** 30)
     k = min(TOPK_K, n_local)
     live = lane_steps > 0  # (b_local,)
-    # Every all_gather over 'batch' carries one scope (the verify's own
-    # gathers are further down; XLA combines them with this one).
+    b_local = lane_steps.shape[0]
+    b_first = jax.lax.axis_index("batch") * b_local
+    # What every shard needs of every lane, gathered once (the winners
+    # themselves reach every shard through the resolution's elections).
     with jax.named_scope("verify_scan"), jax.named_scope("gather"):
         g_steps = jax.lax.all_gather(lane_steps, "batch", tiled=True)  # (B,)
+        g_ask = jax.lax.all_gather(reqs.ask, "batch", tiled=True)  # (B, 3)
+        g_drows = jax.lax.all_gather(delta_rows, "batch", tiled=True)  # (B, K)
+        g_dvals = jax.lax.all_gather(delta_vals, "batch", tiled=True)
+    g_live = g_steps > 0  # (B,)
+    lanes = g_steps.shape[0]
     trip, last_lane = fused_trip_counts(g_steps, n_placements)
 
     def vary(x, axes=("batch",)):
@@ -265,131 +284,171 @@ def _fused_place_batch_local(
         # over those axes up front — a typing formality.
         return jax.lax.pcast(x, axes, to="varying")
 
-    def one(drows, dvals, tg, sc, pen, req, ce, hm, n_steps):
-        local = drows - row_offset
-        mine = (drows >= 0) & (local >= 0) & (local < n_local)
-        safe = jnp.clip(local, 0, n_local - 1)
-        used0 = used.at[safe].add(jnp.where(mine[:, None], dvals, 0.0))
+    def local_rows(rows):
+        """(global rows (...,)) -> (owned by this shard, local index)."""
+        local = rows - row_offset
+        return (rows >= 0) & (local >= 0) & (local < n_local), jnp.clip(
+            local, 0, n_local - 1
+        )
 
-        def step(carry, i):
-            u, tg_cnt, s_hash, s_counts = carry
-            active = i < n_steps
-            req_step = req._replace(s_value_hash=s_hash)
-            with jax.named_scope("score"):
-                res = score_nodes(
-                    arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
-                    features=features,
-                )
-            # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
-            # then a cross-shard reduce of the implicit (shards, k) table —
-            # pmax elects the winning score, pmin the lowest owning row.
-            with jax.named_scope("pick"):
-                vals, idxs = jax.lax.top_k(res.final, k)
-                with jax.named_scope("elect"):
-                    best = jax.lax.pmax(vals[0], "node")
-                ok = (best > NEG_INF / 2) & active
-                cand = jnp.where(
-                    vals == best, row_offset + idxs.astype(jnp.int32), big
-                )
-                # lowest row on ties
-                with jax.named_scope("elect"):
-                    grow = jax.lax.pmin(jnp.min(cand), "node")
-                grow = jnp.where(ok, grow, -1)
-                owner = (
-                    ok & (grow >= row_offset) & (grow < row_offset + n_local)
-                )
-                lwin = jnp.clip(grow - row_offset, 0, n_local - 1)
+    def add_deltas(image, drows, dvals, valid):
+        mine, safe = local_rows(drows)
+        return image.at[safe.reshape(-1)].add(
+            jnp.where((mine & valid)[..., None], dvals, 0.0).reshape(-1, 3)
+        )
 
-                n_feasible = jnp.sum(res.feasible.astype(jnp.int32))
-                n_filtered = jnp.sum(
-                    (~res.feasible & arrays.eligible).astype(jnp.int32)
-                )
-                n_exhausted = jnp.sum(
-                    (res.feasible & ~res.fits).astype(jnp.int32)
-                )
-                with jax.named_scope("count"):
-                    n_eval = jax.lax.psum(n_feasible, "node")
-                    n_filt = jax.lax.psum(n_filtered, "node")
-                    n_exh = jax.lax.psum(n_exhausted, "node")
-
-            with jax.named_scope("update"):
-                u2 = jnp.where(owner, u.at[lwin].add(req.ask), u)
-                tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
-
-                nvals = jnp.where(
-                    owner, spread_values_at(arrays, req_step, lwin), 0
-                )
-                with jax.named_scope("broadcast"):
-                    nvals = jax.lax.psum(nvals, "node")
-                new_hash, new_counts = apply_spread_values(
-                    s_counts, req_step, nvals
-                )
-                s_hash2 = jnp.where(ok, new_hash, s_hash)
-                s_counts2 = jnp.where(ok, new_counts, s_counts)
-
-                own_binp = jnp.where(owner, res.binpack[lwin], 0.0)
-                own_pre = jnp.where(
-                    owner, res.needs_preempt[lwin], False
-                ).astype(jnp.int32)
-                with jax.named_scope("broadcast"):
-                    binp = jax.lax.psum(own_binp, "node")
-                    pre = jax.lax.pmax(own_pre, "node").astype(bool)
-            out = (
-                grow,
-                jnp.where(ok, best, 0.0),
-                jnp.where(ok, binp, 0.0),
-                pre & ok,
-                jnp.where(active, n_eval, 0),
-                jnp.where(active, n_filt, 0),
-                jnp.where(active, n_exh, 0),
+    def score(carry, pen, req, ce, hm):
+        u, tg_cnt, s_hash, s_counts = carry
+        req_step = req._replace(s_value_hash=s_hash)
+        with jax.named_scope("score"):
+            res = score_nodes(
+                arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
+                features=features,
             )
-            return (u2, tg2, s_hash2, s_counts2), out
+        # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
+        # then a cross-shard reduce of the implicit (shards, k) table —
+        # pmax elects the winning score, pmin the lowest owning row.
+        with jax.named_scope("pick"):
+            vals, idxs = jax.lax.top_k(res.final, k)
+            with jax.named_scope("elect"):
+                best = jax.lax.pmax(vals[0], "node")
+            cand = jnp.where(
+                vals == best, row_offset + idxs.astype(jnp.int32), big
+            )
+            # lowest row on ties
+            with jax.named_scope("elect"):
+                own = jax.lax.pmin(jnp.min(cand), "node")
+            own = jnp.where(best > NEG_INF / 2, own, -1)
 
-        init = (used0, tg, req.s_value_hash, sc)
-        bufs = tuple(vary(o) for o in inert_step_outputs(n_placements))
-        with jax.named_scope("place_scan"):
-            _, outs = scan_steps(step, init, bufs, trip)
-        return outs  # each (P,)
+            n_feasible = jnp.sum(res.feasible.astype(jnp.int32))
+            n_filtered = jnp.sum(
+                (~res.feasible & arrays.eligible).astype(jnp.int32)
+            )
+            n_exhausted = jnp.sum(
+                (res.feasible & ~res.fits).astype(jnp.int32)
+            )
+            with jax.named_scope("count"):
+                counts = (
+                    jax.lax.psum(n_feasible, "node"),
+                    jax.lax.psum(n_filtered, "node"),
+                    jax.lax.psum(n_exhausted, "node"),
+                )
+        return req_step, res, counts, own
 
-    rows, scores, binpack, pre, ne, nf, nx = jax.vmap(one)(
-        delta_rows, delta_vals, tg_counts, spread_counts, penalties, reqs,
-        class_eligs, host_masks, lane_steps,
+    def commit(carry, req_step, res, counts, grow, active):
+        u, tg_cnt, s_hash, s_counts = carry
+        ok = grow >= 0
+        owner, lwin = local_rows(grow)
+        with jax.named_scope("update"):
+            u2 = jnp.where(owner, u.at[lwin].add(req_step.ask), u)
+            tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
+
+            nvals = jnp.where(
+                owner, spread_values_at(arrays, req_step, lwin), 0
+            )
+            with jax.named_scope("broadcast"):
+                nvals = jax.lax.psum(nvals, "node")
+            new_hash, new_counts = apply_spread_values(
+                s_counts, req_step, nvals
+            )
+            s_hash2 = jnp.where(ok, new_hash, s_hash)
+            s_counts2 = jnp.where(ok, new_counts, s_counts)
+
+            own_score = jnp.where(
+                owner, jnp.stack([res.final[lwin], res.binpack[lwin]]), 0.0
+            )
+            own_pre = jnp.where(
+                owner, res.needs_preempt[lwin], False
+            ).astype(jnp.int32)
+            with jax.named_scope("broadcast"):
+                final, binp = jax.lax.psum(own_score, "node")
+                pre = jax.lax.pmax(own_pre, "node").astype(bool)
+        out = (
+            grow, final, binp, pre,
+        ) + tuple(jnp.where(active, c, 0) for c in counts)
+        return (u2, tg2, s_hash2, s_counts2), out
+
+    def step(state, i):
+        carry, claims = state
+        req_step, res, counts, own = jax.vmap(score)(
+            carry, penalties, reqs, class_eligs, host_masks
+        )
+        active = i < lane_steps  # (b_local,)
+        own = jnp.where(active, own, -1)
+        with jax.named_scope("pick"), jax.named_scope("resolve"):
+            # Every lane's unresolved pick on every shard: the walk's
+            # fallback, and what says whether the lane places at all.
+            with jax.named_scope("gather"):
+                g_own = jax.lax.all_gather(own, "batch", tiled=True)  # (B,)
+
+            def take(b, picked):
+                claims, rows = picked
+                bl = b - b_first  # this lane among mine, if it is mine
+                holds = (bl >= 0) & (bl < b_local)
+                bl = jnp.clip(bl, 0, b_local - 1)
+                ok = g_own[b] >= 0
+                ask = g_ask[b]
+                room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
+                masked = jnp.where(
+                    (room | res.needs_preempt[bl]) & holds,
+                    res.final[bl], NEG_INF,
+                )
+                idx = jnp.argmax(masked).astype(jnp.int32)
+                with jax.named_scope("elect"):
+                    best = jax.lax.pmax(masked[idx], ("batch", "node"))
+                cand = jnp.where(
+                    (masked[idx] == best) & holds, row_offset + idx, big
+                )
+                with jax.named_scope("elect"):
+                    alt = jax.lax.pmin(cand, ("batch", "node"))
+                row = jnp.where(best > NEG_INF / 2, alt, g_own[b])
+                row = jnp.where(ok, row, -1)
+                mine, safe = local_rows(row)
+                return (
+                    claims.at[safe].add(jnp.where(mine, ask, 0.0)),
+                    jax.lax.dynamic_update_index_in_dim(rows, row, b, 0),
+                )
+
+            claims, g_rows = jax.lax.fori_loop(
+                0, last_lane, take,
+                (claims, vary(jnp.full((lanes,), -1, jnp.int32))),
+            )
+        rows = jax.lax.dynamic_slice_in_dim(g_rows, b_first, b_local)
+        carry, out = jax.vmap(commit)(
+            carry, req_step, res, counts, rows, active
+        )
+        return (carry, claims), out + ((rows >= 0) & (rows != own), g_rows)
+
+    init = (
+        jax.vmap(
+            lambda drows, dvals: add_deltas(used, drows, dvals, drows >= 0)
+        )(delta_rows, delta_vals),
+        tg_counts, reqs.s_value_hash, spread_counts,
     )
-    rows = jnp.where(live[:, None], rows, -1)  # (b_local, P)
+    claims0 = add_deltas(vary(used), g_drows, g_dvals, g_live[:, None])
+    bufs = tuple(
+        vary(o)
+        for o in inert_lane_outputs(b_local, n_placements)
+        + (jnp.full((n_placements, lanes), -1, jnp.int32),)
+    )
+    with jax.named_scope("place_scan"):
+        _, outs = scan_steps(step, (init, claims0), bufs, trip)
+    rows, scores, binpack, pre, ne, nf, nx, repicked, g_rows = (
+        o.T for o in outs
+    )  # each (b_local, P); g_rows (B, P): every lane's rows on every shard
 
-    # Cross-lane AllocsFit re-verify, sharded: every tensor gathered over
-    # the batch axis is winner-row-shaped — (B, P) rows, (B, 3) asks,
-    # (B, K) / (B, K, 3) in-flight deltas, (B,) liveness — never node-axis
-    # shaped.  Each node shard then replays all B lanes in resolve order
-    # against its local (n_local, 3) usage slice; rows it does not own fit
-    # vacuously, and one pmin over 'node' lets each row's owner veto.
-    with jax.named_scope("verify_scan"), jax.named_scope("gather"):
-        g_rows = jax.lax.all_gather(rows, "batch", tiled=True)  # (B, P)
-        g_ask = jax.lax.all_gather(reqs.ask, "batch", tiled=True)  # (B, 3)
-        g_drows = jax.lax.all_gather(delta_rows, "batch", tiled=True)  # (B, K)
-        g_dvals = jax.lax.all_gather(delta_vals, "batch", tiled=True)
-    g_live = g_steps > 0  # (B,)
-
+    # Cross-lane AllocsFit re-verify, sharded: each node shard replays all
+    # B lanes in resolve order against its local (n_local, 3) usage slice;
+    # rows it does not own fit vacuously, and one pmin over 'node' lets
+    # each row's owner veto.
     def lane_step(b, state):
         cum_used, fits_all = state
         l_rows, l_ask, l_live = g_rows[b], g_ask[b], g_live[b]
-        l_drows, l_dvals = g_drows[b], g_dvals[b]
-        l_local = l_drows - row_offset
-        l_mine = (
-            (l_drows >= 0) & (l_local >= 0) & (l_local < n_local) & l_live
-        )
-        l_safe = jnp.clip(l_local, 0, n_local - 1)
-        base = cum_used.at[l_safe].add(
-            jnp.where(l_mine[:, None], l_dvals, 0.0)
-        )
+        base = add_deltas(cum_used, g_drows[b], g_dvals[b], l_live)
 
         def p_step(u, p):
-            row = l_rows[p]
-            p_local = row - row_offset
-            p_mine = (
-                (row >= 0) & (p_local >= 0) & (p_local < n_local) & l_live
-            )
-            p_safe = jnp.clip(p_local, 0, n_local - 1)
+            p_mine, p_safe = local_rows(l_rows[p])
+            p_mine &= l_live
             u2 = u.at[p_safe].add(jnp.where(p_mine, l_ask, 0.0))
             fit = jnp.all(u2[p_safe] <= arrays.totals[p_safe]) | ~p_mine
             return u2, (fit,)
@@ -411,13 +470,11 @@ def _fused_place_batch_local(
         )  # (B, P) bool, identical on every node shard only after the pmin:
         verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
 
-    b_local = rows.shape[0]
-    b_idx = jax.lax.axis_index("batch")
     v_local = jax.lax.dynamic_slice_in_dim(
-        verified, b_idx * b_local, b_local, axis=0
+        verified, b_first, b_local, axis=0
     )  # (b_local, P)
     return pack_fused_lanes(
-        rows, scores, binpack, pre, ne, nf, nx, v_local, live
+        rows, scores, binpack, pre, ne, nf, nx, v_local, repicked, live
     )
 
 

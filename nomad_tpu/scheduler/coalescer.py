@@ -44,8 +44,14 @@ for, not ``PLACEMENT_CHUNK`` times (a full-length launch of 64 lanes x
 
 The reference's analog: many schedulers walk nodes concurrently and the
 plan applier serializes commits (worker.go:49-53, plan_apply.go:49-69).
-The optimistic-concurrency contract is unchanged — coalesced selects may
-pick conflicting nodes; the applier's re-verify catches it.
+The optimistic-concurrency contract is unchanged and the applier's
+re-verify stays authoritative, but the selects coalesced into ONE launch
+no longer race each other: inside the kernel the lanes take their picks
+in lane order, and a lane whose best node an earlier lane has filled
+takes its best node that is left (``kernels._fused_place_batch_impl``;
+``lane_repicks`` counts those picks, ``verify_conflicts`` the ones for
+which no node was left).  Selects in different launches in flight still
+may pick conflicting nodes; the applier's re-verify catches it.
 """
 
 from __future__ import annotations
@@ -231,7 +237,10 @@ class DeviceCoalescer:
         # Batched-launch accounting: launches and live lanes
         # (launches-per-eval = fused_dispatches / fused_lanes),
         # verify-column conflicts (placements an earlier lane's plan will
-        # make the applier reject), and the occupancy-features ratchet —
+        # make the applier reject: after the in-launch resolution, the
+        # picks that found no node left under the launch's claims), the
+        # picks the resolution moved to another node than the lane's own
+        # arg-max, and the occupancy-features ratchet —
         # a monotone widening union, so each Features variant compiles at
         # most once per process instead of flapping per batch.
         self.fused_dispatches = 0
@@ -241,6 +250,7 @@ class DeviceCoalescer:
         # n_live (steps a launch = scan_steps_total / fused_dispatches).
         self.scan_steps_total = 0
         self.verify_conflicts = 0
+        self.lane_repicks = 0
         self.feature_recompiles = 0
         self._features = None
         # Device→host result traffic for fused/sharded dispatches (the
@@ -1156,14 +1166,18 @@ class DeviceCoalescer:
                     ticket.matrix_version,
                 )
                 # The device-resident AllocsFit column: a 0.0 on a real
-                # placement means an earlier lane in THIS launch already
-                # claimed the capacity — at an unchanged matrix version
-                # the applier is guaranteed to reject it.  Advisory: the
-                # serialized applier stays authoritative either way.
+                # placement means earlier lanes in THIS launch already
+                # claimed the capacity and the resolution found the lane
+                # no other node — at an unchanged matrix version the
+                # applier is guaranteed to reject it; a 2.0 is a placement
+                # the resolution moved off such a node, and it fits.
+                # Advisory: the serialized applier stays authoritative
+                # either way.
                 vcol = row[:, kernels.FUSED_PACKED_VERIFIED]
                 placed = rows_i >= 0
                 fit_verified = ~(placed & (vcol == 0.0))
                 self.verify_conflicts += int((~fit_verified).sum())
+                self.lane_repicks += int((placed & (vcol == 2.0)).sum())
                 p.outcome = PlaceOutcome(
                     rows=rows_i,
                     scores=row[:, kernels.PACKED_SCORE],

@@ -259,7 +259,8 @@ class Server:
         m.gauge_fn("nomad.kernel.launches", lambda: c.solo_ops, path="solo")
         # Fused megakernel accounting: one launch serves every coalesced
         # lane (launches/eval = fused_dispatches / fused_lanes), plus the
-        # cross-lane AllocsFit verify verdicts and the occupancy-features
+        # cross-lane AllocsFit verify verdicts, the picks the in-launch
+        # resolution moved to another node, and the occupancy-features
         # recompile ratchet.
         m.gauge_fn(
             "nomad.kernel.launches", lambda: c.fused_dispatches, path="fused"
@@ -275,6 +276,9 @@ class Server:
         )
         m.gauge_fn(
             "nomad.kernel.verify_conflicts", lambda: c.verify_conflicts
+        )
+        m.gauge_fn(
+            "nomad.kernel.lane_repicks_total", lambda: c.lane_repicks
         )
         m.gauge_fn(
             "nomad.kernel.feature_recompiles", lambda: c.feature_recompiles

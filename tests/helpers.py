@@ -121,3 +121,31 @@ def solo_reference(arrays, operands, n_placements, lanes=None):
             )
         ], axis=1))
     return np.stack(out)
+
+
+def fill_frontier(matrix, nodes, picks, ask_cpu, ask_mem, seed=0):
+    """Fill ``nodes[i]`` for i in ``picks`` until each holds room for
+    exactly ONE (ask_cpu MHz, ask_mem MB) ask: the frontier of nearly full
+    nodes that binpack ranks first and that the lanes of one launch all
+    want.  Returns their matrix rows."""
+    import numpy as np
+
+    from nomad_tpu.structs import Allocation, Job, Resources
+
+    rng = np.random.default_rng(seed)
+    host = matrix.snapshot_host()
+    rows = []
+    for i in picks:
+        row = matrix.row_of[nodes[i].id]
+        free_cpu, free_mem = (host["totals"][row] - host["used"][row])[:2]
+        assert free_cpu >= 2 * ask_cpu and free_mem >= 2 * ask_mem
+        matrix.add_alloc(Allocation(
+            node_id=nodes[i].id,
+            job=Job(priority=50),
+            resources=Resources(
+                cpu=int(free_cpu - ask_cpu - rng.integers(0, ask_cpu)),
+                memory_mb=int(free_mem - ask_mem - rng.integers(0, ask_mem)),
+            ),
+        ))
+        rows.append(row)
+    return rows

@@ -178,11 +178,14 @@ def test_megabatch_throughput_floor():
     fused_s = min(_timed(fused_batch) for _ in range(3))
     ratio = staged_s / fused_s
 
-    # Both paths must have placed the same nodes (sanity, not the gate).
-    np.testing.assert_array_equal(
-        fused_out[:, 0, 0].astype(np.int32),
-        np.concatenate(staged_rows).astype(np.int32),
-    )
+    # Both paths placed every eval (sanity, not the gate): the first lane
+    # where it lands alone, the later ones there or, once the lanes before
+    # them have claimed that node's room, on their best node that is left
+    # (the launch resolves its lanes' picks; the re-verify passes them).
+    rows = fused_out[:, 0, 0].astype(np.int32)
+    assert rows[0] == int(staged_rows[0][0]) and (rows >= 0).all()
+    assert np.isin(fused_out[:, 0, kernels.FUSED_PACKED_VERIFIED],
+                   (1.0, 2.0)).all()
     assert ratio >= MEGABATCH_FLOOR, (
         f"fused megakernel processed B={B} at only {ratio:.2f}x the staged "
         f"per-eval path ({staged_s * 1e6 / B:.0f} -> {fused_s * 1e6 / B:.0f} "
@@ -194,3 +197,121 @@ def _timed(fn) -> float:
     t0 = time.perf_counter()
     fn()
     return time.perf_counter() - t0
+
+
+# ---------------------------------------------------------------------------
+# The route by which an operation can fail for good (ISSUE 30): a slot the
+# lane asked for that comes back empty although the cluster has room reads
+# "no node can take it" on the host (stack.py: options.append(None)), the
+# eval completes with queued allocations and a blocked eval, and nothing in
+# a cluster that stops and preempts nothing ever unblocks it.
+# ---------------------------------------------------------------------------
+
+HERD_NODES = 96
+HERD_FRONTIER = 32
+HERD_JOBS = 208
+HERD_SPARE = 4
+
+
+def _place_the_herd(srv):
+    """HERD_JOBS identical binpack jobs of width 1-8 in one burst onto a
+    frontier of nearly full nodes, failed evals (out of plan attempts)
+    registered again as the benchmark's client does.  Returns (jobs, every
+    eval seen)."""
+    from nomad_tpu.structs import Resources
+
+    def make_job(i):
+        job = mock.batch_job() if i % 3 == 0 else mock.job()
+        tg = job.task_groups[0]
+        tg.count = 1 + i % 8
+        tg.tasks[0].resources.cpu = 100
+        tg.tasks[0].resources.memory_mb = 64
+        return job
+
+    jobs = [make_job(i) for i in range(HERD_JOBS)]
+    demand = sum(j.task_groups[0].count for j in jobs)
+
+    nodes = [mock.node() for _ in range(HERD_NODES)]
+    for n in nodes:
+        srv.register_node(n)
+    # The frontier binpack ranks first: room for ONE ask (100 MHz / 64 MB
+    # of the 3,900 / 7,936 a node offers), so the lanes of one launch all
+    # want the same node, over and over.  The other nodes hold the rest of
+    # the burst and HERD_SPARE asks more: the cluster has room for every
+    # job at all times, and only just at the end.  (While it has, the
+    # claims of one launch never exceed the room: the kernel's fallback,
+    # a lane keeping its own pick, is what tests/test_megakernel.py's
+    # few-free-nodes cases run.)
+    rest = HERD_NODES - HERD_FRONTIER
+    slots = [1] * HERD_FRONTIER + [
+        (demand + HERD_SPARE - HERD_FRONTIER) // rest
+        + (i < (demand + HERD_SPARE - HERD_FRONTIER) % rest)
+        for i in range(rest)
+    ]
+    assert sum(slots) == demand + HERD_SPARE and max(slots) < 39
+    fill = []
+    for i, (n, k) in enumerate(zip(nodes, slots)):
+        a = mock.alloc(n=n)
+        a.resources = Resources(cpu=3900 - 100 * k - (i % 90),
+                                memory_mb=1000 - (i % 60))
+        fill.append(a)
+    srv.store.upsert_allocs(srv.next_index(), fill)
+
+    open_evals = {srv.submit_job(j).id: j for j in jobs}
+    seen = {}
+    deadline = time.time() + 90.0
+    last_index = 0
+    while open_evals and time.time() < deadline:
+        for eid in list(open_evals):
+            ev = srv.store.eval_by_id(eid)
+            if ev is None or not ev.terminal_status():
+                continue
+            seen[eid] = ev
+            job = open_evals.pop(eid)
+            if ev.status == "failed":
+                # Lost the plan race: the client registers the job again.
+                open_evals[srv.submit_job(job).id] = job
+        if open_evals:
+            last_index = srv.store.wait_for_table(
+                "evals", last_index, timeout=0.25
+            )
+    assert not open_evals, f"{len(open_evals)} evals never went terminal"
+    return jobs, seen
+
+
+def test_herd_on_a_frontier_leaves_no_eval_blocked(monkeypatch):
+    """A cluster with room, 16 workers racing through one coalescer: every
+    eval ends ``complete`` or ``failed`` (then registered again), none
+    completes with allocations it could not place, nothing is blocked, and
+    every job is placed in full."""
+    monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE", "1")
+    srv = Server(ServerConfig(
+        num_workers=16,
+        node_capacity=128,
+        coalescer_lanes=8,
+        heartbeat_min_ttl=3600.0,
+        heartbeat_max_ttl=7200.0,
+        slo_enabled=False,
+    ))
+    srv.start()
+    try:
+        jobs, seen = _place_the_herd(srv)
+        assert {e.status for e in seen.values()} <= {"complete", "failed"}
+        stuck = [
+            e.id for e in seen.values()
+            if e.queued_allocations and any(e.queued_allocations.values())
+            or e.failed_tg_allocs
+        ]
+        assert not stuck, f"{len(stuck)} evals left allocations unplaced"
+        assert srv.blocked_evals.blocked_count() == 0
+        for job in jobs:
+            live = [
+                a for a in srv.store.allocs_by_job(job.namespace, job.id)
+                if not a.terminal_status()
+            ]
+            assert len(live) == job.task_groups[0].count, job.id
+        coal = srv.coalescer
+        assert coal.fused_lanes > coal.fused_dispatches  # lanes did share
+        assert coal.lane_repicks > 0  # and the resolution engaged
+    finally:
+        srv.shutdown()

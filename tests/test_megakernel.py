@@ -9,9 +9,12 @@ one launch. These tests pin it against each request alone through
 * placement parity, lane by lane, on a seeded 1K-node cluster, across
   constraint/affinity/spread/preemption request shapes and in-flight
   deltas; each of the served traffic's shapes alone in a 64-lane launch;
-* the VERIFIED column: cross-lane capacity conflicts (two lanes claiming
-  the same node, an earlier lane's in-flight delta) are flagged exactly
-  where the plan applier would reject, and nowhere else;
+* in-launch pick resolution (contract C1-C3 of ISSUE 30): a lane passes
+  over a node only because lanes of the same launch claimed the room it
+  needed and takes its best node that is left (VERIFIED 2.0); where no
+  node is left it keeps its own pick (VERIFIED 0.0, the applier decides):
+  never an empty slot, never a placement past what the lane asked for; a
+  launch whose picks never overflow a node is every lane's solo scan;
 * dead-lane masking: one compile serves every batch occupancy, and dead
   lanes can never perturb live lanes' outputs or verdicts;
 * per-lane step counts: the loops stop at what the lanes asked for, the
@@ -47,7 +50,7 @@ from nomad_tpu.structs import (
     TaskGroup,
 )
 
-from helpers import lane_operands, solo_reference
+from helpers import fill_frontier, lane_operands, solo_reference
 
 SCAN = 4
 
@@ -115,8 +118,7 @@ def assert_solo_columns_match(solo, fused, lane_mask=None):
     )
 
 
-@pytest.fixture(scope="module")
-def cluster_1k():
+def build_cluster_1k():
     """Seeded 1K-node cluster with heterogeneous resources, datacenters,
     classes, attrs, and a population of existing allocations."""
     rng = np.random.default_rng(17)
@@ -146,6 +148,11 @@ def cluster_1k():
             ),
         ))
     return m, nodes
+
+
+@pytest.fixture(scope="module")
+def cluster_1k():
+    return build_cluster_1k()
 
 
 def compile_lane_mix(m):
@@ -195,7 +202,9 @@ class TestFusedVsSolo1K:
         assert (fused[:, 0, 0] >= 0).all()
         # ...and every live placement carries a real verify verdict.
         placed = fused[:, :, 0] >= 0
-        assert np.isin(fused[:, :, FUSED_PACKED_VERIFIED], [0.0, 1.0]).all()
+        assert np.isin(
+            fused[:, :, FUSED_PACKED_VERIFIED], [0.0, 1.0, 2.0]
+        ).all()
         assert (fused[~placed][:, FUSED_PACKED_VERIFIED] == 1.0).all()
 
     def test_constraint_lane_filters_match(self, cluster_1k):
@@ -249,34 +258,45 @@ class TestAllocsFitRejection:
         m.upsert_node(node)
         return m, node
 
-    def test_cross_lane_conflict_rejected(self):
-        # Two lanes rank against the same snapshot and both pick the only
-        # node; the second lane's claim exceeds capacity → verified 0.0,
-        # exactly the conflict plan_apply would reject a round-trip later.
-        m, node = self.setup_m()
-        enc = RequestEncoder(m)
-        j = make_job(cpu=600, mem=400)
-        c = enc.compile(j, j.task_groups[0])
-        _, fused = run_both(m, [c, c], scan=1)
-        assert int(fused[0, 0, 0]) == int(fused[1, 0, 0]) == m.row_of[node.id]
-        assert fused[0, 0, FUSED_PACKED_VERIFIED] == 1.0
-        assert fused[1, 0, FUSED_PACKED_VERIFIED] == 0.0
+    # Two lanes rank against the same snapshot and both want the fuller
+    # node, which holds one ask.  (nodes in the cluster, in-flight delta on
+    # lane 0) -> what lanes 0 and 1 read: which node, VERIFIED.
+    CONFLICTS = {
+        # The second lane passes over the node the first one claimed and
+        # takes the one that is left: resolved, and it verifies.
+        "conflict_resolved": (2, False, ("full", 1.0), ("spare", 2.0)),
+        # No node left: the second lane keeps its pick, exactly the
+        # conflict plan_apply rejects a round-trip later (never row -1).
+        "conflict_no_node_left": (1, False, ("full", 1.0), ("full", 0.0)),
+        # Lane 0 carries an in-flight delta claiming the node: its own scan
+        # sees it, and lane 1's resolution accounts for it even though
+        # lane 1's scores cannot.
+        "inflight_delta_resolved": (2, True, ("spare", 1.0), ("spare", 2.0)),
+        "inflight_delta_no_node_left": (1, True, (None, 1.0), ("full", 0.0)),
+    }
 
-    def test_earlier_lane_inflight_delta_rejects(self):
-        # Lane 0 carries an in-flight delta claiming most of the node; its
-        # own scan sees it (places elsewhere / nowhere) and lane 1's
-        # verify must account for it even though lane 1's scan cannot.
+    @pytest.mark.parametrize("case", sorted(CONFLICTS))
+    def test_cross_lane_conflict(self, case):
+        n_nodes, inflight, want0, want1 = self.CONFLICTS[case]
         m, node = self.setup_m()
+        names = {"full": m.row_of[node.id], None: -1}
+        if n_nodes == 2:
+            # Emptier, so binpack ranks it second; room for both lanes.
+            spare = make_node(cpu=4000, mem=4096)
+            m.upsert_node(spare)
+            names["spare"] = m.row_of[spare.id]
         enc = RequestEncoder(m)
         j = make_job(cpu=600, mem=400)
         c = enc.compile(j, j.task_groups[0])
-        _, fused = run_both(
-            m, [c, c], scan=1,
-            deltas={0: [(m.row_of[node.id], (600.0, 400.0, 0.0))]},
+        deltas = (
+            {0: [(names["full"], (600.0, 400.0, 0.0))]} if inflight else None
         )
-        assert int(fused[0, 0, 0]) == -1  # its delta exhausted the node
-        assert int(fused[1, 0, 0]) == m.row_of[node.id]
-        assert fused[1, 0, FUSED_PACKED_VERIFIED] == 0.0
+        solo, fused = run_both(m, [c, c], scan=1, deltas=deltas)
+        for lane, (where, verdict) in enumerate((want0, want1)):
+            assert int(fused[lane, 0, 0]) == names[where], (case, lane)
+            assert fused[lane, 0, FUSED_PACKED_VERIFIED] == verdict
+        # C1: a slot is empty only where the lane's own scan leaves it so.
+        np.testing.assert_array_equal(fused[:, :, 0] >= 0, solo[:, :, 0] >= 0)
 
     def test_disjoint_lanes_all_verify(self):
         m = NodeMatrix(capacity=16)
@@ -289,6 +309,183 @@ class TestAllocsFitRejection:
             compiled.append(enc.compile(j, j.task_groups[0]))
         _, fused = run_both(m, compiled, scan=2)
         assert (fused[:, :, FUSED_PACKED_VERIFIED] == 1.0).all()
+
+
+ASK_CPU, ASK_MEM = 300, 200
+FRONTIER = 40  # nearly full nodes, each with room for exactly one ask
+
+
+@pytest.fixture(scope="module")
+def frontier_1k():
+    """``cluster_1k``'s nodes with a frontier: the matrix, its rows that
+    hold one more (ASK_CPU, ASK_MEM) ask, and that ask's request (plain
+    binpack: the herd)."""
+    m, nodes = build_cluster_1k()
+    rows = fill_frontier(
+        m, nodes, np.random.default_rng(5).choice(1000, FRONTIER, False),
+        ASK_CPU, ASK_MEM,
+    )
+    j = make_job(cpu=ASK_CPU, mem=ASK_MEM, count=8)
+    return m, rows, RequestEncoder(m).compile(j, j.task_groups[0]).request
+
+
+def _mixed_steps(lanes, live, seed):
+    """``live`` lanes asking 1-8 each (a Zipf-like mix: mostly narrow),
+    dead lanes among them when ``live`` < ``lanes``."""
+    rng = np.random.default_rng(seed)
+    ls = np.zeros((lanes,), np.int32)
+    at = np.sort(rng.choice(lanes, live, replace=False))
+    ls[at] = np.minimum(rng.zipf(1.5, live), 8)
+    ls[at[0]] = 8  # the launch runs all eight steps
+    return ls
+
+
+class TestPickResolution:
+    """ISSUE 30's contract for the lanes of one launch (C1-C3, C5)."""
+
+    # name -> (lanes compiled, live lanes, nodes the lanes may use: None =
+    # all, k = only k frontier nodes: fewer free nodes than claims, so the
+    # fallback runs)
+    CASES = {
+        "2_live": (8, 2, None),
+        "3_live": (8, 3, None),
+        "8_live": (8, 8, None),
+        "64_live": (64, 64, None),
+        "8_live_4_free_nodes": (8, 8, 4),
+    }
+
+    def _launch(self, frontier, case, seed):
+        m, frontier_rows, req = frontier
+        lanes, n_live, free = self.CASES[case]
+        ls = _mixed_steps(lanes, n_live, seed)
+        ops = lane_operands(m, [req] * lanes)
+        hm = ops[7]
+        if free is not None:
+            hm[:] = False
+            hm[:, frontier_rows[:free]] = True
+        arrays = m.sync()
+        got = np.asarray(fused_place_batch(
+            arrays, arrays.used, *ops, ls, n_placements=8,
+        ))
+        return m, arrays, ops, ls, got
+
+    @pytest.mark.parametrize("seed", (1, 2))
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_contract(self, frontier_1k, case, seed):
+        m, arrays, ops, ls, got = self._launch(frontier_1k, case, seed)
+        rows = got[:, :, 0].astype(np.int64)
+        vcol = got[:, :, FUSED_PACKED_VERIFIED]
+        asked = np.arange(8)[None, :] < ls[:, None]
+        host = host_view(arrays)
+        ask = np.asarray(ops[5].ask[0])
+        free = self.CASES[case][2]
+
+        # C1: no empty slot.  Every slot a lane asked for holds a node
+        # exactly where the lane's own scan holds one (here: everywhere,
+        # the cluster has room), and nothing lies past what it asked for.
+        live = np.flatnonzero(ls > 0)
+        solo = solo_reference(arrays, ops, 8, lanes=live[:8])
+        for lane, ref in zip(live, solo):
+            np.testing.assert_array_equal(
+                rows[lane, :ls[lane]] >= 0, ref[:ls[lane], 0] >= 0
+            )
+        placed = asked & (rows >= 0)
+        assert free is not None or placed[asked].all()
+        assert (rows[~asked] == -1).all()
+        assert (got[~asked][:, 1:7] == 0.0).all()
+
+        # What the launch claims of every node, all lanes together.
+        claims = host.used.copy()
+        np.add.at(claims, rows[placed], ask)
+        over = ~np.all(claims <= host.totals, axis=1)
+        room = np.all(claims + ask <= host.totals, axis=1) & (
+            fake_device.feasibility_mask(
+                host, frontier_1k[2], ops[6][0], ops[7][0]
+            )
+        )
+        if free is None:
+            # C3: the frontier was contended (re-picks happened), every
+            # placement verifies, and no node is over-committed.
+            assert (vcol[placed] == 2.0).any(), "nobody re-picked: no teeth"
+            assert np.isin(vcol[placed], (1.0, 2.0)).all()
+            assert not over.any()
+            # The first lane's first pick is never passed over.
+            np.testing.assert_array_equal(got[live[0], 0, :7], solo[0][0])
+        else:
+            # The fallback: a slot of room on each of the few nodes the
+            # lanes may use (a lane's own scan places one ask on each and
+            # then runs out, as alone), many more claims.  Each node is
+            # taken once for good; every other pick stays the lane's own (a
+            # node, never -1), reads 0.0, and over-commits a node only
+            # because no node the lane may use had room left.
+            taken = len(set(rows[placed]))
+            assert 1 < taken <= free < placed.sum()
+            assert (np.isin(vcol[placed], (1.0, 2.0))).sum() == taken
+            assert (vcol[placed] == 0.0).sum() == placed.sum() - taken
+            assert over.sum() <= taken and not room.any()
+        assert (vcol[~placed & (ls > 0)[:, None]] == 1.0).all()
+        assert (vcol[ls == 0] == -1.0).all()
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_twin_agrees_on_all_eight_columns(self, frontier_1k, case):
+        """C5: the numpy twin resolves as the kernel does."""
+        m, arrays, ops, ls, got = self._launch(frontier_1k, case, seed=3)
+        drows, dvals, tg, sc, pen, reqs, ce, hm = ops
+        host = host_view(arrays)
+        twin = fake_device.fused_place_batch(
+            host, host.used,
+            *[list(a) for a in (drows, dvals, tg, sc, pen)],
+            [frontier_1k[2]] * len(ls), list(ce), list(hm),
+            ls > 0, n_placements=8, live_counts=list(ls),
+        )
+        for col in (0, 3, 4, 5, 6, FUSED_PACKED_VERIFIED):
+            np.testing.assert_array_equal(
+                got[:, :, col], twin[:, :, col], err_msg=f"column {col}"
+            )
+        np.testing.assert_allclose(
+            got[:, :, 1:3], twin[:, :, 1:3], rtol=1e-5, atol=1e-5
+        )
+
+    # C2: launches whose picks never overflow a node are, bit for bit,
+    # every lane's solo scan, with every verdict 1.0.  Each shape edits the
+    # host masks and the step counts of eight lanes asking 8 in place.
+    def _one_live_lane(hm, ls, frontier_rows):
+        ls[:] = 0
+        ls[3] = 8
+
+    def _disjoint_lanes(hm, ls, frontier_rows):
+        # Lane i may use the rows with row % 8 == i only.
+        hm &= (np.arange(hm.shape[1])[None, :] % 8) == np.arange(8)[:, None]
+
+    def _one_step_off_the_frontier(hm, ls, frontier_rows):
+        # All eight want the same node, which holds all eight asks.
+        hm[:, frontier_rows] = False
+        ls[:] = 1
+
+    NO_CONFLICT = {
+        "one_live_lane": _one_live_lane,
+        "disjoint_lanes": _disjoint_lanes,
+        "one_step_off_the_frontier": _one_step_off_the_frontier,
+    }
+
+    @pytest.mark.parametrize("shape", sorted(NO_CONFLICT))
+    def test_no_conflict_is_the_solo_scan(self, frontier_1k, shape):
+        m, frontier_rows, req = frontier_1k
+        ops = lane_operands(m, [req] * 8)
+        ls = np.full((8,), 8, np.int32)
+        self.NO_CONFLICT[shape](ops[7], ls, frontier_rows)
+        arrays = m.sync()
+        got = np.asarray(fused_place_batch(
+            arrays, arrays.used, *ops, ls, n_placements=8,
+        ))
+        live = np.flatnonzero(ls)
+        solo = solo_reference(arrays, ops, 8, lanes=live)
+        for lane, ref in zip(live, solo):
+            k = ls[lane]
+            assert got[lane, :k, :7].tobytes() == ref[:k].tobytes(), lane
+        assert (got[live, :, FUSED_PACKED_VERIFIED] == 1.0).all()
+        if shape == "one_step_off_the_frontier":
+            assert len(set(got[:, 0, 0])) == 1  # they did share the node
 
 
 class TestDeadLaneMasking:
@@ -377,6 +574,15 @@ class TestFakeDeviceTwinParity:
 
 FULL = 16  # the live scan length (stack.PLACEMENT_CHUNK)
 
+
+def _first_repick(lane_rows) -> int:
+    """The first step at which a lane's pick can have depended on the other
+    lanes: it took another node than its own arg-max (VERIFIED 2.0), or
+    reads 0.0 (which hides whether it did); the lane's length where its
+    picks were all its own."""
+    hit = np.flatnonzero(lane_rows[:, FUSED_PACKED_VERIFIED] != 1.0)
+    return int(hit[0]) if len(hit) else len(lane_rows)
+
 # Placements each lane's caller consumes (what stack.py hands place() as
 # n_live); None = a dead lane.
 N_LIVE_CASES = {
@@ -462,25 +668,40 @@ class TestLaneStepCounts:
                 tail[:, FUSED_PACKED_VERIFIED] == (1.0 if k else -1.0)
             ).all()
         if (steps == FULL).all():
-            # Nothing to cut: the placement columns are bit for bit the
-            # static 16-step scan's (place_task_group still runs one).
-            np.testing.assert_array_equal(got[:, :, :7], solo())
+            # Nothing to cut: until a lane first re-picks (an earlier lane
+            # of the launch claimed the room its own pick needed), its
+            # placement columns are bit for bit the static 16-step scan's
+            # (place_task_group still runs one); the first lane's first
+            # pick is always its own.
+            assert (got[:, :, FUSED_PACKED_VERIFIED] == 2.0).any()
+            solo = solo()
+            for lane in range(len(steps)):
+                n = _first_repick(got[lane])
+                assert n > 0 or lane > 0
+                np.testing.assert_array_equal(got[lane, :n, :7], solo[lane, :n])
 
     @pytest.mark.parametrize(
         "case", [c for c in sorted(N_LIVE_CASES) if c != "all_16"]
     )
     def test_asked_steps_are_the_full_launch_bitwise(self, cluster_1k, case):
-        """Same work, not less: the first ``n`` rows of a lane are bit for
-        bit that lane's rows in a launch where every lane runs all 16 (the
-        later steps never fed back into the earlier ones).  The verdict
-        column is left out: it reads the OTHER lanes' commits, and those
-        no longer include placements nobody asked for."""
+        """Same work, not less: the rows a lane asked for are bit for bit
+        that lane's rows in a launch where every lane runs all 16 (the
+        later steps never fed back into the earlier ones), up to the step
+        at which the lane first re-picks in either launch: from there its
+        picks depend on what the other lanes claimed, and in the full
+        launch they claim more.  The verdict column is left out: it reads
+        the OTHER lanes' commits, and those no longer include placements
+        nobody asked for."""
         m, _ = cluster_1k
         steps, kernel, _, _ = self._launch(m, N_LIVE_CASES[case])
         cut = kernel(steps)
         full = kernel(np.where(steps > 0, FULL, 0).astype(np.int32))
+        compared = 0
         for lane, k in enumerate(steps):
-            assert cut[lane, :k, :7].tobytes() == full[lane, :k, :7].tobytes()
+            n = min(k, _first_repick(cut[lane]), _first_repick(full[lane]))
+            assert cut[lane, :n, :7].tobytes() == full[lane, :n, :7].tobytes()
+            compared += n
+        assert compared >= steps.sum() // 2
 
     def test_short_lane_is_not_charged_beside_a_wide_one(self):
         """A lane that asked for 1 beside a lane that asked for 4 takes no

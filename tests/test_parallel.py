@@ -208,26 +208,42 @@ class TestShardedFusedParity:
         )
         self._assert_parity(ref, out, f"mesh ({nshards},{batch}) {steps}")
 
+    # (nodes, lane_steps, what the resolution must have done): fat asks, a
+    # node holds two or three.  Few nodes: the later lanes find no node
+    # left and keep their picks (0.0).  Many: every lane passed over finds
+    # another node (2.0) and nothing is left to the applier.
+    CONFLICTS = {
+        "no_node_left": (4, [2, 1, 2, 2, 1, 2, 2, 2], {0.0, 1.0, 2.0}),
+        "all_resolved": (24, [2, 1, 3, 2, 1, 4, 2, 3], {1.0, 2.0}),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CONFLICTS))
     @pytest.mark.parametrize("nshards,batch", MESHES)
     def test_cross_lane_conflicts_match(self, eight_devices, nshards,
-                                        batch):
-        """Tiny cluster + fat asks: later lanes collide with earlier
-        winners, so the device-resident AllocsFit re-verify column must
-        flag the same rejections under every sharding."""
-        m, nodes = _cluster(n_nodes=4, capacity=8)
+                                        batch, case):
+        """Small cluster + fat asks: later lanes collide with earlier
+        winners, so the in-launch resolution (every lane's turn elects its
+        best row with room across both mesh axes) and the device-resident
+        AllocsFit re-verify column must give the same picks and verdicts
+        under every sharding, lanes on another batch shard included."""
+        n_nodes, steps, verdicts = self.CONFLICTS[case]
+        m, nodes = _cluster(n_nodes=n_nodes, capacity=2 * n_nodes)
         job = mock.job()
         job.task_groups[0].tasks[0].resources.cpu = 1200
         job.task_groups[0].tasks[0].resources.memory_mb = 900
-        b, scan = 8, 2
+        b, scan = 8, 4
         req = RequestEncoder(m).compile(job, job.task_groups[0]).request
-        ls = np.array([2, 1, 2, 2, 1, 2, 2, 2], np.int32)
+        ls = np.array(steps, np.int32)
         ref, out = self._ref_and_sharded(
             m, lane_operands(m, [req] * b), ls, scan, nshards, batch
         )
-        assert (ref[:, :, kernels.FUSED_PACKED_VERIFIED] == 0.0).any(), (
-            "conflict case produced no rejections — test lost its teeth"
+        placed = ref[:, :, kernels.PACKED_ROW] >= 0
+        assert set(ref[placed][:, kernels.FUSED_PACKED_VERIFIED]) == verdicts, (
+            "conflict case lost its teeth"
         )
-        self._assert_parity(ref, out, f"mesh ({nshards},{batch})")
+        # C1 on the mesh: every asked-for slot holds a node.
+        assert (placed == (np.arange(scan)[None, :] < ls[:, None])).all()
+        self._assert_parity(ref, out, f"mesh ({nshards},{batch}) {case}")
 
 
 class TestTopkHostBytes:
