@@ -193,6 +193,21 @@ def fused_operands(g: Grid) -> Tuple[Any, ...]:
     )
 
 
+def _unpack_packs(g: Grid) -> Tuple[Any, ...]:
+    """((request pack, lane pack), their layouts) as a launch of the server
+    hands them to ``unpack_lanes``: ``RequestSlab``'s and ``_staging``'s."""
+    import jax
+
+    from ..ops.encode import MAX_SPREAD_VALUES, MAX_SPREADS
+    from ..scheduler.coalescer import DeviceCoalescer
+    from ..state.matrix import NodeMatrix
+
+    coal = DeviceCoalescer(NodeMatrix(capacity=g.nodes), max_lanes=g.batch)
+    st, slab = coal._staging(g.nodes, 1, (MAX_SPREADS, MAX_SPREAD_VALUES))
+    slab.fill(0, jax.tree_util.tree_map(lambda f: f[0], _concrete_reqs(1)))
+    return (slab.pack, st["pack"]), (slab.layout, st["layout"])
+
+
 def scatter_operands(g: Grid) -> Tuple[Any, ...]:
     """(device, idx, *row_data) for the dirty-row scatter; ``g.deltas``
     is the (already pow2-padded) dirty-row count."""
@@ -339,6 +354,19 @@ def table() -> Tuple[DeviceContract, ...]:
             compile_grid=compile_grid,
             sweep=lane_steps_sweep,
             # occupancy and step counts are runtime data: ONE compile
+            max_compiles=1,
+        ),
+        DeviceContract(
+            name="unpack_lanes",
+            path="nomad_tpu/ops/kernels.py",
+            build=lambda g: kernels.unpack_lanes,
+            operands=lambda g: _unpack_packs(g)[0],
+            static_kwargs=lambda g: {"layouts": _unpack_packs(g)[1]},
+            trace_grids=trace_grids[:2],
+            out_budget=None,  # feeds the placement program; never fetched
+            donated_args=(),  # views of a staging slot, read until resolved
+            compile_grid=compile_grid,
+            sweep=occupancy_sweep,  # the packs' shapes do not know the fill
             max_compiles=1,
         ),
         DeviceContract(

@@ -124,6 +124,27 @@ class SchedRequest(NamedTuple):
     p_dyn: np.ndarray  # () i32
 
 
+def packed_rows(lanes: int, specs):
+    """One ``(lanes, W)`` uint8 buffer and a ``(lanes,) + shape`` view of it
+    per ``(shape, dtype)`` of ``specs``: what a launch hands to jax as ONE
+    operand instead of one per field (a transfer costs the launching thread
+    by the device buffer, not by the byte: PERF.md section 6, PR 33).
+    Returns (buffer, views, layout); ``kernels.unpack_rows(buffer, layout)``
+    gives the fields back on the device.  Fields start on 4-byte bounds."""
+    layout, views, width = [], [], 0
+    for shape, dtype in specs:
+        dtype = np.dtype(dtype)
+        assert dtype == bool or dtype.itemsize == 4, dtype
+        size = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+        layout.append((width, tuple(shape), dtype.name, size))
+        width += -(-size // 4) * 4
+    buf = np.zeros((lanes, width), np.uint8)
+    for offset, shape, dtype, size in layout:
+        field = buf[:, offset:offset + size].view(dtype)
+        views.append(field.reshape((lanes,) + shape))
+    return buf, views, tuple(layout)
+
+
 class RequestSlab:
     """Preallocated ``(B, …)`` operand slab for batched request encoding.
 
@@ -146,9 +167,11 @@ class RequestSlab:
 
     def _build(self, proto: SchedRequest) -> SchedRequest:
         fields = [np.asarray(f) for f in proto]
-        bufs = SchedRequest(*[
-            np.empty((self.lanes,) + f.shape, f.dtype) for f in fields
-        ])
+        # Every field a view of one buffer: the launch hands over ``pack``.
+        self.pack, views, self.layout = packed_rows(
+            self.lanes, [(f.shape, f.dtype) for f in fields]
+        )
+        bufs = SchedRequest(*views)
         for buf, f in zip(bufs, fields):
             buf[:] = f  # broadcast: every row starts as a valid request
         return bufs
@@ -176,7 +199,7 @@ class RequestSlab:
         return SchedRequest(*[buf[:k] for buf in self._bufs])
 
     def nbytes(self) -> int:
-        return sum(b.nbytes for b in self._bufs) if self._bufs else 0
+        return self.pack.nbytes if self._bufs else 0
 
 
 @dataclass

@@ -1102,6 +1102,31 @@ fused_place_batch_live = functools.partial(
 )(_fused_place_batch_impl)
 
 
+def unpack_rows(buf, layout):
+    """The fields of ``encode.packed_rows``' ``(lanes, W)`` uint8 buffer,
+    on the device: bit for bit what the host wrote into its views."""
+    fields = []
+    for offset, shape, dtype, size in layout:
+        x = buf[:, offset:offset + size]
+        if dtype == "bool":
+            x = x != 0
+        else:
+            x = jax.lax.bitcast_convert_type(
+                x.reshape(buf.shape[0], -1, 4), jnp.dtype(dtype)
+            )
+        fields.append(x.reshape((buf.shape[0],) + shape))
+    return fields
+
+
+@functools.partial(jax.jit, static_argnames=("layouts",))
+def unpack_lanes(*packs, layouts):
+    """Every small lane operand of a launch (the request slab's fields; the
+    class eligibility, spread counts, deltas and step counts) from the two
+    packed buffers they are handed over in.  A program of its own (module
+    ``jit_unpack_lanes``): the placement program keeps its operands."""
+    return tuple(unpack_rows(p, lay) for p, lay in zip(packs, layouts))
+
+
 # ---------------------------------------------------------------------------
 # Plan-apply verification (AllocsFit re-check at commit time)
 # ---------------------------------------------------------------------------

@@ -46,6 +46,7 @@ from ..ops.kernels import (
     scan_steps,
     score_nodes,
     spread_values_at,
+    unpack_lanes,
 )
 from ..state.matrix import DeviceArrays
 
@@ -522,3 +523,21 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
         )
 
     return jax.jit(entry, static_argnames=("features",))
+
+
+def sharded_unpack_lanes(mesh: Mesh):
+    """``kernels.unpack_lanes`` over the mesh: the packed buffers in and
+    every field out split over ``batch`` alone, as the placement program's
+    ``in_specs`` ask of its small lane operands."""
+    lanes = NamedSharding(mesh, P("batch"))
+
+    @functools.lru_cache(maxsize=None)
+    def program(layouts):
+        def unpack_lanes_sharded(*packs):
+            return unpack_lanes.__wrapped__(*packs, layouts=layouts)
+
+        return jax.jit(
+            unpack_lanes_sharded, in_shardings=lanes, out_shardings=lanes
+        )
+
+    return lambda *packs, layouts: program(layouts)(*packs)

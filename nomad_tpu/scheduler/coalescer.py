@@ -35,7 +35,10 @@ every dispatch uses the SAME static shapes — ``max_lanes`` lanes (short
 batches padded by memset of the preallocated staging buffers) and a
 ``PLACEMENT_CHUNK``-long output (callers take the first rows they asked
 for) — so one executable per ``Features`` variant serves every batch
-size; a recompile costs seconds.  What the shapes do not fix is the work:
+size; a recompile costs seconds.  The 30 small lane operands cross as two
+packed buffers that a program of its own gives back on the device
+(``kernels.unpack_lanes``): a launch costs its thread by the device buffer,
+not by the byte.  What the shapes do not fix is the work:
 the fused kernel's two loops take their trip counts from the staged
 ``lane_steps`` operand (each live lane's ``n_live``, 0 for a dead lane),
 so a launch scores all nodes once per placement its widest lane asked
@@ -74,7 +77,7 @@ from ..obs.breaker import (
     watchdog_fetch,
 )
 from ..ops import kernels
-from ..ops.encode import RequestSlab, SchedRequest
+from ..ops.encode import RequestSlab, SchedRequest, packed_rows
 from ..state.matrix import DEVICE_LOCK
 
 log = logging.getLogger(__name__)
@@ -234,6 +237,9 @@ class DeviceCoalescer:
         # traffic staged per batched dispatch.
         self.solo_ops = 0
         self.operand_bytes_total = 0
+        # The mesh's unpacking program, built with _sharded_fused_fn, and
+        # the (program, layouts) the last launch unpacked with.
+        self._sharded_unpack = self._unpack_variant = None
         # Batched-launch accounting: launches and live lanes
         # (launches-per-eval = fused_dispatches / fused_lanes),
         # verify-column conflicts (placements an earlier lane's plan will
@@ -610,6 +616,7 @@ class DeviceCoalescer:
                 mesh_layout,
                 node_shard_count,
                 sharded_fused_place_batch,
+                sharded_unpack_lanes,
             )
 
             batch, _node = mesh_layout(
@@ -619,6 +626,7 @@ class DeviceCoalescer:
             self._sharded_fused_fn = sharded_fused_place_batch(
                 self._mesh, self.scan_length
             )
+            self._sharded_unpack = sharded_unpack_lanes(self._mesh)
             node_shards = node_shard_count(self._mesh)
             # Home rows to their mesh shard so claims balance across the
             # node axis and growth never migrates a row between shards.
@@ -775,18 +783,23 @@ class DeviceCoalescer:
             or st["spread_counts"].shape[1:] != sc_shape
         ):
             lanes = self.max_lanes
+            # The small lane operands are views of one buffer, handed to
+            # jax as one operand (kernels.unpack_lanes gives them back).
+            pack, small, layout = packed_rows(lanes, [
+                ((cw,), bool), (sc_shape, np.float32),
+                ((MAX_DELTA_ROWS,), np.int32),
+                ((MAX_DELTA_ROWS, 3), np.float32), ((), np.int32),
+            ])
             st = self._stage[slot] = {
                 "host_mask": np.zeros((lanes, n), bool),
                 "tg_count": np.zeros((lanes, n), np.int32),
                 "penalty": np.zeros((lanes, n), bool),
-                "class_elig": np.ones((lanes, cw), bool),
-                "spread_counts": np.zeros((lanes,) + sc_shape, np.float32),
-                "delta_rows": np.full((lanes, MAX_DELTA_ROWS), -1, np.int32),
-                "delta_vals": np.zeros(
-                    (lanes, MAX_DELTA_ROWS, 3), np.float32
-                ),
-                "lane_steps": np.zeros((lanes,), np.int32),
+                "pack": pack, "layout": layout,
+                **dict(zip(("class_elig", "spread_counts", "delta_rows",
+                            "delta_vals", "lane_steps"), small)),
             }
+            st["class_elig"][:] = True
+            st["delta_rows"][:] = -1
         return st, self._req_slabs[slot]
 
     def _sync_matrix(self, n_shards: int, degraded: bool):
@@ -990,13 +1003,12 @@ class DeviceCoalescer:
             # results).
             for i, p in enumerate(batch):
                 slab.fill(i, p.request)
-            reqs = slab.batch()
-            # Host→device operand traffic for this launch: the staged lane
-            # buffers plus the request slab (cost-attribution gauge; the
-            # resident matrix itself transfers via scatter, counted by
-            # matrix.upload_bytes_total).
-            self.operand_bytes_total += (
-                sum(a.nbytes for a in st.values()) + slab.nbytes()
+            # Host→device operand traffic for this launch: the node-axis
+            # lane buffers, the pack of the small ones and the request slab
+            # (cost-attribution gauge; the resident matrix itself transfers
+            # via scatter, counted by matrix.upload_bytes_total).
+            self.operand_bytes_total += slab.nbytes() + sum(
+                a.nbytes for a in (hm, tg, pen, st["pack"])
             )
         # The jitted call: the fused megakernel covers feasibility → binpack
         # → spread/affinity → evict-set → the cross-lane AllocsFit
@@ -1004,8 +1016,10 @@ class DeviceCoalescer:
         # scores only its local node slice, the winner comes from the
         # hierarchical top-k reduce, and the packed (B, P, 8) fetch is the
         # sole device→host traffic).  A launch that widened the features
-        # ratchet traces, lowers and compiles (or reads from the cache) a
-        # new variant inside the call: that one is named apart.
+        # ratchet, or whose packs have another layout than the last one's (a
+        # class-count pow2 crossing, a new request-field shape), traces,
+        # lowers and compiles (or reads from the cache) a new variant inside
+        # the call: that one is named apart.
         state, args = "coalescer.enqueue", {"lanes": k}
         variants = self.feature_recompiles
         feats = self._ratchet_features(slab, k)
@@ -1015,7 +1029,18 @@ class DeviceCoalescer:
         if self.feature_recompiles != variants:
             state = "coalescer.trace_variant"
             args["features"] = str(tuple(feats))
+        unpack = kernels.unpack_lanes if n_shards == 1 else self._sharded_unpack
+        layouts = slab.layout, st["layout"]
+        if self._unpack_variant != (unpack, layouts):
+            self._unpack_variant = unpack, layouts
+            state = "coalescer.trace_variant"
         with self._state(state, **args):
+            # The 30 small lane operands cross as two buffers, not 30: the
+            # placement program takes them as device arrays.
+            reqs, (ce, sc, dr, dv, ls) = unpack(
+                slab.pack, st["pack"], layouts=layouts
+            )
+            reqs = SchedRequest(*reqs)
             if n_shards > 1:
                 packed = self._sharded_fused_fn(
                     sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,

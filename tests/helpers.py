@@ -149,3 +149,168 @@ def fill_frontier(matrix, nodes, picks, ask_cpu, ask_mem, seed=0):
         ))
         rows.append(row)
     return rows
+
+
+# Fills a launch is checked at: 1-3 lanes (steady), 8-16 (the backlog cells,
+# 16 workers), past a batch shard of (2, 2) and up to every lane.
+LAUNCH_FILLS = (1, 2, 8, 9, 16, 33, 57, 64)
+NODE_AXIS = ("tg_count", "penalty", "host_mask")
+SMALL = ("class_elig", "spread_counts", "delta_rows", "delta_vals",
+         "lane_steps")
+
+
+def launch_lanes(coal, k, seed=0, classes=2):
+    """One batch of ``k`` lanes straight through ``coal._dispatch`` (no
+    threads, so the launch holds exactly ``k``): lanes whose node-axis
+    operands differ (host masks with holes, job counts, penalties, seeded
+    per lane, the same whatever the launch's width), asks that differ.
+    Returns the fetched packed result, every lane of it."""
+    import numpy as np
+
+    from nomad_tpu import mock
+    from nomad_tpu.ops.encode import RequestEncoder
+    from nomad_tpu.scheduler.coalescer import MAX_DELTA_ROWS, _Pending
+
+    m, n = coal.matrix, int(coal.matrix.capacity)
+    enc = RequestEncoder(m)
+    batch = []
+    for i in range(k):
+        rng = np.random.default_rng(1000 * seed + i)
+        job = mock.job()
+        job.task_groups[0].tasks[0].resources.cpu = 100 + 10 * (i % 7)
+        req = enc.compile(job, job.task_groups[0]).request
+        batch.append(_Pending(
+            request=req,
+            delta_rows=np.full((MAX_DELTA_ROWS,), -1, np.int32),
+            delta_vals=np.zeros((MAX_DELTA_ROWS, 3), np.float32),
+            tg_count=rng.integers(0, 3, n).astype(np.int32),
+            spread_counts=np.zeros_like(req.s_desired),
+            penalty=rng.random(n) < 0.2,
+            class_elig=np.ones((classes,), bool),
+            host_mask=rng.random(n) < 0.7,
+            n_live=1 + i % 3,
+        ))
+    packed, _version = coal._dispatch(batch)
+    return np.asarray(packed)
+
+
+def spy_on_launch(monkeypatch, coal):
+    """Record what the next launches hand jax: ``packs`` gets the unpacking
+    program's operands, ``placed`` the placement program's (positional)."""
+    from nomad_tpu.ops import kernels
+
+    packs, placed = [], []
+
+    def spy(fn, seen):
+        def call(*operands, **static):
+            seen.append(operands)
+            return fn(*operands, **static)
+        return call
+
+    monkeypatch.setattr(
+        kernels, "fused_place_batch_live",
+        spy(kernels.fused_place_batch_live, placed))
+    if coal._sharded_fused_fn is None:
+        monkeypatch.setattr(
+            kernels, "unpack_lanes", spy(kernels.unpack_lanes, packs))
+    else:
+        monkeypatch.setattr(
+            coal, "_sharded_fused_fn", spy(coal._sharded_fused_fn, placed))
+        monkeypatch.setattr(
+            coal, "_sharded_unpack", spy(coal._sharded_unpack, packs))
+    return packs, placed
+
+
+def wide_coalescer(n_device_shards=1, nodes=40, capacity=64, lanes=64):
+    from nomad_tpu import mock
+    from nomad_tpu.scheduler.coalescer import DeviceCoalescer
+    from nomad_tpu.state import NodeMatrix
+
+    m = NodeMatrix(capacity=capacity)
+    for _ in range(nodes):
+        m.upsert_node(mock.node())
+    return DeviceCoalescer(
+        m, max_lanes=lanes, linger_s=0.0, pipeline_depth=1,
+        n_device_shards=n_device_shards,
+    )
+
+
+_WIDE = {}  # devices -> a 64-lane coalescer that has launched once
+
+
+def check_packed_launch(monkeypatch, k, n_device_shards=1):
+    """A launch of ``k`` lanes hands jax five buffers: the node-axis
+    operands at full width (the slot's buffers, dead lanes all-False) and
+    the two packs every small operand is a view of; the placement program
+    takes the small ones as device arrays; the byte counter says so; and
+    every lane reads bit for bit what the placement program gives on the
+    numpy operands as the slot holds them (the route before the packs).
+    The coalescer is kept for the whole file: a mesh compiles its
+    placement program per coalescer.  Returns it and the small operands
+    the placement program was launched on."""
+    import jax
+    import numpy as np
+
+    from nomad_tpu.ops import kernels
+
+    if n_device_shards not in _WIDE:
+        _WIDE[n_device_shards] = wide_coalescer(n_device_shards)
+        launch_lanes(_WIDE[n_device_shards], 1)
+    coal = _WIDE[n_device_shards]
+    n, lanes = int(coal.matrix.capacity), coal.max_lanes
+    packs, placed = spy_on_launch(monkeypatch, coal)
+    bytes0 = coal.operand_bytes_total
+    got = launch_lanes(coal, k, seed=1)
+
+    st, slab = coal._stage[0], coal._req_slabs[0]
+    assert all(np.shares_memory(st[f], st["pack"]) for f in SMALL)
+    assert all(np.shares_memory(f, slab.pack) for f in slab.batch())
+    fields = sum(st[f].nbytes for f in SMALL)  # each padded to 4 bytes a lane
+    assert fields <= st["pack"].nbytes <= fields + lanes * 4 * len(SMALL)
+    ((req_pack, lane_pack),) = packs
+    assert req_pack is slab.pack and lane_pack is st["pack"]
+    assert coal.operand_bytes_total - bytes0 == (
+        lanes * n * (1 + 4 + 1) + st["pack"].nbytes + slab.pack.nbytes)
+    ((_arrays, _used, dr, dv, tg, sc, pen, reqs, ce, hm, ls),) = placed
+    for x, field in zip((tg, pen, hm), NODE_AXIS):
+        assert x is st[field] and x.shape == (lanes, n)
+    assert not hm[k:].any()
+    small = (ce, sc, dr, dv, ls) + tuple(reqs)
+    assert all(isinstance(x, jax.Array) for x in small)
+    for x, field in zip(small, SMALL):  # copied: the live entry donated them
+        assert x.shape == st[field].shape and x.dtype == st[field].dtype
+
+    operands = [st[f].copy() for f in (
+        "delta_rows", "delta_vals", "tg_count", "spread_counts", "penalty")]
+    operands += [type(reqs)(*(f.copy() for f in slab.batch()))]
+    operands += [st[f].copy() for f in ("class_elig", "host_mask", "lane_steps")]
+    if n_device_shards == 1:
+        arrays = coal.matrix.sync()
+        want = kernels.fused_place_batch(
+            arrays, arrays.used, *operands,
+            n_placements=coal.scan_length, features=coal._features)
+    else:
+        arrays = coal.matrix.sync_sharded(coal._mesh)
+        want = coal._sharded_fused_fn(
+            arrays, arrays.used, *operands, features=coal._features)
+    assert (got[:k, 0, kernels.PACKED_ROW] >= 0).any()
+    np.testing.assert_array_equal(got, np.asarray(want))
+    return coal, small
+
+
+_COMPILES = []
+
+
+def backend_compiles() -> int:
+    """XLA compiles this process has made since the first call (what the
+    benchmark's ``compiles_in_window`` counts)."""
+    if not _COMPILES:
+        import jax.monitoring
+
+        def on(event, _duration, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                _COMPILES[0] += 1
+
+        _COMPILES.append(0)
+        jax.monitoring.register_event_duration_secs_listener(on)
+    return _COMPILES[0]

@@ -10,6 +10,7 @@ import time
 import numpy as np
 import pytest
 
+import helpers
 from helpers import _client, _small, _wait
 from nomad_tpu import mock
 from nomad_tpu.scheduler.coalescer import DeviceCoalescer, MAX_DELTA_ROWS
@@ -174,3 +175,113 @@ def test_every_route_returns_the_verify_column(route, eight_devices):
         1 if route == "breaker_open" else 0
     )
     assert (coal.mesh_shape() != (1, 1)) == (route == "mesh")
+
+
+# ---------------------------------------------------------------------------
+# The small lane operands cross as two packed buffers (kernels.unpack_lanes)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("k", helpers.LAUNCH_FILLS)
+def test_a_launch_hands_over_two_packs_and_the_node_axis_operands(
+    monkeypatch, k,
+):
+    coal, _small = helpers.check_packed_launch(monkeypatch, k)
+    assert coal.mesh_shape() == (1, 1)
+
+
+def test_a_lane_live_in_one_launch_and_dead_in_the_next_leaves_no_trace(
+    monkeypatch,
+):
+    """One slot (depth 1): 16 lanes, then 3.  The second launch's lanes
+    3.. are padded by memset on the host and read as dead lanes."""
+    from nomad_tpu.ops import kernels
+
+    coal = helpers.wide_coalescer()
+    helpers.launch_lanes(coal, 16)
+    assert coal._stage[0]["host_mask"][3:16].any()
+    _packs, placed = helpers.spy_on_launch(monkeypatch, coal)
+    got = helpers.launch_lanes(coal, 3, seed=2)
+    hm, ls = placed[0][9], np.asarray(placed[0][10])
+    assert hm[:3].any() and not hm[3:].any()
+    assert ls[:3].all() and not ls[3:].any()
+    assert (got[3:, :, kernels.PACKED_ROW] == -1).all()
+    assert (got[3:, :, kernels.FUSED_PACKED_VERIFIED] == -1.0).all()
+
+
+def test_no_fill_compiles_and_a_new_layout_is_named_a_variant(monkeypatch):
+    """After the first launch at a node width, fills 1..max_lanes compile
+    nothing, of either program; after a matrix growth the same holds.  A
+    launch whose packs have another layout than the last one's (here: the
+    class-eligibility width doubles) compiles a variant of the unpacking
+    program and says so (``coalescer.trace_variant``); the next does not."""
+    from nomad_tpu.ops import kernels
+
+    coal = helpers.wide_coalescer(nodes=10, capacity=16, lanes=8)
+    states = []
+    state = coal._state
+    monkeypatch.setattr(
+        coal, "_state", lambda name, **a: states.append(name) or state(name, **a))
+
+    def launch_states(k, **kw):
+        del states[:]
+        helpers.launch_lanes(coal, k, **kw)
+        return [s for s in states if s != "coalescer.stage"]
+
+    for n in (16, 32):
+        assert int(coal.matrix.capacity) == n
+        first = launch_states(1)
+        assert first[-1] == (
+            "coalescer.trace_variant" if n == 16 else "coalescer.enqueue")
+        before = helpers.backend_compiles(), kernels.unpack_lanes._cache_size()
+        for k in range(1, 9):
+            assert launch_states(k)[-1] == "coalescer.enqueue"
+        assert (helpers.backend_compiles(),
+                kernels.unpack_lanes._cache_size()) == before
+        for _ in range(10):  # past the capacity: the matrix grows
+            coal.matrix.upsert_node(mock.node())
+    size = kernels.unpack_lanes._cache_size()
+    assert launch_states(2, classes=4)[-1] == "coalescer.trace_variant"
+    assert kernels.unpack_lanes._cache_size() == size + 1
+    assert launch_states(2, classes=4)[-1] == "coalescer.enqueue"
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_packed_lane_operands_unpack_bit_for_bit(eight_devices, devices):
+    """Fields of every dtype and rank the lane operands have (scalars a
+    lane, bools of odd width, matrices) are views of one buffer on the host
+    and come back bit for bit on the device, NaN payloads and -0.0 too; on
+    a mesh split over ``batch`` alone."""
+    from nomad_tpu.ops import kernels
+    from nomad_tpu.ops.encode import packed_rows
+    from nomad_tpu.parallel import make_mesh
+    from nomad_tpu.parallel.sharding import sharded_unpack_lanes
+
+    specs = [((3,), bool), ((2, 5), np.float32), ((7,), np.int32),
+             ((), np.int32), ((), bool), ((4, 3), np.float32), ((1,), bool)]
+    lanes = 8
+    buf, views, layout = packed_rows(lanes, specs)
+    assert buf.dtype == np.uint8 and buf.shape[0] == lanes
+    assert all(field[0] % 4 == 0 for field in layout)
+    rng = np.random.default_rng(5)
+    for v, (shape, dtype) in zip(views, specs):
+        assert np.shares_memory(v, buf)
+        assert v.shape == (lanes,) + shape and v.dtype == dtype
+        if dtype == bool:
+            v[...] = rng.random(v.shape) < 0.5
+        else:  # any bit pattern: NaNs, denormals, -0.0, negative ints
+            v[...] = rng.integers(
+                -2**31, 2**31, v.shape, np.int64).astype(np.int32).view(dtype)
+    views[1][0, 0, 0] = -0.0
+    if devices == 1:
+        unpack = kernels.unpack_lanes
+    else:
+        unpack = sharded_unpack_lanes(make_mesh(devices, batch=2))
+    fields, again = unpack(buf, buf.copy(), layouts=(layout, layout))
+    for got, twin, want in zip(fields, again, views):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert np.asarray(got).tobytes() == np.asarray(twin).tobytes() \
+            == np.ascontiguousarray(want).tobytes()
+        if devices > 1:
+            assert got.sharding.spec[0] == "batch"
+            assert all(s is None for s in got.sharding.spec[1:])

@@ -287,11 +287,29 @@ def test_j105_one_compile_serves_all_step_counts():
     assert contracts._cache_size(entry) == before
 
 
+def test_j105_unpacking_program_compiles_once_for_every_fill():
+    """The unpacking program's operands are the server's own packs
+    (``RequestSlab``'s and ``_staging``'s layouts), whose shapes do not
+    know the fill: fills 1..64 after the first launch cost no compile."""
+    from nomad_tpu.ops.encode import SchedRequest
+
+    c = contracts.get("unpack_lanes")
+    assert c.sweep is contracts.occupancy_sweep and c.max_compiles == 1
+    packs, layouts = contracts._unpack_packs(c.compile_grid)
+    assert [len(lay) for lay in layouts] == [len(SchedRequest._fields), 5]
+    assert all(p.dtype == np.uint8 and p.shape[0] == c.compile_grid.batch
+               for p in packs)
+    entry = c.build(c.compile_grid)
+    assert contracts.occupancy_sweep(entry, c) <= 1
+    assert contracts.occupancy_sweep(entry, c) == 0
+
+
 def test_contract_table_names_every_registered_entry():
     names = {c.name for c in contracts.table()}
     assert names == {
         "fused_place_batch",
         "fused_place_batch_live",
+        "unpack_lanes",
         "sharded_fused_place_batch",
         "make_row_scatter",
     }

@@ -17,7 +17,7 @@ from nomad_tpu.ops import kernels
 from nomad_tpu.ops.encode import RequestEncoder
 from nomad_tpu.state.matrix import NodeMatrix
 
-from helpers import lane_operands
+from helpers import LAUNCH_FILLS, check_packed_launch, lane_operands
 
 
 def _cluster(n_nodes=32, capacity=64, seed=0):
@@ -244,6 +244,26 @@ class TestShardedFusedParity:
         # C1 on the mesh: every asked-for slot holds a node.
         assert (placed == (np.arange(scan)[None, :] < ls[:, None])).all()
         self._assert_parity(ref, out, f"mesh ({nshards},{batch}) {case}")
+
+
+class TestPackedLaunch:
+    """On a mesh the two packs are unpacked by ``sharded_unpack_lanes`` and
+    the sharded placement program takes every small lane operand as a
+    device array split over ``batch`` alone, as its ``in_specs`` ask."""
+
+    @pytest.mark.parametrize("k", LAUNCH_FILLS)
+    @pytest.mark.parametrize("devices,mesh_shape", [(2, (1, 2)), (4, (2, 2))])
+    def test_a_launch_hands_over_two_packs_and_the_node_axis_operands(
+        self, eight_devices, monkeypatch, devices, mesh_shape, k,
+    ):
+        """tests/test_coalescer.py's check on the layouts the server gives
+        two and four devices (under (2, 2) a batch shard holds 32 lanes)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        coal, small = check_packed_launch(monkeypatch, k, devices)
+        assert coal.mesh_shape() == mesh_shape
+        want = NamedSharding(coal._mesh, P("batch"))
+        assert all(x.sharding.is_equivalent_to(want, x.ndim) for x in small)
 
 
 class TestTopkHostBytes:
