@@ -208,8 +208,10 @@ def score_gaps(samples, recorded_dtype=None, floor="floor"):
     return score_gap, rank_gap
 
 
-def decide(get, cfg, traffic, records, used0, seed, dump=None):
-    """The numbers compared, ``correct``, and the lines to print."""
+def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
+    """The numbers compared, ``correct``, and the lines to print.  ``state``
+    is what a deployment's own set-up returned (run.py); this check has no
+    such set-up and ignores it."""
     cluster, n = cfg["cluster"], cfg["nodes"]
     row_of = {node_id(i): i for i in range(n)}
     totals = ref.node_totals(cluster)

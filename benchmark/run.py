@@ -17,7 +17,11 @@ CPU for the benchmark's own tests; such a line's device block says ``cpu``, and 
 Everything that belongs to one cell is data found by name: the workload in
 BENCHMARK.json names a configuration (``configs/<name>.json`` through its
 ``file``) and a traffic mix (``traffic/<name>.json``); each per-layer metric
-is read by ``readers/<metric>.py``.
+is read by ``readers/<metric>.py``.  A configuration may bring three things
+of its own, each optional (README.md, "Adding things"): ``scheduler_config``
+(set over the operator API before the first node registers), ``setup`` (a
+module of ``deployments/`` whose ``install`` runs after the seeded usage)
+and ``check`` (the module whose ``decide`` decides ``correct``).
 """
 
 from __future__ import annotations
@@ -31,16 +35,19 @@ import gc  # noqa: E402
 import glob  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
+import urllib.error  # noqa: E402
 import urllib.request  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-for p in (HERE, os.path.join(HERE, "readers"), ROOT):
+for p in (HERE, os.path.join(HERE, "readers"),
+          os.path.join(HERE, "deployments"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
@@ -92,6 +99,40 @@ def metrics_of(bench, cell, kind, moved=None):
         elif moved is None or m["moves"] in moved:
             out.append(m)
     return out
+
+
+# -- the operator's API ------------------------------------------------------------
+
+SCHEDULER_CONFIG = "/v1/operator/scheduler/configuration"
+
+
+def http_json(addr, path, body=None):
+    """GET ``path`` of the agent, or PUT ``body`` to it; the decoded reply."""
+    req = urllib.request.Request(
+        addr + path, method="GET" if body is None else "PUT",
+        data=None if body is None else json.dumps(body).encode())
+    with urllib.request.urlopen(req, timeout=120) as r:
+        return json.loads(r.read())
+
+
+def holds(got, want) -> bool:
+    """``got`` says what ``want`` asks: objects key by key, the rest equal."""
+    if isinstance(want, dict):
+        return isinstance(got, dict) and all(
+            k in got and holds(got[k], v) for k, v in want.items())
+    return got == want
+
+
+def set_scheduler_config(addr, want) -> None:
+    """As ``nomad operator scheduler set-config`` does, then read back: a
+    key the server does not hand back as sent was not set."""
+    try:
+        http_json(addr, SCHEDULER_CONFIG, want)
+    except urllib.error.HTTPError as e:
+        raise Fail(f"scheduler_config: sent {want}, the server says {e}")
+    got = http_json(addr, SCHEDULER_CONFIG)
+    if not holds(got, want):
+        raise Fail(f"scheduler_config: sent {want}, the server reads {got}")
 
 
 # -- the client process ----------------------------------------------------------
@@ -255,6 +296,8 @@ def run(args) -> dict:
         shutil.rmtree(tmp, ignore_errors=True)
         agent.start()
         srv = agent.server
+        if "scheduler_config" in cfg:
+            set_scheduler_config(agent.rpc_addr, cfg["scheduler_config"])
         n = cfg["nodes"]
         node_ids = [check.node_id(i) for i in range(n)]
         for i, nid in enumerate(node_ids):
@@ -273,6 +316,17 @@ def run(args) -> dict:
         setup["register_s"] = time.time() - t
         log(f"agent at {agent.rpc_addr}: {n} nodes, usage of "
             f"{cfg['sim_allocs']} allocations")
+        state = None
+        if "setup" in cfg:
+            # The deployment's own set-up, in the server's process as the
+            # calls above are.  What it returns is the reference's copy of
+            # what it installed: plain data, nothing of the program's.
+            t = time.time()
+            install = importlib.import_module(cfg["setup"]).install
+            state = json.loads(json.dumps(
+                install(srv, cfg, args.seed, rows, seeded)))
+            setup["install_s"] = time.time() - t
+            log(f"deployment set-up {cfg['setup']}: {setup['install_s']:.1f}s")
 
         # -- warm-up: every shape the window will use ---------------------------
         t = time.time()
@@ -378,7 +432,8 @@ def run(args) -> dict:
             "placement_p95_ms": measure.percentile(lat, 0.95),
             "setup_s": setup_s,
         }
-        log(f"attempted {len(attempted)}, failed {len(failed)} {causes}; "
+        log(f"began {len(reply['records'])} of {reply['scheduled']} dealt; "
+            f"attempted {len(attempted)}, failed {len(failed)} {causes}; "
             f"evals ended {reply['evals_ended']}, failed evals "
             f"{reply['evals_failed']}, 429s "
             f"{sum(r['n429'] for r in attempted)}, stream gaps "
@@ -386,13 +441,13 @@ def run(args) -> dict:
 
         # -- correct: the read-back against the plain reference ------------------------
         def get(path):
-            with urllib.request.urlopen(agent.rpc_addr + path, timeout=120) as r:
-                return json.loads(r.read())
+            return http_json(agent.rpc_addr, path)
 
         t = time.time()
-        correct, numbers, lines = check.decide(
+        checker = importlib.import_module(cfg.get("check", "check"))
+        correct, numbers, lines = checker.decide(
             get, cfg, traffic, reply["records"], seeded, args.seed,
-            dump=args.check_dump,
+            dump=args.check_dump, state=state,
         )
         for line in lines:
             print(line, flush=True)
@@ -462,12 +517,22 @@ def run(args) -> dict:
             v = values.get(m["name"])
             if v is not None and v == v and abs(v) != float("inf"):
                 result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
+        # Each number compared beside its limit: the line's last key.
+        # (A gap of a decision with no recorded score is infinite: a word
+        # there, so that the line stays JSON.)
+        result["compared"] = {
+            k: {"value": numbers[k] if math.isfinite(numbers[k])
+                else str(numbers[k]), "limit": limit}
+            for k, limit in checker.LIMITS.items() if k in numbers}
         print("detail: " + json.dumps({
             "causes": causes, "numbers": numbers, "setup": setup, "e2e": e2e,
             "compiles_in_window": compiles_in_window,
             "evals_ended": reply["evals_ended"],
             "evals_failed": reply["evals_failed"],
             "eval_failed_causes": reply["eval_failed_causes"],
+            # Operations the client began of those it was dealt: a closed
+            # loop that uses up its deck reads its own ceiling.
+            "deck_used": [len(reply["records"]), reply["scheduled"]],
             "reregistered_ops": sum(r["registers"] > 1 for r in attempted),
             "n429": sum(r["n429"] for r in attempted),
             "stream_gaps": reply["stream_gaps"],
@@ -515,6 +580,10 @@ def main(argv=None) -> int:
     except Fail as e:
         print(f"benchmark: {e}; nothing was measured", file=sys.stderr)
         return 2
+    for k, c in result["compared"].items():
+        value = c["value"] if isinstance(c["value"], str) else (
+            f"{c['value']:.6g}")
+        print(f"check: {k} = {value} (limit {c['limit']:g})", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

@@ -11,26 +11,22 @@
 
 import json
 import os
-import shutil
 import subprocess
 import sys
 
 import pytest
 
-from conftest import BENCH, ROOT
+import check
+from conftest import BENCH, ROOT, checkout
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 @pytest.fixture(scope="module")
 def tree(tmp_path_factory):
     """A copy of the benchmark with a dummy cell ADDED to it."""
     root = tmp_path_factory.mktemp("checkout")
-    shutil.copytree(BENCH, root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    os.symlink(os.path.join(ROOT, "nomad_tpu"), root / "nomad_tpu")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
-        bench = json.load(fh)
+    bench = checkout(root)
     cfg = json.load(open(root / "benchmark/configs/c2m-10k.json"))
     cfg["name"] = "dummy-cluster"
     (root / "benchmark/configs/dummy-cluster.json").write_text(json.dumps(cfg))
@@ -94,6 +90,15 @@ def test_last_line_has_exactly_the_contracts_keys(traced, tree):
     assert q.returncode == 0, q.stderr[-2000:]
     line = json.loads(q.stdout.strip().splitlines()[-1])
     assert set(line) == KEYS
+    # Each number compared beside its limit: the line's last key, and the
+    # last lines on standard error.
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == set(check.LIMITS)
+    assert all(set(c) == {"value", "limit"} for c in line["compared"].values())
+    assert line["compared"]["score_gap"]["limit"] == check.LIMITS["score_gap"]
+    tail = q.stderr.strip().splitlines()[-len(check.LIMITS):]
+    assert [t.split()[1] for t in tail] == list(check.LIMITS)
+    assert all(t.startswith("check: ") and "(limit " in t for t in tail)
     assert set(line["metrics"]) == {"evals_per_s", "setup_s"}
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
