@@ -211,10 +211,11 @@ def library_level(meter: CompileMeter, sizes, seed: int,
     m = simcluster.build_cluster(nodes, capacity, allocs, seed=seed)
     shapes = simcluster.build_requests(m)
     enc = RequestEncoder(m)
-    # One request that needs preemption somewhere: high priority, an ask
-    # larger than what the fullest nodes have left.
+    # One request that needs preemption: high priority, and more cpu than
+    # any node has left (the seeded usage is at least ~850 of 3,900 MHz;
+    # with room anywhere there is no eviction).
     pjob = mock.job(priority=90)
-    pjob.task_groups[0].tasks[0].resources.cpu = 1400
+    pjob.task_groups[0].tasks[0].resources.cpu = 3200
     pjob.task_groups[0].tasks[0].resources.memory_mb = 2600
     preempting = enc.compile(
         pjob, pjob.task_groups[0], preemption_enabled=True

@@ -211,17 +211,21 @@ def fit_and_binpack(arrays, used, req: SchedRequest):
     fits = np.all(fits_dim, axis=1)
     exhausted = np.argmax(~fits_dim, axis=1).astype(np.int32)
     exhausted = np.where(fits, -1, exhausted).astype(np.int32)
+    return fits, score_fit(arrays, util, req), exhausted
 
+
+def score_fit(arrays, util, req: SchedRequest):
+    """Twin of kernels.score_fit: ScoreFit of the utilisation ``util``."""
     denom = np.maximum(arrays.totals, np.float32(1.0))
     free = np.float32(1.0) - util / denom
     # exp2(x·log₂10) mirrors the kernel's 10**x lowering exactly (see
-    # kernels.fit_and_binpack).
+    # kernels.score_fit).
     log2_10 = np.float32(3.321928094887362)
     total = np.exp2(free[:, 0] * log2_10) + np.exp2(free[:, 1] * log2_10)
     binpack = np.clip(np.float32(20.0) - total, 0.0, 18.0)
     spread = np.clip(total - np.float32(2.0), 0.0, 18.0)
     score = np.where(int(req.algorithm) == 1, spread, binpack) / np.float32(18.0)
-    return fits, score.astype(np.float32), exhausted
+    return score.astype(np.float32)
 
 
 def anti_affinity_score(tg_count, req: SchedRequest):
@@ -441,43 +445,201 @@ def _compute_static_parts(arrays, req: SchedRequest, penalty_mask,
     )
 
 
-def _score_step(arrays, req: SchedRequest, sp: _StaticParts, used, tg_count,
-                spread_counts):
-    """One scan step's ScoreResult equivalents (final, needs_preempt,
-    binpack, counters) given the current carry."""
-    feas = sp.feas
-    if sp.distinct:
-        feas = feas & ~(tg_count > 0)
-    fits, binpack, _ = fit_and_binpack(arrays, used, req)
+class _Ranked(NamedTuple):
+    """One step's ranking of some rows, before the two-tier gate
+    (kernels.score_nodes): a row is ranked as a node that fits, as a node
+    that fits after an eviction, or not at all."""
 
+    fits: np.ndarray  # bool: the ask fits without eviction
+    can_pre: np.ndarray  # bool: it fits only after one
+    final_fit: np.ndarray  # f32 mean as a fitting node, NEG_INF elsewhere
+    final_pre: np.ndarray  # f32 mean as a preempting node, NEG_INF elsewhere
+    bin_fit: np.ndarray  # f32 ScoreFit of used + ask
+    bin_pre: np.ndarray  # f32 the two estimated terms of ``final_pre``
+    terms_pre: np.ndarray  # f32 number of terms ``final_pre`` is the mean of
+
+
+def _rank_rows(view, req: SchedRequest, sp: _StaticParts, r, feas, used,
+               tg_count, spr_score=None, spr_app=None) -> _Ranked:
+    """Rank the rows ``r`` (a slice) of the request's static parts;
+    ``view`` (totals), ``feas``, ``used``, ``tg_count`` and the spread
+    terms are already cut to them.  The float32 expressions of
+    kernels.score_nodes, term for term."""
+    f32 = np.float32
+    fits, bin_fit, _ = fit_and_binpack(view, used, req)
     util = used + sp.ask[None, :]
-    fits_with_preempt = np.all(util - sp.extra_free <= arrays.totals, axis=1)
-    needs_preempt = ~fits & fits_with_preempt & sp.pre_usable
-    fits_all = fits | needs_preempt
-
+    deficit = np.maximum(util - view.totals, f32(0.0))
+    freeable = sp.extra_free[r]
+    can_pre = (
+        ~fits & np.all(deficit <= freeable, axis=1) & sp.pre_usable[r]
+    )
     aa_score, aa_app = anti_affinity_score(tg_count, req)
-    spr_score, spr_app = spread_score(arrays, req, spread_counts)
-    pre_component = np.where(needs_preempt, sp.pre_score, 0.0)
-
-    total = (
-        binpack + aa_score + sp.pen_score + sp.aff_score + spr_score
-        + pre_component
-    )
     count = (
-        1.0
-        + aa_app.astype(np.float32)
-        + sp.pen_app.astype(np.float32)
-        + sp.aff_app.astype(np.float32)
-        + spr_app.astype(np.float32)
-        + needs_preempt.astype(np.float32)
+        f32(1.0)
+        + aa_app.astype(f32)
+        + sp.pen_app[r].astype(f32)
+        + sp.aff_app[r].astype(f32)
     )
-    final = total / count
-    final = np.where(feas & fits_all, final, NEG_INF).astype(np.float32)
+    if spr_score is not None:
+        count = count + spr_app.astype(f32)
 
-    n_eval = int(np.sum(feas))
-    n_filt = int(np.sum(~feas & arrays.eligible))
-    n_exh = int(np.sum(feas & ~fits_all))
-    return final, needs_preempt, binpack, n_eval, n_filt, n_exh
+    def total(binpack):  # the kernel's order of additions
+        t = binpack + aa_score + sp.pen_score[r] + sp.aff_score[r]
+        return t if spr_score is None else t + spr_score
+
+    final_fit = np.where(
+        feas & fits, total(bin_fit) / count, NEG_INF
+    ).astype(f32)
+    if not can_pre.any():
+        none = np.full(fits.shape, NEG_INF, f32)
+        zero = np.zeros(fits.shape, f32)
+        return _Ranked(fits, can_pre, final_fit, none, bin_fit, zero, zero)
+    evicted = np.minimum(freeable, deficit)
+    bin_est = score_fit(view, util - evicted, req)
+    pre_score = sp.pre_score[r]
+    terms_pre = count + f32(1.0)
+    final_pre = np.where(
+        feas & can_pre, (total(bin_est) + pre_score) / terms_pre, NEG_INF
+    ).astype(f32)
+    return _Ranked(
+        fits, can_pre, final_fit, final_pre, bin_fit,
+        (bin_est + pre_score).astype(f32), terms_pre.astype(f32),
+    )
+
+
+class _TotalsView(NamedTuple):
+    """1-row stand-in for DeviceArrays when rescoring a single node."""
+
+    totals: np.ndarray
+
+
+class _LaneScan:
+    """One request's placement scan, a step at a time: ``final`` is the
+    step's score vector, ``commit(row)`` charges the pick and returns the
+    step's seven packed columns.  Twin of the two halves of a kernel step
+    (kernels._score_step / _commit_step); the solo scan takes the arg-max
+    between them, the fused twin resolves the lanes' picks there.
+
+    Every row is ranked both ways (``_Ranked``) and the two-tier gate is
+    applied on reading: nodes that need an eviction are in the arg-max
+    only while no feasible node fits without one (``n_fit`` == 0).
+
+    Without spread stanzas every node is scored once and only the placed
+    row is rescored between steps (the carry changes nowhere else; the
+    single-row rescore runs the same float32 expressions on 1-element
+    slices, so the outputs are identical to a full recompute).  Spread
+    stanzas shift every node's score when a placement bumps a value
+    count: full recompute per step."""
+
+    def __init__(self, arrays, req: SchedRequest, used0, tg_count,
+                 spread_counts, penalty_mask, class_elig, host_mask):
+        self.arrays, self.req = arrays, req
+        self.sp = sp = _static_parts(
+            arrays, req, penalty_mask, class_elig, host_mask
+        )
+        self.used = np.array(used0, np.float32, copy=True)
+        self.tg = np.array(tg_count, np.int32, copy=True)
+        self.spreads = bool((np.asarray(req.s_slot) >= 0).any())
+        self.feas = sp.feas & ~(self.tg > 0) if sp.distinct else sp.feas
+        if self.spreads:
+            self.s_hash = np.array(req.s_value_hash, copy=True)
+            self.s_counts = np.array(spread_counts, np.float32, copy=True)
+        self._rescore_all()
+
+    def _rescore_all(self):
+        spr = (None, None)
+        self.req_step = self.req
+        if self.spreads:
+            self.req_step = self.req._replace(s_value_hash=self.s_hash)
+            spr = spread_score(self.arrays, self.req_step, self.s_counts)
+        # Arrays the single-row rescore writes into: own copies.
+        self.rk = rk = _Ranked(*(
+            np.array(x) for x in _rank_rows(
+                self.arrays, self.req_step, self.sp, slice(None), self.feas,
+                self.used, self.tg, *spr,
+            )
+        ))
+        feas = self.feas
+        self.n_eval = int(np.sum(feas))
+        self.n_filt = int(np.sum(~feas & self.arrays.eligible))
+        self.n_fit = int(np.sum(feas & rk.fits))
+        self.n_pre = int(np.sum(feas & rk.can_pre))
+
+    # -- the two-tier gate, on reading -------------------------------------
+
+    @property
+    def preempting(self) -> bool:
+        """This step's arg-max is over the nodes that need an eviction."""
+        return self.n_fit == 0 and self.n_pre > 0
+
+    @property
+    def final(self) -> np.ndarray:
+        return self.rk.final_pre if self.preempting else self.rk.final_fit
+
+    @property
+    def needs_pre(self) -> np.ndarray:
+        if self.preempting:
+            return self.rk.can_pre
+        return np.zeros(self.rk.can_pre.shape, bool)
+
+    @property
+    def n_exh(self) -> int:
+        # feasible, and not in this step's arg-max for want of room
+        open_ = self.n_eval - self.n_fit
+        return open_ - self.n_pre if self.preempting else open_
+
+    def failed_row(self) -> tuple:
+        """The packed columns of a step in which no node can take the
+        request (the carry stays as it is, so every later step reads the
+        same)."""
+        return (-1.0, 0.0, 0.0, 0.0, self.n_eval, self.n_filt, self.n_exh)
+
+    def commit(self, row: int) -> tuple:
+        arrays, sp, rk = self.arrays, self.sp, self.rk
+        pre = self.preempting and bool(rk.can_pre[row])
+        out = (
+            row,
+            self.final[row],
+            rk.bin_pre[row] if pre else rk.bin_fit[row],
+            rk.terms_pre[row] if pre else 0.0,
+            self.n_eval,
+            self.n_filt,
+            self.n_exh,
+        )
+        old_feas = bool(self.feas[row])
+        old_fit = old_feas and bool(rk.fits[row])
+        old_pre = old_feas and bool(rk.can_pre[row])
+        self.used[row] += sp.ask
+        self.tg[row] += 1
+        if sp.distinct:
+            if self.feas is sp.feas:
+                self.feas = self.feas.copy()
+            self.feas[row] = False
+        if self.spreads:
+            nvalues = arrays.attr_hash[
+                row, np.maximum(np.asarray(self.req_step.s_slot), 0)
+            ]
+            _apply_spread_values(
+                self.req_step, self.s_hash, self.s_counts, nvalues
+            )
+            self._rescore_all()
+            return out
+
+        r = slice(row, row + 1)
+        new = _rank_rows(
+            _TotalsView(arrays.totals[r]), self.req, sp, r, self.feas[r],
+            self.used[r], self.tg[r],
+        )
+        for dst, src in zip(rk, new):
+            dst[row] = src[0]
+        new_feas = bool(self.feas[row])
+        if new_feas != old_feas:
+            self.n_eval += 1 if new_feas else -1
+            if bool(arrays.eligible[row]):
+                self.n_filt += -1 if new_feas else 1
+        self.n_fit += int(new_feas and bool(rk.fits[row])) - int(old_fit)
+        self.n_pre += int(new_feas and bool(rk.can_pre[row])) - int(old_pre)
+        return out
 
 
 def _apply_spread_values(req: SchedRequest, s_hash, s_counts, nvalues):
@@ -497,147 +659,6 @@ def _apply_spread_values(req: SchedRequest, s_hash, s_counts, nvalues):
             vh[idx] = nv
         if can:
             s_counts[s, idx] += 1.0
-
-
-class _TotalsView(NamedTuple):
-    """1-row stand-in for DeviceArrays when rescoring a single node."""
-
-    totals: np.ndarray
-
-
-class _LaneScan:
-    """One request's placement scan, a step at a time: ``final`` is the
-    step's score vector, ``commit(row)`` charges the pick and returns the
-    step's seven packed columns.  Twin of the two halves of a kernel step
-    (kernels._score_step / _commit_step); the solo scan takes the arg-max
-    between them, the fused twin resolves the lanes' picks there.
-
-    Without spread stanzas every node is scored once and only the placed
-    row is rescored between steps (the carry changes nowhere else; the
-    single-row rescore runs the same float32 expressions on 1-element
-    slices, so the outputs are identical to a full recompute).  Spread
-    stanzas shift every node's score when a placement bumps a value
-    count: full recompute per step."""
-
-    def __init__(self, arrays, req: SchedRequest, used0, tg_count,
-                 spread_counts, penalty_mask, class_elig, host_mask):
-        self.arrays, self.req = arrays, req
-        self.sp = sp = _static_parts(
-            arrays, req, penalty_mask, class_elig, host_mask
-        )
-        self.used = np.array(used0, np.float32, copy=True)
-        self.tg = np.array(tg_count, np.int32, copy=True)
-        self.spreads = bool((np.asarray(req.s_slot) >= 0).any())
-        if self.spreads:
-            self.s_hash = np.array(req.s_value_hash, copy=True)
-            self.s_counts = np.array(spread_counts, np.float32, copy=True)
-            self._rescore_all()
-            return
-        f32 = np.float32
-        self.feas = sp.feas & ~(self.tg > 0) if sp.distinct else sp.feas
-        fits, self.binpack, _ = fit_and_binpack(arrays, self.used, req)
-        util = self.used + sp.ask[None, :]
-        fwp = np.all(util - sp.extra_free <= arrays.totals, axis=1)
-        self.needs_pre = ~fits & fwp & sp.pre_usable
-        self.fits_all = fits | self.needs_pre
-        aa_score, aa_app = anti_affinity_score(self.tg, req)
-        pre_component = np.where(self.needs_pre, sp.pre_score, 0.0)
-        total = (
-            self.binpack + aa_score + sp.pen_score + sp.aff_score
-            + pre_component
-        )
-        count = (
-            1.0
-            + aa_app.astype(f32)
-            + sp.pen_app.astype(f32)
-            + sp.aff_app.astype(f32)
-            + self.needs_pre.astype(f32)
-        )
-        self.final = np.where(
-            self.feas & self.fits_all, total / count, NEG_INF
-        ).astype(f32)
-        self.n_eval = int(np.sum(self.feas))
-        self.n_filt = int(np.sum(~self.feas & arrays.eligible))
-        self.n_exh = int(np.sum(self.feas & ~self.fits_all))
-
-    def _rescore_all(self):
-        self.req_step = self.req._replace(s_value_hash=self.s_hash)
-        (self.final, self.needs_pre, self.binpack, self.n_eval, self.n_filt,
-         self.n_exh) = _score_step(
-            self.arrays, self.req_step, self.sp, self.used, self.tg,
-            self.s_counts,
-        )
-
-    def failed_row(self) -> tuple:
-        """The packed columns of a step in which no node can take the
-        request (the carry stays as it is, so every later step reads the
-        same)."""
-        return (-1.0, 0.0, 0.0, 0.0, self.n_eval, self.n_filt, self.n_exh)
-
-    def commit(self, row: int) -> tuple:
-        arrays, req, sp = self.arrays, self.req, self.sp
-        out = (
-            row,
-            self.final[row],
-            self.binpack[row],
-            1.0 if self.needs_pre[row] else 0.0,
-            self.n_eval,
-            self.n_filt,
-            self.n_exh,
-        )
-        self.used[row] += sp.ask
-        self.tg[row] += 1
-        if self.spreads:
-            nvalues = arrays.attr_hash[
-                row, np.maximum(np.asarray(self.req_step.s_slot), 0)
-            ]
-            _apply_spread_values(
-                self.req_step, self.s_hash, self.s_counts, nvalues
-            )
-            self._rescore_all()
-            return out
-
-        f32 = np.float32
-        old_feas = bool(self.feas[row])
-        old_open = old_feas and not bool(self.fits_all[row])
-        if sp.distinct:
-            if self.feas is sp.feas:
-                self.feas = self.feas.copy()
-            self.feas[row] = False
-        r = slice(row, row + 1)
-        fits_r, bin_r, _ = fit_and_binpack(_TotalsView(arrays.totals[r]),
-                                           self.used[r], req)
-        util_r = self.used[r] + sp.ask[None, :]
-        fwp_r = np.all(util_r - sp.extra_free[r] <= arrays.totals[r], axis=1)
-        np_r = ~fits_r & fwp_r & sp.pre_usable[r]
-        fa_r = fits_r | np_r
-        aa_r, aa_app_r = anti_affinity_score(self.tg[r], req)
-        pre_r = np.where(np_r, sp.pre_score[r], 0.0)
-        tot_r = bin_r + aa_r + sp.pen_score[r] + sp.aff_score[r] + pre_r
-        cnt_r = (
-            1.0
-            + aa_app_r.astype(f32)
-            + sp.pen_app[r].astype(f32)
-            + sp.aff_app[r].astype(f32)
-            + np_r.astype(f32)
-        )
-        fin_r = np.where(
-            self.feas[r] & fa_r, tot_r / cnt_r, NEG_INF
-        ).astype(f32)
-        self.binpack[row] = bin_r[0]
-        self.needs_pre[row] = np_r[0]
-        self.fits_all[row] = fa_r[0]
-        self.final[row] = fin_r[0]
-
-        new_feas = bool(self.feas[row])
-        if new_feas != old_feas:
-            self.n_eval += 1 if new_feas else -1
-            if bool(arrays.eligible[row]):
-                self.n_filt += -1 if new_feas else 1
-        self.n_exh += (
-            int(new_feas and not bool(self.fits_all[row])) - int(old_open)
-        )
-        return out
 
 
 def _place_scan(arrays, req: SchedRequest, used0, tg_count, spread_counts,
@@ -685,7 +706,7 @@ def place_task_group(arrays, req: SchedRequest, used0, tg_count,
         rows=packed[:, 0].astype(np.int32),
         scores=packed[:, 1],
         binpack=packed[:, 2],
-        preempted=packed[:, 3] != 0.0,
+        preempted=packed[:, 3],
         nodes_evaluated=packed[:, 4].astype(np.int32),
         nodes_filtered=packed[:, 5].astype(np.int32),
         nodes_exhausted=packed[:, 6].astype(np.int32),
@@ -715,8 +736,9 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
     their picks in lane order against one image of the launch's claims
     (the shared usage, every live lane's in-flight deltas, every pick so
     far): the arg-max of the lane's own scores over the nodes where the
-    image has room for its ask (nodes it may take by preempting count as
-    having room), its own arg-max where none has.  Then the sequential
+    image has room for its ask (a node it may take by preempting counts
+    as having room until a claim over-fills it), its own arg-max where
+    none has.  Then the sequential
     cross-lane AllocsFit VERIFIED column: lanes commit their in-flight
     deltas and placements to a cumulative usage image in lane order, and
     each placement is checked against it (1.0 fits on the lane's own
@@ -768,7 +790,10 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
                 continue
             ask = lane.sp.ask
             room = np.all(claims + ask[None, :] <= totals, axis=1)
-            masked = np.where(room | lane.needs_pre, lane.final, NEG_INF)
+            unclaimed = np.all(claims <= totals, axis=1)
+            masked = np.where(
+                room | (lane.needs_pre & unclaimed), lane.final, NEG_INF
+            )
             row = int(np.argmax(masked))
             if not masked[row] > NEG_INF / 2:
                 row = own

@@ -321,7 +321,13 @@ def fit_and_binpack(arrays, used, req: SchedRequest):
     # first exhausted dim index for metrics (0=cpu,1=mem,2=disk, -1 = fits)
     exhausted = jnp.argmax(~fits_dim, axis=1).astype(jnp.int32)
     exhausted = jnp.where(fits, -1, exhausted)
+    return fits, score_fit(arrays, util, req), exhausted
 
+
+def score_fit(arrays, util, req: SchedRequest):
+    """(N,) f32 — ScoreFit of the utilisation ``util`` (N, 3), normalized
+    by 18: ``fit_and_binpack``'s score of ``used + ask``, and the score of
+    a preempting node's utilisation after eviction (``score_nodes``)."""
     denom = jnp.maximum(arrays.totals, 1.0)
     free = 1.0 - util / denom  # (N, 3)
     free_cpu, free_mem = free[:, 0], free[:, 1]
@@ -331,8 +337,7 @@ def fit_and_binpack(arrays, used, req: SchedRequest):
     total = jnp.exp2(free_cpu * log2_10) + jnp.exp2(free_mem * log2_10)
     binpack = jnp.clip(20.0 - total, 0.0, 18.0)
     spread = jnp.clip(total - 2.0, 0.0, 18.0)
-    score = jnp.where(req.algorithm == 1, spread, binpack) / 18.0
-    return fits, score, exhausted
+    return jnp.where(req.algorithm == 1, spread, binpack) / 18.0
 
 
 @jax.named_scope("affinity_spread")
@@ -520,9 +525,14 @@ class ScoreResult(NamedTuple):
     final: jnp.ndarray  # (N,) f32, NEG_INF where infeasible
     feasible: jnp.ndarray  # (N,) bool (constraints, pre-resource)
     fits: jnp.ndarray  # (N,) bool (resources, incl. preemption assist)
-    needs_preempt: jnp.ndarray  # (N,) bool
-    binpack: jnp.ndarray  # (N,) f32
+    needs_preempt: jnp.ndarray  # (N,) bool: in the arg-max only by eviction
+    # (N,) f32.  On a ``needs_preempt`` node: the part of the ranked mean
+    # that depends on the victims, an ESTIMATE (below).
+    binpack: jnp.ndarray
     exhausted_dim: jnp.ndarray  # (N,) i32
+    # (N,) f32: how many terms ``final`` is the mean of on a
+    # ``needs_preempt`` node, 0.0 elsewhere; None with preemption off.
+    pre_terms: Optional[jnp.ndarray] = None
 
 
 def score_nodes(
@@ -535,6 +545,7 @@ def score_nodes(
     class_elig,
     host_mask,
     features: Features = FULL_FEATURES,
+    node_axis: Optional[str] = None,
 ) -> ScoreResult:
     """The full ranking pipeline as one fused program (GenericStack.Select,
     stack.go:117-179, minus the sampling the TPU design makes unnecessary).
@@ -542,7 +553,32 @@ def score_nodes(
     ``features`` (static) bounds every sub-pass to the dispatch's batch
     occupancy — padded constraint/affinity/spread slots, unused preemption
     tables and port bitmaps cost nothing when no eval in the batch uses
-    them."""
+    them.
+
+    **Preemption** (``features.preempt``), as Nomad makes it
+    (generic_sched.go:773-792: select without preemption, and only when no
+    option was found select again with it).  Two tiers in ONE arg-max: a
+    node that needs an eviction is in this step's arg-max only if NO
+    feasible node fits without one (one more reduction a step;
+    ``node_axis`` names the mesh axis the nodes are sharded over, so that
+    "no node" is said of the whole cluster).  Among preempting nodes the
+    rank is Nomad's mean with two of its terms ESTIMATED, because the
+    victims are chosen on the host, after the launch, for the one node
+    picked (scheduler/preemption.py):
+
+    * binpack: ScoreFit of the utilisation after the LEAST eviction the
+      bucket tables can express, ``used + ask - min(freeable, deficit)``
+      (rank.go scores ``proposed`` less the allocations to preempt; whole
+      allocations free more than the deficit, so the exact score is lower
+      or equal).  Not the clipped 1.0 an over-full node would read.
+    * preemption: the logistic of the net priority of the evictable
+      buckets' midpoints (``preemption_state``), not of the victims.
+
+    The host records the exact score (stack.py: binpack after the chosen
+    victims, logistic of their net priority).  For that the packed output
+    of a preempting pick carries, beside the ranked mean in SCORE, the sum
+    of the two estimated terms in BINPACK and the number of terms of the
+    mean in PREEMPT (0.0 = no eviction): the host swaps the two terms."""
     feas = feasibility_mask(arrays, req, class_elig, host_mask, features)
     # distinct_hosts: one proposed alloc of this job+TG per node, enforced
     # in-scan via tg_count so multi-placement batches can't stack a node.
@@ -550,14 +586,23 @@ def score_nodes(
     fits, binpack, exhausted = fit_and_binpack(arrays, used, req)
 
     if features.preempt:
-        # Preemption assist: nodes that don't fit but could after evicting
-        # lower-priority work (generic_sched.go:773-792 retry pass).
-        extra_free, pre_score, pre_usable = preemption_state(arrays, req)
-        util = used + req.ask[None, :]
-        fits_with_preempt = jnp.all(util - extra_free <= arrays.totals, axis=1)
-        needs_preempt = ~fits & fits_with_preempt & pre_usable
-        fits_all = fits | needs_preempt
-        pre_component = jnp.where(needs_preempt, pre_score, 0.0)
+        freeable, pre_score, pre_usable = preemption_state(arrays, req)
+        with jax.named_scope("preemption"):
+            util = used + req.ask[None, :]
+            deficit = jnp.maximum(util - arrays.totals, 0.0)
+            can_preempt = (
+                ~fits & jnp.all(deficit <= freeable, axis=1) & pre_usable
+            )
+            any_fit = jnp.any(feas & fits)
+            if node_axis is not None:
+                any_fit = lax.pmax(any_fit.astype(jnp.int32), node_axis) > 0
+            needs_preempt = can_preempt & ~any_fit
+            fits_all = fits | needs_preempt
+            evicted = jnp.minimum(freeable, deficit)
+            binpack = jnp.where(
+                needs_preempt, score_fit(arrays, util - evicted, req), binpack
+            )
+            pre_component = jnp.where(needs_preempt, pre_score, 0.0)
     else:
         needs_preempt = jnp.zeros_like(fits)
         fits_all = fits
@@ -580,6 +625,10 @@ def score_nodes(
     )
     final = total / count
     final = jnp.where(feas & fits_all, final, NEG_INF)
+    pre_terms = None
+    if features.preempt:
+        pre_terms = jnp.where(needs_preempt, count, 0.0)
+        binpack = binpack + pre_component
     return ScoreResult(
         final=final,
         feasible=feas,
@@ -587,6 +636,7 @@ def score_nodes(
         needs_preempt=needs_preempt,
         binpack=binpack,
         exhausted_dim=exhausted,
+        pre_terms=pre_terms,
     )
 
 
@@ -699,11 +749,14 @@ def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
         s_hash2 = jnp.where(ok, new_hash, s_hash)
         s_counts2 = jnp.where(ok, new_counts, s_counts)
 
+    preempted = ok & res.needs_preempt[safe_row]
+    if res.pre_terms is not None:
+        preempted = jnp.where(preempted, res.pre_terms[safe_row], 0.0)
     out = (
         row,
         jnp.where(ok, res.final[safe_row], 0.0),
         jnp.where(ok, res.binpack[safe_row], 0.0),
-        ok & res.needs_preempt[safe_row],
+        preempted,
     ) + counts
     return (used2, tg2, s_hash2, s_counts2), out
 
@@ -792,6 +845,9 @@ def place_task_group(
 PACKED_ROW = 0
 PACKED_SCORE = 1
 PACKED_BINPACK = 2
+# 0.0 = no eviction; on a preempting pick the number of terms of the mean
+# in PACKED_SCORE, and PACKED_BINPACK the sum of its two estimated terms
+# (``score_nodes``): what the host needs to record the exact score.
 PACKED_PREEMPT = 3
 PACKED_EVALUATED = 4
 PACKED_FILTERED = 5
@@ -888,18 +944,21 @@ def pack_fused_lanes(
     )  # (B, P, FUSED_PACKED_WIDTH)
 
 
-def inert_lane_outputs(lanes: int, n_placements: int) -> tuple:
+def inert_lane_outputs(lanes: int, n_placements: int,
+                       preempt: bool = False) -> tuple:
     """The stacked outputs of a launch in which no step ran, step-major
     ((P, B): a step's outputs of all lanes land at one index): row -1,
     zero scores, flags and node counts (what a failed-or-never-asked
     placement reads, and what the numpy twin fills its tail rows with),
-    and the re-pick flag as an eighth buffer."""
+    and the re-pick flag as an eighth buffer.  With ``preempt`` (the
+    launch's ``Features``) the PREEMPT buffer holds a count, not a flag
+    (``score_nodes``)."""
     shape = (n_placements, lanes)
     return (
         jnp.full(shape, -1, jnp.int32),
         jnp.zeros(shape, jnp.float32),
         jnp.zeros(shape, jnp.float32),
-        jnp.zeros(shape, bool),
+        jnp.zeros(shape, jnp.float32 if preempt else bool),
         jnp.zeros(shape, jnp.int32),
         jnp.zeros(shape, jnp.int32),
         jnp.zeros(shape, jnp.int32),
@@ -955,9 +1014,13 @@ def _fused_place_batch_impl(
       float32 scores, nothing sampled.  If no feasible node has room under
       the claims, the lane keeps its own arg-max (never an empty slot that
       its own scores would fill: an empty slot reads "no node can take it"
-      on the host and blocks the eval).  Nodes a lane may only take by
-      preempting are never masked: eviction frees their room at apply
-      time.  A launch whose lanes' picks never overflow a node — one live
+      on the host and blocks the eval).  A node a lane may only take by
+      preempting is not masked for want of room (eviction frees it at
+      apply time) but by a claim that already over-fills it: an earlier
+      lane of this launch took it by preempting, the host would choose
+      the same victims for both, and the applier would reject the second
+      (on a full cluster every lane's arg-max is the same node).  A
+      launch whose lanes' picks never overflow a node — one live
       lane in particular — is bit for bit every lane's solo scan: the
       behaviour follows from the picks and the claims, there is no switch.
     * The packed output's VERIFIED column is a device-resident
@@ -1012,8 +1075,16 @@ def _fused_place_batch_impl(
             ok = own_ok[b] & active[b]
             ask = reqs.ask[b]
             room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
+            # A node this lane may only take by preempting is not masked
+            # for want of room under the claims (eviction frees it at
+            # apply time), but by a claim that already over-fills it: an
+            # earlier lane of this launch took it by preempting, the host
+            # would choose the same victims for both, and the applier
+            # would reject the second.
+            unclaimed = jnp.all(claims <= arrays.totals, axis=1)
             row = resolved_pick(
-                res.final[b], room | res.needs_preempt[b], own[b]
+                res.final[b], room | (res.needs_preempt[b] & unclaimed),
+                own[b],
             )
             row = jnp.where(ok, row, -1)
             return (
@@ -1038,7 +1109,7 @@ def _fused_place_batch_impl(
     with jax.named_scope("place_scan"):
         _, outs = scan_steps(
             step, (init, claims_image(used, delta_rows, delta_vals, live)),
-            inert_lane_outputs(lanes, n_placements), trip,
+            inert_lane_outputs(lanes, n_placements, features.preempt), trip,
         )
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, repicked = (
         o.T for o in outs

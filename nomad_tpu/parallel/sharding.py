@@ -304,7 +304,7 @@ def _fused_place_batch_local(
         with jax.named_scope("score"):
             res = score_nodes(
                 arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
-                features=features,
+                features=features, node_axis="node",
             )
         # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
         # then a cross-shard reduce of the implicit (shards, k) table —
@@ -358,12 +358,19 @@ def _fused_place_batch_local(
             own_score = jnp.where(
                 owner, jnp.stack([res.final[lwin], res.binpack[lwin]]), 0.0
             )
-            own_pre = jnp.where(
-                owner, res.needs_preempt[lwin], False
-            ).astype(jnp.int32)
+            if res.pre_terms is None:
+                own_pre = jnp.where(
+                    owner, res.needs_preempt[lwin], False
+                ).astype(jnp.int32)
+            else:  # the count of the mean's terms, not a flag
+                own_pre = jnp.where(
+                    owner & res.needs_preempt[lwin], res.pre_terms[lwin], 0.0
+                )
             with jax.named_scope("broadcast"):
                 final, binp = jax.lax.psum(own_score, "node")
-                pre = jax.lax.pmax(own_pre, "node").astype(bool)
+                pre = jax.lax.pmax(own_pre, "node")
+            if res.pre_terms is None:
+                pre = pre.astype(bool)
         out = (
             grow, final, binp, pre,
         ) + tuple(jnp.where(active, c, 0) for c in counts)
@@ -390,8 +397,9 @@ def _fused_place_batch_local(
                 ok = g_own[b] >= 0
                 ask = g_ask[b]
                 room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
+                unclaimed = jnp.all(claims <= arrays.totals, axis=1)
                 masked = jnp.where(
-                    (room | res.needs_preempt[bl]) & holds,
+                    (room | (res.needs_preempt[bl] & unclaimed)) & holds,
                     res.final[bl], NEG_INF,
                 )
                 idx = jnp.argmax(masked).astype(jnp.int32)
@@ -429,7 +437,7 @@ def _fused_place_batch_local(
     claims0 = add_deltas(vary(used), g_drows, g_dvals, g_live[:, None])
     bufs = tuple(
         vary(o)
-        for o in inert_lane_outputs(b_local, n_placements)
+        for o in inert_lane_outputs(b_local, n_placements, features.preempt)
         + (jnp.full((n_placements, lanes), -1, jnp.int32),)
     )
     with jax.named_scope("place_scan"):

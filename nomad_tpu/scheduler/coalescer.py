@@ -105,7 +105,7 @@ class PlaceOutcome:
     rows: np.ndarray  # (P,) i32
     scores: np.ndarray  # (P,) f32
     binpack: np.ndarray  # (P,) f32
-    preempted: np.ndarray  # (P,) bool
+    preempted: np.ndarray  # (P,) f32, kernels.PACKED_PREEMPT (0.0 = no)
     nodes_evaluated: np.ndarray  # (P,) i32
     nodes_filtered: np.ndarray  # (P,) i32
     nodes_exhausted: np.ndarray  # (P,) i32
@@ -257,6 +257,8 @@ class DeviceCoalescer:
         self.scan_steps_total = 0
         self.verify_conflicts = 0
         self.lane_repicks = 0
+        self.picks_placed = 0
+        self.preempt_picks = 0
         self.feature_recompiles = 0
         self._features = None
         # Device→host result traffic for fused/sharded dispatches (the
@@ -1203,11 +1205,14 @@ class DeviceCoalescer:
                 fit_verified = ~(placed & (vcol == 0.0))
                 self.verify_conflicts += int((~fit_verified).sum())
                 self.lane_repicks += int((placed & (vcol == 2.0)).sum())
+                pcol = row[:, kernels.PACKED_PREEMPT]
+                self.picks_placed += int(placed.sum())
+                self.preempt_picks += int((placed & (pcol != 0.0)).sum())
                 p.outcome = PlaceOutcome(
                     rows=rows_i,
                     scores=row[:, kernels.PACKED_SCORE],
                     binpack=row[:, kernels.PACKED_BINPACK],
-                    preempted=row[:, kernels.PACKED_PREEMPT] != 0.0,
+                    preempted=pcol,
                     nodes_evaluated=row[:, kernels.PACKED_EVALUATED].astype(
                         np.int32
                     ),

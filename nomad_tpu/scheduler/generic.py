@@ -25,10 +25,8 @@ from ..structs.types import (
     Plan,
     RescheduleEvent,
     RescheduleTracker,
-    Resources,
 )
 from .context import EvalContext
-from .preemption import select_victims
 from .reconcile import (
     ALLOC_RESCHEDULED,
     ALLOC_UPDATING,
@@ -313,27 +311,11 @@ class GenericScheduler:
         if place.canary:
             alloc.deployment_status = AllocDeploymentStatus(canary=True)
 
-        if opt.needs_preempt:
-            node = opt.node
-            proposed = ctx.proposed_allocs(node.id)
-            avail = node.comparable_resources()
-            used = Resources(cpu=0, memory_mb=0, disk_mb=0)
-            for a in proposed:
-                used.add(a.resources)
-            remaining = Resources(
-                cpu=avail.cpu - used.cpu,
-                memory_mb=avail.memory_mb - used.memory_mb,
-                disk_mb=avail.disk_mb - used.disk_mb,
-            )
-            victims = select_victims(job, node, proposed, resources, remaining)
-            if victims is None:
-                self.queued_allocs[tg.name] = (
-                    self.queued_allocs.get(tg.name, 0) + 1
-                )
-                self.failed_tg_allocs.setdefault(tg.name, AllocMetric())
-                return
-            for v in victims:
-                ctx.plan.append_preempted_alloc(v, alloc.id)
+        # A preempting pick's victims were chosen with the node (stack.py:
+        # the node's room is the matrix's there, as the kernel scored it
+        # and the applier verifies it); they leave in the same plan.
+        for v in opt.victims:
+            ctx.plan.append_preempted_alloc(v, alloc.id)
 
         ctx.plan.append_alloc(alloc)
 

@@ -1,20 +1,41 @@
-"""Preemption victim selection — host-side residue of the vectorized search.
+"""Preemption on the host: the victims of one pick, and its exact score.
 
-The kernel identifies nodes where evicting lower-priority work would make the
-ask fit (prio_used prefix-sum, ops/kernels.preemption_state). This module
-picks the *actual* victim allocs on the single chosen node — the reference's
-greedy search (scheduler/preemption.go:198-557) reduced to one node.
+The kernel says which nodes could take the ask after evicting
+lower-priority work, and ranks them by an estimate (``prio_used`` prefix
+sums, ops/kernels.score_nodes).  This module does what needs the node's
+allocations themselves, for the ONE node picked — the reference's
+``Preemptor.PreemptForTaskGroup`` (scheduler/preemption.go:198-268):
 
-Victims must have priority < job.priority − 10 (preemption.go:663); chosen
-greedily by (priority, resource distance) until the deficit is covered,
-then filtered back (superset elimination, preemption.go:702).
+* candidates have priority < job.priority − 10 (preemption.go:663),
+  grouped by priority, lowest first;
+* within a group the allocation closest to what is still needed
+  (``basicResourceDistance``, :608) is taken, and what is needed shrinks by
+  it, until node room + freed covers the whole ask;
+* then the set is filtered (``filterSuperset``, :702): farthest from the
+  ask first, stop as soon as the ask is covered.
+
+The node's room is the caller's to state, and the caller states the
+matrix's (aggregates included): the view the kernel scored and the applier
+verifies.  Equal distances fall to the lower allocation id (the reference
+takes list order).
+
+``preempting_scores`` is the score such a pick RECORDS: Nomad's ScoreFit of
+the utilisation after the victims are gone (rank.go BinPackIterator scores
+``proposed`` less the allocations to preempt) and the logistic of the
+victims' net priority (rank.go:773-844).
 """
 
 from __future__ import annotations
 
 import math
-from typing import List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
+from ..structs.funcs import (
+    net_priority,
+    preemption_score,
+    score_fit_binpack,
+    score_fit_spread,
+)
 from ..structs.types import (
     Allocation,
     Job,
@@ -24,86 +45,95 @@ from ..structs.types import (
 )
 
 
-def resource_distance(delta: Resources, ask: Resources) -> float:
-    """Euclidean distance between a victim's resources and the remaining
-    deficit, normalized per-dimension (preemption.go basicResourceDistance
-    :608)."""
-    total = 0.0
-    n = 0
-    for d, a in (
-        (delta.cpu, ask.cpu),
-        (delta.memory_mb, ask.memory_mb),
-        (delta.disk_mb, ask.disk_mb),
-    ):
-        if a > 0:
-            total += ((d - a) / a) ** 2
-            n += 1
-    return math.sqrt(total / n) if n else 0.0
+def _dims(r) -> Tuple[float, float, float]:
+    return (float(r.cpu), float(r.memory_mb), float(r.disk_mb))
+
+
+def resource_distance(needed: Sequence[float], used: Sequence[float]) -> float:
+    """``basicResourceDistance``: how far an allocation's resources are
+    from what is needed, per dimension relative to the need; a dimension
+    nothing is needed of does not count."""
+    return math.sqrt(sum(
+        ((n - u) / n) ** 2 for n, u in zip(needed, used) if n > 0
+    ))
+
+
+def _covers(avail: Sequence[float], ask: Sequence[float]) -> bool:
+    return all(a >= q for a, q in zip(avail, ask))
 
 
 def select_victims(
     job: Job,
-    node: Node,
     proposed: List[Allocation],
     ask: Resources,
-    available: Resources,
+    room: Sequence[float],
 ) -> Optional[List[Allocation]]:
-    """Pick allocs to evict so that ``ask`` fits in ``available`` + freed.
-
-    Returns None when no admissible victim set covers the deficit.
-    """
-    deficit = Resources(
-        cpu=max(0, ask.cpu - available.cpu),
-        memory_mb=max(0, ask.memory_mb - available.memory_mb),
-        disk_mb=max(0, ask.disk_mb - available.disk_mb),
-    )
-    if deficit.cpu == 0 and deficit.memory_mb == 0 and deficit.disk_mb == 0:
+    """Allocations of ``proposed`` to evict so that ``ask`` fits into
+    ``room`` (cpu, memory, disk the node has left) + what they free.
+    [] when it fits as it is; None when no admissible set covers it."""
+    want = _dims(ask)
+    avail = [float(x) for x in room]
+    if _covers(avail, want):
         return []
 
     threshold = job.priority - PREEMPTION_PRIORITY_DELTA
-    candidates = [
-        a
-        for a in proposed
-        if not a.terminal_status() and a.job_priority() < threshold
-    ]
-    # Lowest priority first, then best resource-distance match.
-    candidates.sort(
-        key=lambda a: (a.job_priority(), resource_distance(a.resources, deficit))
-    )
+    groups: Dict[int, List[Allocation]] = {}
+    for a in proposed:
+        if not a.terminal_status() and a.job_priority() < threshold:
+            groups.setdefault(a.job_priority(), []).append(a)
 
-    victims: List[Allocation] = []
-    freed = Resources(cpu=0, memory_mb=0, disk_mb=0)
-    for a in candidates:
-        if (
-            freed.cpu >= deficit.cpu
-            and freed.memory_mb >= deficit.memory_mb
-            and freed.disk_mb >= deficit.disk_mb
-        ):
+    needed = list(want)
+    best: List[Allocation] = []
+    met = False
+    for prio in sorted(groups):
+        group = sorted(groups[prio], key=lambda a: a.id)
+        while group and not met:
+            i = min(
+                range(len(group)),
+                key=lambda k: resource_distance(
+                    needed, _dims(group[k].resources)
+                ),
+            )
+            a = group.pop(i)
+            best.append(a)
+            res = _dims(a.resources)
+            avail = [x + r for x, r in zip(avail, res)]
+            needed = [n - r for n, r in zip(needed, res)]
+            met = _covers(avail, want)
+        if met:
             break
-        victims.append(a)
-        freed.add(a.resources)
-
-    if not (
-        freed.cpu >= deficit.cpu
-        and freed.memory_mb >= deficit.memory_mb
-        and freed.disk_mb >= deficit.disk_mb
-    ):
+    if not met:
         return None
 
-    # Superset elimination: drop victims whose removal still covers the
-    # deficit (preemption.go filterSuperset :702).
-    filtered: List[Allocation] = list(victims)
-    for a in sorted(victims, key=lambda v: -v.job_priority()):
-        without = Resources(
-            cpu=freed.cpu - a.resources.cpu,
-            memory_mb=freed.memory_mb - a.resources.memory_mb,
-            disk_mb=freed.disk_mb - a.resources.disk_mb,
-        )
-        if (
-            without.cpu >= deficit.cpu
-            and without.memory_mb >= deficit.memory_mb
-            and without.disk_mb >= deficit.disk_mb
-        ):
-            filtered.remove(a)
-            freed = without
-    return filtered
+    # filterSuperset: farthest from the ask first, until it is covered.
+    best.sort(key=lambda a: -resource_distance(want, _dims(a.resources)))
+    avail = [float(x) for x in room]
+    out: List[Allocation] = []
+    for a in best:
+        out.append(a)
+        avail = [x + r for x, r in zip(avail, _dims(a.resources))]
+        if _covers(avail, want):
+            break
+    return out
+
+
+def preempting_scores(
+    node: Node,
+    used: Sequence[float],
+    ask: Resources,
+    victims: List[Allocation],
+    spread: bool,
+) -> Tuple[float, float]:
+    """(binpack, preemption) as a preempting placement records them:
+    ScoreFit (funcs.go:186/213, over 18) of ``used`` + ``ask`` less the
+    victims on ``node``; the logistic of the victims' net priority.
+    Plain float64: the reference's own arithmetic."""
+    util = [u + q for u, q in zip(used, _dims(ask))]
+    for v in victims:
+        util = [u - r for u, r in zip(util, _dims(v.resources))]
+    fit = score_fit_spread if spread else score_fit_binpack
+    binpack = fit(node, Resources(cpu=util[0], memory_mb=util[1])) / 18.0
+    pre = preemption_score(
+        net_priority([v.job_priority() for v in victims])
+    )
+    return binpack, pre

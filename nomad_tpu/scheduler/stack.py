@@ -42,6 +42,7 @@ from ..structs.types import (
 )
 from .context import EvalContext
 from .feasible_host import check_constraint_host, check_host_volumes
+from .preemption import preempting_scores, select_victims
 
 # Dynamic port range (reference: structs/network.go MinDynamicPort/MaxDynamicPort).
 from ..state.matrix import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT  # noqa: E402
@@ -51,7 +52,8 @@ from ..state.matrix import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT  # noqa: E402
 # ever sees to {1, 2, 4, 8, 16} (SURVEY.md §7 hard-part e).
 PLACEMENT_CHUNK = 16
 # Bound on kernel re-entries after host-side rejections (gone node, port
-# conflict) or preemption-assisted picks.
+# conflict, a preemptable node without an admissible victim set).  The
+# re-entry after a preempting pick is no rejection: it places one.
 MAX_SELECT_RETRIES = 8
 
 # Solo-path occupancy ratchet (mirrors DeviceCoalescer._features): the
@@ -110,6 +112,9 @@ class SelectionOption:
     binpack_score: float
     needs_preempt: bool
     metric: AllocMetric = field(default_factory=AllocMetric)
+    # The allocations this placement evicts (a preempting pick's victims,
+    # chosen here, on the host, for the one node picked).
+    victims: List[Allocation] = field(default_factory=list)
     # task -> {label: port} assigned host-side for the chosen node
     assigned_ports: Dict[str, Dict[str, int]] = field(default_factory=dict)
     # Coalesced-launch advisory: the device's sequential cross-lane
@@ -481,6 +486,50 @@ class GenericStack:
                 result.setdefault(owner, {}).update(ports)
         return result
 
+    # -- preemption (host-side, chosen node only) ---------------------------
+
+    def _preempt(self, job: Job, tg: TaskGroup, node: Node, row: int,
+                 delta, taken: set, est_terms: float, est_final: float,
+                 terms: float, spread: bool):
+        """The victims of one preempting pick and the score it records:
+        (victims, binpack, final, preemption), or None where no
+        admissible set of allocations covers the ask.
+
+        The node's room is the matrix's: its ``used`` as the kernel scored
+        it and the applier will verify it (usage aggregates included; a
+        sum over the node's Allocation objects would miss them), plus this
+        plan's ``delta`` on the row.  The victims come from the proposed
+        allocations, less those ``taken`` by earlier picks of this select.
+
+        The kernel ranked the node by an estimate (kernels.score_nodes):
+        ``est_final`` is a mean of ``terms`` terms, two of which, summing
+        to ``est_terms``, depend on the victims.  What is recorded is
+        Nomad's: those two replaced by ScoreFit after the victims are
+        gone and the logistic of their net priority."""
+        ask = tg.combined_resources()
+        with trace.span("sched.preempt", node=node.id):
+            host = self.matrix.snapshot_host()
+            used = host["used"][row].astype(np.float64) + delta
+            totals = host["totals"][row].astype(np.float64)
+            proposed = [
+                a for a in self.ctx.proposed_allocs(node.id)
+                if a.id not in taken
+            ]
+            victims = select_victims(job, proposed, ask, totals - used)
+            trace.add_args(
+                victims=-1 if victims is None else len(victims)
+            )
+            if victims is None:
+                return None
+            binpack, pre = preempting_scores(
+                node, used, ask, victims, spread
+            )
+        others = est_final * terms - est_terms
+        if not victims:
+            # Room appeared since the launch: a placement like any other.
+            return victims, binpack, (others + binpack) / (terms - 1.0), 0.0
+        return victims, binpack, (others + binpack + pre) / terms, pre
+
     # -- the main entry ------------------------------------------------------
 
     def select(
@@ -672,6 +721,7 @@ class GenericStack:
         # later chunks/retries must fold them in here to avoid over-commit.
         chosen_rows: List[int] = []
         chosen_ports: Dict[str, set] = {}
+        chosen_victims: List[Tuple[int, Allocation]] = []
         remaining = n_placements
         retries = 0
         while remaining > 0 and retries <= MAX_SELECT_RETRIES:
@@ -686,6 +736,11 @@ class GenericStack:
             for row in chosen_rows:
                 d = deltas.setdefault(row, np.zeros(3, np.float32))
                 d += np.asarray(compiled.request.ask, np.float32)
+            for row, v in chosen_victims:
+                r = v.resources
+                deltas[row] -= np.array(
+                    [r.cpu, r.memory_mb, r.disk_mb], np.float32
+                )
 
             tg_counts = self._tg_counts(job, tg)
             for row in chosen_rows:
@@ -719,6 +774,7 @@ class GenericStack:
             n_exh = n_exh_all[:take]
 
             retry = False
+            launched = len(chosen_rows)
             for i, row in enumerate(rows_out):
                 metric = AllocMetric(
                     nodes_evaluated=int(n_eval[i]),
@@ -749,16 +805,45 @@ class GenericStack:
                     retries += 1
                     retry = True
                     break
-                metric.score_node(node_id, "binpack", float(binpack[i]))
-                metric.score_node(node_id, "final", float(scores[i]))
+                binpack_i, final_i = float(binpack[i]), float(scores[i])
+                victims: List[Allocation] = []
+                if preempted[i]:
+                    # This plan's usage on the row as the launch saw it at
+                    # this step: the deltas it was handed and the picks of
+                    # its earlier steps.
+                    delta = deltas.get(int(row), 0.0) + (
+                        chosen_rows[launched:].count(int(row))
+                        * np.asarray(compiled.request.ask, np.float64)
+                    )
+                    found = self._preempt(
+                        job, tg, node, int(row), delta,
+                        {v.id for _, v in chosen_victims},
+                        binpack_i, final_i, float(preempted[i]),
+                        compiled.request.algorithm == 1,
+                    )
+                    if found is None:
+                        # Evictable usage with no allocation behind it
+                        # (an aggregate), or victims another plan took:
+                        # not this node again in this eval.
+                        trace.count("nomad.sched.preempt_no_victims")
+                        banned_rows.append(int(row))
+                        retries += 1
+                        retry = True
+                        break
+                    victims, binpack_i, final_i, pre_i = found
+                    if victims:
+                        metric.score_node(node_id, "preemption", pre_i)
+                metric.score_node(node_id, "binpack", binpack_i)
+                metric.score_node(node_id, "final", final_i)
                 opt = SelectionOption(
                     node_id=node_id,
                     node=node,
                     row=int(row),
-                    final_score=float(scores[i]),
-                    binpack_score=float(binpack[i]),
-                    needs_preempt=bool(preempted[i]),
+                    final_score=final_i,
+                    binpack_score=binpack_i,
+                    needs_preempt=bool(victims),
                     metric=metric,
+                    victims=victims,
                     assigned_ports=ports,
                     fit_verified=(
                         bool(verified_all[i])
@@ -772,12 +857,16 @@ class GenericStack:
                     for per_task in ports.values():
                         bag.update(per_task.values())
                 remaining -= 1
-                if bool(preempted[i]):
-                    # A preemption-assisted pick changes proposed state in a
-                    # way the in-scan accounting can't see (victims are chosen
-                    # host-side afterwards); re-enter conservatively — the
-                    # chosen_rows delta keeps this node's ask accounted.
-                    retries += 1
+                if preempted[i]:
+                    # A preempting pick changes the proposed state in a
+                    # way the in-scan accounting cannot see (the victims
+                    # are chosen here, after the launch): the rows the
+                    # launch placed after it are dropped and the loop
+                    # re-enters, one launch per such pick, with the ask
+                    # and the victims in this node's delta.
+                    chosen_victims.extend((int(row), v) for v in victims)
+                    if remaining:
+                        trace.count("nomad.sched.preempt_reentries")
                     retry = True
                     break
             if not retry:

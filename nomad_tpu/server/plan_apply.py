@@ -29,6 +29,9 @@ from .. import trace
 
 from ..structs.types import (
     Allocation,
+    EvalStatus,
+    EvalTrigger,
+    Evaluation,
     NodeStatus,
     Plan,
     PlanResult,
@@ -201,21 +204,17 @@ class PlanApplier:
         allocs = [a for lst in committed_allocs.values() for a in lst]
         allocs.extend(plan.alloc_updates)
         stops = [a for lst in plan.node_update.values() for a in lst]
-        preempts = [
-            a
+        node_preemptions = {
+            nid: lst
             for nid, lst in plan.node_preemptions.items()
             if nid not in failed_nodes
-            for a in lst
-        ]
+        }
+        preempts = [a for lst in node_preemptions.values() for a in lst]
 
         result = PlanResult(
             node_allocation=committed_allocs,
             node_update=dict(plan.node_update),
-            node_preemptions={
-                nid: lst
-                for nid, lst in plan.node_preemptions.items()
-                if nid not in failed_nodes
-            },
+            node_preemptions=node_preemptions,
             deployment=plan.deployment,
             deployment_updates=plan.deployment_updates,
         )
@@ -229,6 +228,7 @@ class PlanApplier:
             return result, 0
 
         index = self.server.next_index()
+        result.preemption_evals = self._preemption_evals(preempts)
         self.server.store.upsert_plan_results(
             index,
             allocs,
@@ -236,8 +236,16 @@ class PlanApplier:
             preempts,
             deployment=plan.deployment,
             deployment_updates=plan.deployment_updates,
+            evals=result.preemption_evals,
         )
         result.alloc_index = index
+        if preempts:
+            self.server.metrics.incr(
+                "nomad.plan.preempted_allocs", len(preempts)
+            )
+            self.server.metrics.incr(
+                "nomad.plan.preemption_evals", len(result.preemption_evals)
+            )
         if failed_nodes:
             # Partial commit ⇒ RefreshIndex so the worker re-snapshots past
             # this apply (plan_apply.go:166-178).
@@ -251,6 +259,33 @@ class PlanApplier:
             outcome="partial" if failed_nodes else "committed",
         )
         return result, index
+
+    def _preemption_evals(self, preempts: List[Allocation]) -> List[Evaluation]:
+        """One pending eval per job that loses allocations to a plan's
+        preemptions, so that the job is placed again or blocks — the
+        reference's applyPlan (plan_apply.go: ``TriggeredBy:
+        EvalTriggerPreemption``, the job's own type and priority),
+        committed with the plan result in one index.  The caller hands
+        them to the broker once the store's locks are released
+        (``Server.on_plan_applied``)."""
+        evals: List[Evaluation] = []
+        seen = set()
+        for a in preempts:
+            key = (a.namespace, a.job_id)
+            job = a.job or self.server.store.job_by_id(*key)
+            if key in seen or job is None:
+                continue
+            seen.add(key)
+            evals.append(Evaluation(
+                namespace=a.namespace,
+                priority=job.priority,
+                type=job.type,
+                triggered_by=EvalTrigger.PREEMPTION.value,
+                job_id=a.job_id,
+                status=EvalStatus.PENDING.value,
+                create_time=time.time(),
+            ))
+        return evals
 
     # ------------------------------------------------------------------
 
@@ -308,6 +343,17 @@ class PlanApplier:
                     delta -= (pr.cpu, pr.memory_mb, pr.disk_mb)
                     for d in pr.devices:
                         dev_delta[d.name] = dev_delta.get(d.name, 0) - d.count
+            # A victim another plan already evicted (or stopped): what
+            # this plan scored the node by is gone, whether or not the
+            # placement would still fit.  The worker refreshes and picks
+            # again; no allocation is evicted twice.
+            if any(
+                a.id not in store.allocs
+                or store.allocs[a.id].terminal_status()
+                for a in plan.node_preemptions.get(nid, [])
+            ):
+                failed.add(nid)
+                continue
             for a in plan.node_update.get(nid, []) + plan.node_preemptions.get(
                 nid, []
             ):

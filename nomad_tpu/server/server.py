@@ -281,6 +281,12 @@ class Server:
             "nomad.kernel.lane_repicks_total", lambda: c.lane_repicks
         )
         m.gauge_fn(
+            "nomad.kernel.picks_placed_total", lambda: c.picks_placed
+        )
+        m.gauge_fn(
+            "nomad.kernel.preempt_picks_total", lambda: c.preempt_picks
+        )
+        m.gauge_fn(
             "nomad.kernel.feature_recompiles", lambda: c.feature_recompiles
         )
         m.gauge_fn(
@@ -1246,12 +1252,18 @@ class Server:
     def on_plan_applied(self, plan, result, index: int) -> None:
         """Post-commit: stopped/preempted allocs free capacity → unblock
         their nodes' classes (the watchCapacity feed, blocked_evals.go:508)."""
-        freed = set(result.node_update.keys()) | set(result.node_preemptions.keys())
+        freed = set(result.node_update.keys()) | {
+            nid for nid, lst in result.node_preemptions.items() if lst
+        }
         for nid in freed:
             node = self.store.node_by_id(nid)
             if node is not None:
                 cls = computed_class_key(node_attributes(node), node)
                 self.blocked_evals.unblock(cls, index)
+        # The jobs that lost allocations to the plan's preemptions are
+        # evaluated again (the evals were committed with the plan result).
+        for ev in result.preemption_evals:
+            self.eval_broker.enqueue(ev)
 
     # ------------------------------------------------------------------
     # Leader reapers

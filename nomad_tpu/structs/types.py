@@ -978,6 +978,9 @@ class PlanResult:
     deployment_updates: List["DeploymentStatusUpdate"] = field(default_factory=list)
     refresh_index: int = 0
     alloc_index: int = 0
+    # One pending eval per job that lost allocations to this plan's
+    # preemptions, committed in the plan's own index (plan_apply.py).
+    preemption_evals: List["Evaluation"] = field(default_factory=list)
 
     def full_commit(self, plan: Plan) -> tuple:
         expected = sum(len(a) for a in plan.node_allocation.values())

@@ -11,6 +11,7 @@ from .core import (
     clear,
     config,
     configure,
+    count,
     current,
     dump,
     event,
@@ -33,6 +34,7 @@ __all__ = [
     "clear",
     "config",
     "configure",
+    "count",
     "current",
     "dump",
     "dump_flight_record",

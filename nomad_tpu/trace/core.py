@@ -217,6 +217,15 @@ def set_default_metrics(registry: Any) -> None:
         _default_metrics = registry
 
 
+def count(name: str, by: int = 1, metrics: Any = None, **labels: Any) -> None:
+    """Bump a counter of the registry the ambient spans feed (the
+    server's), whether or not spans are being recorded: for code that has
+    no server handle (the scheduler stack's ``nomad.sched.*``)."""
+    reg = metrics if metrics is not None else _default_metrics
+    if reg is not None:
+        reg.incr(name, by, **labels)
+
+
 def _observe_phase(name: str, dur: float, metrics: Any) -> None:
     reg = metrics if metrics is not None else _default_metrics
     if reg is not None:

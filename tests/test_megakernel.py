@@ -244,7 +244,9 @@ class TestPreemptionEvictSets:
         assert_solo_columns_match(solo, fused)
         assert int(fused[0, 0, 0]) == -1  # no preemption → no room
         assert int(fused[1, 0, 0]) >= 0
-        assert fused[1, 0, 3] == 1.0  # placed by evicting
+        # placed by evicting: PREEMPT holds the terms of the mean (binpack,
+        # preemption)
+        assert fused[1, 0, 3] == 2.0
         # Preempted placements verify against *current* usage — the evict
         # set frees capacity only at apply time, so the device-resident
         # AllocsFit conservatively flags them for the applier to re-check.
@@ -752,7 +754,9 @@ def served_shapes():
     shapes = simcluster.build_requests(m)
     assert len(shapes) == 8
     pjob = mock.job(priority=90)
-    pjob.task_groups[0].tasks[0].resources.cpu = 1400
+    # More cpu than any node has left (the seeded usage is at least ~850
+    # of 3,900 MHz): with room anywhere there is no eviction.
+    pjob.task_groups[0].tasks[0].resources.cpu = 3200
     pjob.task_groups[0].tasks[0].resources.memory_mb = 2600
     preempting = RequestEncoder(m).compile(
         pjob, pjob.task_groups[0], preemption_enabled=True
@@ -781,7 +785,7 @@ def test_each_shape_alone_matches_solo(served_shapes, shape):
     assert got.shape == (SERVED_LANES, FULL, FUSED_PACKED_WIDTH)
     assert (got[0, :, 0] >= 0).all(), "the shape placed nothing"
     if shape == "preempting":
-        assert (got[0, :, 3] == 1.0).any(), "never needed preemption"
+        assert (got[0, :, 3] >= 2.0).all(), "placed without an eviction"
 
     solo = solo_reference(arrays, ops, FULL, lanes=[0])
     np.testing.assert_array_equal(got[0, :, :7], solo[0])

@@ -28,7 +28,12 @@ import pytest
 
 from nomad_tpu import mock, trace
 from nomad_tpu.server import Server, ServerConfig
-from nomad_tpu.structs.types import Plan, Resources
+from nomad_tpu.structs.types import (
+    Plan,
+    PreemptionConfig,
+    Resources,
+    SchedulerConfiguration,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -75,6 +80,9 @@ def emitted(tmp_path_factory):
         num_workers=1, node_capacity=16, coalescer_lanes=4,
         heartbeat_min_ttl=3600.0, heartbeat_max_ttl=7200.0,
         slo_enabled=False,
+        scheduler_config=SchedulerConfiguration(
+            preemption_config=PreemptionConfig(
+                service_scheduler_enabled=True)),
     )))
     agent.start()
     try:
@@ -96,6 +104,13 @@ def emitted(tmp_path_factory):
             with urllib.request.urlopen(req, timeout=120) as r:
                 eval_id = json.loads(r.read())["EvalID"]
             assert srv.wait_for_eval(eval_id, timeout=300.0)
+        # Two instances that each need a node nearly to themselves, on
+        # four nodes that carry twenty allocations of 500 MHz (at most one
+        # of them empty): lower-priority work is evicted (sched.preempt).
+        big = mock.job(priority=90)
+        big.task_groups[0].count = 2
+        big.task_groups[0].tasks[0].resources.cpu = 3600
+        assert srv.wait_for_eval(srv.submit_job(big).id, timeout=300.0)
         srv.coalescer.sync_arrays()                        # a device op
         gc.collect()                                       # a full collection
         salt = float(time.time_ns() % 1000003)             # never cached
