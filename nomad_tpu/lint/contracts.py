@@ -318,7 +318,18 @@ def table() -> Tuple[DeviceContract, ...]:
     from ..parallel import sharding
     from ..state import matrix
 
-    fused_kwargs = lambda g: {"n_placements": g.placements, "features": g.features}
+    def overlay(g: Grid) -> Tuple[Any, Any]:
+        # The in-flight claims overlay as the coalescer hands it over: a few
+        # rows a lane, -1 padded (scheduler/claims.py).
+        return (
+            np.full((g.batch, 2), -1, np.int32),
+            np.zeros((g.batch, 2, 3), np.float32),
+        )
+
+    fused_kwargs = lambda g: {
+        "n_placements": g.placements, "features": g.features,
+        "overlay": overlay(g),
+    }
     trace_grids = _fused_trace_grids()
     compile_grid = _fused_compile_grid()
 
@@ -374,7 +385,9 @@ def table() -> Tuple[DeviceContract, ...]:
             path="nomad_tpu/parallel/sharding.py",
             build=build_sharded,
             operands=fused_operands,
-            static_kwargs=lambda g: {"features": g.features},
+            static_kwargs=lambda g: {
+                "features": g.features, "overlay": overlay(g),
+            },
             trace_grids=trace_grids,
             out_budget=_fused_budget,
             donated_args=(),  # matrix stays shared with in-flight dispatches

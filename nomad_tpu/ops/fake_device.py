@@ -729,13 +729,14 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
                       host_masks: List[np.ndarray],
                       lane_mask,
                       n_placements: int,
-                      live_counts: Optional[List[int]] = None) -> np.ndarray:
+                      live_counts: Optional[List[int]] = None,
+                      overlay=None) -> np.ndarray:
     """Twin of kernels.fused_place_batch — (B, P, FUSED_PACKED_WIDTH) f32.
 
     The lanes' scans run in lockstep.  Within a step the live lanes take
     their picks in lane order against one image of the launch's claims
-    (the shared usage, every live lane's in-flight deltas, every pick so
-    far): the arg-max of the lane's own scores over the nodes where the
+    (the shared usage with the in-flight overlay under it, every live
+    lane's in-flight deltas, every pick so far): the arg-max of the lane's own scores over the nodes where the
     image has room for its ask (a node it may take by preempting counts
     as having room until a claim over-fills it), its own arg-max where
     none has.  Then the sequential
@@ -751,6 +752,11 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
     after that many steps and its tail rows are inert (row=-1, zeros,
     verified=1.0, nothing added to either usage image) —
     kernel-exact, tests/test_megakernel.py compares all eight columns.
+
+    ``overlay`` = (rows, vals), -1 padded, any leading shape: the
+    in-flight claims of the launches before this one
+    (``kernels.overlay_usage``), added to the usage under the claims image
+    and the verify pass and to no score; None = empty.
     """
     b = len(reqs)
     lane_mask = np.asarray(lane_mask, bool)
@@ -764,6 +770,15 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
     live = [i for i in range(b) if lane_mask[i] and steps[i] > 0]
     totals = arrays.totals
     claims = np.array(used, np.float32, copy=True)
+    if overlay is not None:
+        orows = np.asarray(overlay[0]).reshape(-1)
+        ovals = np.asarray(overlay[1], np.float32).reshape(-1, 3)
+        # As the kernel's scatter: padding adds nothing, a row past the
+        # snapshot (registered after a growth this launch has not synced)
+        # is dropped.
+        inside = (orows >= 0) & (orows < claims.shape[0])
+        np.add.at(claims, orows[inside], ovals[inside])
+    cum_used = claims.copy()  # the verify pass starts from the same image
     scans = {}
     for i in live:
         drows = np.asarray(delta_rows[i])
@@ -801,7 +816,6 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
             out[i, step, :7] = lane.commit(row)
             claims[row] += ask
     # Sequential AllocsFit re-verify against the cumulative image.
-    cum_used = np.array(used, np.float32, copy=True)
     out[:, :, FUSED_PACKED_VERIFIED] = -1.0
     for i in live:
         drows = np.asarray(delta_rows[i])
@@ -827,7 +841,7 @@ def sharded_fused_place_batch(arrays, used, delta_rows, delta_vals,
                               class_eligs, host_masks, lane_mask,
                               n_shards: int, n_placements: int,
                               live_counts: Optional[List[int]] = None,
-                              ) -> np.ndarray:
+                              overlay=None) -> np.ndarray:
     """Twin of parallel.sharding.sharded_fused_place_batch for host-only CI.
 
     The sharded kernel's hierarchical top-k election (per-shard stable
@@ -846,7 +860,7 @@ def sharded_fused_place_batch(arrays, used, delta_rows, delta_vals,
     return fused_place_batch(
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
         penalties, reqs, class_eligs, host_masks, lane_mask,
-        n_placements=n_placements, live_counts=live_counts,
+        n_placements=n_placements, live_counts=live_counts, overlay=overlay,
     )
 
 

@@ -891,11 +891,29 @@ def fused_trip_counts(lane_steps, n_placements: int):
     return trip, last_lane
 
 
+def overlay_usage(used, overlay):
+    """``used`` plus the in-flight claims overlay: the usage of plans the
+    launches before this one picked and the applier has not decided yet
+    (``overlay`` = (rows (..., K) i32 with -1 padding, vals (..., K, 3)
+    f32), the coalescer's ``ClaimsLedger``; None or all padding = ``used``
+    bit for bit).  It stands under the claims image and the verify pass
+    and under nothing else: no score ever reads it."""
+    if overlay is None:
+        return used
+    rows, vals = overlay
+    with jax.named_scope("overlay"):
+        add = jnp.where((rows >= 0)[..., None], vals, 0.0)
+        return used.at[jnp.maximum(rows, 0).reshape(-1)].add(
+            add.reshape(-1, 3)
+        )
+
+
 def claims_image(used, delta_rows, delta_vals, live):
-    """What the launch's lanes have claimed before any of them places: the
-    shared usage plus every live lane's in-flight deltas ((N, 3); the
-    resolution adds each pick's ask to it as the lanes take their turns).
-    With one live lane it is, bit for bit, that lane's own ``used0``."""
+    """What is claimed before any lane of the launch places: the shared
+    usage (with the in-flight overlay under it: ``overlay_usage``) plus
+    every live lane's in-flight deltas ((N, 3); the resolution adds each
+    pick's ask to it as the lanes take their turns).  With one live lane
+    and an empty overlay it is, bit for bit, that lane's own ``used0``."""
     valid = (delta_rows >= 0) & live[:, None]  # (B, K)
     add = jnp.where(valid[:, :, None], delta_vals, 0.0)
     return used.at[jnp.maximum(delta_rows, 0).reshape(-1)].add(
@@ -980,6 +998,7 @@ def _fused_place_batch_impl(
     lane_steps,
     n_placements: int,
     features: Features = FULL_FEATURES,
+    overlay=None,
 ) -> jnp.ndarray:
     """The mega-batched ranking megakernel: B eval pipelines — feasibility →
     binpack → spread/affinity → preemption evict-state → placement scan —
@@ -1005,13 +1024,14 @@ def _fused_place_batch_impl(
     * **In-launch pick resolution.**  Every lane scores the nodes against
       its own proposed usage, exactly as alone.  Then, within a step, the
       live lanes take their picks in lane order against one image of the
-      launch's claims (``claims_image``: the shared usage, every live
-      lane's in-flight deltas, every pick so far): a lane takes the
-      arg-max of its own scores over the nodes where that image still has
-      room for its ask, and adds its ask to the image.  So a lane passes
-      over a node only because lanes of this launch took the room it
-      needed, and then takes its best node that is left — exact arg-max,
-      float32 scores, nothing sampled.  If no feasible node has room under
+      launch's claims (``claims_image``: the shared usage with the
+      in-flight overlay under it, every live lane's in-flight deltas,
+      every pick so far): a lane takes the arg-max of its own scores over
+      the nodes where that image still has room for its ask, and adds its
+      ask to the image.  So a lane passes over a node only because lanes
+      of this launch (or plans still in flight: the overlay, below) took
+      the room it needed, and then takes its best node that is left —
+      exact arg-max, float32 scores, nothing sampled.  If no feasible node has room under
       the claims, the lane keeps its own arg-max (never an empty slot that
       its own scores would fill: an empty slot reads "no node can take it"
       on the host and blocks the eval).  A node a lane may only take by
@@ -1034,16 +1054,36 @@ def _fused_place_batch_impl(
       overflowed) reads 0.0 — exactly the conflicts the plan applier's
       optimistic-concurrency re-verify (plan_apply.py:_evaluate) rejects
       one plan-apply round-trip later.  The applier against live state
-      stays authoritative and serialized: launches in flight do not see
-      one another, and any lane's *stop* is credited to the claims before
-      its plan commits (the verify column, which adds deltas in lane
-      order, credits it to the later lanes only and reads 0.0 otherwise).
+      stays authoritative and serialized; any lane's *stop* is credited to
+      the claims before its plan commits (the verify column, which adds
+      deltas in lane order, credits it to the later lanes only and reads
+      0.0 otherwise).
+    * **The in-flight claims overlay** (PR 38; ``overlay`` = (rows (B, K)
+      i32, -1 padded, vals (B, K, 3) f32), read as one flat list; None =
+      empty).  Launches in flight used not to see one another: the picks
+      of the launch before, whose plans are still being built, queued or
+      applied, were in neither the matrix nor this launch's deltas, and
+      two launches named the same nodes.  The coalescer's ``ClaimsLedger``
+      hands each launch those undecided picks, and they are added to the
+      usage under the claims image and under the verify pass
+      (``overlay_usage``) and to NOTHING else: every lane still scores the
+      nodes against its own proposed usage, so the overlay only decides
+      which nodes have ``room`` (and are ``unclaimed``, for a node taken
+      by preempting).  A lane passes over a node because lanes of this
+      launch or plans still in flight took the room it needed, takes its
+      best node that is left, and keeps its own arg-max where none is
+      (VERIFIED 0.0, never row -1).  Advisory, like the VERIFIED column,
+      and a departure from the reference, whose workers never see each
+      other's plans.  With an empty overlay the output is bit for bit
+      what it is without one.
 
     Returns (B, n_placements, FUSED_PACKED_WIDTH) f32 — one fetch.
     """
     live = lane_steps > 0  # (B,)
     trip, last_lane = fused_trip_counts(lane_steps, n_placements)
     lanes = lane_steps.shape[0]
+    # Shared usage as the claims and the verify see it; the scores do not.
+    claimed = overlay_usage(used, overlay)
 
     def lane_used0(drows, dvals):
         add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
@@ -1108,7 +1148,7 @@ def _fused_place_batch_impl(
     )
     with jax.named_scope("place_scan"):
         _, outs = scan_steps(
-            step, (init, claims_image(used, delta_rows, delta_vals, live)),
+            step, (init, claims_image(claimed, delta_rows, delta_vals, live)),
             inert_lane_outputs(lanes, n_placements, features.preempt), trip,
         )
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, repicked = (
@@ -1147,7 +1187,7 @@ def _fused_place_batch_impl(
 
     with jax.named_scope("verify_scan"):
         _, verified = lax.fori_loop(
-            0, last_lane, lane_step, (used, jnp.ones(rows.shape, bool))
+            0, last_lane, lane_step, (claimed, jnp.ones(rows.shape, bool))
         )  # (B, P) bool
 
     return pack_fused_lanes(
@@ -1161,8 +1201,9 @@ fused_place_batch = functools.partial(
 )(_fused_place_batch_impl)
 
 # Live entry: per-dispatch lane operands (argnums 2..10, including the lane
-# step counts) are DONATED, so XLA reuses their freshly-transferred device
-# buffers as scratch instead of holding them live alongside the outputs.
+# step counts, and the claims overlay) are DONATED, so XLA reuses their
+# freshly-transferred device buffers as scratch instead of holding them live
+# alongside the outputs.
 # ``arrays``/``used`` stay shared with in-flight pipelined dispatches and
 # are never donated.  Kept apart from ``fused_place_batch`` because callers
 # of the un-donated entry (tests, the smoke) reuse their inputs across calls.
@@ -1170,6 +1211,7 @@ fused_place_batch_live = functools.partial(
     jax.jit,
     static_argnames=("n_placements", "features"),
     donate_argnums=tuple(range(2, 11)),
+    donate_argnames=("overlay",),
 )(_fused_place_batch_impl)
 
 

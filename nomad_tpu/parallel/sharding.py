@@ -219,8 +219,8 @@ def make_sharded_row_scatter(mesh: Mesh):
 
 def _fused_place_batch_local(
     arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-    penalties, reqs, class_eligs, host_masks, lane_steps, n_placements,
-    features,
+    penalties, reqs, class_eligs, host_masks, lane_steps, overlay,
+    n_placements, features,
 ):
     """Per-shard body of ``kernels.fused_place_batch`` under a
     ('batch', 'node') mesh — the full megakernel (ranking scan with the
@@ -240,7 +240,8 @@ def _fused_place_batch_local(
     interconnect or reaching the host is O(B · P) or (shards, k).
 
     The resolution walks ALL B lanes in lane order on every shard, against
-    this shard's slice of the launch's claims image (every batch replica
+    this shard's slice of the launch's claims image (the in-flight overlay
+    under it, as on one device; every batch replica
     holds the same slice and makes the same updates).  A lane's scores
     live on one batch shard, so each turn elects the lane's best row with
     room across the whole mesh (a ``pmax`` and a ``pmin`` over both axes;
@@ -252,6 +253,12 @@ def _fused_place_batch_local(
     usage slice with non-owned rows vacuously fitting, and combines
     verdicts with a single ``pmin`` over the node axis — each row's owner
     alone decides.
+
+    The in-flight claims overlay (``overlay``: global rows, split over
+    'batch' like the deltas; None = empty) is gathered with them and each
+    node shard adds the rows it holds to its slice of the usage under the
+    claims image and the verify pass, as ``kernels.overlay_usage`` does on
+    one device: no score reads it.
 
     Both loops run as many iterations as the launch's live lanes asked for
     (``lane_steps``, as in the single-device kernel).  Every step holds
@@ -274,6 +281,10 @@ def _fused_place_batch_local(
         g_ask = jax.lax.all_gather(reqs.ask, "batch", tiled=True)  # (B, 3)
         g_drows = jax.lax.all_gather(delta_rows, "batch", tiled=True)  # (B, K)
         g_dvals = jax.lax.all_gather(delta_vals, "batch", tiled=True)
+        if overlay is not None:
+            g_orows, g_ovals = (
+                jax.lax.all_gather(o, "batch", tiled=True) for o in overlay
+            )
     g_live = g_steps > 0  # (B,)
     lanes = g_steps.shape[0]
     trip, last_lane = fused_trip_counts(g_steps, n_placements)
@@ -434,7 +445,12 @@ def _fused_place_batch_local(
         )(delta_rows, delta_vals),
         tg_counts, reqs.s_value_hash, spread_counts,
     )
-    claims0 = add_deltas(vary(used), g_drows, g_dvals, g_live[:, None])
+    # Shared usage as the claims and the verify see it; the scores do not.
+    claimed = vary(used)
+    if overlay is not None:
+        with jax.named_scope("overlay"):
+            claimed = add_deltas(claimed, g_orows, g_ovals, g_orows >= 0)
+    claims0 = add_deltas(claimed, g_drows, g_dvals, g_live[:, None])
     bufs = tuple(
         vary(o)
         for o in inert_lane_outputs(b_local, n_placements, features.preempt)
@@ -475,7 +491,7 @@ def _fused_place_batch_local(
     with jax.named_scope("verify_scan"):
         _, fits_all = jax.lax.fori_loop(
             0, last_lane, lane_step,
-            (vary(used), vary(jnp.ones(g_rows.shape, bool), ("batch", "node"))),
+            (claimed, vary(jnp.ones(g_rows.shape, bool), ("batch", "node"))),
         )  # (B, P) bool, identical on every node shard only after the pmin:
         verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
 
@@ -501,7 +517,7 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
     def entry(
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
         penalties, reqs, class_eligs, host_masks, lane_steps, *,
-        features=FULL_FEATURES,
+        features=FULL_FEATURES, overlay=None,
     ):
         fn = shard_map(
             functools.partial(
@@ -522,12 +538,15 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
                 P("batch", None),  # class_eligs
                 P("batch", "node"),  # host_masks
                 P("batch"),  # lane_steps
+                # overlay rows (global ids) and vals
+                None if overlay is None
+                else (P("batch", None), P("batch", None, None)),
             ),
             out_specs=P("batch", None, None),
         )
         return fn(
             arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-            penalties, reqs, class_eligs, host_masks, lane_steps,
+            penalties, reqs, class_eligs, host_masks, lane_steps, overlay,
         )
 
     return jax.jit(entry, static_argnames=("features",))

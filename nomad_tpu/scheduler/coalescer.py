@@ -53,8 +53,15 @@ no longer race each other: inside the kernel the lanes take their picks
 in lane order, and a lane whose best node an earlier lane has filled
 takes its best node that is left (``kernels._fused_place_batch_impl``;
 ``lane_repicks`` counts those picks, ``verify_conflicts`` the ones for
-which no node was left).  Selects in different launches in flight still
-may pick conflicting nodes; the applier's re-verify catches it.
+which no node was left).  Nor do launches in flight race each other as
+they did: the picks whose plans the applier has not decided yet are kept in
+a claims ledger (``scheduler/claims.py``; the resolver enters them, the
+applier and the workers release them) and every launch takes the live ones
+as an overlay under its claims image, so its lanes pass over the nodes a
+launch before it took.  What a launch cannot see is the picks of a launch
+whose result had not reached the host when it was enqueued
+(``launches_unresolved_predecessor`` counts those); the applier's re-verify
+catches what is left.
 """
 
 from __future__ import annotations
@@ -79,6 +86,7 @@ from ..obs.breaker import (
 from ..ops import kernels
 from ..ops.encode import RequestSlab, SchedRequest, packed_rows
 from ..state.matrix import DEVICE_LOCK
+from .claims import OVERLAY_ROWS, ClaimsLedger
 
 log = logging.getLogger(__name__)
 
@@ -148,6 +156,12 @@ class _Pending:
     # the dispatch thread stitches coalescer.queue_wait onto it and the
     # resolver thread stitches coalescer.device — the launch→resolver hop.
     trace_ctx: Optional[trace.SpanContext] = None
+    # The eval this lane places for ("" = none that holds claims) and what
+    # its plan advertises on each of ``delta_rows`` to the launches after
+    # this one: the deltas with no eviction credited, never below zero
+    # (scheduler/claims.py; place() fills in the deltas' positive part).
+    eval_id: str = ""
+    claim_vals: Optional[np.ndarray] = None  # (MAX_DELTA_ROWS, 3) f32
 
 
 @dataclass
@@ -259,6 +273,13 @@ class DeviceCoalescer:
         self.lane_repicks = 0
         self.picks_placed = 0
         self.preempt_picks = 0
+        # The in-flight claims overlay (scheduler/claims.py): the ledger,
+        # the rows handed to launches, and the launches enqueued while an
+        # earlier launch's result was not yet on the host (its picks are in
+        # no ledger yet: the race that is left).
+        self.claims = ClaimsLedger()
+        self.overlay_rows_total = 0
+        self.launches_unresolved_predecessor = 0
         self.feature_recompiles = 0
         self._features = None
         # Device→host result traffic for fused/sharded dispatches (the
@@ -336,11 +357,15 @@ class DeviceCoalescer:
         host_mask: np.ndarray,
         timeout: float = 600.0,  # must cover a cold TPU jit compile
         n_live: int = 0,
+        eval_id: str = "",
+        claim_vals: Optional[np.ndarray] = None,
     ) -> PlaceOutcome:
         """Submit one placement request; blocks until its batch lands.
         Every output is ``scan_length`` long — take ``rows[:k]``.  Only
         the first ``n_live`` steps are computed (0 = all): the rows past
-        them read -1 and charge no usage."""
+        them read -1 and charge no usage.  ``eval_id`` names the eval the
+        picks are entered under in the claims ledger (if a worker holds it
+        open), ``claim_vals`` what its plan advertises on ``delta_rows``."""
         p = _Pending(
             request=request,
             delta_rows=delta_rows,
@@ -353,6 +378,11 @@ class DeviceCoalescer:
             n_live=n_live,
             enqueued_at=time.time(),
             trace_ctx=trace.current(),
+            eval_id=eval_id,
+            claim_vals=(
+                claim_vals if claim_vals is not None or not eval_id
+                else np.maximum(delta_vals, 0.0)
+            ),
         )
         with self._cond:
             if self._stop.is_set():
@@ -787,10 +817,14 @@ class DeviceCoalescer:
             lanes = self.max_lanes
             # The small lane operands are views of one buffer, handed to
             # jax as one operand (kernels.unpack_lanes gives them back).
+            # The claims overlay is one flat list to the program; it rides
+            # the pack as a few rows a lane (no device buffer of its own).
+            ov = -(-OVERLAY_ROWS // lanes)
             pack, small, layout = packed_rows(lanes, [
                 ((cw,), bool), (sc_shape, np.float32),
                 ((MAX_DELTA_ROWS,), np.int32),
                 ((MAX_DELTA_ROWS, 3), np.float32), ((), np.int32),
+                ((ov,), np.int32), ((ov, 3), np.float32),
             ])
             st = self._stage[slot] = {
                 "host_mask": np.zeros((lanes, n), bool),
@@ -798,15 +832,18 @@ class DeviceCoalescer:
                 "penalty": np.zeros((lanes, n), bool),
                 "pack": pack, "layout": layout,
                 **dict(zip(("class_elig", "spread_counts", "delta_rows",
-                            "delta_vals", "lane_steps"), small)),
+                            "delta_vals", "lane_steps", "overlay_rows",
+                            "overlay_vals"), small)),
             }
             st["class_elig"][:] = True
             st["delta_rows"][:] = -1
+            st["overlay_rows"][:] = -1
         return st, self._req_slabs[slot]
 
     def _sync_matrix(self, n_shards: int, degraded: bool):
-        """The snapshot one launch reads: (arrays, sharded arrays, matrix
-        version, node-axis width) — dirty rows go host → device here."""
+        """The snapshot one launch reads: (arrays, sharded arrays, the
+        matrix version it holds everything up to and nothing after,
+        node-axis width) — dirty rows go host → device here."""
         from ..ops import fake_device
 
         mx = self.matrix
@@ -819,7 +856,7 @@ class DeviceCoalescer:
                 # shard instead of re-laying the full matrix per dispatch.
                 with DEVICE_LOCK:
                     sharded = mx.sync_sharded(self._mesh)
-                    version = mx.version
+                    version = mx.synced_version
                 n = int(mx.capacity)
             elif degraded and not fake_device.enabled():
                 # Breaker open on a real backend: feed the host twin from
@@ -827,12 +864,12 @@ class DeviceCoalescer:
                 # snapshot on the very device the breaker just declared
                 # wedged.
                 arrays = mx.sync_host()
-                version = mx.version
+                version = mx.synced_version
                 n = int(arrays.used.shape[0])
             else:
                 with DEVICE_LOCK:
                     arrays = mx.sync()
-                    version = mx.version
+                    version = mx.synced_version
                 n = int(arrays.used.shape[0])
             trace.add_args(
                 rows=mx.rows_scattered_total - rows0,
@@ -840,6 +877,45 @@ class DeviceCoalescer:
                 shards=self.mesh_shape()[1] if sharded is not None else 1,
             )
         return arrays, sharded, version, n
+
+    def _overlay(self, batch: List[_Pending], version: int):
+        """The claims overlay of the launch about to be enqueued: (rows,
+        vals) of the picks whose plans the applier has not decided, or has
+        committed past ``version`` (the launch's snapshot), the launch's
+        own lanes' evals left out.  Read as late as the launch allows: a
+        predecessor whose result arrived during this launch's host part is
+        in it."""
+        if self.inflight:
+            self.launches_unresolved_predecessor += 1
+        rows, vals = self.claims.overlay(
+            version, [p.eval_id for p in batch if p.eval_id],
+            self.matrix.relocated_at,
+        )
+        self.overlay_rows_total += len(rows)
+        return rows, vals
+
+    def _register_claims(self, p: _Pending, rows: np.ndarray,
+                         preempted: np.ndarray, layout: int) -> None:
+        """Enter a resolved lane's whole proposed usage into the ledger:
+        what its plan held before the launch (``claim_vals`` on
+        ``delta_rows``) and the picks its caller will consume, the first
+        ``n_live`` up to a preempting one (``stack.py`` drops the rows
+        after it and re-enters).  Before the lane's future completes, so
+        the entry is there when its plan reaches the applier."""
+        n = lane_step_count(p.n_live, self.scan_length)
+        picks = rows[:n]
+        pre = np.flatnonzero(preempted[:n] != 0.0)
+        if len(pre):
+            picks = picks[: pre[0] + 1]
+        picks = picks[picks >= 0]
+        held = p.delta_rows >= 0
+        ask = np.asarray(p.request.ask, np.float32)
+        rows = np.concatenate([p.delta_rows[held], picks])
+        vals = np.concatenate(
+            [p.claim_vals[held], np.broadcast_to(ask, (len(picks), 3))]
+        )
+        claims = vals.any(axis=1)
+        self.claims.register(p.eval_id, rows[claims], vals[claims], layout)
 
     def _dispatch(self, batch: List[_Pending], degraded: bool = False):
         """Launch one placement batch; returns (unfetched packed result,
@@ -940,6 +1016,7 @@ class DeviceCoalescer:
                     lane_mask=np.ones((len(batch),), bool),
                     n_placements=self.scan_length,
                     live_counts=live_counts,
+                    overlay=self._overlay(batch, version),
                 )
             self.fused_dispatches += 1
             self.fused_lanes += len(batch)
@@ -1037,16 +1114,27 @@ class DeviceCoalescer:
             self._unpack_variant = unpack, layouts
             state = "coalescer.trace_variant"
         with self._state(state, **args):
+            # The claims overlay goes into the pack last, immediately
+            # before the call that hands the pack over.
+            rows, vals = self._overlay(batch, version)
+            orows, ovals = st["overlay_rows"], st["overlay_vals"]
+            flat = np.full((orows.size,), -1, np.int32)
+            flat[: len(rows)] = rows
+            orows[:] = flat.reshape(orows.shape)
+            if len(rows):
+                flat = np.zeros((orows.size, 3), np.float32)
+                flat[: len(rows)] = vals
+                ovals[:] = flat.reshape(ovals.shape)
             # The 30 small lane operands cross as two buffers, not 30: the
             # placement program takes them as device arrays.
-            reqs, (ce, sc, dr, dv, ls) = unpack(
+            reqs, (ce, sc, dr, dv, ls, orows, ovals) = unpack(
                 slab.pack, st["pack"], layouts=layouts
             )
             reqs = SchedRequest(*reqs)
             if n_shards > 1:
                 packed = self._sharded_fused_fn(
                     sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
-                    hm, ls, features=feats,
+                    hm, ls, features=feats, overlay=(orows, ovals),
                 )
             else:
                 # The live entry donates the per-dispatch lane operands
@@ -1056,7 +1144,7 @@ class DeviceCoalescer:
                 packed = kernels.fused_place_batch_live(
                     arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
                     ls, n_placements=self.scan_length,
-                    features=feats,
+                    features=feats, overlay=(orows, ovals),
                 )
         return packed, version
 
@@ -1208,6 +1296,10 @@ class DeviceCoalescer:
                 pcol = row[:, kernels.PACKED_PREEMPT]
                 self.picks_placed += int(placed.sum())
                 self.preempt_picks += int((placed & (pcol != 0.0)).sum())
+                if p.eval_id:
+                    self._register_claims(
+                        p, rows_i, pcol, ticket.matrix_version
+                    )
                 p.outcome = PlaceOutcome(
                     rows=rows_i,
                     scores=row[:, kernels.PACKED_SCORE],

@@ -198,6 +198,21 @@ class GenericStack:
                 add(node_id, a.resources, -1.0)
         return deltas
 
+    def _plan_evictions(self) -> Dict[int, np.ndarray]:
+        """(cpu, mem, disk) the in-flight plan's preemptions free per node
+        row: what ``_plan_usage_deltas`` credits and the claims the plan
+        advertises to other launches do not (``_dispatch_place``)."""
+        freed: Dict[int, np.ndarray] = {}
+        for node_id, allocs in self.ctx.plan.node_preemptions.items():
+            row = self.matrix.row_of.get(node_id)
+            if row is None:
+                continue
+            d = freed.setdefault(row, np.zeros(3, np.float32))
+            for a in allocs:
+                r = a.resources
+                d += np.array([r.cpu, r.memory_mb, r.disk_mb], np.float32)
+        return freed
+
     def _tg_counts(self, job: Job, tg: TaskGroup) -> Dict[int, int]:
         """Proposed allocs of this job+TG per node row (JobAntiAffinity and
         distinct_hosts inputs)."""
@@ -570,11 +585,20 @@ class GenericStack:
         class_elig: np.ndarray,
         host_mask: Optional[np.ndarray],
         remaining: int,
+        evicted: Optional[Dict[int, np.ndarray]] = None,
     ):
         """Run one placement scan; returns host-side arrays (rows, scores,
         binpack, preempted, n_eval, n_filt, n_exh, fit_verified) of scan
         length ≥ the bucket for ``remaining``.  fit_verified is the
         coalesced launch's cross-lane verify column, None on the solo path.
+
+        ``evicted``: what the plan's evictions free per row (credited in
+        ``deltas``).  The coalesced launch enters this eval's proposed
+        usage into the in-flight claims ledger (scheduler/claims.py) with
+        no eviction credited and nothing below zero: a node this plan took
+        by preempting reads over-full to the launches after it, as it does
+        to the later lanes of its own launch, and room a stop will free is
+        not offered before the plan commits.
 
         With a mesh configured the coalescer routes the batch through the
         node-sharded fused entry (parallel/sharding.py, hierarchical
@@ -595,6 +619,11 @@ class GenericStack:
             for i, (row, d) in enumerate(deltas.items()):
                 drows[i] = row
                 dvals[i] = d
+            claims = dvals
+            if evicted:
+                claims = dvals.copy()
+                for i, row in enumerate(deltas):
+                    claims[i] += evicted.get(row, 0.0)
             out = coal.place(
                 compiled.request,
                 drows,
@@ -606,6 +635,8 @@ class GenericStack:
                 host_mask if host_mask is not None
                 else self.matrix.shared_masks()[1],
                 n_live=remaining,
+                eval_id=self.ctx.plan.eval_id,
+                claim_vals=np.maximum(claims, 0.0),
             )
             return (
                 out.rows, out.scores, out.binpack, out.preempted,
@@ -733,14 +764,15 @@ class GenericStack:
                 host_mask[banned_rows] = False
 
             deltas = self._plan_usage_deltas()
+            evicted = self._plan_evictions()
             for row in chosen_rows:
                 d = deltas.setdefault(row, np.zeros(3, np.float32))
                 d += np.asarray(compiled.request.ask, np.float32)
             for row, v in chosen_victims:
                 r = v.resources
-                deltas[row] -= np.array(
-                    [r.cpu, r.memory_mb, r.disk_mb], np.float32
-                )
+                freed = np.array([r.cpu, r.memory_mb, r.disk_mb], np.float32)
+                deltas[row] -= freed
+                evicted[row] = evicted.get(row, 0.0) + freed
 
             tg_counts = self._tg_counts(job, tg)
             for row in chosen_rows:
@@ -762,7 +794,7 @@ class GenericStack:
                 (rows_all, scores_all, binpack_all, preempted_all, n_eval_all,
                  n_filt_all, n_exh_all, verified_all) = self._dispatch_place(
                     compiled, deltas, tg_count, spread_counts, penalty,
-                    class_elig, host_mask, remaining,
+                    class_elig, host_mask, remaining, evicted,
                 )
             take = min(len(rows_all), remaining)
             rows_out = rows_all[:take]

@@ -225,6 +225,7 @@ class PlanApplier:
             result.refresh_index = self.server.store.latest_index
             self.plans_partial += 1
             self.server.metrics.incr("nomad.plan.result", outcome="rejected")
+            self.server.coalescer.claims.refuse(plan.eval_id)
             return result, 0
 
         index = self.server.next_index()
@@ -239,6 +240,15 @@ class PlanApplier:
             evals=result.preemption_evals,
         )
         result.alloc_index = index
+        # The plan's picks leave the in-flight claims ledger: the refused
+        # nodes' at once, the committed ones' with the first launch whose
+        # snapshot holds this commit.  Every matrix mutator runs under the
+        # store lock held here, so ``version`` is this commit's own.
+        matrix = self.server.store.matrix
+        self.server.coalescer.claims.commit(
+            plan.eval_id, matrix.version,
+            [matrix.row_of.get(nid, -1) for nid in failed_nodes],
+        )
         if preempts:
             self.server.metrics.incr(
                 "nomad.plan.preempted_allocs", len(preempts)

@@ -286,6 +286,22 @@ class Server:
         m.gauge_fn(
             "nomad.kernel.preempt_picks_total", lambda: c.preempt_picks
         )
+        # The in-flight claims overlay (scheduler/claims.py): rows handed
+        # to launches, the ledger's rows by event, and the launches that
+        # could not see their predecessor's picks.
+        m.gauge_fn(
+            "nomad.kernel.overlay_rows_total", lambda: c.overlay_rows_total
+        )
+        for event in c.claims.counts:
+            m.gauge_fn(
+                "nomad.coalescer.claims",
+                lambda event=event: c.claims.counts[event],
+                event=event,
+            )
+        m.gauge_fn(
+            "nomad.coalescer.launches_unresolved_predecessor",
+            lambda: c.launches_unresolved_predecessor,
+        )
         m.gauge_fn(
             "nomad.kernel.feature_recompiles", lambda: c.feature_recompiles
         )

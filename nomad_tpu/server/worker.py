@@ -147,11 +147,18 @@ class Worker:
         # The renewer thread extends this delivery's unack lease for as
         # long as the scheduler runs (eval_broker.renew).
         self._active_lease = (ev.id, token) if token else None
+        # Only while this bracket is open can the eval's picks stand in the
+        # in-flight claims ledger (scheduler/claims.py); whatever happens
+        # inside, close() drops what the applier has not decided: a leaked
+        # claim would make a node look full for good.
+        claims = self.server.coalescer.claims
+        claims.open(ev.id)
         try:
             with trace.span("worker.invoke_scheduler", metrics=metrics), \
                     metrics.timer("nomad.worker.invoke_scheduler").time():
                 sched.process(ev)
         finally:
+            claims.close(ev.id)
             self._active_lease = None
         if ev.create_time:
             # Enqueue→scheduled end-to-end latency (eval_broker telemetry).
@@ -171,6 +178,9 @@ class Worker:
             try:
                 result = pending.wait(timeout=PLAN_APPLY_TIMEOUT)
             except Exception:  # noqa: BLE001 — queue disabled / apply error
+                # No verdict: the plan's claims go at once (the applier
+                # releases those of the plans it decides).
+                self.server.coalescer.claims.refuse(plan.eval_id)
                 return None, self.server.store.snapshot()
         snapshot = None
         if result.refresh_index:

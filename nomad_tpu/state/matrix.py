@@ -305,6 +305,11 @@ class NodeMatrix:
         # time means the dispatch scored a stale snapshot (counted by the
         # coalescer — the applier's re-verify is the correctness backstop).
         self.version = 0
+        # ``version`` as the last sync read it under the host lock, with the
+        # rows it drained: that snapshot holds every mutation up to it and
+        # none after (the coalescer's claims ledger releases a committed
+        # plan's claims by it).
+        self.synced_version = 0
         # Transfer telemetry (exported via /v1/metrics): proves steady-state
         # syncs move O(dirty rows), not the whole matrix.
         self.full_uploads = 0
@@ -508,6 +513,14 @@ class NodeMatrix:
             return [
                 nid for r, nid in self.node_of.items() if r // blk == shard
             ]
+
+    @property
+    def relocated_at(self) -> int:
+        """``version`` after the last row relocation (growth of a sharded
+        layout, a re-layout); 0 = no row ever moved.  Row ids recorded
+        before it name rows of another layout."""
+        remaps = self._remaps
+        return remaps[-1][0] if remaps else 0
 
     def translate_rows(
         self, rows: np.ndarray, from_version: int
@@ -906,6 +919,7 @@ class NodeMatrix:
         feeds the fake-device twin from this without ever touching the
         device, so a wedged device cannot stall the fallback."""
         with self._host_lock:
+            self.synced_version = self.version
             return DeviceArrays(
                 **{f: self._alloc[f].copy() for f in DeviceArrays._fields}
             )
@@ -939,6 +953,7 @@ class NodeMatrix:
                     f: self._alloc[f].copy() for f in DeviceArrays._fields
                 }
                 self._dirty.clear()
+                self.synced_version = self.version
                 # Claim validity for THIS copy while still under the lock:
                 # a concurrent _grow after this point flips it back to
                 # False and the next sync re-uploads — setting it after
@@ -973,6 +988,7 @@ class NodeMatrix:
             return self._device
 
         with self._host_lock:
+            self.synced_version = self.version
             if not self._dirty:
                 return self._device
             rows = np.fromiter(self._dirty, np.int32)
@@ -1043,6 +1059,7 @@ class NodeMatrix:
                     f: self._alloc[f].copy() for f in DeviceArrays._fields
                 }
                 self._sharded_dirty.clear()
+                self.synced_version = self.version
                 # Same ordering contract as _sync_locked: claim validity
                 # under the lock so a concurrent _grow's invalidation wins.
                 self._sharded_valid = True
@@ -1060,6 +1077,7 @@ class NodeMatrix:
             return self._sharded_device
 
         with self._host_lock:
+            self.synced_version = self.version
             if not self._sharded_dirty:
                 return self._sharded_device
             rows = np.fromiter(self._sharded_dirty, np.int32)

@@ -156,7 +156,7 @@ def fill_frontier(matrix, nodes, picks, ask_cpu, ask_mem, seed=0):
 LAUNCH_FILLS = (1, 2, 8, 9, 16, 33, 57, 64)
 NODE_AXIS = ("tg_count", "penalty", "host_mask")
 SMALL = ("class_elig", "spread_counts", "delta_rows", "delta_vals",
-         "lane_steps")
+         "lane_steps", "overlay_rows", "overlay_vals")
 
 
 def launch_lanes(coal, k, seed=0, classes=2):
@@ -196,14 +196,15 @@ def launch_lanes(coal, k, seed=0, classes=2):
 
 def spy_on_launch(monkeypatch, coal):
     """Record what the next launches hand jax: ``packs`` gets the unpacking
-    program's operands, ``placed`` the placement program's (positional)."""
+    program's operands, ``placed`` the placement program's (positional,
+    then the claims overlay's two)."""
     from nomad_tpu.ops import kernels
 
     packs, placed = [], []
 
     def spy(fn, seen):
         def call(*operands, **static):
-            seen.append(operands)
+            seen.append(operands + tuple(static.get("overlay", ())))
             return fn(*operands, **static)
         return call
 
@@ -252,6 +253,7 @@ def check_packed_launch(monkeypatch, k, n_device_shards=1):
     import numpy as np
 
     from nomad_tpu.ops import kernels
+    from nomad_tpu.scheduler.claims import OVERLAY_ROWS
 
     if n_device_shards not in _WIDE:
         _WIDE[n_device_shards] = wide_coalescer(n_device_shards)
@@ -271,11 +273,13 @@ def check_packed_launch(monkeypatch, k, n_device_shards=1):
     assert req_pack is slab.pack and lane_pack is st["pack"]
     assert coal.operand_bytes_total - bytes0 == (
         lanes * n * (1 + 4 + 1) + st["pack"].nbytes + slab.pack.nbytes)
-    ((_arrays, _used, dr, dv, tg, sc, pen, reqs, ce, hm, ls),) = placed
+    ((_arrays, _used, dr, dv, tg, sc, pen, reqs, ce, hm, ls, orows,
+      ovals),) = placed
     for x, field in zip((tg, pen, hm), NODE_AXIS):
         assert x is st[field] and x.shape == (lanes, n)
     assert not hm[k:].any()
-    small = (ce, sc, dr, dv, ls) + tuple(reqs)
+    assert orows.size >= OVERLAY_ROWS and (st["overlay_rows"] == -1).all()
+    small = (ce, sc, dr, dv, ls, orows, ovals) + tuple(reqs)
     assert all(isinstance(x, jax.Array) for x in small)
     for x, field in zip(small, SMALL):  # copied: the live entry donated them
         assert x.shape == st[field].shape and x.dtype == st[field].dtype
