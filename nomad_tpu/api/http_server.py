@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
 from .. import trace
+from ..trace import runtime
 from ..jobspec import api_to_job, parse_job
 from ..structs.types import DrainStrategy, SchedulerConfiguration
 
@@ -258,7 +259,16 @@ class HTTPAPIServer:
             def do_DELETE(self):
                 self._handle("DELETE")
 
-        self.httpd = ThreadingHTTPServer((host, port), Handler)
+        class Httpd(ThreadingHTTPServer):
+            def process_request_thread(self, request, client_address):
+                # A handler thread lives for one connection: its CPU goes
+                # to its group as it ends (nomad.runtime.cpu_seconds).
+                try:
+                    super().process_request_thread(request, client_address)
+                finally:
+                    runtime.thread_ended("http-api")
+
+        self.httpd = Httpd((host, port), Handler)
         self.httpd.daemon_threads = True
         self.port = self.httpd.server_address[1]
         self.addr = f"http://{host}:{self.port}"

@@ -43,6 +43,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from .. import trace
+from ..trace import runtime
 from ..metrics import RollingWindow
 from ..retry import env_float, env_int
 
@@ -119,6 +120,9 @@ def watchdog_fetch(
         except BaseException as e:  # noqa: BLE001 — ferried to the caller
             box["error"] = e
         finally:
+            # One thread a fetch: its CPU goes to its group as it ends
+            # (nomad.runtime.cpu_seconds).
+            runtime.thread_ended("device-fetch")
             fetched.set()
 
     t0 = time.monotonic()

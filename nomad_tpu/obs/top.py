@@ -73,6 +73,37 @@ def _phase_rows(snap: Dict[str, Any], limit: int = 12) -> List[tuple]:
     return rows[:limit]
 
 
+_CPU = "nomad.runtime.cpu_seconds{group="
+
+
+def _runtime_row(
+    prev: Optional[Dict[str, Any]], cur: Dict[str, Any], interval: float
+) -> str:
+    """Work or waiting, between two refreshes: the Python threads' CPU as
+    a share of one core (near 100: the interpreter is the bottleneck),
+    the native threads' beside it, how late the runtime probe woke on
+    average (what a thread pays to get the interpreter back), and the
+    seconds of stalls (wakes more than 250 ms late) since the start."""
+    if prev is None or interval <= 0 or _CPU + "process}" not in cur:
+        return ""
+
+    def grew(key: str) -> float:
+        return max(0.0, _num(cur, key) - _num(prev, key))
+
+    python = sum(
+        grew(k) for k in cur
+        if k.startswith(_CPU) and k[len(_CPU):-1] not in ("process", "native")
+    )
+    wakes = grew("nomad.runtime.wakes_total")
+    late = grew("nomad.runtime.wake_late_seconds_total")
+    return (
+        f"runtime : interpreter busy {100.0 * python / interval:5.1f}%"
+        f"  native {100.0 * grew(_CPU + 'native}') / interval:5.1f}%"
+        f"  wake late {1e3 * late / wakes if wakes else 0.0:.2f} ms"
+        f"  stalls {_num(cur, 'nomad.runtime.stall_seconds_total'):.1f}s"
+    )
+
+
 def render(
     metrics: Dict[str, Any],
     slo: Optional[Dict[str, Any]],
@@ -148,6 +179,9 @@ def render(
             f"  flips {int(flips.get('total', 0))}"
             f" (supp {int(flips.get('suppressed', 0))})"
         )
+    runtime = _runtime_row(prev_metrics, metrics, interval)
+    if runtime:
+        lines.append(runtime)
     dev = h.get("device")
     if isinstance(dev, dict):
         lines.append(

@@ -432,9 +432,13 @@ class DeviceCoalescer:
     def _state(self, name: str, **args):
         """One state of the dispatch or resolver loop as a span: ambient
         (per launch, not per eval), on the thread that is in that state,
-        and annotated, so a profiler session shows it beside the device's
-        lanes (OBSERVABILITY.md, "Span taxonomy")."""
-        return trace.span(name, metrics=self.metrics, annotate=True, **args)
+        annotated, so a profiler session shows it beside the device's
+        lanes, and with the thread's CPU beside its duration, so that the
+        state's work is told from its waiting (OBSERVABILITY.md, "Span
+        taxonomy")."""
+        return trace.span(
+            name, metrics=self.metrics, annotate=True, cpu=True, **args
+        )
 
     def _run(self) -> None:
         """Dispatch (producer) loop: build batches, launch, hand tickets to
@@ -848,13 +852,16 @@ class DeviceCoalescer:
 
         mx = self.matrix
         rows0, bytes0 = mx.rows_scattered_total, mx.upload_bytes_total
+        wait0 = mx.sync_lock_wait_total
         arrays = sharded = None
         with self._state("coalescer.sync"):
+            t_lock, device_wait = time.time(), 0.0
             if n_shards > 1:
                 # Multi-chip: the matrix stays RESIDENT across the mesh —
                 # sync_sharded scatters only dirty rows to the owning
                 # shard instead of re-laying the full matrix per dispatch.
                 with DEVICE_LOCK:
+                    device_wait = time.time() - t_lock
                     sharded = mx.sync_sharded(self._mesh)
                     version = mx.synced_version
                 n = int(mx.capacity)
@@ -868,6 +875,7 @@ class DeviceCoalescer:
                 n = int(arrays.used.shape[0])
             else:
                 with DEVICE_LOCK:
+                    device_wait = time.time() - t_lock
                     arrays = mx.sync()
                     version = mx.synced_version
                 n = int(arrays.used.shape[0])
@@ -875,6 +883,11 @@ class DeviceCoalescer:
                 rows=mx.rows_scattered_total - rows0,
                 bytes=mx.upload_bytes_total - bytes0,
                 shards=self.mesh_shape()[1] if sharded is not None else 1,
+                # Blocked acquiring DEVICE_LOCK and the matrix's host lock
+                # (held by the applier's mutators): with the span's
+                # ``cpu``, the rest of ``dur`` is the wait for the GIL and
+                # the time blocked inside the jitted scatter.
+                lock_wait=device_wait + mx.sync_lock_wait_total - wait0,
             )
         return arrays, sharded, version, n
 

@@ -107,10 +107,15 @@ class PlanApplier:
         # thread was doing while the per-plan records below (stitched onto
         # each eval's trace afterwards) say what the evals waited for.
         with trace.span("plan.batch", metrics=self.server.metrics,
-                        annotate=True, plans=len(staged)):
+                        annotate=True, cpu=True, plans=len(staged)):
             with self.server.metrics.timer("nomad.plan.apply").time():
+                wait_t0 = time.time()
                 with store._write_lock:
                     with store._lock:
+                        # Blocked behind the store's other writers and
+                        # readers: with ``cpu``, what of the batch was
+                        # neither work nor this wait.
+                        trace.add_args(lock_wait=time.time() - wait_t0)
                         for pending in staged:
                             t0 = time.time()
                             try:

@@ -247,10 +247,6 @@ class Server:
             "nomad.matrix.rows_scattered_total", lambda: mx.rows_scattered_total
         )
         m.gauge_fn(
-            "nomad.matrix.rows_per_scatter",
-            lambda: round(mx.rows_scattered_total / (mx.scatter_syncs or 1), 2),
-        )
-        m.gauge_fn(
             "nomad.matrix.upload_bytes_total", lambda: mx.upload_bytes_total
         )
         # Per-kernel attribution: launch counts by path, request
@@ -258,8 +254,8 @@ class Server:
         m.gauge_fn("nomad.kernel.launches", lambda: c.dispatches, path="batched")
         m.gauge_fn("nomad.kernel.launches", lambda: c.solo_ops, path="solo")
         # Fused megakernel accounting: one launch serves every coalesced
-        # lane (launches/eval = fused_dispatches / fused_lanes), plus the
-        # cross-lane AllocsFit verify verdicts, the picks the in-launch
+        # lane (launches an eval = launches{path=fused} / fused_lanes), plus
+        # the cross-lane AllocsFit verify verdicts, the picks the in-launch
         # resolution moved to another node, and the occupancy-features
         # recompile ratchet.
         m.gauge_fn(
@@ -268,11 +264,6 @@ class Server:
         m.gauge_fn("nomad.kernel.fused_lanes", lambda: c.fused_lanes)
         m.gauge_fn(
             "nomad.kernel.scan_steps_total", lambda: c.scan_steps_total
-        )
-        m.gauge_fn(
-            "nomad.kernel.launches_per_eval",
-            lambda: round(c.fused_dispatches / (c.fused_lanes or 1), 4),
-            path="fused",
         )
         m.gauge_fn(
             "nomad.kernel.verify_conflicts", lambda: c.verify_conflicts
@@ -335,6 +326,31 @@ class Server:
         m.gauge_fn(
             "nomad.topk.host_bytes_total", lambda: c.topk_host_bytes_total
         )
+        # Work told from waiting (trace/runtime.py), computed only when
+        # the snapshot is read: CPU seconds by thread group, how late the
+        # probe woke, and the time the threads were runnable and had no
+        # core.  Absent where the platform has no such clock or file.
+        from ..trace import runtime
+
+        for group in runtime.cpu_groups():
+            m.gauge_fn(
+                "nomad.runtime.cpu_seconds",
+                lambda group=group: runtime.cpu_seconds(group),
+                group=group,
+            )
+        m.gauge_fn("nomad.runtime.wakes_total", lambda: runtime.wakes_total)
+        m.gauge_fn(
+            "nomad.runtime.wake_late_seconds_total",
+            lambda: runtime.wake_late_seconds_total,
+        )
+        m.gauge_fn(
+            "nomad.runtime.stall_seconds_total",
+            lambda: runtime.stall_seconds_total,
+        )
+        if runtime.run_delay_seconds() is not None:
+            m.gauge_fn(
+                "nomad.runtime.run_delay_seconds", runtime.run_delay_seconds
+            )
 
     # ------------------------------------------------------------------
     # Consensus (server/replication.py)

@@ -36,7 +36,9 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 import zlib
+from contextlib import contextmanager
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -316,6 +318,11 @@ class NodeMatrix:
         self.scatter_syncs = 0
         self.rows_scattered_total = 0
         self.upload_bytes_total = 0
+        # Seconds the syncs spent blocked acquiring ``_host_lock`` (held by
+        # the store's mutators): the coalescer attaches each launch's share
+        # to its ``coalescer.sync`` span as ``lock_wait``.  Timed per sync,
+        # never on the per-mutation acquires.
+        self.sync_lock_wait_total = 0.0
         # Sharded residency (multi-chip dispatch path): a second device
         # mirror laid out across a mesh, with its own dirty set so the
         # single-device and sharded copies sync independently.
@@ -909,6 +916,15 @@ class NodeMatrix:
         with DEVICE_LOCK:
             return fn()
 
+    @contextmanager
+    def _host_lock_timed(self):
+        """``_host_lock`` for a sync, the time blocked acquiring it added
+        to ``sync_lock_wait_total``."""
+        t0 = time.time()
+        with self._host_lock:
+            self.sync_lock_wait_total += time.time() - t0
+            yield
+
     def snapshot_host(self) -> Dict[str, np.ndarray]:
         """Host-side view (no copy) of the active arrays."""
         return self._alloc
@@ -918,7 +934,7 @@ class NodeMatrix:
         numpy arrays — the degraded dispatch path (device breaker open)
         feeds the fake-device twin from this without ever touching the
         device, so a wedged device cannot stall the fallback."""
-        with self._host_lock:
+        with self._host_lock_timed():
             self.synced_version = self.version
             return DeviceArrays(
                 **{f: self._alloc[f].copy() for f in DeviceArrays._fields}
@@ -948,7 +964,7 @@ class NodeMatrix:
         # run concurrently from the store); the device transfer itself
         # happens outside it.  `_alloc[f][rows]` fancy-indexing copies.
         if self._device is None or not self._device_valid:
-            with self._host_lock:
+            with self._host_lock_timed():
                 host_copy = {
                     f: self._alloc[f].copy() for f in DeviceArrays._fields
                 }
@@ -987,7 +1003,7 @@ class NodeMatrix:
                 raise
             return self._device
 
-        with self._host_lock:
+        with self._host_lock_timed():
             self.synced_version = self.version
             if not self._dirty:
                 return self._device
@@ -1054,7 +1070,7 @@ class NodeMatrix:
             self._sharded_valid = False
 
         if self._sharded_device is None or not self._sharded_valid:
-            with self._host_lock:
+            with self._host_lock_timed():
                 host_copy = {
                     f: self._alloc[f].copy() for f in DeviceArrays._fields
                 }
@@ -1076,7 +1092,7 @@ class NodeMatrix:
             )
             return self._sharded_device
 
-        with self._host_lock:
+        with self._host_lock_timed():
             self.synced_version = self.version
             if not self._sharded_dirty:
                 return self._sharded_device

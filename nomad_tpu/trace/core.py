@@ -40,6 +40,7 @@ import hashlib
 import itertools
 import threading
 import time
+import weakref
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass
@@ -127,17 +128,30 @@ def _trace_sampled(trace_id: str) -> bool:
 # Flight recorder
 
 
+# Rings of threads that have ended are kept, so that a dump still shows
+# what a short-lived thread (an HTTP handler, a test's producer) did; the
+# oldest go first once this many are held.
+MAX_RETIRED_RINGS = 256
+
+
 class FlightRecorder:
     """Per-thread ring buffers of finished span/event records.
 
     The writing thread owns its ring; the registry dict is locked only
     on ring creation and when draining for a dump, so recording never
-    contends across threads on the hot path."""
+    contends across threads on the hot path.
+
+    A ring is filed under an id of its own, never under the thread's
+    ident: the next thread to start reuses an ended thread's ident, and a
+    ring filed under it would replace the ended thread's records."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
+        self._ring_ids = itertools.count(1)
         self._rings: Dict[int, deque] = {}
         self._thread_names: Dict[int, str] = {}
+        # ring id -> its thread (lanes have none and are never retired)
+        self._owners: Dict[int, "weakref.ref[threading.Thread]"] = {}
         self._lanes: Dict[str, int] = {}  # lane name -> synthetic tid (< 0)
         self._tls = threading.local()
 
@@ -145,20 +159,38 @@ class FlightRecorder:
         ring = getattr(self._tls, "ring", None)
         if ring is None or ring.maxlen != _cfg.ring:
             t = threading.current_thread()
-            ring = deque(getattr(self._tls, "ring", ()) or (), maxlen=_cfg.ring)
+            ring = deque(ring or (), maxlen=_cfg.ring)
             self._tls.ring = ring
             with self._lock:
-                self._rings[t.ident or 0] = ring
-                self._thread_names[t.ident or 0] = t.name
+                rid = getattr(self._tls, "ring_id", None)
+                if rid is None:
+                    rid = self._tls.ring_id = next(self._ring_ids)
+                    self._retire_locked(MAX_RETIRED_RINGS)
+                self._rings[rid] = ring
+                self._thread_names[rid] = t.name
+                self._owners[rid] = weakref.ref(t)
         return ring
+
+    def _retire_locked(self, keep: int) -> None:
+        """Drop all but the ``keep`` newest rings of ended threads (a
+        dict keeps insertion order, and rings are filed as they are made:
+        the oldest come first)."""
+        retired = []
+        for rid, ref in self._owners.items():
+            t = ref()
+            if t is None or not t.is_alive():
+                retired.append(rid)
+        for rid in retired[: max(0, len(retired) - keep)]:
+            del self._rings[rid], self._thread_names[rid], self._owners[rid]
 
     def record(self, rec: Dict[str, Any], lane: Optional[str] = None) -> None:
         (self._ring() if lane is None else self._lane(lane)).append(rec)
 
     def _lane(self, name: str) -> deque:
         """A ring that belongs to no thread: what stops the whole process
-        (``runtime.gc_pause``) gets a row of its own in the dump instead
-        of overlapping the spans of whichever thread noticed it."""
+        (``runtime.gc_pause``, ``runtime.stall``) gets a row of its own in
+        the dump instead of overlapping the spans of whichever thread
+        noticed it."""
         with self._lock:
             tid = self._lanes.get(name)
             if tid is None:
@@ -168,7 +200,9 @@ class FlightRecorder:
             return self._rings[tid]
 
     def records(self, limit: Optional[int] = None) -> List[Dict[str, Any]]:
-        """Snapshot every thread's ring, globally ordered by start time."""
+        """Snapshot every ring, those of ended threads included, globally
+        ordered by start time.  ``tid`` is the ring's id, not the
+        thread's ident."""
         with self._lock:
             rings = [(tid, list(ring)) for tid, ring in self._rings.items()]
             names = dict(self._thread_names)
@@ -188,6 +222,7 @@ class FlightRecorder:
         with self._lock:
             for ring in self._rings.values():
                 ring.clear()
+            self._retire_locked(0)
 
     def span_count(self) -> int:
         with self._lock:
@@ -289,8 +324,8 @@ def record_span(
     record under a named row of the dump instead of this thread's."""
     if not _cfg.enabled:
         return
-    if _gc_pauses:
-        _flush_gc_pauses()
+    if _runtime_spans:
+        _flush_runtime_spans()
     if t1 < t0:
         t1 = t0
     _observe_phase(name, t1 - t0, metrics)
@@ -316,24 +351,22 @@ def record_span(
     )
 
 
-# Full collections, as the gc hook of trace/runtime.py saw them: (start,
-# end, objects collected).  The hook runs wherever the collection was
-# triggered — possibly inside a registry or recorder lock — so it only
-# appends here; the next span recorded from ordinary code (or the next
-# dump) turns them into ``runtime.gc_pause`` records.
-_gc_pauses: deque = deque(maxlen=256)
+# What trace/runtime.py saw the runtime do: (name, start, end, args) of a
+# full collection (``runtime.gc_pause``) or of a wake of the probe that
+# came late (``runtime.stall``).  The gc hook runs wherever the collection
+# was triggered — possibly inside a registry or recorder lock — so it
+# only appends here; the next span recorded from ordinary code (or the
+# next dump) turns them into records of the lane ``runtime``.
+_runtime_spans: deque = deque(maxlen=256)
 
 
-def _flush_gc_pauses() -> None:
+def _flush_runtime_spans() -> None:
     while True:
         try:
-            t0, t1, collected = _gc_pauses.popleft()
+            name, t0, t1, args = _runtime_spans.popleft()
         except IndexError:
             return
-        record_span(
-            "runtime.gc_pause", t0, t1, lane="runtime",
-            generation=2, collected=collected,
-        )
+        record_span(name, t0, t1, lane="runtime", **args)
 
 
 def event(
@@ -369,6 +402,7 @@ def span(
     trace_id: Optional[str] = None,
     metrics: Any = None,
     annotate: bool = False,
+    cpu: bool = False,
     **args: Any,
 ) -> Iterator[Optional[SpanContext]]:
     """Timed span, pushed on this thread's stack for automatic nesting.
@@ -383,6 +417,16 @@ def span(
     otherwise) shows the span on the profiler's own clock beside the
     device's lanes. For the per-launch and per-batch spans, not the
     per-eval ones.
+
+    ``cpu`` also reads this thread's CPU clock at entry and exit and
+    stores the difference as ``cpu`` (seconds) beside ``dur``: what of
+    the span the thread ran, the rest being what it waited (a lock, the
+    GIL, the device, a socket). A reading costs three times a
+    ``time.time()`` on Linux proper and a 6 us call under gVisor, whose
+    CPU clocks also tick every 10 ms (read sums and means there, not one
+    record): for the same few-hundred-a-second spans as ``annotate``; the per-eval spans' CPU is read per thread group
+    (``nomad.runtime.cpu_seconds``). ``record_span`` never carries it:
+    the interval it files is another thread's.
     """
     if not _cfg.enabled:
         yield None
@@ -405,9 +449,11 @@ def span(
     if ann is not None:
         ann.__enter__()
     t0 = time.time()
+    c0 = time.thread_time() if cpu else 0.0
     try:
         yield my
     finally:
+        c1 = time.thread_time() if cpu else 0.0
         t1 = time.time()
         if ann is not None:
             ann.__exit__(None, None, None)
@@ -418,20 +464,21 @@ def span(
             st.pop()
         _observe_phase(name, t1 - t0, metrics)
         if my.sampled:
-            _recorder.record(
-                {
-                    "name": name,
-                    "ph": "X",
-                    "ts": t0,
-                    "dur": t1 - t0,
-                    "trace": my.trace_id,
-                    "span": my.span_id,
-                    "parent": parent_id,
-                    "args": args or {},
-                }
-            )
-        if _gc_pauses:
-            _flush_gc_pauses()
+            rec = {
+                "name": name,
+                "ph": "X",
+                "ts": t0,
+                "dur": t1 - t0,
+                "trace": my.trace_id,
+                "span": my.span_id,
+                "parent": parent_id,
+                "args": args or {},
+            }
+            if cpu:
+                rec["cpu"] = c1 - c0
+            _recorder.record(rec)
+        if _runtime_spans:
+            _flush_runtime_spans()
 
 
 _TraceAnnotation = None  # jax.profiler's, imported on first use
@@ -451,8 +498,8 @@ def _annotation(name: str, args: Dict[str, Any]) -> Any:
 
 
 def dump(limit: Optional[int] = None) -> List[Dict[str, Any]]:
-    if _gc_pauses and _cfg.enabled:
-        _flush_gc_pauses()
+    if _runtime_spans and _cfg.enabled:
+        _flush_runtime_spans()
     return _recorder.records(limit=limit)
 
 

@@ -60,6 +60,8 @@ def chrome_trace(
         args["trace"] = r.get("trace", "")
         args["span"] = r.get("span", 0)
         args["parent"] = r.get("parent", 0)
+        if "cpu" in r:
+            args["cpu"] = r["cpu"]  # seconds this thread ran, of ``dur``
         ev: Dict[str, Any] = {
             "name": r["name"],
             "cat": "nomad",

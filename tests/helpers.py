@@ -318,3 +318,17 @@ def backend_compiles() -> int:
         _COMPILES.append(0)
         jax.monitoring.register_event_duration_secs_listener(on)
     return _COMPILES[0]
+
+
+def hold_gil(seconds: float) -> float:
+    """Hold the interpreter for about ``seconds`` inside ONE C call
+    (``sum`` over a ``range`` never reaches a bytecode boundary, so no
+    other thread gets the GIL; ``time.sleep`` would release it).  Returns
+    the seconds the call took."""
+    n = 2_000_000
+    t0 = time.perf_counter()
+    sum(range(n))
+    per_item = (time.perf_counter() - t0) / n
+    t0 = time.perf_counter()
+    sum(range(int(seconds / per_item)))
+    return time.perf_counter() - t0

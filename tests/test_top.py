@@ -77,6 +77,31 @@ class TestRender:
         # A single-shard (or unsharded) matrix renders no shard row.
         assert "shards  :" not in render(_metrics(), None, None)
 
+    def test_runtime_row_reads_work_and_waiting_between_snapshots(self):
+        def snap(worker, api, native, wakes, late, stall):
+            g = "nomad.runtime.cpu_seconds{group=%s}"
+            return dict(_metrics(), **{
+                g % "worker": worker, g % "http-api": api,
+                g % "native": native,
+                g % "process": worker + api + native,
+                "nomad.runtime.wakes_total": wakes,
+                "nomad.runtime.wake_late_seconds_total": late,
+                "nomad.runtime.stall_seconds_total": stall,
+            })
+
+        prev = snap(10.0, 2.0, 5.0, 1000, 1.0, 0.0)
+        cur = snap(11.2, 2.6, 5.5, 1200, 2.0, 0.4)
+        out = render(cur, None, None, prev_metrics=prev, interval=2.0)
+        (row,) = [ln for ln in out.splitlines() if ln.startswith("runtime :")]
+        assert "interpreter busy  90.0%" in row      # 1.8 s of 2 s
+        assert "native  25.0%" in row
+        assert "wake late 5.00 ms" in row            # 1 s over 200 wakes
+        assert "stalls 0.4s" in row
+        # No row on the first frame, or from a server without the gauges.
+        assert "runtime :" not in render(cur, None, None)
+        assert "runtime :" not in render(
+            _metrics(), None, None, prev_metrics=_metrics(), interval=2.0)
+
     def test_rates_are_deltas_between_snapshots(self):
         prev = _metrics(evals=100)
         cur = _metrics(evals=300)

@@ -170,6 +170,57 @@ class TestRingBounding:
         assert "ev-199" in names
         assert "ev-0" not in names
 
+    def test_ended_threads_keep_their_records(self):
+        """64 short threads in turn, each of which ends before the next
+        starts (so the next reuses its ident): every span is in the dump.
+        A ring filed under ``get_ident()`` lost all but the last."""
+        def one(i):
+            with trace.span("short.thread", trace_id=f"ev-{i}"):
+                pass
+
+        for i in range(64):
+            t = threading.Thread(target=one, args=(i,), name=f"short-{i}")
+            t.start()
+            t.join()
+        recs = [r for r in trace.dump() if r["name"] == "short.thread"]
+        assert sorted(r["trace"] for r in recs) == sorted(
+            f"ev-{i}" for i in range(64))
+        assert {r["thread"] for r in recs} == {f"short-{i}" for i in range(64)}
+        # One row of the dump per thread, not per ident.
+        assert len({r["tid"] for r in recs}) == 64
+
+    def test_retired_rings_are_bounded_oldest_first(self, monkeypatch):
+        from nomad_tpu.trace import core
+
+        monkeypatch.setattr(core, "MAX_RETIRED_RINGS", 8)
+
+        def one(i):
+            with trace.span("short.thread", trace_id=f"ev-{i}"):
+                pass
+
+        for i in range(20):
+            t = threading.Thread(target=one, args=(i,))
+            t.start()
+            t.join()
+        kept = {r["trace"] for r in trace.dump()
+                if r["name"] == "short.thread"}
+        # The bound is applied as a ring is made: the newest thread's ring
+        # and the eight newest ended ones.
+        assert kept == {f"ev-{i}" for i in range(11, 20)}
+        # A clear drops the rings nobody will write to again.
+        trace.clear()
+        assert len(trace.recorder()._rings) <= 1 + len(
+            trace.recorder()._lanes) + threading.active_count()
+
+    def test_ring_resize_keeps_the_thread_its_row(self):
+        with trace.span("before", trace_id="ev-a"):
+            pass
+        trace.configure(ring=64)
+        with trace.span("after", trace_id="ev-b"):
+            pass
+        recs = {r["name"]: r for r in trace.dump()}
+        assert recs["before"]["tid"] == recs["after"]["tid"]
+
     def test_limit_returns_most_recent(self):
         for i in range(10):
             with trace.span("s", trace_id=f"ev-{i}"):

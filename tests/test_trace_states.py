@@ -10,7 +10,8 @@
 * ``nomad.plan.result`` telling a committed, a partial and an entirely
   rejected plan apart;
 * the runtime hooks (full collections as ``runtime.gc_pause``): on while a
-  server runs, gone after the last one stops.
+  server runs, gone after the last one stops (the probe and
+  ``runtime.stall``: tests/test_trace_work_or_waiting.py).
 """
 
 from __future__ import annotations
@@ -26,6 +27,7 @@ import urllib.request
 
 import pytest
 
+from helpers import hold_gil
 from nomad_tpu import mock, trace
 from nomad_tpu.server import Server, ServerConfig
 from nomad_tpu.structs.types import (
@@ -67,8 +69,8 @@ def clean_trace():
 def emitted(tmp_path_factory):
     """Span names of one live run: a real-jit agent on the CPU (the fake
     device neither lingers nor traces a Features variant), two jobs over
-    HTTP, a device op, a forced collection, a fresh compile, a flight
-    dump."""
+    HTTP, a device op, a forced collection, the interpreter held by one C
+    call, a fresh compile, a flight dump."""
     import jax
 
     from nomad_tpu.api.agent import Agent, AgentConfig
@@ -113,6 +115,8 @@ def emitted(tmp_path_factory):
         assert srv.wait_for_eval(srv.submit_job(big).id, timeout=300.0)
         srv.coalescer.sync_arrays()                        # a device op
         gc.collect()                                       # a full collection
+        hold_gil(0.4)                    # the probe wakes late: runtime.stall
+        time.sleep(0.05)
         salt = float(time.time_ns() % 1000003)             # never cached
         jax.jit(lambda x: x * salt + 1.0)(1.0).block_until_ready()
         trace.dump_flight_record(
@@ -357,13 +361,23 @@ def _pauses():
     return [r for r in trace.dump() if r["name"] == "runtime.gc_pause"]
 
 
+@pytest.fixture()
+def only_forced_collections():
+    """No automatic collection while the test counts pauses: a server's
+    start allocates enough to trigger a full one of its own."""
+    gc.collect()
+    gc.disable()
+    yield
+    gc.enable()
+
+
 def _server():
     return Server(ServerConfig(num_workers=1, heartbeat_min_ttl=3600.0,
                                heartbeat_max_ttl=7200.0, slo_enabled=False))
 
 
-def test_full_collection_is_a_span_while_a_server_runs(monkeypatch,
-                                                       clean_trace):
+def test_full_collection_is_a_span_while_a_server_runs(
+        monkeypatch, clean_trace, only_forced_collections):
     from nomad_tpu.trace import runtime
 
     monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE", "1")
@@ -388,8 +402,8 @@ def test_full_collection_is_a_span_while_a_server_runs(monkeypatch,
     assert len(_pauses()) == 1  # none after shutdown
 
 
-def test_runtime_hooks_last_until_the_last_server_stops(monkeypatch,
-                                                        clean_trace):
+def test_runtime_hooks_last_until_the_last_server_stops(
+        monkeypatch, clean_trace, only_forced_collections):
     from nomad_tpu.trace import runtime
 
     monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE", "1")

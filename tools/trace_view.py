@@ -9,7 +9,8 @@ Usage:
     python tools/trace_view.py trace.json --phase plan.apply --slowest 10
 
 Per-phase table: span count, total/mean/max duration, share of the
-summed root-span time.  With ``--trace ID`` prints that eval's span
+summed root-span time, and the CPU the spans' own threads used where the
+program recorded it (``trace.span(cpu=True)``: the rest is waiting).  With ``--trace ID`` prints that eval's span
 tree with per-span durations instead.  ``--phase NAME`` narrows any
 view to spans whose phase name contains NAME (so ``--phase plan``
 matches plan.queue_wait + plan.apply); ``--slowest N`` lists the N
@@ -44,12 +45,16 @@ def load_events(path: str) -> List[Dict[str, Any]]:
 
 def summarize(events: List[Dict[str, Any]]) -> None:
     by_name: Dict[str, List[float]] = defaultdict(list)
+    cpu_by_name: Dict[str, float] = {}
     roots = 0.0
     for e in events:
         dur_ms = e.get("dur", 0) / 1000.0
         by_name[e["name"]].append(dur_ms)
         if not e.get("args", {}).get("parent"):
             roots += dur_ms
+        cpu = e.get("args", {}).get("cpu")  # seconds; spans made with cpu=True
+        if cpu is not None:
+            cpu_by_name[e["name"]] = cpu_by_name.get(e["name"], 0.0) + cpu * 1e3
     if not by_name:
         print("no complete spans in file")
         return
@@ -58,16 +63,19 @@ def summarize(events: List[Dict[str, Any]]) -> None:
         total = sum(durs)
         rows.append((
             name, len(durs), total, total / len(durs), max(durs),
-            100.0 * total / roots if roots else 0.0,
+            100.0 * total / roots if roots else 0.0, cpu_by_name.get(name),
         ))
     rows.sort(key=lambda r: -r[2])
+    # ``cpu ms``: what of ``total ms`` the spans' own threads ran; the rest
+    # they waited (a lock, the GIL, the device).  "-" where not recorded.
     hdr = f"{'phase':<28}{'count':>7}{'total ms':>11}{'mean ms':>10}" \
-          f"{'max ms':>10}{'% root':>8}"
+          f"{'max ms':>10}{'% root':>8}{'cpu ms':>11}"
     print(hdr)
     print("-" * len(hdr))
-    for name, n, total, mean, mx, pct in rows:
+    for name, n, total, mean, mx, pct, cpu in rows:
+        cpu_col = "-" if cpu is None else f"{cpu:.2f}"
         print(f"{name:<28}{n:>7}{total:>11.2f}{mean:>10.3f}"
-              f"{mx:>10.3f}{pct:>8.1f}")
+              f"{mx:>10.3f}{pct:>8.1f}{cpu_col:>11}")
     print(f"\n{len(events)} spans; summed root-span time {roots:.2f} ms")
     print("full timeline: load this file in https://ui.perfetto.dev")
 
@@ -113,8 +121,10 @@ def show_trace(events: List[Dict[str, Any]], trace_id: str) -> None:
         for e in by_parent.get(parent, ()):
             off = (e["ts"] - t0) / 1000.0
             dur = e.get("dur", 0) / 1000.0
+            cpu = e["args"].get("cpu")
             print(f"{'  ' * depth}{e['name']:<{30 - 2 * depth}}"
-                  f" +{off:8.3f} ms  {dur:8.3f} ms")
+                  f" +{off:8.3f} ms  {dur:8.3f} ms"
+                  + ("" if cpu is None else f"  cpu {cpu * 1e3:8.3f} ms"))
             walk(e["args"].get("span"), depth + 1)
 
     print(f"trace {trace_id} ({len(mine)} spans)")
