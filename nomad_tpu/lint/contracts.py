@@ -76,10 +76,17 @@ class DeviceContract:
     # J102: device→host bytes per launch; None = outputs are
     # device-resident by design (budget and node-independence both skipped).
     out_budget: Optional[Callable[[Grid], int]] = None
+    # J102: bytes of the outputs that stay on the device by design beside
+    # a fetched one (the placement programs' carry of claims blocks: the
+    # next launch's operand).  Left out of the budget, not out of the
+    # node-count independence: no output may be node-axis shaped.
+    resident_out: Optional[Callable[[Grid], int]] = None
     # J104: positional argnums declared donated. Checked BOTH ways — a
     # declared-donated operand lowered undonated fires, and so does an
     # undeclared donation.
     donated_args: Tuple[int, ...] = ()
+    # J104: keyword operands declared donated, checked both ways too.
+    donated_kwargs: Tuple[str, ...] = ()
     # J103: entry is ALLOWED to emit node-axis-shaped outputs across the
     # mesh boundary (the scatter returns the resident matrix itself).
     node_axis_outputs_ok: bool = False
@@ -88,9 +95,10 @@ class DeviceContract:
     # count ever collides with it.
     boundary_exempt_shapes: Tuple[Tuple[int, ...], ...] = ()
     # J104: require an explicit input_output_alias in the compiled HLO.
-    # Off for the current entries: the fused kernel's donated
-    # lane operands are scratch-reusable but never output-ALIASED,
-    # because no donated aval matches the packed (B, P, 8) output.
+    # The fused kernel's donated lane operands are scratch-reusable but
+    # never output-ALIASED (no donated aval matches the packed (B, P, 8)
+    # output); the live entry's carry is: the carry a launch hands on
+    # takes the buffer of the carry it was handed.
     expect_alias: bool = False
     # J104/J105 run at this (small) grid; None skips both.
     compile_grid: Optional[Grid] = None
@@ -264,14 +272,16 @@ def lane_steps_sweep(entry: Callable[..., Any], c: DeviceContract) -> int:
 
 
 def pow2_rows_sweep(entry: Callable[..., Any], c: DeviceContract) -> int:
-    """Scatter sweep: dirty-row counts 1..batch, pow2-padded the way
+    """Scatter sweep: dirty-row counts 1..batch, padded as
     ``NodeMatrix._sync_locked`` pads them, so the distinct idx shapes —
     and therefore compiles — stay logarithmic in the row count."""
+    from ..state.matrix import scatter_bucket
+
     g = c.compile_grid
     assert g is not None
     return _compiles_over(
         entry, c,
-        (g._replace(deltas=1 << (k - 1).bit_length())
+        (g._replace(deltas=scatter_bucket(k))
          for k in range(1, g.batch + 1)),
     )
 
@@ -326,9 +336,24 @@ def table() -> Tuple[DeviceContract, ...]:
             np.zeros((g.batch, 2, 3), np.float32),
         )
 
+    def chain(g: Grid) -> Tuple[Any, Any, Any]:
+        # The claims chained on the device as the coalescer hands them
+        # over: the carry of the launch before (blocks of padding), the
+        # flags and what each lane's plan advertises on its delta rows.
+        from ..scheduler.claims import CHAIN_DEPTH, empty_carry
+
+        return (
+            empty_carry(g.batch, g.deltas + g.placements),
+            np.zeros((g.batch, 1 - (-CHAIN_DEPTH // g.batch)), bool),
+            np.zeros((g.batch, g.deltas, 3), np.float32),
+        )
+
+    def carry_bytes(g: Grid) -> int:
+        return int(chain(g)[0].nbytes)
+
     fused_kwargs = lambda g: {
         "n_placements": g.placements, "features": g.features,
-        "overlay": overlay(g),
+        "overlay": overlay(g), "chain": chain(g),
     }
     trace_grids = _fused_trace_grids()
     compile_grid = _fused_compile_grid()
@@ -350,6 +375,7 @@ def table() -> Tuple[DeviceContract, ...]:
             static_kwargs=fused_kwargs,
             trace_grids=trace_grids,
             out_budget=_fused_budget,
+            resident_out=carry_bytes,
             donated_args=(),  # the un-donated entry: tests/tools reuse inputs
             compile_grid=compile_grid,
         ),
@@ -361,7 +387,11 @@ def table() -> Tuple[DeviceContract, ...]:
             static_kwargs=fused_kwargs,
             trace_grids=trace_grids,
             out_budget=_fused_budget,
+            resident_out=carry_bytes,
             donated_args=tuple(range(2, 11)),  # per-dispatch lane operands
+            # ... the overlay, and the chain: a carry has one reader
+            donated_kwargs=("overlay", "chain"),
+            expect_alias=True,  # carry in -> carry out, in place
             compile_grid=compile_grid,
             sweep=lane_steps_sweep,
             # occupancy and step counts are runtime data: ONE compile
@@ -387,10 +417,14 @@ def table() -> Tuple[DeviceContract, ...]:
             operands=fused_operands,
             static_kwargs=lambda g: {
                 "features": g.features, "overlay": overlay(g),
+                "chain": chain(g),
             },
             trace_grids=trace_grids,
             out_budget=_fused_budget,
-            donated_args=(),  # matrix stays shared with in-flight dispatches
+            resident_out=carry_bytes,  # split over 'batch', never fetched
+            # matrix stays shared with in-flight dispatches; the carry is a
+            # few hundred KB a device, not worth a donation of its own
+            donated_args=(),
         ),
         DeviceContract(
             name="make_row_scatter",
@@ -404,7 +438,7 @@ def table() -> Tuple[DeviceContract, ...]:
             donated_args=(),  # in-flight dispatches still read the old snapshot
             compile_grid=scatter_grid._replace(nodes=32),
             sweep=pow2_rows_sweep,
-            max_compiles=3,  # pow2 buckets of 1..4 dirty rows: {1, 2, 4}
+            max_compiles=2,  # pow2 buckets of 1..4 dirty rows: {2, 4}
         ),
     )
 

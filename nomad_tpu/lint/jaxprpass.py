@@ -238,7 +238,28 @@ def _check_donation(c: Any, emit: Callable[[str, str], None]) -> None:
                 "— in-flight dispatches sharing that buffer would read "
                 "freed memory",
             )
-    if not declared:
+    kw_info = lowered.args_info[1] if (
+        isinstance(lowered.args_info, tuple) and len(lowered.args_info) == 2
+        and isinstance(lowered.args_info[1], dict)
+    ) else {}
+    for name, info in sorted(kw_info.items()):
+        donated = [
+            bool(getattr(leaf, "donated", False))
+            for leaf in jax.tree_util.tree_leaves(info)
+        ]
+        if name in c.donated_kwargs and not all(donated):
+            emit(
+                "J104",
+                f"keyword operand '{name}' is declared donated but lowered "
+                f"with {donated.count(False)}/{len(donated)} leaves undonated",
+            )
+        if name not in c.donated_kwargs and any(donated):
+            emit(
+                "J104",
+                f"keyword operand '{name}' is donated but not declared in "
+                "the contract",
+            )
+    if not declared and not c.donated_kwargs:
         return
     with warnings.catch_warnings(record=True):
         warnings.simplefilter("always")
@@ -278,10 +299,11 @@ def check_contract(c: Any, root: Optional[str] = None) -> List[Finding]:
             if c.out_budget is None:
                 continue
             budget = int(c.out_budget(g))
-            if out_bytes > budget:
+            resident = int(c.resident_out(g)) if c.resident_out else 0
+            if out_bytes - resident > budget:
                 emit(
                     "J102",
-                    f"launch returns {out_bytes} B to the host at grid "
+                    f"launch returns {out_bytes - resident} B to the host at grid "
                     f"{g!r}, over the declared budget of {budget} B",
                 )
             # Node-count independence: same grid modulo N must cost the

@@ -730,7 +730,7 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
                       lane_mask,
                       n_placements: int,
                       live_counts: Optional[List[int]] = None,
-                      overlay=None) -> np.ndarray:
+                      overlay=None, chain=None):
     """Twin of kernels.fused_place_batch — (B, P, FUSED_PACKED_WIDTH) f32.
 
     The lanes' scans run in lockstep.  Within a step the live lanes take
@@ -757,6 +757,13 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
     in-flight claims of the launches before this one
     (``kernels.overlay_usage``), added to the usage under the claims image
     and the verify pass and to no score; None = empty.
+
+    ``chain`` = (carry (D, Bc, K + P, 4) f32 with Bc >= B, live (D,) bool,
+    claim_vals [(K, 3)] a lane, holds [bool] a lane): the claims chained
+    from launch to launch (``kernels.claims_block`` / ``chained_carry``).
+    The live blocks' rows are added where the overlay's are, and the twin
+    returns (packed, the carry for the next launch: this launch's block,
+    then the carried ones shifted by one); None = the packed result alone.
     """
     b = len(reqs)
     lane_mask = np.asarray(lane_mask, bool)
@@ -778,6 +785,13 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
         # is dropped.
         inside = (orows >= 0) & (orows < claims.shape[0])
         np.add.at(claims, orows[inside], ovals[inside])
+    if chain is not None:
+        carry, live_blocks, claim_vals, holds = chain
+        carry = np.asarray(carry, np.float32)
+        blocks = carry[np.asarray(live_blocks, bool)[: len(carry)]]
+        crows = blocks[..., 0].astype(np.int64).reshape(-1)
+        inside = (crows >= 0) & (crows < claims.shape[0])
+        np.add.at(claims, crows[inside], blocks[..., 1:].reshape(-1, 3)[inside])
     cum_used = claims.copy()  # the verify pass starts from the same image
     scans = {}
     for i in live:
@@ -833,7 +847,24 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
             out[i, p, FUSED_PACKED_VERIFIED] = (
                 (2.0 if repicked[i, p] else 1.0) if fits else 0.0
             )
-    return out
+    if chain is None:
+        return out
+    own = np.zeros(carry.shape[1:], np.float32)
+    own[..., 0] = -1.0
+    for i in live:
+        if not holds[i]:
+            continue
+        drows = np.asarray(delta_rows[i])
+        held = np.flatnonzero(drows >= 0)
+        own[i, held, 0] = drows[held]
+        own[i, held, 1:] = np.asarray(claim_vals[i], np.float32)[held]
+        # The picks up to and including the first preempting one.
+        pre = np.flatnonzero(out[i, :, 3] != 0.0)
+        cut = pre[0] + 1 if len(pre) else n_placements
+        picks = np.flatnonzero(out[i, :cut, 0] >= 0)
+        own[i, len(drows) + picks, 0] = out[i, picks, 0]
+        own[i, len(drows) + picks, 1:] = scans[i].sp.ask
+    return out, np.concatenate([own[None], carry[:-1]])
 
 
 def sharded_fused_place_batch(arrays, used, delta_rows, delta_vals,
@@ -841,7 +872,7 @@ def sharded_fused_place_batch(arrays, used, delta_rows, delta_vals,
                               class_eligs, host_masks, lane_mask,
                               n_shards: int, n_placements: int,
                               live_counts: Optional[List[int]] = None,
-                              overlay=None) -> np.ndarray:
+                              overlay=None, chain=None):
     """Twin of parallel.sharding.sharded_fused_place_batch for host-only CI.
 
     The sharded kernel's hierarchical top-k election (per-shard stable
@@ -861,6 +892,7 @@ def sharded_fused_place_batch(arrays, used, delta_rows, delta_vals,
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
         penalties, reqs, class_eligs, host_masks, lane_mask,
         n_placements=n_placements, live_counts=live_counts, overlay=overlay,
+        chain=chain,
     )
 
 

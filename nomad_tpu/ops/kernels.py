@@ -891,21 +891,95 @@ def fused_trip_counts(lane_steps, n_placements: int):
     return trip, last_lane
 
 
-def overlay_usage(used, overlay):
-    """``used`` plus the in-flight claims overlay: the usage of plans the
-    launches before this one picked and the applier has not decided yet
-    (``overlay`` = (rows (..., K) i32 with -1 padding, vals (..., K, 3)
-    f32), the coalescer's ``ClaimsLedger``; None or all padding = ``used``
-    bit for bit).  It stands under the claims image and the verify pass
-    and under nothing else: no score ever reads it."""
-    if overlay is None:
+def add_claims(used, rows, vals):
+    """``used`` with ``vals`` (..., 3) added on ``rows`` (...,); the caller
+    has zeroed the vals of what does not count (padding rows read 0)."""
+    return used.at[jnp.maximum(rows, 0).reshape(-1)].add(vals.reshape(-1, 3))
+
+
+def overlay_usage(used, overlay, chain=None):
+    """``used`` plus the in-flight claims: the usage of plans the launches
+    before this one picked and the applier has not decided yet.  Two
+    sources, each pick in exactly one of them (``scheduler/claims.py``):
+    ``overlay`` = (rows (..., K) i32 with -1 padding, vals (..., K, 3)
+    f32), the coalescer's ``ClaimsLedger`` (launches whose result is on the
+    host), and ``chain`` = (carry, flags, ...), the blocks the launches
+    before this one wrote on the device (``claims_block``), of which the
+    live ones are the launches whose result is NOT on the host yet.  None
+    or all padding / no live block = ``used`` bit for bit.  It stands under
+    the claims image and the verify pass and under nothing else: no score
+    ever reads it."""
+    if overlay is None and chain is None:
         return used
-    rows, vals = overlay
     with jax.named_scope("overlay"):
-        add = jnp.where((rows >= 0)[..., None], vals, 0.0)
-        return used.at[jnp.maximum(rows, 0).reshape(-1)].add(
-            add.reshape(-1, 3)
-        )
+        if overlay is not None:
+            rows, vals = overlay
+            used = add_claims(
+                used, rows, jnp.where((rows >= 0)[..., None], vals, 0.0)
+            )
+        if chain is not None:
+            carry, flags = chain[:2]
+            used = add_claims(used, *carried_claims(
+                carry, chain_flags(flags, carry.shape[0])[1]
+            ))
+        return used
+
+
+def chain_flags(flags, depth: int):
+    """The chain's flags as they ride the packed lane buffer ((B, 1 + w)
+    bool): column 0 says whether the lane holds claims (an eval stands
+    behind it: ``_Pending.eval_id``), the rest, read as one flat list, whether
+    carried block d is live (its launch's result is not on the host).
+    Returns (holds (B,), live (depth,))."""
+    return flags[:, 0], flags[:, 1:].reshape(-1)[:depth]
+
+
+def carried_claims(carry, live):
+    """The carried blocks ((D, B, R, 4) f32: row, cpu, mem, disk; row -1 =
+    padding) as (rows (D, B, R) i32, vals (D, B, R, 3)) with everything but
+    the ``live`` (D,) blocks' rows zeroed."""
+    rows = carry[..., 0].astype(jnp.int32)
+    valid = (rows >= 0) & live[:, None, None]
+    return rows, jnp.where(valid[..., None], carry[..., 1:], 0.0)
+
+
+@jax.named_scope("chain")
+def claims_block(delta_rows, claim_vals, rows, preempted, ask, holds):
+    """The claims block a launch writes for the launches after it: for
+    every lane that ``holds`` claims (live, with an eval behind it) what
+    the resolver will enter into the ledger when the result reaches the
+    host (``coalescer._lane_claims``) — the rows its plan held before the
+    launch with what the plan advertises on them (``claim_vals``: no
+    eviction credited, nothing below zero), then its picks up to and
+    including the first preempting one (the host drops the rows after it
+    and re-enters), each with the lane's ask.  (B, K + P, 4) f32: row,
+    cpu, mem, disk; row -1 = padding (a row id is exact in float32 below
+    2**24, as in the packed output's ROW column).  Independent of the node
+    count, and never fetched: the next launch reads it on the device."""
+    held = (delta_rows >= 0) & holds[:, None]  # (B, K)
+    pre = (preempted != 0).astype(jnp.int32)
+    first = jnp.cumsum(pre, axis=1) - pre == 0  # no preempting pick before
+    picked = (rows >= 0) & holds[:, None] & first  # (B, P)
+    b_rows = jnp.concatenate(
+        [jnp.where(held, delta_rows, -1), jnp.where(picked, rows, -1)], axis=1
+    )
+    b_vals = jnp.concatenate(
+        [
+            jnp.where(held[..., None], claim_vals, 0.0),
+            jnp.where(picked[..., None], ask[:, None, :], 0.0),
+        ],
+        axis=1,
+    )
+    return jnp.concatenate(
+        [b_rows.astype(jnp.float32)[..., None], b_vals], axis=2
+    )
+
+
+def chained_carry(own, carry):
+    """What a launch hands the next one: its own block first, the blocks
+    it was handed shifted by one (the oldest falls off): one buffer in, one
+    buffer out, however deep the chain."""
+    return jnp.concatenate([own[None], carry[:-1]], axis=0)
 
 
 def claims_image(used, delta_rows, delta_vals, live):
@@ -915,9 +989,8 @@ def claims_image(used, delta_rows, delta_vals, live):
     pick's ask to it as the lanes take their turns).  With one live lane
     and an empty overlay it is, bit for bit, that lane's own ``used0``."""
     valid = (delta_rows >= 0) & live[:, None]  # (B, K)
-    add = jnp.where(valid[:, :, None], delta_vals, 0.0)
-    return used.at[jnp.maximum(delta_rows, 0).reshape(-1)].add(
-        add.reshape(-1, 3)
+    return add_claims(
+        used, delta_rows, jnp.where(valid[:, :, None], delta_vals, 0.0)
     )
 
 
@@ -999,7 +1072,8 @@ def _fused_place_batch_impl(
     n_placements: int,
     features: Features = FULL_FEATURES,
     overlay=None,
-) -> jnp.ndarray:
+    chain=None,
+):
     """The mega-batched ranking megakernel: B eval pipelines — feasibility →
     binpack → spread/affinity → preemption evict-state → placement scan —
     with the lanes' picks resolved in lane order inside every placement
@@ -1076,14 +1150,30 @@ def _fused_place_batch_impl(
       and a departure from the reference, whose workers never see each
       other's plans.  With an empty overlay the output is bit for bit
       what it is without one.
+    * **Claims chained on the device** (PR 41; ``chain`` = (carry (D, B,
+      K + P, 4) f32, flags (B, 1 + w) bool, claim_vals (B, K, 3) f32); None
+      = the program without it).  The ledger knows a launch's picks only
+      once its result is on the host; a launch that leaves before that
+      found them nowhere.  So every launch writes its own claims block
+      (``claims_block``: lane for lane what the resolver will enter) as a
+      second, device-resident output, and takes the blocks of the D
+      launches before it (``carry``) with one flag a block (``chain_flags``):
+      live = that launch's result is not on the host yet, decided by the
+      host in one step with its read of the ledger, so a pick is in the
+      overlay or in a live block and never in both.  Live blocks enter
+      exactly where the overlay enters (``overlay_usage``) and nowhere
+      else.  The second output is ``chained_carry``: this launch's block,
+      then the carried ones shifted by one.  With no live block the packed
+      output is bit for bit what it is without the operand.
 
-    Returns (B, n_placements, FUSED_PACKED_WIDTH) f32 — one fetch.
+    Returns (B, n_placements, FUSED_PACKED_WIDTH) f32 — one fetch; with a
+    ``chain``, that and the carry for the next launch (never fetched).
     """
     live = lane_steps > 0  # (B,)
     trip, last_lane = fused_trip_counts(lane_steps, n_placements)
     lanes = lane_steps.shape[0]
     # Shared usage as the claims and the verify see it; the scores do not.
-    claimed = overlay_usage(used, overlay)
+    claimed = overlay_usage(used, overlay, chain)
 
     def lane_used0(drows, dvals):
         add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
@@ -1190,10 +1280,18 @@ def _fused_place_batch_impl(
             0, last_lane, lane_step, (claimed, jnp.ones(rows.shape, bool))
         )  # (B, P) bool
 
-    return pack_fused_lanes(
+    packed = pack_fused_lanes(
         rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
         repicked, live,
     )
+    if chain is None:
+        return packed
+    carry, flags, claim_vals = chain
+    own = claims_block(
+        delta_rows, claim_vals, rows, preempted, reqs.ask,
+        live & chain_flags(flags, carry.shape[0])[0],
+    )
+    return packed, chained_carry(own, carry)
 
 
 fused_place_batch = functools.partial(
@@ -1201,9 +1299,10 @@ fused_place_batch = functools.partial(
 )(_fused_place_batch_impl)
 
 # Live entry: per-dispatch lane operands (argnums 2..10, including the lane
-# step counts, and the claims overlay) are DONATED, so XLA reuses their
-# freshly-transferred device buffers as scratch instead of holding them live
-# alongside the outputs.
+# step counts, the claims overlay and the chain) are DONATED, so XLA reuses
+# their freshly-transferred device buffers as scratch instead of holding them
+# live alongside the outputs; the carry a launch is handed is consumed by
+# that launch alone, and the carry it hands on takes its buffer.
 # ``arrays``/``used`` stay shared with in-flight pipelined dispatches and
 # are never donated.  Kept apart from ``fused_place_batch`` because callers
 # of the un-donated entry (tests, the smoke) reuse their inputs across calls.
@@ -1211,7 +1310,7 @@ fused_place_batch_live = functools.partial(
     jax.jit,
     static_argnames=("n_placements", "features"),
     donate_argnums=tuple(range(2, 11)),
-    donate_argnames=("overlay",),
+    donate_argnames=("overlay", "chain"),
 )(_fused_place_batch_impl)
 
 

@@ -40,6 +40,10 @@ from ..ops.kernels import (
     FULL_FEATURES,
     NEG_INF,
     apply_spread_values,
+    carried_claims,
+    chain_flags,
+    chained_carry,
+    claims_block,
     fused_trip_counts,
     inert_lane_outputs,
     pack_fused_lanes,
@@ -219,7 +223,7 @@ def make_sharded_row_scatter(mesh: Mesh):
 
 def _fused_place_batch_local(
     arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-    penalties, reqs, class_eligs, host_masks, lane_steps, overlay,
+    penalties, reqs, class_eligs, host_masks, lane_steps, overlay, chain,
     n_placements, features,
 ):
     """Per-shard body of ``kernels.fused_place_batch`` under a
@@ -260,6 +264,16 @@ def _fused_place_batch_local(
     claims image and the verify pass, as ``kernels.overlay_usage`` does on
     one device: no score reads it.
 
+    The claims chained on the device (``chain`` = (carry, flags,
+    claim_vals), ``kernels._fused_place_batch_impl``; None = the program
+    without it) are split over 'batch' like the lanes they are of: the
+    carried blocks are gathered with the overlay and each node shard adds
+    the live ones' rows it holds, exactly where the overlay enters; every
+    batch shard writes its own lanes' part of this launch's block
+    (``claims_block``: the winners are on every shard), so the carry
+    leaves as it came, one buffer split over 'batch', and no collective is
+    spent on it.
+
     Both loops run as many iterations as the launch's live lanes asked for
     (``lane_steps``, as in the single-device kernel).  Every step holds
     collectives and the walk and the verify visit all B lanes on every
@@ -285,6 +299,10 @@ def _fused_place_batch_local(
             g_orows, g_ovals = (
                 jax.lax.all_gather(o, "batch", tiled=True) for o in overlay
             )
+        if chain is not None:
+            carry, flags, claim_vals = chain
+            g_carry = jax.lax.all_gather(carry, "batch", axis=1, tiled=True)
+            g_flags = jax.lax.all_gather(flags, "batch", tiled=True)
     g_live = g_steps > 0  # (B,)
     lanes = g_steps.shape[0]
     trip, last_lane = fused_trip_counts(g_steps, n_placements)
@@ -447,9 +465,14 @@ def _fused_place_batch_local(
     )
     # Shared usage as the claims and the verify see it; the scores do not.
     claimed = vary(used)
-    if overlay is not None:
-        with jax.named_scope("overlay"):
+    with jax.named_scope("overlay"):
+        if overlay is not None:
             claimed = add_deltas(claimed, g_orows, g_ovals, g_orows >= 0)
+        if chain is not None:
+            c_rows, c_vals = carried_claims(
+                g_carry, chain_flags(g_flags, carry.shape[0])[1]
+            )
+            claimed = add_deltas(claimed, c_rows, c_vals, c_rows >= 0)
     claims0 = add_deltas(claimed, g_drows, g_dvals, g_live[:, None])
     bufs = tuple(
         vary(o)
@@ -498,9 +521,16 @@ def _fused_place_batch_local(
     v_local = jax.lax.dynamic_slice_in_dim(
         verified, b_first, b_local, axis=0
     )  # (b_local, P)
-    return pack_fused_lanes(
+    packed = pack_fused_lanes(
         rows, scores, binpack, pre, ne, nf, nx, v_local, repicked, live
     )
+    if chain is None:
+        return packed
+    own = claims_block(
+        delta_rows, claim_vals, rows, pre, reqs.ask,
+        live & chain_flags(flags, carry.shape[0])[0],
+    )
+    return packed, chained_carry(own, carry)
 
 
 def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
@@ -517,8 +547,9 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
     def entry(
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
         penalties, reqs, class_eligs, host_masks, lane_steps, *,
-        features=FULL_FEATURES, overlay=None,
+        features=FULL_FEATURES, overlay=None, chain=None,
     ):
+        lanes = P("batch", None, None)
         fn = shard_map(
             functools.partial(
                 _fused_place_batch_local,
@@ -539,17 +570,33 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
                 P("batch", "node"),  # host_masks
                 P("batch"),  # lane_steps
                 # overlay rows (global ids) and vals
-                None if overlay is None
-                else (P("batch", None), P("batch", None, None)),
+                None if overlay is None else (P("batch", None), lanes),
+                # the chain: carry (blocks of all lanes: split on its lane
+                # axis), flags, claim_vals
+                None if chain is None
+                else (P(None, "batch", None, None), P("batch", None), lanes),
             ),
-            out_specs=P("batch", None, None),
+            out_specs=(
+                lanes if chain is None
+                else (lanes, P(None, "batch", None, None))
+            ),
         )
         return fn(
             arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
             penalties, reqs, class_eligs, host_masks, lane_steps, overlay,
+            chain,
         )
 
     return jax.jit(entry, static_argnames=("features",))
+
+
+def shard_carry(mesh: Mesh, carry):
+    """The chain's first carry laid out as the placement program hands its
+    own on (split over 'batch' on its lane axis): a carry of another layout
+    would be another program to ``jit``, compiled at the second launch."""
+    return jax.device_put(
+        carry, NamedSharding(mesh, P(None, "batch", None, None))
+    )
 
 
 def sharded_unpack_lanes(mesh: Mesh):

@@ -58,10 +58,18 @@ they did: the picks whose plans the applier has not decided yet are kept in
 a claims ledger (``scheduler/claims.py``; the resolver enters them, the
 applier and the workers release them) and every launch takes the live ones
 as an overlay under its claims image, so its lanes pass over the nodes a
-launch before it took.  What a launch cannot see is the picks of a launch
-whose result had not reached the host when it was enqueued
-(``launches_unresolved_predecessor`` counts those); the applier's re-verify
-catches what is left.
+launch before it took.  The picks of a launch whose result had not reached
+the host when the next one was enqueued (``launches_unresolved_predecessor``
+counts those launches) are in no ledger yet: they reach the next launch on
+the device.  Every launch writes its own claims block as a second output of
+the placement program and is handed the carry of the launch before it (its
+block and the ``CHAIN_DEPTH - 1`` before: one ``jax.Array`` in, one out,
+never fetched); ``_overlay`` decides, in one step with its read of the
+ledger, which carried blocks are live (``chained_launches``,
+``chained_rows_total``).  What a launch still cannot see is an unresolved
+launch older than the carry holds (``chain_overflow``) or one that took
+another route (the numpy twin while the breaker is open, another mesh); the
+applier's re-verify catches what is left.
 """
 
 from __future__ import annotations
@@ -86,7 +94,13 @@ from ..obs.breaker import (
 from ..ops import kernels
 from ..ops.encode import RequestSlab, SchedRequest, packed_rows
 from ..state.matrix import DEVICE_LOCK
-from .claims import OVERLAY_ROWS, ClaimsLedger
+from .claims import (
+    CHAIN_DEPTH,
+    OVERLAY_ROWS,
+    ClaimsLedger,
+    Launch,
+    empty_carry,
+)
 
 log = logging.getLogger(__name__)
 
@@ -176,6 +190,9 @@ class _Ticket:
     # True when this launch is the half-open breaker's single probe; its
     # fetch verdict decides whether the device path is re-admitted.
     canary: bool = False
+    # The chain's record of this launch (scheduler/claims.py): resolved when
+    # its lanes' claims are in the ledger, or when it is given up.
+    launch: Launch = field(default_factory=lambda: Launch((), 0))
 
 
 class DeviceCoalescer:
@@ -280,6 +297,20 @@ class DeviceCoalescer:
         self.claims = ClaimsLedger()
         self.overlay_rows_total = 0
         self.launches_unresolved_predecessor = 0
+        # Claims chained on the device: the carry the last launch wrote
+        # (its own claims block and the CHAIN_DEPTH - 1 before it; a
+        # jax.Array that never visits the host, numpy on the twin's route),
+        # the route that wrote it, and the launches not yet known resolved,
+        # newest first: the first CHAIN_DEPTH are the carry's blocks in
+        # order.  Counted: launches enqueued with a live carried block, the
+        # rows of those blocks (on the host, when the block's launch
+        # resolves), launches with an unresolved predecessor the carry no
+        # longer holds.
+        self._carry = self._carry_route = None
+        self._launches: List[Launch] = []
+        self.chained_launches = 0
+        self.chained_rows_total = 0
+        self.chain_overflow = 0
         self.feature_recompiles = 0
         self._features = None
         # Device→host result traffic for fused/sharded dispatches (the
@@ -490,6 +521,8 @@ class DeviceCoalescer:
                     # The probe died before producing a fetch verdict —
                     # release the slot so half-open can retry.
                     self.breaker.cancel_canary()
+                # The call may have consumed the carry it was handed.
+                self._drop_carry()
                 self._depth_sem.release()
                 for p in batch:
                     p.error = exc
@@ -501,7 +534,7 @@ class DeviceCoalescer:
             self._tickets.put(
                 _Ticket(
                     packed, batch, version, launched_at=waited,
-                    canary=canary,
+                    canary=canary, launch=self._launches[0],
                 )
             )
 
@@ -541,6 +574,7 @@ class DeviceCoalescer:
                 continue
             if ticket.canary:
                 self.breaker.cancel_canary()
+            self.claims.register_launch(ticket.launch)
             for p in ticket.entries:
                 if not p.done.is_set():
                     p.error = err
@@ -575,6 +609,9 @@ class DeviceCoalescer:
                             p.error = exc
                             p.done.set()
                 finally:
+                    # A launch that failed, wedged or raised entered
+                    # nothing: its block stops counting all the same.
+                    self.claims.register_launch(ticket.launch)
                     self.inflight -= 1
                     self._depth_sem.release()
                     with self._cond:
@@ -823,12 +860,17 @@ class DeviceCoalescer:
             # jax as one operand (kernels.unpack_lanes gives them back).
             # The claims overlay is one flat list to the program; it rides
             # the pack as a few rows a lane (no device buffer of its own).
+            # So do the chain's flags (kernels.chain_flags: the lane holds
+            # claims; then, as one flat list, carried block d is live) and
+            # what a lane's plan advertises on its delta rows.
             ov = -(-OVERLAY_ROWS // lanes)
             pack, small, layout = packed_rows(lanes, [
                 ((cw,), bool), (sc_shape, np.float32),
                 ((MAX_DELTA_ROWS,), np.int32),
                 ((MAX_DELTA_ROWS, 3), np.float32), ((), np.int32),
                 ((ov,), np.int32), ((ov, 3), np.float32),
+                ((MAX_DELTA_ROWS, 3), np.float32),
+                ((1 - (-CHAIN_DEPTH // lanes),), bool),
             ])
             st = self._stage[slot] = {
                 "host_mask": np.zeros((lanes, n), bool),
@@ -837,7 +879,8 @@ class DeviceCoalescer:
                 "pack": pack, "layout": layout,
                 **dict(zip(("class_elig", "spread_counts", "delta_rows",
                             "delta_vals", "lane_steps", "overlay_rows",
-                            "overlay_vals"), small)),
+                            "overlay_vals", "claim_vals", "chain_flags"),
+                           small)),
             }
             st["class_elig"][:] = True
             st["delta_rows"][:] = -1
@@ -892,29 +935,78 @@ class DeviceCoalescer:
         return arrays, sharded, version, n
 
     def _overlay(self, batch: List[_Pending], version: int):
-        """The claims overlay of the launch about to be enqueued: (rows,
-        vals) of the picks whose plans the applier has not decided, or has
-        committed past ``version`` (the launch's snapshot), the launch's
-        own lanes' evals left out.  Read as late as the launch allows: a
+        """What the launch about to be enqueued is told of the launches
+        before it: (rows, vals) of the claims overlay, the picks whose
+        plans the applier has not decided, or has committed past
+        ``version`` (the launch's snapshot), the launch's own lanes' evals
+        left out; and which carried blocks are live ((CHAIN_DEPTH,) bool):
+        those of the launches whose result is not on the host, whose picks
+        are therefore in no entry.  One step of the ledger decides both, so
+        a pick is counted once.  Read as late as the launch allows: a
         predecessor whose result arrived during this launch's host part is
-        in it."""
+        in the overlay."""
         if self.inflight:
             self.launches_unresolved_predecessor += 1
-        rows, vals = self.claims.overlay(
+        rows, vals, live = self.claims.overlay(
             version, [p.eval_id for p in batch if p.eval_id],
-            self.matrix.relocated_at,
+            self.matrix.relocated_at, self._launches[:CHAIN_DEPTH],
         )
         self.overlay_rows_total += len(rows)
-        return rows, vals
+        self.chained_launches += any(live)
+        # An unresolved predecessor no block stands for: older than the
+        # carry holds, of another route or layout.
+        unresolved = sum(not launch.resolved for launch in self._launches)
+        self.chain_overflow += unresolved > sum(live)
+        blocks = np.zeros((CHAIN_DEPTH,), bool)
+        blocks[: len(live)] = live
+        return rows, vals, blocks
 
-    def _register_claims(self, p: _Pending, rows: np.ndarray,
-                         preempted: np.ndarray, layout: int) -> None:
-        """Enter a resolved lane's whole proposed usage into the ledger:
+    def _carry_in(self, route):
+        """The carry to hand a launch on ``route`` ("twin", "device" or
+        the mesh): the one the launch before it wrote, or blocks of padding
+        where there is none or another program wrote it (those launches'
+        blocks are lost to the chain: ``Launch.block``)."""
+        if self._carry is None or self._carry_route != route:
+            self._drop_carry()
+            carry = empty_carry(
+                self.max_lanes, MAX_DELTA_ROWS + self.scan_length
+            )
+            if route not in ("twin", "device"):
+                from ..parallel.sharding import shard_carry
+
+                carry = shard_carry(route, carry)
+            self._carry, self._carry_route = carry, route
+        return self._carry
+
+    def _drop_carry(self) -> None:
+        self._carry = None
+        for launch in self._launches:
+            launch.block = False
+
+    def _chain(self, batch: List[_Pending], version: int, carry) -> None:
+        """File the launch just made at the head of the chain, with the
+        carry it wrote: the blocks shifted by one, and the oldest fell
+        off.  Past the carry's depth only the unresolved are kept (for
+        ``chain_overflow``)."""
+        self._carry = carry
+        for launch in self._launches[CHAIN_DEPTH - 1:]:
+            launch.block = False
+        self._launches = [
+            Launch([p.eval_id for p in batch if p.eval_id], version)
+        ] + [
+            launch for i, launch in enumerate(self._launches)
+            if i < CHAIN_DEPTH - 1 or not launch.resolved
+        ]
+
+    def _lane_claims(self, p: _Pending, rows: np.ndarray,
+                     preempted: np.ndarray):
+        """A resolved lane's whole proposed usage, as the ledger takes it
+        ((rows, vals); what the lane's part of the launch's claims block
+        holds on the device, ``kernels.claims_block``):
         what its plan held before the launch (``claim_vals`` on
         ``delta_rows``) and the picks its caller will consume, the first
         ``n_live`` up to a preempting one (``stack.py`` drops the rows
-        after it and re-enters).  Before the lane's future completes, so
-        the entry is there when its plan reaches the applier."""
+        after it and re-enters)."""
         n = lane_step_count(p.n_live, self.scan_length)
         picks = rows[:n]
         pre = np.flatnonzero(preempted[:n] != 0.0)
@@ -928,7 +1020,7 @@ class DeviceCoalescer:
             [p.claim_vals[held], np.broadcast_to(ask, (len(picks), 3))]
         )
         claims = vals.any(axis=1)
-        self.claims.register(p.eval_id, rows[claims], vals[claims], layout)
+        return rows[claims], vals[claims]
 
     def _dispatch(self, batch: List[_Pending], degraded: bool = False):
         """Launch one placement batch; returns (unfetched packed result,
@@ -1021,16 +1113,26 @@ class DeviceCoalescer:
             live_counts = [
                 lane_step_count(p.n_live, self.scan_length) for p in batch
             ]
+            no_claims = np.zeros((MAX_DELTA_ROWS, 3), np.float32)
             with self._state("coalescer.enqueue", lanes=len(batch)):
-                packed = fake_device.fused_place_batch(
+                carry = self._carry_in("twin")
+                rows, vals, blocks = self._overlay(batch, version)
+                packed, carry = fake_device.fused_place_batch(
                     arrays,
                     arrays.used,
                     *lane_lists,
                     lane_mask=np.ones((len(batch),), bool),
                     n_placements=self.scan_length,
                     live_counts=live_counts,
-                    overlay=self._overlay(batch, version),
+                    overlay=(rows, vals),
+                    chain=(
+                        carry, blocks,
+                        [no_claims if p.claim_vals is None else p.claim_vals
+                         for p in batch],
+                        [bool(p.eval_id) for p in batch],
+                    ),
                 )
+            self._chain(batch, version, carry)
             self.fused_dispatches += 1
             self.fused_lanes += len(batch)
             self.scan_steps_total += max(live_counts)
@@ -1056,6 +1158,7 @@ class DeviceCoalescer:
             pen, ce = st["penalty"], st["class_elig"]
             sc = st["spread_counts"]
             dr, dv = st["delta_rows"], st["delta_vals"]
+            cv, flags = st["claim_vals"], st["chain_flags"]
             ls = st["lane_steps"]
             ls[k:] = 0
             for i, p in enumerate(batch):
@@ -1080,6 +1183,8 @@ class DeviceCoalescer:
                 sc[i] = p.spread_counts
                 dr[i] = p.delta_rows
                 dv[i] = p.delta_vals
+                cv[i] = 0.0 if p.claim_vals is None else p.claim_vals
+                flags[i, 0] = bool(p.eval_id)
             if k < self.max_lanes:
                 # Pad lanes by memset: an all-False host mask makes every
                 # placement in the lane fail cheaply; whatever the other
@@ -1127,9 +1232,14 @@ class DeviceCoalescer:
             self._unpack_variant = unpack, layouts
             state = "coalescer.trace_variant"
         with self._state(state, **args):
-            # The claims overlay goes into the pack last, immediately
-            # before the call that hands the pack over.
-            rows, vals = self._overlay(batch, version)
+            # The carry goes from call to call on the device: the second
+            # output of the launch before is this launch's operand.
+            carry = self._carry_in("device" if n_shards == 1 else self._mesh)
+            # The claims overlay and the chain's live flags go into the
+            # pack last, immediately before the call that hands the pack
+            # over.
+            rows, vals, blocks = self._overlay(batch, version)
+            flags[:, 1:].flat[:CHAIN_DEPTH] = blocks
             orows, ovals = st["overlay_rows"], st["overlay_vals"]
             flat = np.full((orows.size,), -1, np.int32)
             flat[: len(rows)] = rows
@@ -1140,25 +1250,28 @@ class DeviceCoalescer:
                 ovals[:] = flat.reshape(ovals.shape)
             # The 30 small lane operands cross as two buffers, not 30: the
             # placement program takes them as device arrays.
-            reqs, (ce, sc, dr, dv, ls, orows, ovals) = unpack(
+            reqs, (ce, sc, dr, dv, ls, orows, ovals, cv, flags) = unpack(
                 slab.pack, st["pack"], layouts=layouts
             )
             reqs = SchedRequest(*reqs)
+            chain = carry, flags, cv
             if n_shards > 1:
-                packed = self._sharded_fused_fn(
+                packed, carry = self._sharded_fused_fn(
                     sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
                     hm, ls, features=feats, overlay=(orows, ovals),
+                    chain=chain,
                 )
             else:
                 # The live entry donates the per-dispatch lane operands
                 # (their device buffers become XLA scratch); `arrays`/`used`
                 # stay live — they are matrix-resident and shared with
                 # in-flight dispatches.
-                packed = kernels.fused_place_batch_live(
+                packed, carry = kernels.fused_place_batch_live(
                     arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
                     ls, n_placements=self.scan_length,
-                    features=feats, overlay=(orows, ovals),
+                    features=feats, overlay=(orows, ovals), chain=chain,
                 )
+        self._chain(batch, version, carry)
         return packed, version
 
     def _resolve(self, ticket: _Ticket) -> None:
@@ -1283,6 +1396,7 @@ class DeviceCoalescer:
                 # gauge over this attribute by the server).
                 self.stale_dispatches += 1
                 trace.event("coalescer.stale_dispatch")
+            lanes, claims = [], []
             for i, p in enumerate(entries):
                 row = arr[i]
                 # Shard-preserving capacity growth relocates rows; a dispatch
@@ -1310,9 +1424,20 @@ class DeviceCoalescer:
                 self.picks_placed += int(placed.sum())
                 self.preempt_picks += int((placed & (pcol != 0.0)).sum())
                 if p.eval_id:
-                    self._register_claims(
-                        p, rows_i, pcol, ticket.matrix_version
+                    claims.append(
+                        (p.eval_id,) + self._lane_claims(p, rows_i, pcol)
                     )
+                lanes.append((p, row, rows_i, pcol, fit_verified))
+            # All lanes' claims enter the ledger in one step with the
+            # launch's being resolved (a launch being enqueued meanwhile
+            # reads them there or in the carried block, not in both), and
+            # before any lane's future completes, so the entry is there
+            # when its plan reaches the applier.
+            carried = self.claims.register_launch(ticket.launch, claims)
+            self.chained_rows_total += carried * sum(
+                len(rows) for _eval, rows, _vals in claims
+            )
+            for p, row, rows_i, pcol, fit_verified in lanes:
                 p.outcome = PlaceOutcome(
                     rows=rows_i,
                     scores=row[:, kernels.PACKED_SCORE],

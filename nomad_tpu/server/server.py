@@ -293,6 +293,16 @@ class Server:
             "nomad.coalescer.launches_unresolved_predecessor",
             lambda: c.launches_unresolved_predecessor,
         )
+        # Claims chained on the device: launches handed a live carried
+        # block, those blocks' rows, and launches with an unresolved
+        # predecessor the carry no longer held.
+        m.gauge_fn(
+            "nomad.coalescer.chained_launches", lambda: c.chained_launches
+        )
+        m.gauge_fn(
+            "nomad.kernel.chained_rows_total", lambda: c.chained_rows_total
+        )
+        m.gauge_fn("nomad.coalescer.chain_overflow", lambda: c.chain_overflow)
         m.gauge_fn(
             "nomad.kernel.feature_recompiles", lambda: c.feature_recompiles
         )

@@ -245,6 +245,16 @@ class DeviceArrays(NamedTuple):
 _SCATTER_FN = None
 
 
+def scatter_bucket(rows: int) -> int:
+    """The row count a dirty-row scatter of ``rows`` rows is padded to: the
+    next power of two, and at least 2.  A bucket of one row would be a
+    program of its own that only a sync of exactly one dirty row compiles:
+    a warm-up that commits several plans a launch (none is refused since
+    PR 41) never meets it, and the first such sync of a window compiled it
+    there (15 ms on a v5e: PERF.md section 6, PR 41)."""
+    return max(2, 1 << max(0, rows - 1).bit_length())
+
+
 def make_row_scatter():
     """Build the jitted multi-field dirty-row scatter.
 
@@ -1022,7 +1032,7 @@ class NodeMatrix:
             # scatter compiles once per bucket; the numpy operands ride
             # the dispatch instead of paying a dozen per-field transfers.
             k = len(rows)
-            padded = 1 << max(0, (k - 1)).bit_length()
+            padded = scatter_bucket(k)
             idx = np.full((padded,), rows[0], np.int32)
             idx[:k] = rows
             row_data = [self._alloc[f][idx] for f in DeviceArrays._fields]
@@ -1107,7 +1117,7 @@ class NodeMatrix:
             # Pow2 row-count buckets, as in _sync_locked, so the sharded
             # scatter compiles once per bucket.
             k = len(rows)
-            padded = 1 << max(0, (k - 1)).bit_length()
+            padded = scatter_bucket(k)
             idx = np.full((padded,), rows[0], np.int32)
             idx[:k] = rows
             row_data = [self._alloc[f][idx] for f in DeviceArrays._fields]

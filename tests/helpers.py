@@ -156,7 +156,8 @@ def fill_frontier(matrix, nodes, picks, ask_cpu, ask_mem, seed=0):
 LAUNCH_FILLS = (1, 2, 8, 9, 16, 33, 57, 64)
 NODE_AXIS = ("tg_count", "penalty", "host_mask")
 SMALL = ("class_elig", "spread_counts", "delta_rows", "delta_vals",
-         "lane_steps", "overlay_rows", "overlay_vals")
+         "lane_steps", "overlay_rows", "overlay_vals", "claim_vals",
+         "chain_flags")
 
 
 def launch_lanes(coal, k, seed=0, classes=2):
@@ -197,14 +198,15 @@ def launch_lanes(coal, k, seed=0, classes=2):
 def spy_on_launch(monkeypatch, coal):
     """Record what the next launches hand jax: ``packs`` gets the unpacking
     program's operands, ``placed`` the placement program's (positional,
-    then the claims overlay's two)."""
+    then the claims overlay's two and the chain's three)."""
     from nomad_tpu.ops import kernels
 
     packs, placed = [], []
 
     def spy(fn, seen):
         def call(*operands, **static):
-            seen.append(operands + tuple(static.get("overlay", ())))
+            seen.append(operands + tuple(static.get("overlay", ()))
+                        + tuple(static.get("chain", ())))
             return fn(*operands, **static)
         return call
 
@@ -274,12 +276,12 @@ def check_packed_launch(monkeypatch, k, n_device_shards=1):
     assert coal.operand_bytes_total - bytes0 == (
         lanes * n * (1 + 4 + 1) + st["pack"].nbytes + slab.pack.nbytes)
     ((_arrays, _used, dr, dv, tg, sc, pen, reqs, ce, hm, ls, orows,
-      ovals),) = placed
+      ovals, _carry, flags, cv),) = placed
     for x, field in zip((tg, pen, hm), NODE_AXIS):
         assert x is st[field] and x.shape == (lanes, n)
     assert not hm[k:].any()
     assert orows.size >= OVERLAY_ROWS and (st["overlay_rows"] == -1).all()
-    small = (ce, sc, dr, dv, ls, orows, ovals) + tuple(reqs)
+    small = (ce, sc, dr, dv, ls, orows, ovals, cv, flags) + tuple(reqs)
     assert all(isinstance(x, jax.Array) for x in small)
     for x, field in zip(small, SMALL):  # copied: the live entry donated them
         assert x.shape == st[field].shape and x.dtype == st[field].dtype

@@ -354,7 +354,7 @@ class TestLedger:
             led.register(
                 f"e{i}", np.arange(8) + 8 * i, np.tile(V, (8, 1)), layout=0
             )
-        rows, vals = led.overlay(0)
+        rows, vals, _live = led.overlay(0)
         assert len(rows) == OVERLAY_ROWS and vals.shape == (OVERLAY_ROWS, 3)
         assert led.counts["truncated"] == 24
         # The newest go.
@@ -398,9 +398,9 @@ class TestLedger:
 # ---------------------------------------------------------------------------
 
 
-def _server(monkeypatch, **cfg):
+def _server(monkeypatch, latency_ms=2, **cfg):
     monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE", "1")
-    monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE_LATENCY_MS", "2")
+    monkeypatch.setenv("NOMAD_TPU_FAKE_DEVICE_LATENCY_MS", str(latency_ms))
     srv = Server(ServerConfig(
         node_capacity=128, heartbeat_min_ttl=3600.0,
         heartbeat_max_ttl=7200.0, **cfg,
@@ -465,8 +465,8 @@ class TestLiveServer:
             read = ClaimsLedger.overlay
 
             def nothing(self, *a, **kw):
-                read(self, *a, **kw)
-                return claims_mod._NO_ROWS, claims_mod._NO_VALS
+                live = read(self, *a, **kw)[2]
+                return claims_mod._NO_ROWS, claims_mod._NO_VALS, live
 
             monkeypatch.setattr(ClaimsLedger, "overlay", nothing)
         # Depth 1: a launch leaves when its predecessor's result is on the

@@ -296,7 +296,7 @@ def test_j105_unpacking_program_compiles_once_for_every_fill():
     c = contracts.get("unpack_lanes")
     assert c.sweep is contracts.occupancy_sweep and c.max_compiles == 1
     packs, layouts = contracts._unpack_packs(c.compile_grid)
-    assert [len(lay) for lay in layouts] == [len(SchedRequest._fields), 7]
+    assert [len(lay) for lay in layouts] == [len(SchedRequest._fields), 9]
     assert all(p.dtype == np.uint8 and p.shape[0] == c.compile_grid.batch
                for p in packs)
     entry = c.build(c.compile_grid)

@@ -214,7 +214,7 @@ class TestStagingReuse:
             launches.append(
                 InFlight([dr, dv, tg, sc, pen, ce, hm, ls, *reqs])
             )
-            return launches[-1]
+            return launches[-1], _static["chain"][0]  # packed, the carry
 
         monkeypatch.setattr(kernels, "fused_place_batch_live", stand_in)
         inputs = []
