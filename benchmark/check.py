@@ -22,6 +22,20 @@ PERF.md section 2 gives the readings each limit was set from).
     beyond what float32 sums can decide (reference.has_room): the program
     decides what fits by float32 sums, as the configuration states, and a
     node it rightly saw as full is not one it passed over.
+(e) where the traffic registers resident jobs again, unchanged
+    (``register_again_fraction``): every resident job that an ``again``
+    operation ended placed on still has the version its first registration
+    got (``resubmit_version_bumped``) and, id for id and node for node, the
+    live allocations the resident phase placed, none stopped and none added
+    (``resubmit_allocs_replaced``), which are its asked count
+    (``count_mismatch``).  ``state["resident"]`` is run.py's read-back of
+    the resident set before the window; a job it does not hold counts under
+    both numbers.  Which numbers a run compares follows from its TRAFFIC
+    (``expected_numbers``), not from its records: with
+    ``register_again_fraction`` above 0 both are required and at least one
+    ``again`` operation has to have ended placed, or the run is not correct;
+    without the key a run compares exactly the numbers it compared before
+    there was one.
 """
 
 from __future__ import annotations
@@ -37,6 +51,8 @@ LIMITS = {
     "count_mismatch": 0,
     "overcommitted_nodes": 0,
     "constraint_violations": 0,
+    "resubmit_version_bumped": 0,
+    "resubmit_allocs_replaced": 0,
     "score_gap": 3e-5,
     "rank_gap": 1e-5,
 }
@@ -170,6 +186,17 @@ def _candidates(s, dtype):
     return b, f
 
 
+RESUBMIT = ("resubmit_version_bumped", "resubmit_allocs_replaced")
+
+
+def expected_numbers(traffic):
+    """The numbers a run of this traffic has to compare, in LIMITS' order:
+    decided by the traffic file, so a run that lost its ``again`` records
+    cannot pass by comparing less."""
+    again = float(traffic.get("register_again_fraction", 0) or 0) > 0
+    return [k for k in LIMITS if again or k not in RESUBMIT]
+
+
 def _sample_gaps(s, recorded_dtype=None, floor="floor"):
     """(score gap, rank gap or None) of one sampled decision."""
     if s["binpack"] is None or s["final"] is None:
@@ -235,8 +262,12 @@ def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
 
     mismatch = violations = 0
     notes = []  # what to print where a number is over its limit
-    run_jobs = {r["job_id"]: r for r in records}
-    for r in records:
+    # Several ``again`` operations name one resident job: it is held once.
+    again = {r["job_id"]: r for r in records
+             if r.get("kind") == "again" and r["status"] == "placed"}
+    fresh = [r for r in records if r.get("kind") != "again"]
+    run_jobs = {r["job_id"]: r for r in fresh}
+    for r in fresh + list(again.values()):
         mine = by_job.get(r["job_id"], [])
         if r["status"] == "placed" and (
             len(mine) != r["width"] or any(a["task_group"] != "g" for a in mine)
@@ -253,10 +284,38 @@ def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
     numbers["count_mismatch"] = mismatch
     numbers["constraint_violations"] = violations
 
+    expected = expected_numbers(traffic)
+    if RESUBMIT[0] in expected:
+        resident = (state or {}).get("resident") or {}
+        bumped = replaced = 0
+        versions = {
+            j["id"]: j["version"]
+            for ns in sorted({r["namespace"] for r in again.values()})
+            for j in get(f"/v1/jobs?namespace={ns}&prefix=res-")}
+        for jid in again:
+            first = resident.get(jid) or {}
+            if (first.get("version") is None
+                    or versions.get(jid) != first["version"]):
+                bumped += 1
+                notes.append(
+                    f"resubmit_version_bumped: {jid} reads version "
+                    f"{versions.get(jid)}, its first registration got "
+                    f"{first.get('version')}")
+            now = {a["id"]: a["node_id"] for a in by_job.get(jid, [])}
+            if now != first.get("allocs"):
+                replaced += 1
+                notes.append(
+                    f"resubmit_allocs_replaced: {jid} had "
+                    f"{first.get('allocs')}, has {now}")
+        numbers["resubmit_version_bumped"] = bumped
+        numbers["resubmit_allocs_replaced"] = replaced
+
     before = [a for a in live if a["job_id"] not in run_jobs]
     used_start = ref.usage_after(used0, before, row_of)
+    # The window's NEW jobs alone are sampled: a resident job's allocations
+    # were placed before the window's start, which the rank is read against.
     samples = build_samples(
-        records, by_job, by_node, used0, row_of, traffic, tables, totals,
+        fresh, by_job, by_node, used0, row_of, traffic, tables, totals,
         used_start, used_end, seed,
     )
     numbers["score_gap"], numbers["rank_gap"] = score_gaps(samples)
@@ -276,7 +335,7 @@ def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
 
     lines = [
         f"check: {k} = {numbers[k]:.6g} (limit {LIMITS[k]:g})"
-        for k in LIMITS
+        for k in expected
     ]
     lines.append(
         f"check: rank_gap under the loose reading of room = {rank_loose:.6g} "
@@ -286,8 +345,16 @@ def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
         f"check: compared {sum(r['status'] == 'placed' for r in records)} "
         f"operations, {len(live)} live allocations, {len(samples)} sampled "
         f"placement decisions"
+        + (f"; {sum(r.get('kind') == 'again' for r in records)} of the "
+           f"operations registered {len(again)} resident jobs again"
+           if RESUBMIT[0] in expected else "")
     )
-    correct = bool(samples) and all(numbers[k] <= LIMITS[k] for k in LIMITS)
+    correct = bool(samples) and all(numbers[k] <= LIMITS[k] for k in expected)
+    if RESUBMIT[0] in expected and not again:
+        correct = False
+        notes.append("resubmit: the traffic registers resident jobs again "
+                     "and no such operation ended placed: nothing of it "
+                     "was compared")
     if not correct:
         if numbers["nodes_wrong"]:
             states = {}

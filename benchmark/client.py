@@ -8,6 +8,8 @@ heartbeats.  It speaks JSON lines with its parent over stdin / stdout:
     {"cmd": "init", "addr", "traffic", "seed", "seconds", "overrides"}
     {"cmd": "warmup", "tag"}         one op of every shape class, then a
                                      burst of the window's own first ops
+    {"cmd": "resident"}              register and place the mix's resident
+                                     jobs (where it has any), to the end
     {"cmd": "run", "t0", "seconds"}  the measured window, then the drain
     {"cmd": "exit"}
 
@@ -19,6 +21,12 @@ makes the client register the same job again, as ``nomad job run`` in a
 pipeline would, until it is placed or ``limit_s`` has passed (and at most
 ``max_reregister`` times); a 429 is retried after its ``Retry-After``.  The
 operation's clock runs through all of it.
+
+An operation's ``kind`` is data (traffic.py): ``new`` registers a new job,
+``again`` registers a resident job once more with the bytes it was first
+sent.  Several operations name one resident job over a run, so an ``again``
+operation ends on the eval its OWN registration returned, and on no other
+eval of that job.
 """
 
 from __future__ import annotations
@@ -44,13 +52,14 @@ class Op:
     __slots__ = (
         "i", "job_id", "spec", "due", "sent", "sent_last", "placed",
         "registers", "evals_failed", "n429", "blocked", "status", "cause",
-        "evals", "submit_s", "gen",
+        "evals", "submit_s", "gen", "kind",
     )
 
     def __init__(self, spec, job_id):
         self.i = spec["i"]
         self.job_id = job_id
         self.spec = spec
+        self.kind = spec.get("kind", "new")
         self.due = None        # wall time the registration was due / begun
         self.sent = None       # wall time the first register call started
         self.sent_last = None
@@ -70,7 +79,8 @@ class Op:
             "i": self.i, "job_id": self.job_id,
             "namespace": self.spec["namespace"], "width": self.spec["width"],
             "type": self.spec["type"], "shape": self.spec["shape"],
-            "due": self.due, "sent": self.sent, "placed": self.placed,
+            "kind": self.kind, "due": self.due, "sent": self.sent,
+            "placed": self.placed,
             "registers": self.registers, "evals_failed": self.evals_failed,
             "n429": self.n429, "blocked": self.blocked,
             "status": self.status, "cause": self.cause,
@@ -89,6 +99,10 @@ class Client:
         self.ops = {}            # job_id -> Op (every phase)
         self.seen_evals = set()  # terminal eval ids already handled
         self.retries = collections.deque()  # ops to register again
+        self.bodies = {}         # resident job id -> the bytes first sent
+        self.own = {}            # eval id -> the ``again`` op it belongs to
+        self.early = {}          # eval id -> (payload, time): an ``again``
+        #                          op's eval that ended before its id was known
         self.evals_ended = 0
         self.evals_failed = 0
         self.eval_failed_causes = {}
@@ -124,10 +138,11 @@ class Client:
             self._tls.conn = c
         return c
 
-    def call(self, method, path, body=None):
+    def call(self, method, path, body=None, data=None):
         """(status, headers, parsed body); one reconnect on a dropped
-        keep-alive connection."""
-        data = json.dumps(body).encode() if body is not None else None
+        keep-alive connection.  ``data``: the body's bytes as they are."""
+        if body is not None:
+            data = json.dumps(body).encode()
         for attempt in (0, 1):
             c = self._conn()
             try:
@@ -195,8 +210,15 @@ class Client:
         with self.lock:
             if p["id"] in self.seen_evals:
                 return
+            op = self.own.pop(p["id"], None) or self.ops.get(p.get("job_id"))
+            if (op is not None and op.kind == "again"
+                    and p["id"] not in op.evals):
+                # An eval of this job that is not (or not yet known to be)
+                # an ``again`` registration's own: kept for _register to
+                # claim.
+                self.early[p["id"]] = (p, now)
+                return
             self.seen_evals.add(p["id"])
-            op = self.ops.get(p.get("job_id"))
             if op is None:
                 return
             if op.due is not None and self.phase == "run":
@@ -236,8 +258,18 @@ class Client:
     # -- registering ----------------------------------------------------------
 
     def _register(self, op, prefix):
-        payload = {"Job": traffic_mod.job_payload(self.traffic, op.spec,
-                                                   prefix)}
+        # A new job's body is serialised inside the timed call, as it always
+        # was (``submit_s`` counts it); an ``again`` operation sends the
+        # bytes the resident phase sent.
+        payload = data = None
+        if op.kind == "again":
+            data = self.bodies[op.job_id]
+        else:
+            payload = {"Job": traffic_mod.job_payload(self.traffic, op.spec,
+                                                       prefix)}
+            if self.phase == "resident":
+                data = self.bodies[op.job_id] = json.dumps(payload).encode()
+                payload = None
         for _ in range(MAX_429 + 1):
             t = time.time()
             with self.lock:
@@ -246,7 +278,8 @@ class Client:
                 op.sent_last = t
                 op.registers += 1
             try:
-                code, headers, body = self.call("PUT", "/v1/jobs", payload)
+                code, headers, body = self.call("PUT", "/v1/jobs", payload,
+                                                data)
             except (OSError, http.client.HTTPException) as e:
                 code, headers, body = 599, {}, {"error": repr(e)}
             took = time.time() - t
@@ -255,6 +288,11 @@ class Client:
                     op.evals.append(body["EvalID"])
                     if op.submit_s is None:
                         op.submit_s = took
+                    if op.kind == "again":
+                        self.own[body["EvalID"]] = op
+                    early = self.early.pop(body["EvalID"], None)
+                if early is not None:
+                    self._on_eval(*early)
                 return
             with self.lock:
                 op.registers -= 1  # a refused call registered nothing
@@ -280,14 +318,16 @@ class Client:
         "closed": keep ``outstanding`` in flight, begin none after
         t0 + seconds.  "all": as closed, until every op has ended.
         Returns the ops in order."""
-        ops = [Op(s, prefix + s["job_id"]) for s in specs]
+        ops = [Op(s, s["job_id"] if s.get("kind") == "again"
+                  else prefix + s["job_id"]) for s in specs]
         with self.lock:
             self.phase = phase
             self.gen += 1
             self.ended = 0
             for op in ops:
                 op.gen = self.gen
-                self.ops[op.job_id] = op
+                if op.kind != "again":  # those take the job as they begin
+                    self.ops[op.job_id] = op
         t_end = t0 + seconds if seconds is not None else float("inf")
         state = {"next": 0}
         if loop == "open":
@@ -314,6 +354,7 @@ class Client:
                         if due <= now:
                             op.due = due
                             state["next"] += 1
+                            self.ops[op.job_id] = op
                             return op
                         self.lock.wait(min(due - now, 0.05))
                         continue
@@ -327,6 +368,7 @@ class Client:
                     if state["next"] - self.ended < outstanding:
                         op.due = now
                         state["next"] += 1
+                        self.ops[op.job_id] = op
                         return op
                     self.lock.wait(0.05)
 
@@ -345,6 +387,8 @@ class Client:
             th.join()
         with self.lock:
             self.retries.clear()
+            self.early.clear()
+            self.own.clear()
             self.phase = None
         return ops[: state["next"]], t_end
 
@@ -365,7 +409,8 @@ class Client:
                             4, WARMUP_TIMEOUT_S, "warmup")
         # Then the window's own concurrency: its first operations at once.
         n = int(min(t.get("outstanding", 64), 64))  # 16 workers, 64 lanes
-        burst = traffic_mod.schedule(t, self.seed, self.seconds)[:n]
+        burst = [s for s in traffic_mod.schedule(t, self.seed, self.seconds)
+                 if s.get("kind") != "again"][:n]
         ops2, _ = self.drive(burst, f"{tag}b-", "all", time.time(), None,
                              n, WARMUP_TIMEOUT_S, "warmup")
         done = ops + ops2
@@ -374,6 +419,23 @@ class Client:
             "placed": sum(op.status == "placed" for op in done),
             "not_placed": [op.job_id + ":" + (op.cause or op.status)
                            for op in done if op.status != "placed"][:8],
+        }
+
+    def resident(self):
+        """Register and place the resident set, closed loop to the end;
+        the bytes of each registration are kept for the window's ``again``
+        operations."""
+        t = self.traffic
+        t_begin = time.time()
+        ops, _ = self.drive(
+            traffic_mod.resident_ops(t, self.seed), "", "all", t_begin, None,
+            int(t.get("outstanding", 64)), WARMUP_TIMEOUT_S, "resident")
+        return {
+            "ops": len(ops), "seconds": time.time() - t_begin,
+            "placed": sum(op.status == "placed" for op in ops),
+            "not_placed": [op.job_id + ":" + (op.cause or op.status)
+                           for op in ops if op.status != "placed"][:8],
+            "jobs": [op.job_id for op in ops],
         }
 
     def run(self, t0, seconds):
@@ -423,6 +485,8 @@ def main():
                 reply = {"ok": True}
             elif cmd == "warmup":
                 reply = client.warmup(msg["tag"])
+            elif cmd == "resident":
+                reply = client.resident()
             elif cmd == "run":
                 reply = client.run(msg["t0"], msg["seconds"])
             elif cmd == "exit":

@@ -21,7 +21,10 @@ is read by ``readers/<metric>.py``.  A configuration may bring three things
 of its own, each optional (README.md, "Adding things"): ``scheduler_config``
 (set over the operator API before the first node registers), ``setup`` (a
 module of ``deployments/`` whose ``install`` runs after the seeded usage)
-and ``check`` (the module whose ``decide`` decides ``correct``).
+and ``check`` (the module whose ``decide`` decides ``correct``).  A traffic
+mix may have part of its operations register a resident job again
+(``register_again_fraction``, ``resident_jobs``): the resident set is
+registered and placed after the warm-up, untimed.
 """
 
 from __future__ import annotations
@@ -133,6 +136,21 @@ def set_scheduler_config(addr, want) -> None:
     got = http_json(addr, SCHEDULER_CONFIG)
     if not holds(got, want):
         raise Fail(f"scheduler_config: sent {want}, the server reads {got}")
+
+
+def read_resident(addr, traffic, jobs):
+    """The resident set as the server holds it before the window: job id ->
+    its version and live allocations (id -> node).  The check holds the
+    window's ``again`` operations to exactly this."""
+    out = {jid: {"version": None, "allocs": {}} for jid in jobs}
+    for ns in traffic_mod.namespaces(traffic):
+        for j in http_json(addr, f"/v1/jobs?namespace={ns}&prefix=res-"):
+            if j["id"] in out:
+                out[j["id"]]["version"] = j["version"]
+        for a in http_json(addr, f"/v1/allocations?namespace={ns}"):
+            if a["job_id"] in out and a["desired_status"] == "run":
+                out[a["job_id"]]["allocs"][a["id"]] = a["node_id"]
+    return out
 
 
 # -- the client process ----------------------------------------------------------
@@ -352,6 +370,18 @@ def run(args) -> dict:
                 break
         setup["warmup_s"] = time.time() - t
         setup["warmup_passes"] = k + 1
+        if traffic.get("resident_jobs"):
+            # The jobs the window's ``again`` operations register again:
+            # registered and placed now, untimed, and read back as the
+            # check will want them (their versions and allocations).
+            reply = client.ask(cmd="resident")
+            if reply["placed"] != reply["ops"]:
+                raise Fail(f"resident set: {reply}")
+            state = dict(state or {}, resident=read_resident(
+                agent.rpc_addr, traffic, reply["jobs"]))
+            setup["resident_s"] = reply["seconds"]
+            log(f"resident set: {reply['ops']} jobs placed in "
+                f"{reply['seconds']:.1f}s")
         n_shards = coal.n_device_shards
         if not args.rehearse and n_shards != cell["chips"]:
             raise Fail(f"n_device_shards {n_shards}, cell has {cell['chips']} chips")

@@ -314,6 +314,80 @@ def test_the_check_fails_a_read_back_with_one_fault(fault, number):
                for l in lines), lines
 
 
+def _preempting(w, aid, node, job, width, index, ev, victim, victim_prio):
+    """``aid`` of ``job`` placed on the full ``node`` at ``index`` by
+    evicting ``victim`` in the same plan, scored as the reference scores
+    it, with the victim's job evaluated again."""
+    v = w.by_id(victim)
+    v.update(desired_status="evict", modify_index=index,
+             alloc_modify_index=index,
+             desired_description=f"Preempted by alloc ID {aid}")
+    b, p = pref.preempting_scores(
+        w.used0[node] + (800, 1024, 1200), (200, 256, 300), TOTALS,
+        [{"priority": victim_prio, "res": (200, 256, 300)}])
+    scores = {"binpack": float(b), "preemption": float(p),
+              "final": float(pref.final_score(b, p, 0, width, 0.0))}
+    w.allocs.append(_alloc(
+        aid, node, job, "default", 200, 256, index, eval=ev,
+        metrics={"scores": {f"sim-node-{node:06d}": scores}}))
+    w.evals.append(_eval(f"ev-pre-{aid}", v["job_id"], v["namespace"],
+                         victim_prio, "preemption", index, "batch"))
+
+
+def _race(w, second_attempt=True):
+    """PERF.md section 6, PR 38: a batch job of two (priority 30) commits
+    its plan in part (b1, index 31); a service job (50) justly evicts b1
+    (index 33); the batch eval's second attempt, from a snapshot that still
+    held b1, commits the rest (b2, index 34) and ends ``complete`` with one
+    live; the follow-up eval of index 33 has placed nothing by the cut."""
+    w.evals.append(_eval("ev-2", "op-000001", "default", 30, "job-register",
+                         30, "batch"))
+    w.evals[-1]["modify_index"] = 35
+    w.evals.append(_eval("ev-3", "op-000002", "default", 50, "job-register",
+                         32))
+    _preempting(w, "b1", 7, "op-000001", 2, 31, "ev-2", "tier-07-0", 10)
+    _preempting(w, "s1", 7, "op-000002", 1, 33, "ev-3", "b1", 30)
+    if second_attempt:
+        _preempting(w, "b2", 8, "op-000001", 2, 34, "ev-2", "tier-08-0", 10)
+    w.records += [
+        {"i": 1, "job_id": "op-000001", "namespace": "default", "width": 2,
+         "type": "batch", "shape": 0, "status": "placed", "registers": 1},
+        {"i": 2, "job_id": "op-000002", "namespace": "default", "width": 1,
+         "type": "service", "shape": 0, "status": "placed", "registers": 1}]
+
+
+def test_an_allocation_justly_evicted_before_the_evals_last_commit_counts():
+    w = World()
+    _race(w)
+    # By the rule before PR 43 (the most live at once) this read 1: the
+    # job of two never has more than one live.
+    steps = sorted(
+        [(a["create_index"], 1) for a in w.allocs
+         if a["job_id"] == "op-000001"]
+        + [(a["modify_index"], -1) for a in w.allocs
+           if a["job_id"] == "op-000001" and a["desired_status"] == "evict"])
+    live, peak = 0, 0
+    for _, d in steps:
+        live += d
+        peak = max(peak, live)
+    assert peak == 1
+    correct, numbers, lines = w.decide()
+    assert numbers["count_mismatch"] == 0, lines
+    assert correct, lines
+    assert any("5 evictions" in l for l in lines)
+
+
+def test_an_eviction_after_the_evals_last_commit_earns_no_credit():
+    """The same job one short because its second attempt never committed:
+    b1 was evicted AFTER the eval's last commit, so nothing excuses it."""
+    w = World()
+    _race(w, second_attempt=False)
+    correct, numbers, lines = w.decide()
+    assert not correct and numbers["count_mismatch"] == 1, lines
+    assert any(l.startswith("check: over its limit: count_mismatch")
+               for l in lines), lines
+
+
 def test_a_commit_after_the_cut_is_left_out():
     """The evicted tier's evals run on while the check reads: what the
     first pass over the evals has not seen is not replayed."""

@@ -10,6 +10,7 @@ import os
 import pytest
 
 import stage_reduce
+from conftest import ROOT
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -229,3 +230,66 @@ def test_scope_of():
     ) == "place_scan/score/binpack"
     assert stage_reduce.scope_of("jit(f)/jit(main)/mul") == ""
     assert stage_reduce.scope_of("") == ""
+
+
+# -- the list-bound metrics in the cell PR 43 lists them for - --------------------------
+
+NEW_CELL = "c2m-10k-preempt.tiers-backlog"
+TEN = ("launch_host_ms", "matrix_sync_ms", "coalescer_idle_share",
+       "gc_pause_share", "plan_rejected_share", "plan_partial_share",
+       "kernel_scan_share", "kernel_verify_share", "scan_steps_per_launch",
+       "deck_used_share")
+LISTED = [(m, NEW_CELL) for m in TEN + ("setup_variant_trace_s",)]
+
+
+def _bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        return json.load(fh)
+
+
+def recorded(cell, run):
+    """The hand-made window above as a traced run of ``cell`` hands it to
+    the readers: a closed loop with its deck, the cell's own configuration
+    file, the counters and the trace reduction of one chip."""
+    bench = _bench()
+    config = {w["name"]: w["config"] for w in bench["workloads"]}[cell]
+    path = {c["name"]: c["file"] for c in bench["configs"]}[config]
+    with open(os.path.join(ROOT, path)) as fh:
+        cfg = json.load(fh)
+    records = [
+        {"i": i, "ok": i != 7,
+         "due": 1000.0 + i * 0.01, "placed": 1000.3 + i * 0.012}
+        for i in range(40)]
+    run = dict(run, loop="closed", cfg=cfg, attempted=records,
+               device_kind="TPU v5 lite", matrix_bytes=48.8e6,
+               device={"busy_s": 0.3, "launches": 90, "kernel_s": 0.27,
+                       "devices": 1})
+    run["client"] = dict(run["client"], records=records, scheduled=512)
+    for m, grown in (("nomad.kernel.launches{path=fused}", 400),
+                     ("nomad.kernel.fused_lanes", 1700),
+                     ("nomad.kernel.scan_steps_total", 1500),
+                     ("nomad.kernel.overlay_rows_total", 900)):
+        run["m0"][m], run["m1"][m] = 10, 10 + grown
+    return run
+
+
+def test_benchmark_json_lists_the_cell_where_pr_43_says():
+    per_layer = {m["name"]: m for m in _bench()["per_layer"]}
+    for name, cell in LISTED:
+        assert cell in per_layer[name]["workloads"], (name, cell)
+    # ... and took no cell away from any list.
+    for name in TEN[:-1]:
+        assert set(per_layer[name]["workloads"]) >= {
+            "c2m-10k.steady", "c2m-10k.backlog", "c2m-100k.backlog-x4"}
+
+
+@pytest.mark.parametrize("name,cell", LISTED)
+def test_list_bound_reader_reads_a_number_in_a_cell_it_now_lists(
+        run, xplane, monkeypatch, name, cell):
+    monkeypatch.setattr(stage_reduce, "TRACE_DIR",
+                        os.path.dirname(os.path.dirname(os.path.dirname(
+                            os.path.dirname(xplane)))))
+    value = read(name, recorded(cell, run))
+    assert isinstance(value, float) and value == value and value >= 0.0
+    if name == "deck_used_share":
+        assert value == pytest.approx(100.0 * 40 / 512)

@@ -1,4 +1,6 @@
 import collections
+import hashlib
+import json
 
 import pytest
 
@@ -41,3 +43,92 @@ def test_mix_follows_the_file():
     payload = traffic.job_payload(t, ops[0])
     assert payload["task_groups"][0]["count"] == ops[0]["width"]
     assert len(traffic.warmup_ops(t)) == 2 * len(t["shapes"]) * 2
+
+
+# -- what an operation is, as data (PR 43) -------------------------------------------
+
+def _digest(name, seed, seconds=50):
+    """Everything the generator gives for one file and seed: the window's
+    operations, the warm-up's, and every payload as it is PUT."""
+    t = traffic.load(name)
+    ops = traffic.schedule(t, seed, seconds)
+    warm = traffic.warmup_ops(t)
+    h = hashlib.sha256()
+    for part in (ops, warm, [traffic.job_payload(t, o) for o in ops],
+                 [traffic.job_payload(t, o, "w0s-") for o in warm]):
+        h.update(json.dumps(part, sort_keys=True).encode())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,want", [
+    ("steady", ["dbd4e0215117fca1", "9c5b4a374cff0773"]),
+    ("backlog", ["4b2a222a08b12aeb", "0e35a2a754e91e60"]),
+    # backlog-x4 deals backlog's mix and as many blocks (41)
+    ("backlog-x4", ["4b2a222a08b12aeb", "0e35a2a754e91e60"]),
+    ("tiers-backlog", ["0f01b3959f660c85", "38f9281dd1cd4ab9"]),
+])
+def test_the_files_that_were_there_give_the_operations_they_gave(name, want):
+    """Digests taken from the parent tree (259c845) before ``traffic.py``
+    learned the kinds: a file without the new keys gives, bit for bit, what
+    it gave."""
+    assert [_digest(name, s) for s in (7, 2 ** 31 + 4300)] == want
+    t = traffic.load(name)
+    assert all("kind" not in o for o in traffic.schedule(t, 7, 2))
+    payload = traffic.job_payload(t, traffic.schedule(t, 7, 2)[0])
+    assert list(payload) == ["id", "name", "namespace", "type", "priority",
+                             "datacenters", "task_groups"]
+    assert list(payload["task_groups"][0])[:2] == ["name", "count"]
+
+
+def _mix(**keys):
+    t = traffic.load("backlog")
+    t.update(keys)
+    return t
+
+
+@pytest.mark.parametrize("fraction", [0.8, 0.5])
+def test_kind_deck(fraction):
+    """Exact proportion per block, the same multiset for every seed, and no
+    resident job registered again within ``resident_jobs`` operations."""
+    t = _mix(register_again_fraction=fraction, resident_jobs=2048,
+             max_rate_per_s=400)
+    want_again = traffic.deal([1 - fraction, fraction], traffic.BLOCK).count(1)
+    runs = [traffic.schedule(t, seed, 50) for seed in (1, 2 ** 31 + 99)]
+    for ops in runs:
+        assert len(ops) == 41 * traffic.BLOCK
+        for b in range(0, len(ops), traffic.BLOCK):
+            kinds = collections.Counter(
+                o["kind"] for o in ops[b:b + traffic.BLOCK])
+            assert kinds == {"again": want_again,
+                             "new": traffic.BLOCK - want_again}
+        last = {}
+        for o in ops:
+            if o["kind"] == "again":
+                assert o["job_id"] == f"res-{o['resident']:06d}"
+                assert o["i"] - last.get(o["job_id"], -10 ** 9) >= 2048
+                last[o["job_id"]] = o["i"]
+            else:
+                assert o["job_id"] == f"op-{o['i']:06d}"
+        # An ``again`` operation carries its resident job's attributes: the
+        # payload is the one first sent.
+        resident = traffic.resident_ops(t, [1, 2 ** 31 + 99][runs.index(ops)])
+        assert len(resident) == 2048
+        for o in ops[:2000]:
+            if o["kind"] == "again":
+                assert traffic.job_payload(t, o) == traffic.job_payload(
+                    t, resident[o["resident"]])
+    a, b = runs
+    assert [o["kind"] for o in a] != [o["kind"] for o in b]
+    # The new jobs are the same multiset for every seed; the ``again``
+    # operations go round the resident set, itself the same multiset for
+    # every seed, in whole cycles (a run ends part way through one).
+    again = [[o for o in ops if o["kind"] == "again"] for ops in runs]
+    for key in ("width", "namespace", "type", "shape"):
+        count = lambda ops: collections.Counter(o[key] for o in ops)
+        assert (count(o for o in a if o["kind"] == "new")
+                == count(o for o in b if o["kind"] == "new")), key
+        assert count(traffic.resident_ops(t, 1)) == count(
+            traffic.resident_ops(t, 2 ** 31 + 99)), key
+        assert count(again[0][:4 * 2048]) == count(again[1][:4 * 2048]), key
+    for cycle in (again[0][:2048], again[1][2048:4096]):
+        assert sorted(o["resident"] for o in cycle) == list(range(2048))

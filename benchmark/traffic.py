@@ -10,6 +10,11 @@ inter-arrival gaps, in another order: each attribute is a deck dealt in
 exact proportion (largest remainder) and shuffled by the seed.  So the work
 of a window does not depend on the seed, only its order does — which is
 what lets runs on different seeds be compared.
+
+Whether an operation registers a new job or a resident one AGAIN, unchanged,
+is data too (README.md, "What one operation is"): ``register_again_fraction``
+and ``resident_jobs``.  A file without these keys gives the operations it
+gave before there were any.
 """
 
 from __future__ import annotations
@@ -96,6 +101,33 @@ def namespaces(t: Dict) -> List[str]:
     return ["default"] + [f"tenant-{i}" for i in range(1, t["tenants"])]
 
 
+def again_fraction(t: Dict) -> float:
+    return float(t.get("register_again_fraction", 0) or 0)
+
+
+def kinds(t: Dict, n: int, seed: int) -> List[int]:
+    """0 (register a new job) or 1 (register a resident job again) for each
+    of ``n`` operations: dealt in exact proportion per block of ``BLOCK``
+    and shuffled by the seed, so every seed has the same count of each kind
+    in every block."""
+    f = again_fraction(t)
+    out: List[int] = []
+    for b in range(int(math.ceil(n / BLOCK))):
+        m = min(BLOCK, n - b * BLOCK)
+        out.extend(_shuffled(deal([1.0 - f, f], m), seed, f"kind{b}"))
+    return out
+
+
+def resident_ops(t: Dict, seed: int) -> List[Dict]:
+    """The resident set: ``resident_jobs`` jobs of the mix's own decks,
+    registered and placed before the window (run.py), which the window's
+    ``again`` operations register again, unchanged."""
+    ops = _decks(t, int(t.get("resident_jobs", 0)), seed, "resident")
+    for i, op in enumerate(ops):
+        op.update(due=None, i=i, job_id=f"res-{i:06d}")
+    return ops
+
+
 def schedule(t: Dict, seed: int, seconds: float) -> List[Dict]:
     """The operations of one window.
 
@@ -104,27 +136,60 @@ def schedule(t: Dict, seed: int, seconds: float) -> List[Dict]:
     sampling noise taken out), shuffled by the seed; ``due`` is seconds
     from the window's start.  Closed loop: a sequence long enough for any
     sustainable rate, ``due`` None; the client begins the next as one ends.
+
+    With ``register_again_fraction`` each operation has a ``kind``: ``new``
+    ones are dealt the mix's attributes as above (the decks cover the new
+    operations alone, so their multiset stays the same for every seed); the
+    k-th ``again`` of the run names resident job ``perm[k mod
+    resident_jobs]`` (``perm`` a seeded permutation) and carries that job's
+    attributes, so two registrations of one job are ``resident_jobs``
+    operations apart or more.
     """
     if t["loop"] == "open":
         n = int(round(t["rate_per_s"] * seconds))
         gaps = [-math.log(1.0 - (k + 0.5) / n) for k in range(n)]
         gaps = _shuffled(gaps, seed, "gaps")
         scale = seconds / (sum(gaps) + 1.0)  # the last gap runs to the end
-        ops = _decks(t, n, seed, "open")
-        at = 0.0
-        for op, g in zip(ops, gaps):
-            at += g * scale
-            op["due"] = at
+        salts = ["open"]
     else:
         n_blocks = int(math.ceil(t["max_rate_per_s"] * seconds / BLOCK)) + 1
-        ops = []
-        for b in range(n_blocks):
-            ops.extend(_decks(t, BLOCK, seed, f"closed{b}"))
-        for op in ops:
-            op["due"] = None
+        n = n_blocks * BLOCK
+        salts = [f"closed{b}" for b in range(n_blocks)]
+    again = again_fraction(t) > 0
+    if again:
+        kind = kinds(t, n, seed)
+        resident = resident_ops(t, seed)
+        if not resident:
+            raise ValueError("register_again_fraction needs resident_jobs")
+        perm = _shuffled(range(len(resident)), seed, "again")
+    else:
+        kind = [0] * n
+    # The new operations' decks: one for an open loop's whole window, one a
+    # block for a closed loop.
+    per = n if t["loop"] == "open" else BLOCK
+    ops, k_again = [], 0
+    for b, salt in enumerate(salts):
+        block = kind[b * per:(b + 1) * per]
+        fresh = iter(_decks(t, block.count(0), seed, salt))
+        for k in block:
+            if k:
+                r = perm[k_again % len(perm)]
+                k_again += 1
+                ops.append(dict(resident[r], kind="again", resident=r))
+            else:
+                ops.append(next(fresh))
+                if again:
+                    ops[-1]["kind"] = "new"
+    at = 0.0
     for i, op in enumerate(ops):
+        if t["loop"] == "open":
+            at += gaps[i] * scale
+            op["due"] = at
+        else:
+            op["due"] = None
         op["i"] = i
-        op["job_id"] = f"op-{i:06d}"
+        if op.get("kind") != "again":
+            op["job_id"] = f"op-{i:06d}"
     return ops
 
 
