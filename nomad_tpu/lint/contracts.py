@@ -142,6 +142,8 @@ def _concrete_reqs(b: int) -> Any:
         MAX_AFFINITIES,
         MAX_CONSTRAINTS,
         MAX_DATACENTERS,
+        MAX_DISTINCT_PROPS,
+        MAX_DISTINCT_VALUES,
         MAX_SPREAD_VALUES,
         MAX_SPREADS,
         MAX_STATIC_PORTS,
@@ -176,6 +178,12 @@ def _concrete_reqs(b: int) -> Any:
         distinct_hosts=np.zeros((b,), bool),
         p_static=np.full((b, MAX_STATIC_PORTS), -1, i32),
         p_dyn=np.zeros((b,), i32),
+        # Slot 0 live: the rows trace the distinct_property stage too.
+        dp_slot=np.tile(np.array([1, -1], i32)[:MAX_DISTINCT_PROPS], (b, 1)),
+        dp_limit=np.ones((b, MAX_DISTINCT_PROPS), f32),
+        dp_value_hash=np.zeros(
+            (b, MAX_DISTINCT_PROPS, MAX_DISTINCT_VALUES), i32),
+        dp_count=np.zeros((b, MAX_DISTINCT_PROPS, MAX_DISTINCT_VALUES), f32),
     )
 
 
@@ -300,7 +308,8 @@ _N_A, _N_B = 97, 159
 def _fused_trace_grids() -> Tuple[Grid, ...]:
     from ..ops.kernels import FULL_FEATURES, Features
 
-    narrow = Features(c_width=0, a_width=0, s_width=0, preempt=False, ports=False)
+    narrow = Features(c_width=0, a_width=0, s_width=0, preempt=False,
+                      ports=False, dp_width=0)
     base = Grid(nodes=_N_A, batch=6, placements=3, deltas=5, live=6,
                 features=FULL_FEATURES)
     return (base, base._replace(nodes=_N_B), base._replace(features=narrow))
@@ -309,7 +318,8 @@ def _fused_trace_grids() -> Tuple[Grid, ...]:
 def _fused_compile_grid() -> Grid:
     from ..ops.kernels import Features
 
-    narrow = Features(c_width=0, a_width=0, s_width=0, preempt=False, ports=False)
+    narrow = Features(c_width=0, a_width=0, s_width=0, preempt=False,
+                      ports=False, dp_width=0)
     # The live scan length, so the step-count sweep covers 1..16.
     return Grid(nodes=32, batch=4, placements=16, deltas=4, live=4, features=narrow)
 

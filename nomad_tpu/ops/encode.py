@@ -50,6 +50,12 @@ MAX_DATACENTERS = 8
 MAX_SPREADS = 2
 MAX_SPREAD_VALUES = 16
 MAX_STATIC_PORTS = 8
+# distinct_property constraints the placement scan holds per request, and
+# the property values a request can seed with a count (a job's live and
+# proposed allocations: ``stack._distinct_property_seed``); what does not
+# fit escapes to the host (``CompiledTaskGroup.escaped``).
+MAX_DISTINCT_PROPS = 2
+MAX_DISTINCT_VALUES = MAX_SPREAD_VALUES
 
 # Kernel op codes.
 OP_EQ = 0
@@ -122,6 +128,17 @@ class SchedRequest(NamedTuple):
     # host-verified) and the dynamic-port ask count.
     p_static: np.ndarray  # (P,) i32
     p_dyn: np.ndarray  # () i32
+    # distinct_property (DistinctPropertyIterator, feasible.go:604): at most
+    # ``dp_limit`` proposed allocs of the job per value of the attribute in
+    # ``dp_slot``; a node without the attribute is infeasible.  The scan
+    # carries a count per node (of the job's allocs on nodes sharing its
+    # value), seeded from ``dp_value_hash`` / ``dp_count`` (the values the
+    # job already holds, filled per select by the stack) and raised at each
+    # pick, so the limit holds from pick to pick inside one launch.
+    dp_slot: np.ndarray  # (DP,) i32, -1 = inactive
+    dp_limit: np.ndarray  # (DP,) f32
+    dp_value_hash: np.ndarray  # (DP, V) i32 — values held, 0 padded
+    dp_count: np.ndarray  # (DP, V) f32 — allocs held per value
 
 
 def packed_rows(lanes: int, specs):
@@ -221,6 +238,9 @@ class CompiledTaskGroup:
     # True when job.datacenters overflowed MAX_DATACENTERS; the kernel then
     # skips the dc check (sentinel) and the host filters by datacenter.
     dc_escaped: bool = False
+    # The distinct_property constraints the request holds, slot for slot
+    # (``request.dp_slot``): the stack seeds their counts per select.
+    distinct_props: List[Constraint] = field(default_factory=list)
     # host-only soft metadata
     spreads: List[Spread] = field(default_factory=list)
     affinities: List[Affinity] = field(default_factory=list)
@@ -271,6 +291,12 @@ def _encode_version_operand(r_target: str) -> Optional[Tuple[int, float]]:
         "=": OP_VER_EQ,
     }[comparator]
     return op, packed
+
+
+def distinct_property_limit(con: Constraint) -> int:
+    """Allocs of the job a value of the property may hold: ``r_target``
+    where it is digits, else 1 (feasible.go:604 / propertyset.go)."""
+    return int(con.r_target) if str(con.r_target).isdigit() else 1
 
 
 class RequestEncoder:
@@ -353,6 +379,9 @@ class RequestEncoder:
         c_num = np.full((MAX_CONSTRAINTS,), np.nan, np.float32)
         escaped: List[EscapedConstraint] = []
         ci = 0
+        dp_slot = np.full((MAX_DISTINCT_PROPS,), -1, np.int32)
+        dp_limit = np.ones((MAX_DISTINCT_PROPS,), np.float32)
+        distinct_props: List[Constraint] = []
 
         def emit(slot: int, op: int, h: int = 0, num: float = math.nan) -> bool:
             nonlocal ci
@@ -374,6 +403,19 @@ class RequestEncoder:
                 emit(slot, OP_EQ, stable_hash("1"))
 
         for con in constraints:
+            if con.operand == Op.DISTINCT_PROPERTY.value:
+                # In the scan where the attribute has a column and a slot of
+                # the request is free; on the host otherwise.
+                name = _resolve_attr_name(con.l_target)
+                slot = reg_attr(name) if name else None
+                di = len(distinct_props)
+                if slot is None or di >= MAX_DISTINCT_PROPS:
+                    escaped.append(self._escape(con))
+                    continue
+                dp_slot[di] = slot
+                dp_limit[di] = distinct_property_limit(con)
+                distinct_props.append(con)
+                continue
             if not self._encode_constraint(con, emit, escaped, reg_attr):
                 escaped.append(self._escape(con))
 
@@ -506,12 +548,21 @@ class RequestEncoder:
             ),
             p_static=p_static,
             p_dyn=np.int32(p_dyn),
+            dp_slot=dp_slot,
+            dp_limit=dp_limit,
+            dp_value_hash=np.zeros(
+                (MAX_DISTINCT_PROPS, MAX_DISTINCT_VALUES), np.int32
+            ),
+            dp_count=np.zeros(
+                (MAX_DISTINCT_PROPS, MAX_DISTINCT_VALUES), np.float32
+            ),
         )
         return CompiledTaskGroup(
             request=req,
             escaped=escaped,
             escaped_devices=escaped_devices,
             dc_escaped=dc_escaped,
+            distinct_props=distinct_props,
             spreads=spreads,
             affinities=affinities,
             drivers=drivers,
@@ -535,8 +586,9 @@ class RequestEncoder:
 
     def _encode_constraint(self, con: Constraint, emit, escaped,
                            reg_attr: Optional[AttrRecorder] = None) -> bool:
-        if con.operand in (Op.DISTINCT_HOSTS.value, Op.DISTINCT_PROPERTY.value):
-            # Handled by dedicated host-side iterators (feasible.go:505,604).
+        if con.operand == Op.DISTINCT_HOSTS.value:
+            # ``request.distinct_hosts``; the host masks the nodes the job
+            # already holds (feasible.go:505).
             escaped.append(self._escape(con))
             return True
         enc = self._encode_predicate(

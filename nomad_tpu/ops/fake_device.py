@@ -199,6 +199,33 @@ def feasibility_mask(arrays, req: SchedRequest,
     return mask
 
 
+def _distinct_property_columns(arrays, req: SchedRequest):
+    """((DP,) bool active, (DP, N) value id of every node): twin of
+    kernels._distinct_property_columns at the request's full width."""
+    slot = np.asarray(req.dp_slot)
+    return slot >= 0, arrays.attr_hash[:, np.maximum(slot, 0)].T
+
+
+def distinct_property_counts(arrays, req: SchedRequest) -> np.ndarray:
+    """(DP, N) f32: twin of kernels.distinct_property_counts."""
+    active, col = _distinct_property_columns(arrays, req)
+    value_hash = np.asarray(req.dp_value_hash)
+    counts = np.asarray(req.dp_count, np.float32)
+    out = np.zeros(col.shape, np.float32)
+    for d in np.flatnonzero(active):
+        for v in np.flatnonzero(value_hash[d]):
+            out[d] += np.where(col[d] == value_hash[d, v], counts[d, v], 0.0)
+    return out
+
+
+def distinct_property_mask(arrays, req: SchedRequest, dp_cnt) -> np.ndarray:
+    """(N,) bool: twin of kernels.distinct_property_mask."""
+    active, col = _distinct_property_columns(arrays, req)
+    limit = np.asarray(req.dp_limit, np.float32)[:, None]
+    full = (col == 0) | (dp_cnt >= limit)
+    return ~np.any(active[:, None] & full, axis=0)
+
+
 # ---------------------------------------------------------------------------
 # Scoring
 # ---------------------------------------------------------------------------
@@ -529,7 +556,13 @@ class _LaneScan:
     single-row rescore runs the same float32 expressions on 1-element
     slices, so the outputs are identical to a full recompute).  Spread
     stanzas shift every node's score when a placement bumps a value
-    count: full recompute per step."""
+    count, and a distinct_property limit takes every node that shares the
+    picked node's value out at once: full recompute per step.
+
+    With a distinct_property slot the rows are ranked as the limit's
+    absence would rank them and the limit is applied after, so that
+    ``dp_blocked_best`` (the best score among the nodes it alone excluded)
+    is there for the ``moved`` flag (kernels._commit_step)."""
 
     def __init__(self, arrays, req: SchedRequest, used0, tg_count,
                  spread_counts, penalty_mask, class_elig, host_mask):
@@ -541,6 +574,10 @@ class _LaneScan:
         self.tg = np.array(tg_count, np.int32, copy=True)
         self.spreads = bool((np.asarray(req.s_slot) >= 0).any())
         self.feas = sp.feas & ~(self.tg > 0) if sp.distinct else sp.feas
+        self.dp = bool((np.asarray(req.dp_slot) >= 0).any())
+        if self.dp:
+            self.dp_cnt = distinct_property_counts(arrays, req)
+            self.feas_open = self.feas
         if self.spreads:
             self.s_hash = np.array(req.s_value_hash, copy=True)
             self.s_counts = np.array(spread_counts, np.float32, copy=True)
@@ -552,13 +589,30 @@ class _LaneScan:
         if self.spreads:
             self.req_step = self.req._replace(s_value_hash=self.s_hash)
             spr = spread_score(self.arrays, self.req_step, self.s_counts)
+        open_ = self.feas_open if self.dp else self.feas
         # Arrays the single-row rescore writes into: own copies.
         self.rk = rk = _Ranked(*(
             np.array(x) for x in _rank_rows(
-                self.arrays, self.req_step, self.sp, slice(None), self.feas,
+                self.arrays, self.req_step, self.sp, slice(None), open_,
                 self.used, self.tg, *spr,
             )
         ))
+        self.dp_blocked_best = np.float32(NEG_INF)
+        if self.dp:
+            dp_ok = distinct_property_mask(self.arrays, self.req, self.dp_cnt)
+            self.feas = open_ & dp_ok
+            gate_pre = (
+                not np.any(self.feas & rk.fits)
+                and np.any(self.feas & rk.can_pre)
+            )
+            blocked = open_ & ~dp_ok
+            best = rk.final_fit[blocked]
+            if gate_pre:
+                best = np.maximum(best, rk.final_pre[blocked])
+            if best.size:
+                self.dp_blocked_best = best.max()
+            rk.final_fit[~dp_ok] = NEG_INF
+            rk.final_pre[~dp_ok] = NEG_INF
         feas = self.feas
         self.n_eval = int(np.sum(feas))
         self.n_filt = int(np.sum(~feas & self.arrays.eligible))
@@ -588,6 +642,11 @@ class _LaneScan:
         open_ = self.n_eval - self.n_fit
         return open_ - self.n_pre if self.preempting else open_
 
+    def moved(self, row: int) -> bool:
+        """A node a distinct_property limit alone excluded scored higher
+        than ``row`` (read before ``commit``)."""
+        return bool(self.dp and self.dp_blocked_best > self.final[row])
+
     def failed_row(self) -> tuple:
         """The packed columns of a step in which no node can take the
         request (the carry stays as it is, so every later step reads the
@@ -612,9 +671,14 @@ class _LaneScan:
         self.used[row] += sp.ask
         self.tg[row] += 1
         if sp.distinct:
-            if self.feas is sp.feas:
-                self.feas = self.feas.copy()
-            self.feas[row] = False
+            which = "feas_open" if self.dp else "feas"
+            if getattr(self, which) is sp.feas:
+                setattr(self, which, sp.feas.copy())
+            getattr(self, which)[row] = False
+        if self.dp:
+            active, col = _distinct_property_columns(arrays, self.req)
+            v = col[:, row:row + 1]
+            self.dp_cnt += (col == v) & (v != 0) & active[:, None]
         if self.spreads:
             nvalues = arrays.attr_hash[
                 row, np.maximum(np.asarray(self.req_step.s_slot), 0)
@@ -622,6 +686,7 @@ class _LaneScan:
             _apply_spread_values(
                 self.req_step, self.s_hash, self.s_counts, nvalues
             )
+        if self.spreads or self.dp:
             self._rescore_all()
             return out
 
@@ -715,6 +780,7 @@ def place_task_group(arrays, req: SchedRequest, used0, tg_count,
 
 # Packed-output constants of the fused megakernel, mirrored from
 # ops/kernels.py (this module stays importable without JAX).
+PACKED_FILTERED = 5  # + 0.5: a distinct_property limit moved the pick
 FUSED_PACKED_VERIFIED = 7
 FUSED_PACKED_WIDTH = 8
 
@@ -827,7 +893,9 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
             if not masked[row] > NEG_INF / 2:
                 row = own
             repicked[i, step] = row != own
+            moved = lane.moved(row)
             out[i, step, :7] = lane.commit(row)
+            out[i, step, PACKED_FILTERED] += 0.5 * moved
             claims[row] += ask
     # Sequential AllocsFit re-verify against the cumulative image.
     out[:, :, FUSED_PACKED_VERIFIED] = -1.0
@@ -900,6 +968,9 @@ def system_feasible(arrays, used0, req: SchedRequest, class_elig,
                     host_mask) -> np.ndarray:
     """Twin of kernels.system_feasible — stacked (2, N) [mask, fits]."""
     mask = feasibility_mask(arrays, req, class_elig, host_mask)
+    mask &= distinct_property_mask(
+        arrays, req, distinct_property_counts(arrays, req)
+    )
     fits, _, _ = fit_and_binpack(arrays, used0, req)
     return np.stack([mask, fits])
 

@@ -34,6 +34,7 @@ from ..state.matrix import PRIORITY_BUCKETS
 from .encode import (
     MAX_AFFINITIES,
     MAX_CONSTRAINTS,
+    MAX_DISTINCT_PROPS,
     MAX_SPREADS,
     OP_EQ,
     OP_GT,
@@ -76,7 +77,7 @@ class Features(NamedTuple):
     slots still execute (each inactive predicate is two table gathers
     plus the full decode over all N nodes). ``Features`` makes the
     *occupancy* static: widths are pow2-bucketed so the jit cache stays
-    bounded (≤ 6·5·3·2·2 variants, in practice a handful), and a
+    bounded (≤ 6·5·3·2·2·3 variants, in practice a handful), and a
     dispatcher that ratchets via :meth:`widen` compiles each variant at
     most once per process.
 
@@ -89,6 +90,7 @@ class Features(NamedTuple):
     s_width: int = MAX_SPREADS  # active spread stanzas (0..2)
     preempt: bool = True  # any eval has preemption enabled
     ports: bool = True  # any eval asks for static/dynamic ports
+    dp_width: int = MAX_DISTINCT_PROPS  # distinct_property slots (0..2)
 
     def widen(self, other: "Features") -> "Features":
         """Monotone union — the dispatcher's recompile ratchet."""
@@ -98,10 +100,30 @@ class Features(NamedTuple):
             s_width=max(self.s_width, other.s_width),
             preempt=self.preempt or other.preempt,
             ports=self.ports or other.ports,
+            dp_width=max(self.dp_width, other.dp_width),
         )
 
 
 FULL_FEATURES = Features()
+
+# The request's distinct_property operands, its last fields.  A launch at
+# ``dp_width`` 0 leaves them on the host (None in the request it hands the
+# placement program): a device buffer costs the launching thread by the
+# buffer, not by the byte (PERF.md section 6, PR 33), and four more a
+# launch showed on four chips (PR 44).
+DP_FIELDS = SchedRequest._fields.index("dp_slot")
+assert SchedRequest._fields[DP_FIELDS:] == (
+    "dp_slot", "dp_limit", "dp_value_hash", "dp_count"
+)
+
+
+def device_request(fields, dp_width: int) -> SchedRequest:
+    """The request a launch hands the placement program from its unpacked
+    ``fields`` (all of them, or all but the last four at ``dp_width`` 0)."""
+    fields = list(fields)[: None if dp_width else DP_FIELDS]
+    return SchedRequest(
+        *fields, *[None] * (len(SchedRequest._fields) - len(fields))
+    )
 
 
 def _slot_width(slots, max_width: int) -> int:
@@ -130,6 +152,7 @@ def features_of(reqs: SchedRequest) -> Features:
             np.any(np.asarray(reqs.p_static) >= 0)
             or np.any(np.asarray(reqs.p_dyn) > 0)
         ),
+        dp_width=_slot_width(reqs.dp_slot, MAX_DISTINCT_PROPS),
     )
 
 
@@ -287,6 +310,55 @@ def feasibility_mask(arrays, req: SchedRequest,
     return mask
 
 
+def _distinct_property_columns(arrays, req: SchedRequest, dp_width: int):
+    """((W,) bool active, (W, N) i32 value id of every node) of the
+    request's first ``dp_width`` distinct_property slots: the attribute's
+    hash column, 0 where a node lacks it."""
+    slot = req.dp_slot[:dp_width]
+    return slot >= 0, arrays.attr_hash.T[jnp.maximum(slot, 0)]
+
+
+def distinct_property_counts(arrays, req: SchedRequest, dp_width: int):
+    """(W, N) f32 — the scan's first carry of the distinct_property stage:
+    for every node, the allocs the job already holds on nodes that share
+    its value of the property (``req.dp_value_hash`` / ``req.dp_count``,
+    seeded by the stack from the live and proposed allocations)."""
+    _, col = _distinct_property_columns(arrays, req, dp_width)
+    value_hash = req.dp_value_hash[:dp_width]  # (W, V)
+    vmatch = (col[:, :, None] == value_hash[:, None, :]) & (
+        value_hash[:, None, :] != 0
+    )  # (W, N, V), at most one hit a node: a masked sum, as spread_score
+    return jnp.sum(
+        jnp.where(vmatch, req.dp_count[:dp_width, None, :], 0.0), axis=2
+    )
+
+
+def distinct_property_mask(arrays, req: SchedRequest, dp_cnt, dp_width: int):
+    """(N,) bool — the nodes every distinct_property limit still admits
+    (DistinctPropertyIterator, feasible.go:604-700): the node has the
+    property, and its value holds fewer allocs of the job than the limit.
+    ``dp_cnt`` (W, N) changes from pick to pick (``distinct_property_pick``):
+    this is the feasibility term that is NOT loop-invariant."""
+    active, col = _distinct_property_columns(arrays, req, dp_width)
+    full = (col == 0) | (dp_cnt >= req.dp_limit[:dp_width, None])
+    return ~jnp.any(active[:, None] & full, axis=0)
+
+
+def distinct_property_values_at(arrays, req: SchedRequest, row):
+    """Per-slot property value of node ``row`` ((DP,) i32), split out like
+    ``spread_values_at`` for the node-sharded step."""
+    return arrays.attr_hash[row, jnp.maximum(req.dp_slot, 0)]
+
+
+def distinct_property_pick(arrays, req: SchedRequest, dp_cnt, values,
+                           dp_width: int):
+    """``dp_cnt`` after a pick on a node whose property values are
+    ``values`` ((DP,) i32): one more alloc on every node that shares one."""
+    active, col = _distinct_property_columns(arrays, req, dp_width)
+    v = values[:dp_width, None]
+    return dp_cnt + ((col == v) & (v != 0) & active[:, None])
+
+
 @jax.jit
 def system_feasible(arrays, used0, req: SchedRequest, class_elig, host_mask):
     """Fused system-scheduler pass: feasibility ∧ fit for every node in one
@@ -297,6 +369,13 @@ def system_feasible(arrays, used0, req: SchedRequest, class_elig, host_mask):
     single device→host fetch (each separate fetch is its own synchronous
     round-trip)."""
     mask = feasibility_mask(arrays, req, class_elig, host_mask)
+    # A system job places one alloc a node: the limit as the seeds alone
+    # read it (the host re-checks what it takes, system.py).
+    mask &= distinct_property_mask(
+        arrays, req,
+        distinct_property_counts(arrays, req, MAX_DISTINCT_PROPS),
+        MAX_DISTINCT_PROPS,
+    )
     fits, _, _ = fit_and_binpack(arrays, used0, req)
     return jnp.stack([mask, fits])
 
@@ -533,6 +612,9 @@ class ScoreResult(NamedTuple):
     # (N,) f32: how many terms ``final`` is the mean of on a
     # ``needs_preempt`` node, 0.0 elsewhere; None with preemption off.
     pre_terms: Optional[jnp.ndarray] = None
+    # () f32: the best ``final`` among the nodes a distinct_property limit
+    # alone excluded (NEG_INF where none); None at ``dp_width`` 0.
+    dp_blocked_best: Optional[jnp.ndarray] = None
 
 
 def score_nodes(
@@ -546,6 +628,7 @@ def score_nodes(
     host_mask,
     features: Features = FULL_FEATURES,
     node_axis: Optional[str] = None,
+    dp_cnt=None,
 ) -> ScoreResult:
     """The full ranking pipeline as one fused program (GenericStack.Select,
     stack.go:117-179, minus the sampling the TPU design makes unnecessary).
@@ -583,6 +666,21 @@ def score_nodes(
     # distinct_hosts: one proposed alloc of this job+TG per node, enforced
     # in-scan via tg_count so multi-placement batches can't stack a node.
     feas &= ~(req.distinct_hosts & (tg_count > 0))
+    # distinct_property: at most ``limit`` allocs of the job a value, by the
+    # per-node counts the scan carries (``dp_cnt`` (W, N); None = the seeds).
+    dp_ok = None
+    if features.dp_width:
+        with jax.named_scope("feasibility"), jax.named_scope(
+            "distinct_property"
+        ):
+            if dp_cnt is None:
+                dp_cnt = distinct_property_counts(
+                    arrays, req, features.dp_width
+                )
+            dp_ok = distinct_property_mask(
+                arrays, req, dp_cnt, features.dp_width
+            )
+            feas_open, feas = feas, feas & dp_ok
     fits, binpack, exhausted = fit_and_binpack(arrays, used, req)
 
     if features.preempt:
@@ -624,6 +722,14 @@ def score_nodes(
         + needs_preempt.astype(jnp.float32)
     )
     final = total / count
+    dp_blocked_best = None
+    if dp_ok is not None:
+        with jax.named_scope("feasibility"), jax.named_scope(
+            "distinct_property"
+        ):
+            dp_blocked_best = jnp.max(
+                jnp.where(feas_open & ~dp_ok & fits_all, final, NEG_INF)
+            )
     final = jnp.where(feas & fits_all, final, NEG_INF)
     pre_terms = None
     if features.preempt:
@@ -637,6 +743,7 @@ def score_nodes(
         binpack=binpack,
         exhausted_dim=exhausted,
         pre_terms=pre_terms,
+        dp_blocked_best=dp_blocked_best,
     )
 
 
@@ -718,12 +825,12 @@ def _score_step(arrays, req: SchedRequest, carry, penalty_mask, class_elig,
                 host_mask, features: Features):
     """One placement step's scores from the scan's carry: (the request as
     this step reads it, its ``ScoreResult``, the three node counts)."""
-    used, tg_cnt, s_hash, s_counts = carry
+    used, tg_cnt, s_hash, s_counts, dp_cnt = carry
     req_step = req._replace(s_value_hash=s_hash)
     with jax.named_scope("score"):
         res = score_nodes(
             arrays, used, tg_cnt, s_counts, penalty_mask, req_step,
-            class_elig, host_mask, features,
+            class_elig, host_mask, features, dp_cnt=dp_cnt,
         )
     with jax.named_scope("pick"):
         counts = (
@@ -734,11 +841,28 @@ def _score_step(arrays, req: SchedRequest, carry, penalty_mask, class_elig,
     return req_step, res, counts
 
 
+def scan_carry(arrays, req: SchedRequest, used0, tg_count, spread_counts,
+               features: Features):
+    """A request's carry at the scan's first step: proposed usage, the
+    job's allocs per node, the spread stage's value table and counts, the
+    distinct_property stage's counts per node ((dp_width, N))."""
+    if not features.dp_width:
+        # No lane carries the stage: nothing of it is read (the launch may
+        # not even hand its operands over: ``DP_FIELDS``).
+        dp_cnt = jnp.zeros((0,) + tg_count.shape, jnp.float32)
+        return used0, tg_count, req.s_value_hash, spread_counts, dp_cnt
+    with jax.named_scope("feasibility"), jax.named_scope("distinct_property"):
+        dp_cnt = distinct_property_counts(arrays, req, features.dp_width)
+    return used0, tg_count, req.s_value_hash, spread_counts, dp_cnt
+
+
 def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
-                 counts, row, ok):
+                 counts, row, ok, dp_width: int = 0):
     """Charge a step's pick (``row``; nothing where ``ok`` is false) to the
-    scan's carry; returns (carry, the step's seven output columns)."""
-    used, tg_cnt, s_hash, s_counts = carry
+    scan's carry; returns (carry, the step's seven output columns, and at
+    ``dp_width`` > 0 an eighth: a node a distinct_property limit alone
+    excluded scored higher than the node taken)."""
+    used, tg_cnt, s_hash, s_counts, dp_cnt = carry
     with jax.named_scope("update"):
         safe_row = jnp.maximum(row, 0)
         used2 = jnp.where(ok, used.at[safe_row].add(req_step.ask), used)
@@ -748,6 +872,12 @@ def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
         )
         s_hash2 = jnp.where(ok, new_hash, s_hash)
         s_counts2 = jnp.where(ok, new_counts, s_counts)
+        if dp_width:
+            dp_cnt = jnp.where(ok, distinct_property_pick(
+                arrays, req_step, dp_cnt,
+                distinct_property_values_at(arrays, req_step, safe_row),
+                dp_width,
+            ), dp_cnt)
 
     preempted = ok & res.needs_preempt[safe_row]
     if res.pre_terms is not None:
@@ -758,7 +888,9 @@ def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
         jnp.where(ok, res.binpack[safe_row], 0.0),
         preempted,
     ) + counts
-    return (used2, tg2, s_hash2, s_counts2), out
+    if dp_width:
+        out += (ok & (res.dp_blocked_best > res.final[safe_row]),)
+    return (used2, tg2, s_hash2, s_counts2, dp_cnt), out
 
 
 def _place_scan(
@@ -787,14 +919,16 @@ def _place_scan(
             row = jnp.argmax(res.final).astype(jnp.int32)
             ok = res.final[row] > NEG_INF / 2
             row = jnp.where(ok, row, -1)
-        return _commit_step(arrays, req_step, carry, res, counts, row, ok)
+        return _commit_step(
+            arrays, req_step, carry, res, counts, row, ok, features.dp_width
+        )
 
-    init = (used0, tg_count, req.s_value_hash, spread_counts)
+    init = scan_carry(arrays, req, used0, tg_count, spread_counts, features)
     with jax.named_scope("place_scan"):
-        (used_after, tg_after, _, _), outs = lax.scan(
+        (used_after, tg_after, *_), outs = lax.scan(
             step, init, None, length=n_placements
         )
-    rows, scores, binpack, preempted, n_eval, n_filt, n_exh = outs
+    rows, scores, binpack, preempted, n_eval, n_filt, n_exh = outs[:7]
     return PlacementResult(
         rows=rows,
         scores=scores,
@@ -850,6 +984,9 @@ PACKED_BINPACK = 2
 # (``score_nodes``): what the host needs to record the exact score.
 PACKED_PREEMPT = 3
 PACKED_EVALUATED = 4
+# A whole number of nodes; + 0.5 where a node a distinct_property limit
+# alone excluded scored higher than the node taken (``pack_fused_lanes``):
+# ``astype(int)`` reads the count, ``% 1`` the flag.
 PACKED_FILTERED = 5
 PACKED_EXHAUSTED = 6
 PACKED_WIDTH = 7
@@ -1007,7 +1144,7 @@ def resolved_pick(final, room, own):
 @jax.named_scope("pack")
 def pack_fused_lanes(
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
-    repicked, live
+    repicked, live, dp_moved=None
 ):
     """Stack per-lane placement outputs into the fused (B, P, 8) layout with
     dead-lane masking: row/-1, VERIFIED/-1.0, zeros elsewhere.  VERIFIED
@@ -1015,8 +1152,16 @@ def pack_fused_lanes(
     the single-device fused kernel and the shard_map local body
     (parallel/sharding.py) so the two paths cannot drift column-wise —
     tests/test_parallel.py asserts bitwise parity across them.
+
+    ``dp_moved`` (``Features.dp_width`` > 0): the FILTERED column, a whole
+    number of nodes, carries + 0.5 where a distinct_property limit moved the
+    pick (``PACKED_FILTERED``); all false it is bit for bit the column
+    without it.
     """
     lv = live[:, None]
+    filtered = jnp.where(lv, n_filt, 0).astype(jnp.float32)
+    if dp_moved is not None:
+        filtered = filtered + jnp.where(lv & dp_moved, 0.5, 0.0)
     fits = verified.astype(bool)
     vcol = jnp.where(fits, jnp.where(repicked, 2.0, 1.0), 0.0)
     vcol = jnp.where(lv, vcol, -1.0)
@@ -1027,7 +1172,7 @@ def pack_fused_lanes(
             jnp.where(lv, binpack, 0.0),
             jnp.where(lv, preempted, False).astype(jnp.float32),
             jnp.where(lv, n_eval, 0).astype(jnp.float32),
-            jnp.where(lv, n_filt, 0).astype(jnp.float32),
+            filtered,
             jnp.where(lv, n_exh, 0).astype(jnp.float32),
             vcol,
         ],
@@ -1036,15 +1181,17 @@ def pack_fused_lanes(
 
 
 def inert_lane_outputs(lanes: int, n_placements: int,
-                       preempt: bool = False) -> tuple:
+                       preempt: bool = False, dp: bool = False) -> tuple:
     """The stacked outputs of a launch in which no step ran, step-major
     ((P, B): a step's outputs of all lanes land at one index): row -1,
     zero scores, flags and node counts (what a failed-or-never-asked
     placement reads, and what the numpy twin fills its tail rows with),
     and the re-pick flag as an eighth buffer.  With ``preempt`` (the
     launch's ``Features``) the PREEMPT buffer holds a count, not a flag
-    (``score_nodes``)."""
+    (``score_nodes``); with ``dp`` (``Features.dp_width`` > 0) a ninth
+    buffer holds the distinct_property stage's flag (``_commit_step``)."""
     shape = (n_placements, lanes)
+    moved = (jnp.zeros(shape, bool),) if dp else ()
     return (
         jnp.full(shape, -1, jnp.int32),
         jnp.zeros(shape, jnp.float32),
@@ -1054,7 +1201,7 @@ def inert_lane_outputs(lanes: int, n_placements: int,
         jnp.zeros(shape, jnp.int32),
         jnp.zeros(shape, jnp.int32),
         jnp.zeros(shape, bool),
-    )
+    ) + moved
 
 
 def _fused_place_batch_impl(
@@ -1189,7 +1336,10 @@ def _fused_place_batch_impl(
 
     def commit(carry, req_step, res, counts, row, active):
         counts = tuple(jnp.where(active, c, 0) for c in counts)
-        return _commit_step(arrays, req_step, carry, res, counts, row, row >= 0)
+        return _commit_step(
+            arrays, req_step, carry, res, counts, row, row >= 0,
+            features.dp_width,
+        )
 
     def step(state, i):
         carry, claims = state
@@ -1230,20 +1380,27 @@ def _fused_place_batch_impl(
         carry, out = jax.vmap(commit)(
             carry, req_step, res, counts, rows, active
         )
-        return (carry, claims), out + ((rows >= 0) & (rows != own),)
+        return (carry, claims), (
+            out[:7] + ((rows >= 0) & (rows != own),) + out[7:]
+        )
 
-    init = (
-        jax.vmap(lane_used0)(delta_rows, delta_vals),
-        tg_counts, reqs.s_value_hash, spread_counts,
-    )
+    init = jax.vmap(
+        lambda req, drows, dvals, tg, sc: scan_carry(
+            arrays, req, lane_used0(drows, dvals), tg, sc, features
+        )
+    )(reqs, delta_rows, delta_vals, tg_counts, spread_counts)
     with jax.named_scope("place_scan"):
         _, outs = scan_steps(
             step, (init, claims_image(claimed, delta_rows, delta_vals, live)),
-            inert_lane_outputs(lanes, n_placements, features.preempt), trip,
+            inert_lane_outputs(
+                lanes, n_placements, features.preempt, bool(features.dp_width)
+            ),
+            trip,
         )
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, repicked = (
-        o.T for o in outs
+        o.T for o in outs[:8]
     )  # each (B, P)
+    dp_moved = outs[8].T if features.dp_width else None
 
     # Sequential cross-lane AllocsFit: a loop over lanes carrying the
     # cumulative proposed usage. Each lane first applies its own in-flight
@@ -1282,7 +1439,7 @@ def _fused_place_batch_impl(
 
     packed = pack_fused_lanes(
         rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
-        repicked, live,
+        repicked, live, dp_moved,
     )
     if chain is None:
         return packed

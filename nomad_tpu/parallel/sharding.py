@@ -44,9 +44,12 @@ from ..ops.kernels import (
     chain_flags,
     chained_carry,
     claims_block,
+    distinct_property_pick,
+    distinct_property_values_at,
     fused_trip_counts,
     inert_lane_outputs,
     pack_fused_lanes,
+    scan_carry,
     scan_steps,
     score_nodes,
     spread_values_at,
@@ -174,6 +177,10 @@ _REQS_SPEC = SchedRequest(
     distinct_hosts=P("batch"),
     p_static=P("batch", None),
     p_dyn=P("batch"),
+    dp_slot=P("batch", None),
+    dp_limit=P("batch", None),
+    dp_value_hash=P("batch", None, None),
+    dp_count=P("batch", None, None),
 )
 
 
@@ -258,6 +265,11 @@ def _fused_place_batch_local(
     verdicts with a single ``pmin`` over the node axis — each row's owner
     alone decides.
 
+    The distinct_property stage (``Features.dp_width`` > 0) carries its
+    counts per node on each shard's slice; the picked node's property
+    values ride the spread stage's broadcast, and "a node the limit alone
+    excluded scored higher" is one more ``pmax`` over 'node' a step.
+
     The in-flight claims overlay (``overlay``: global rows, split over
     'batch' like the deltas; None = empty) is gathered with them and each
     node shard adds the rows it holds to its slice of the usage under the
@@ -328,12 +340,12 @@ def _fused_place_batch_local(
         )
 
     def score(carry, pen, req, ce, hm):
-        u, tg_cnt, s_hash, s_counts = carry
+        u, tg_cnt, s_hash, s_counts, dp_cnt = carry
         req_step = req._replace(s_value_hash=s_hash)
         with jax.named_scope("score"):
             res = score_nodes(
                 arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
-                features=features, node_axis="node",
+                features=features, node_axis="node", dp_cnt=dp_cnt,
             )
         # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
         # then a cross-shard reduce of the implicit (shards, k) table —
@@ -366,23 +378,33 @@ def _fused_place_batch_local(
         return req_step, res, counts, own
 
     def commit(carry, req_step, res, counts, grow, active):
-        u, tg_cnt, s_hash, s_counts = carry
+        u, tg_cnt, s_hash, s_counts, dp_cnt = carry
         ok = grow >= 0
         owner, lwin = local_rows(grow)
         with jax.named_scope("update"):
             u2 = jnp.where(owner, u.at[lwin].add(req_step.ask), u)
             tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
 
-            nvals = jnp.where(
-                owner, spread_values_at(arrays, req_step, lwin), 0
-            )
+            # The picked node's spread and property values, from its owner.
+            nvals = spread_values_at(arrays, req_step, lwin)
+            if features.dp_width:
+                nvals = jnp.concatenate([
+                    nvals, distinct_property_values_at(arrays, req_step, lwin)
+                ])
+            nvals = jnp.where(owner, nvals, 0)
             with jax.named_scope("broadcast"):
                 nvals = jax.lax.psum(nvals, "node")
+            n_spreads = req_step.s_slot.shape[0]
             new_hash, new_counts = apply_spread_values(
-                s_counts, req_step, nvals
+                s_counts, req_step, nvals[:n_spreads]
             )
             s_hash2 = jnp.where(ok, new_hash, s_hash)
             s_counts2 = jnp.where(ok, new_counts, s_counts)
+            if features.dp_width:
+                dp_cnt = jnp.where(ok, distinct_property_pick(
+                    arrays, req_step, dp_cnt, nvals[n_spreads:],
+                    features.dp_width,
+                ), dp_cnt)
 
             own_score = jnp.where(
                 owner, jnp.stack([res.final[lwin], res.binpack[lwin]]), 0.0
@@ -403,7 +425,11 @@ def _fused_place_batch_local(
         out = (
             grow, final, binp, pre,
         ) + tuple(jnp.where(active, c, 0) for c in counts)
-        return (u2, tg2, s_hash2, s_counts2), out
+        if features.dp_width:
+            with jax.named_scope("broadcast"):
+                blocked = jax.lax.pmax(res.dp_blocked_best, "node")
+            out += (ok & (blocked > final),)
+        return (u2, tg2, s_hash2, s_counts2, dp_cnt), out
 
     def step(state, i):
         carry, claims = state
@@ -455,14 +481,16 @@ def _fused_place_batch_local(
         carry, out = jax.vmap(commit)(
             carry, req_step, res, counts, rows, active
         )
-        return (carry, claims), out + ((rows >= 0) & (rows != own), g_rows)
+        return (carry, claims), (
+            out[:7] + ((rows >= 0) & (rows != own), g_rows) + out[7:]
+        )
 
-    init = (
-        jax.vmap(
-            lambda drows, dvals: add_deltas(used, drows, dvals, drows >= 0)
-        )(delta_rows, delta_vals),
-        tg_counts, reqs.s_value_hash, spread_counts,
-    )
+    init = jax.vmap(
+        lambda req, drows, dvals, tg, sc: scan_carry(
+            arrays, req, add_deltas(used, drows, dvals, drows >= 0), tg, sc,
+            features,
+        )
+    )(reqs, delta_rows, delta_vals, tg_counts, spread_counts)
     # Shared usage as the claims and the verify see it; the scores do not.
     claimed = vary(used)
     with jax.named_scope("overlay"):
@@ -474,16 +502,20 @@ def _fused_place_batch_local(
             )
             claimed = add_deltas(claimed, c_rows, c_vals, c_rows >= 0)
     claims0 = add_deltas(claimed, g_drows, g_dvals, g_live[:, None])
+    inert = inert_lane_outputs(
+        b_local, n_placements, features.preempt, bool(features.dp_width)
+    )
     bufs = tuple(
         vary(o)
-        for o in inert_lane_outputs(b_local, n_placements, features.preempt)
-        + (jnp.full((n_placements, lanes), -1, jnp.int32),)
+        for o in inert[:8]
+        + (jnp.full((n_placements, lanes), -1, jnp.int32),) + inert[8:]
     )
     with jax.named_scope("place_scan"):
         _, outs = scan_steps(step, (init, claims0), bufs, trip)
     rows, scores, binpack, pre, ne, nf, nx, repicked, g_rows = (
-        o.T for o in outs
+        o.T for o in outs[:9]
     )  # each (b_local, P); g_rows (B, P): every lane's rows on every shard
+    dp_moved = outs[9].T if features.dp_width else None
 
     # Cross-lane AllocsFit re-verify, sharded: each node shard replays all
     # B lanes in resolve order against its local (n_local, 3) usage slice;
@@ -522,7 +554,8 @@ def _fused_place_batch_local(
         verified, b_first, b_local, axis=0
     )  # (b_local, P)
     packed = pack_fused_lanes(
-        rows, scores, binpack, pre, ne, nf, nx, v_local, repicked, live
+        rows, scores, binpack, pre, ne, nf, nx, v_local, repicked, live,
+        dp_moved,
     )
     if chain is None:
         return packed

@@ -288,6 +288,10 @@ class DeviceCoalescer:
         self.scan_steps_total = 0
         self.verify_conflicts = 0
         self.lane_repicks = 0
+        # Lanes launched with a distinct_property slot live, and the picks
+        # such a limit moved off a better-scoring node (PACKED_FILTERED).
+        self.distinct_property_lanes = 0
+        self.distinct_property_blocked = 0
         self.picks_placed = 0
         self.preempt_picks = 0
         # The in-flight claims overlay (scheduler/claims.py): the ledger,
@@ -1135,6 +1139,9 @@ class DeviceCoalescer:
             self._chain(batch, version, carry)
             self.fused_dispatches += 1
             self.fused_lanes += len(batch)
+            self.distinct_property_lanes += sum(
+                bool((p.request.dp_slot >= 0).any()) for p in batch
+            )
             self.scan_steps_total += max(live_counts)
             self.operand_bytes_total += sum(
                 p.host_mask.nbytes + p.tg_count.nbytes + p.penalty.nbytes
@@ -1222,12 +1229,19 @@ class DeviceCoalescer:
         feats = self._ratchet_features(slab, k)
         self.fused_dispatches += 1
         self.fused_lanes += k
+        self.distinct_property_lanes += int(
+            (slab.live_view(k).dp_slot >= 0).any(axis=1).sum()
+        )
         self.scan_steps_total += int(ls[:k].max())
         if self.feature_recompiles != variants:
             state = "coalescer.trace_variant"
             args["features"] = str(tuple(feats))
         unpack = kernels.unpack_lanes if n_shards == 1 else self._sharded_unpack
-        layouts = slab.layout, st["layout"]
+        # At ``dp_width`` 0 the distinct_property operands stay on the host.
+        layouts = (
+            slab.layout if feats.dp_width
+            else slab.layout[: kernels.DP_FIELDS]
+        ), st["layout"]
         if self._unpack_variant != (unpack, layouts):
             self._unpack_variant = unpack, layouts
             state = "coalescer.trace_variant"
@@ -1253,7 +1267,7 @@ class DeviceCoalescer:
             reqs, (ce, sc, dr, dv, ls, orows, ovals, cv, flags) = unpack(
                 slab.pack, st["pack"], layouts=layouts
             )
-            reqs = SchedRequest(*reqs)
+            reqs = kernels.device_request(reqs, feats.dp_width)
             chain = carry, flags, cv
             if n_shards > 1:
                 packed, carry = self._sharded_fused_fn(
@@ -1423,6 +1437,9 @@ class DeviceCoalescer:
                 pcol = row[:, kernels.PACKED_PREEMPT]
                 self.picks_placed += int(placed.sum())
                 self.preempt_picks += int((placed & (pcol != 0.0)).sum())
+                self.distinct_property_blocked += int(
+                    (placed & (row[:, kernels.PACKED_FILTERED] % 1 != 0)).sum()
+                )
                 if p.eval_id:
                     claims.append(
                         (p.eval_id,) + self._lane_claims(p, rows_i, pcol)

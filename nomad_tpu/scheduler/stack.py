@@ -26,8 +26,10 @@ import numpy as np
 from .. import trace
 from ..ops.encode import (
     CompiledTaskGroup,
-    MAX_SPREAD_VALUES,
+    MAX_DISTINCT_VALUES,
     RequestEncoder,
+    SchedRequest,
+    distinct_property_limit,
     pow2_bucket as _pow2_bucket,
 )
 from ..ops import fake_device, kernels
@@ -41,7 +43,7 @@ from ..structs.types import (
     TaskGroup,
 )
 from .context import EvalContext
-from .feasible_host import check_constraint_host, check_host_volumes
+from .feasible_host import attr_name, check_constraint_host
 from .preemption import preempting_scores, select_victims
 
 # Dynamic port range (reference: structs/network.go MinDynamicPort/MaxDynamicPort).
@@ -88,6 +90,13 @@ def _dense_used0(arrays, deltas: Dict[int, np.ndarray]):
 def _full_mask(n: int, host_mask: Optional[np.ndarray]) -> np.ndarray:
     """host_mask with the all-pass default materialized."""
     return host_mask if host_mask is not None else np.ones((n,), bool)
+
+
+def _and_mask(a: Optional[np.ndarray], b: Optional[np.ndarray]):
+    """The conjunction of two optional node masks (None = all pass)."""
+    if a is None or b is None:
+        return b if a is None else a
+    return a & b
 
 
 def _pad_width(arr: np.ndarray, n: int, fill) -> np.ndarray:
@@ -164,11 +173,12 @@ class GenericStack:
         in the same plan)."""
         self.replaced_allocs = set(alloc_ids)
 
-    def _record_eligibility(self, class_elig: np.ndarray, host_mask) -> None:
+    def _record_eligibility(self, class_elig: np.ndarray, host_mask,
+                            compiled: CompiledTaskGroup) -> None:
         for key, cid in self.matrix.class_ids.items():
             if cid < len(class_elig):
                 self.class_eligibility[key] = bool(class_elig[cid])
-        if host_mask is not None:
+        if host_mask is not None or compiled.distinct_props:
             # Per-node (class-unhashable) checks were in play — the eval
             # escapes class caching and must retry on any capacity change.
             self.escaped_computed_class = True
@@ -281,12 +291,14 @@ class GenericStack:
         return counts
 
     def _class_eligibility(self, compiled: CompiledTaskGroup) -> np.ndarray:
-        """Evaluate escaped non-unique constraints once per computed class
-        (the ComputedClass cache, feasible.go:1029). Returns a padded bool
-        vector indexed by class id."""
+        """Escaped non-unique constraints by computed class (the
+        ComputedClass cache, feasible.go:1029): a padded bool vector indexed
+        by class id.  Each constraint is evaluated once per distinct value
+        of its attribute's column and broadcast (``HostFeasibility``); the
+        representatives are visited one by one only where an attribute has
+        no column."""
         n_classes = max(1, len(self.matrix.class_ids))
         pad = _pow2_bucket(n_classes)
-        elig = np.ones((pad,), bool)
         escaped = [
             e.constraint
             for e in compiled.escaped
@@ -295,15 +307,39 @@ class GenericStack:
             not in (Op.DISTINCT_HOSTS.value, Op.DISTINCT_PROPERTY.value)
         ]
         if not escaped:
+            return np.ones((pad,), bool)
+        hf = self.matrix.host_feasibility()
+        elig = hf.class_vector(escaped, pad)
+        if elig is not None:
             return elig
-        for cid, rep_node_id in self.matrix.class_repr.items():
+        elig = np.ones((pad,), bool)
+        for cid, rep_node_id in list(self.matrix.class_repr.items()):
             node = self.ctx.snapshot.node_by_id(rep_node_id)
             if node is None:
                 continue
+            hf.predicates_evaluated += len(escaped)
             ok = all(check_constraint_host(c, node) for c in escaped)
             if cid < pad:
                 elig[cid] = ok
         return elig
+
+    def _feasibility(self, job: Job, tg: TaskGroup,
+                     compiled: CompiledTaskGroup):
+        """(class eligibility, host mask or None) of what the kernels do
+        not evaluate, under the span ``sched.feasibility`` (tags: host-mask
+        terms ``escaped``, computed ``classes``, predicate ``values``
+        evaluated in Python: 0 once the masks are cached)."""
+        hf = self.matrix.host_feasibility()
+        values0 = hf.predicates_evaluated
+        with trace.span("sched.feasibility", cpu=True):
+            class_elig = self._class_eligibility(compiled)
+            host_mask = self._host_mask(job, tg, compiled)
+            trace.add_args(
+                classes=len(self.matrix.class_ids),
+                values=hf.predicates_evaluated - values0,
+            )
+        self._record_eligibility(class_elig, host_mask, compiled)
+        return class_elig, host_mask
 
     def _volume_claimable(self, vol, vreq, job: Job) -> bool:
         """Do the volume's live claims admit this request?  Claims from
@@ -328,126 +364,164 @@ class GenericStack:
             return False
         return True
 
+    def _walk(self, pred) -> np.ndarray:
+        """(N,) bool — ``pred(node)`` for the matrix's nodes, one by one:
+        the fallback where an attribute has no column to read (the
+        registry is full).  Counted (``nomad.sched.host_walk_nodes_total``):
+        at 10,000 nodes one walk costs several whole placed jobs."""
+        m = np.ones((self.matrix.capacity,), bool)
+        rows = list(self.matrix.row_of.items())
+        self.matrix.host_feasibility().walked_nodes += len(rows)
+        for node_id, row in rows:
+            node = self.ctx.snapshot.node_by_id(node_id)
+            if row < m.shape[0]:
+                m[row] = node is not None and pred(node)
+        return m
+
+    def _job_rows(self, job: Job) -> np.ndarray:
+        """Matrix rows of the job's proposed allocations, one entry an
+        alloc: the live ones the plan does not remove, and the plan's own
+        (distinct_property counts the job's, whatever their group)."""
+        removed = self.ctx.plan_removed_ids()
+        node_ids = [
+            a.node_id
+            for a in self.ctx.snapshot.allocs_by_job(job.namespace, job.id)
+            if not a.terminal_status() and a.id not in removed
+        ]
+        for allocs in self.ctx.plan.node_allocation.values():
+            node_ids.extend(a.node_id for a in allocs)
+        row_of = self.matrix.row_of
+        rows = [row_of.get(nid) for nid in node_ids]
+        return np.array([r for r in rows if r is not None], np.int64)
+
     def _host_mask(
         self, job: Job, tg: TaskGroup, compiled: CompiledTaskGroup
     ) -> Optional[np.ndarray]:
-        """Per-node mask for unique-attr escapes, distinct_hosts,
-        distinct_property, host volumes, and escaped device asks. None when
-        nothing applies (the common case — no O(N) host walk)."""
+        """Per-node mask for unique-attr escapes, distinct_hosts, a
+        datacenter list past the encoding, host volumes, escaped device
+        asks and escaped distinct_property constraints.  None when nothing
+        applies (the common case).  Every term is a column of the matrix
+        read whole (``HostFeasibility``: once per distinct value, cached
+        across evals); no term walks the nodes unless its attribute has no
+        column (``_walk``)."""
         n = self.matrix.capacity
-        mask: Optional[np.ndarray] = None
-
-        def ensure() -> np.ndarray:
-            nonlocal mask
-            if mask is None:
-                mask = np.ones((n,), bool)
-            return mask
-
-        unique = [e.constraint for e in compiled.escaped if e.unique]
-        distinct_hosts = any(
-            e.constraint.operand == Op.DISTINCT_HOSTS.value for e in compiled.escaped
-        )
-        distinct_props = [
-            e.constraint
-            for e in compiled.escaped
-            if e.constraint.operand == Op.DISTINCT_PROPERTY.value
-        ]
+        hf = self.matrix.host_feasibility()
+        terms: List[np.ndarray] = []
 
         # Registered-volume feasibility (CSIVolumeChecker, feasible.go:209):
         # the volume must exist, its claims must admit this request, and
         # only nodes exposing its backing host volume qualify.
-        csi_sources: List[str] = []
+        volumes: List[str] = list(compiled.host_volumes)
         for vreq in compiled.csi_volumes:
             vol = self.ctx.snapshot.volume_by_id(job.namespace, vreq.source)
             if vol is None or not self._volume_claimable(vol, vreq, job):
                 return np.zeros((n,), bool)  # nothing feasible → blocked
-            csi_sources.append(vol.source)
+            volumes.append(vol.source)
 
-        if (
-            unique or compiled.host_volumes or csi_sources
-            or compiled.escaped_devices or compiled.dc_escaped
-        ):
-            m = ensure()
-            dcs = set(job.datacenters)
-            for node_id, row in self.matrix.row_of.items():
-                node = self.ctx.snapshot.node_by_id(node_id)
-                if node is None:
-                    m[row] = False
-                    continue
-                if compiled.dc_escaped and node.datacenter not in dcs:
-                    m[row] = False
-                    continue
-                if unique and not all(
-                    check_constraint_host(c, node) for c in unique
-                ):
-                    m[row] = False
-                    continue
-                if compiled.host_volumes and not check_host_volumes(
-                    node, compiled.host_volumes
-                ):
-                    m[row] = False
-                    continue
-                if csi_sources and not check_host_volumes(
-                    node, csi_sources
-                ):
-                    m[row] = False
-                    continue
-                for name, count in compiled.escaped_devices:
-                    if len(node.resources.devices.get(name, [])) < count:
-                        m[row] = False
-                        break
+        if compiled.dc_escaped:
+            terms.append(hf.datacenter_mask(job.datacenters))
+        for e in compiled.escaped:
+            con = e.constraint
+            if not e.unique or con.operand in (
+                Op.DISTINCT_HOSTS.value, Op.DISTINCT_PROPERTY.value
+            ):
+                continue
+            m = hf.constraint_mask(con)
+            if m is None:
+                m = self._walk(lambda node: check_constraint_host(con, node))
+            terms.append(m)
+        if volumes:
+            terms.append(hf.volume_mask(volumes))
+        for name, count in compiled.escaped_devices:
+            terms.append(hf.device_mask(name, count))
 
+        distinct_hosts = any(
+            e.constraint.operand == Op.DISTINCT_HOSTS.value
+            for e in compiled.escaped
+        )
         if distinct_hosts:
             # Mask nodes already holding a proposed alloc of this job
             # (DistinctHostsIterator, feasible.go:505).
-            m = ensure()
-            removed = self.ctx.plan_removed_ids()
-            for a in self.ctx.snapshot.allocs_by_job(job.namespace, job.id):
-                if a.terminal_status() or a.id in removed:
-                    continue
-                row = self.matrix.row_of.get(a.node_id)
-                if row is not None:
-                    m[row] = False
-            for node_id, allocs in self.ctx.plan.node_allocation.items():
-                if allocs:
-                    row = self.matrix.row_of.get(node_id)
-                    if row is not None:
-                        m[row] = False
+            m = np.ones((n,), bool)
+            rows = self._job_rows(job)
+            m[rows[rows < n]] = False
+            terms.append(m)
 
-        for con in distinct_props:
-            # DistinctPropertyIterator (feasible.go:604): limit allocs of the
-            # job per distinct value of the property.
-            m = ensure()
-            limit = int(con.r_target) if str(con.r_target).isdigit() else 1
-            name = con.l_target
-            if name.startswith("${") and name.endswith("}"):
-                name = name[2:-1]
-            if name.startswith("attr."):
-                name = name[len("attr.") :]
-            counts: Dict[str, int] = {}
-            removed = self.ctx.plan_removed_ids()
-            live = [
-                a
-                for a in self.ctx.snapshot.allocs_by_job(job.namespace, job.id)
-                if not a.terminal_status() and a.id not in removed
-            ]
-            for allocs in self.ctx.plan.node_allocation.values():
-                live.extend(allocs)
-            for a in live:
-                anode = self.ctx.snapshot.node_by_id(a.node_id)
-                if anode is None:
-                    continue
-                v = node_attributes(anode).get(name)
+        for e in compiled.escaped:
+            con = e.constraint
+            if con.operand != Op.DISTINCT_PROPERTY.value:
+                continue
+            # A distinct_property the request had no slot for: the limit as
+            # the job's proposed allocs read it now, fixed for this select
+            # (DistinctPropertyIterator, feasible.go:604).
+            limit = distinct_property_limit(con)
+            name = attr_name(con.l_target)
+            col = hf.column(name)
+            if col is not None:
+                held, counts = np.unique(
+                    col[self._job_rows(job)], return_counts=True
+                )
+                full = held[(counts >= limit) & (held != 0)]
+                terms.append((col != 0) & ~np.isin(col, full))
+                continue
+            held: Dict[str, int] = {}
+            for row in self._job_rows(job):
+                node = self.ctx.snapshot.node_by_id(
+                    self.matrix.node_of.get(int(row), "")
+                )
+                v = node_attributes(node).get(name) if node else None
                 if v:
-                    counts[v] = counts.get(v, 0) + 1
-            for node_id, row in self.matrix.row_of.items():
-                node = self.ctx.snapshot.node_by_id(node_id)
-                if node is None:
-                    continue
-                v = node_attributes(node).get(name)
-                if v is not None and counts.get(v, 0) >= limit:
-                    m[row] = False
-        return mask
+                    held[v] = held.get(v, 0) + 1
+            terms.append(self._walk(
+                lambda node: bool(node_attributes(node).get(name))
+                and held.get(node_attributes(node).get(name), 0) < limit
+            ))
+
+        # (a registration can grow the matrix between two reads: rows past
+        # a term's width were not checked by it)
+        terms = [_pad_width(t, n, False)[:n] for t in terms]
+        trace.add_args(escaped=len(terms))
+        if not terms:
+            return None
+        return np.logical_and.reduce(terms) if len(terms) > 1 else terms[0]
+
+    def _distinct_property_seed(
+        self, job: Job, compiled: CompiledTaskGroup, chosen_rows=()
+    ) -> Tuple[SchedRequest, Optional[np.ndarray]]:
+        """The request with its distinct_property stage seeded: per slot,
+        the property values the job's proposed allocs hold (those of
+        ``chosen_rows`` too: picks of this select the plan has not got yet)
+        with their counts, read off the attribute's column.  The scan
+        raises them pick by pick (kernels.distinct_property_pick).  Where a
+        job holds more values than the request has room for, the values
+        already at their limit go into a host mask instead (second result;
+        they stay full whatever the scan picks) and what is still left over
+        is taken for full too: never a pick past a limit."""
+        req = compiled.request
+        if not compiled.distinct_props:
+            return req, None
+        attr_hash = self.matrix.snapshot_host()["attr_hash"]
+        rows = np.concatenate(
+            [self._job_rows(job), np.asarray(chosen_rows, np.int64)]
+        )
+        rows = rows[rows < attr_hash.shape[0]]
+        value_hash = np.zeros_like(req.dp_value_hash)
+        count = np.zeros_like(req.dp_count)
+        mask = None
+        for di in range(len(compiled.distinct_props)):
+            col = attr_hash[:, int(req.dp_slot[di])]
+            held, counts = np.unique(col[rows], return_counts=True)
+            counts = counts[held != 0]
+            held = held[held != 0]
+            if len(held) > MAX_DISTINCT_VALUES:
+                order = np.argsort(counts >= req.dp_limit[di], kind="stable")
+                over = held[order[MAX_DISTINCT_VALUES:]]
+                mask = _and_mask(mask, ~np.isin(col, over))
+                held = held[order[:MAX_DISTINCT_VALUES]]
+                counts = counts[order[:MAX_DISTINCT_VALUES]]
+            value_hash[di, : len(held)] = held
+            count[di, : len(held)] = counts
+        return req._replace(dp_value_hash=value_hash, dp_count=count), mask
 
     # -- port assignment (host-side, chosen node only) ----------------------
 
@@ -577,7 +651,7 @@ class GenericStack:
 
     def _dispatch_place(
         self,
-        compiled: CompiledTaskGroup,
+        request: SchedRequest,
         deltas: Dict[int, np.ndarray],
         tg_count: np.ndarray,
         spread_counts: np.ndarray,
@@ -625,7 +699,7 @@ class GenericStack:
                 for i, row in enumerate(deltas):
                     claims[i] += evicted.get(row, 0.0)
             out = coal.place(
-                compiled.request,
+                request,
                 drows,
                 dvals,
                 tg_count,
@@ -654,7 +728,7 @@ class GenericStack:
             if fake_device.enabled():
                 result = fake_device.place_task_group(
                     arrays,
-                    compiled.request,
+                    request,
                     fake_device.dense_used0(arrays, deltas),
                     _pad_width(tg_count, n_dev, 0),
                     spread_counts,
@@ -674,7 +748,7 @@ class GenericStack:
 
             result = kernels.place_task_group(
                 arrays,
-                compiled.request,
+                request,
                 _dense_used0(arrays, deltas),
                 jnp.asarray(_pad_width(tg_count, n_dev, 0)),
                 jnp.asarray(spread_counts),
@@ -682,7 +756,7 @@ class GenericStack:
                 jnp.asarray(class_elig),
                 jnp.asarray(_pad_width(_full_mask(n, host_mask), n_dev, False)),
                 n_placements=bucket,
-                features=_ratchet_features(compiled.request),
+                features=_ratchet_features(request),
             )
             return (
                 np.asarray(result.rows),
@@ -730,10 +804,7 @@ class GenericStack:
             # read-only all-False mask instead of allocating per eval.
             penalty = self.matrix.shared_masks()[0]
 
-        with trace.span("sched.feasibility"):
-            class_elig = self._class_eligibility(compiled)
-            base_host_mask = self._host_mask(job, tg, compiled)
-        self._record_eligibility(class_elig, base_host_mask)
+        class_elig, base_host_mask = self._feasibility(job, tg, compiled)
         if restrict_nodes is not None:
             allowed = np.zeros((n,), bool)
             for node_id in restrict_nodes:
@@ -787,13 +858,19 @@ class GenericStack:
                 tg_count = self.matrix.shared_zero_i32()
 
             spread_counts = self._spread_counts(job, tg, compiled)
+            # The distinct_property stage's seeds, the picks of this
+            # select's earlier launches included.
+            request, dp_mask = self._distinct_property_seed(
+                job, compiled, chosen_rows
+            )
+            host_mask = _and_mask(host_mask, dp_mask)
 
             # Binpack + score are fused into the placement kernel, so one
             # span covers the whole device dispatch (launch + result wait).
             with trace.span("sched.dispatch", lanes=remaining):
                 (rows_all, scores_all, binpack_all, preempted_all, n_eval_all,
                  n_filt_all, n_exh_all, verified_all) = self._dispatch_place(
-                    compiled, deltas, tg_count, spread_counts, penalty,
+                    request, deltas, tg_count, spread_counts, penalty,
                     class_elig, host_mask, remaining, evicted,
                 )
             take = min(len(rows_all), remaining)
@@ -923,10 +1000,9 @@ class SystemStack(GenericStack):
             compiled = self.encoder.compile(
                 job, tg, algorithm=self.algorithm, preemption_enabled=False
             )
-        with trace.span("sched.feasibility"):
-            class_elig = self._class_eligibility(compiled)
-            host_mask = self._host_mask(job, tg, compiled)
-        self._record_eligibility(class_elig, host_mask)
+        class_elig, host_mask = self._feasibility(job, tg, compiled)
+        request, dp_mask = self._distinct_property_seed(job, compiled)
+        host_mask = _and_mask(host_mask, dp_mask)
         n = self.matrix.capacity
 
         # Fit must judge the node *without* this job's own TG alloc — a
@@ -950,7 +1026,7 @@ class SystemStack(GenericStack):
                 return fake_device.system_feasible(
                     arrays,
                     fake_device.dense_used0(arrays, deltas),
-                    compiled.request,
+                    request,
                     class_elig,
                     _pad_width(_full_mask(n, host_mask), n_dev, False),
                 )
@@ -962,7 +1038,7 @@ class SystemStack(GenericStack):
             return np.asarray(kernels.system_feasible(
                 arrays,
                 _dense_used0(arrays, deltas),
-                compiled.request,
+                request,
                 jnp.asarray(class_elig),
                 jnp.asarray(
                     _pad_width(_full_mask(n, host_mask), n_dev, False)

@@ -277,6 +277,28 @@ class Server:
         m.gauge_fn(
             "nomad.kernel.preempt_picks_total", lambda: c.preempt_picks
         )
+        # Placement rules: lanes launched with a distinct_property limit in
+        # the scan and the picks it moved to another node; and the host's
+        # side of feasibility: predicate evaluations in Python (one per
+        # distinct value of an attribute's column, on first sight) and
+        # nodes walked one by one (0 where every attribute has a column:
+        # the regression alarm).
+        m.gauge_fn(
+            "nomad.kernel.distinct_property_lanes_total",
+            lambda: c.distinct_property_lanes,
+        )
+        m.gauge_fn(
+            "nomad.kernel.distinct_property_blocked_total",
+            lambda: c.distinct_property_blocked,
+        )
+        m.gauge_fn(
+            "nomad.sched.host_walk_nodes_total",
+            lambda: mx.host_feasibility().walked_nodes,
+        )
+        m.gauge_fn(
+            "nomad.sched.escaped_predicates_total",
+            lambda: mx.host_feasibility().predicates_evaluated,
+        )
         # The in-flight claims overlay (scheduler/claims.py): rows handed
         # to launches, the ledger's rows by event, and the launches that
         # could not see their predecessor's picks.

@@ -96,6 +96,7 @@ def build_cluster(
         id_hash = np.fromiter(
             (stable_hash(s) for s in ids), np.int32, len(ids)
         )
+        m.value_of.update(zip(id_hash.tolist(), ids))
         for attr in ("node.unique.name", "node.unique.id"):
             slot = m.attrs.lookup(attr)
             if slot is not None:

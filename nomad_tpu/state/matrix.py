@@ -308,6 +308,18 @@ class NodeMatrix:
         # class bookkeeping
         self.class_ids: Dict[str, int] = {}  # class key -> id
         self.class_repr: Dict[int, str] = {}  # class id -> representative node
+        # What the host's column-wise feasibility reads (scheduler/
+        # feasible_host.py ``HostFeasibility``): the string behind each
+        # value id of ``attr_hash`` (an escaped predicate is evaluated once
+        # per distinct value of its column); the rows that expose a host
+        # volume or carry instances of a device, by name ({row: count});
+        # and a counter bumped whenever any of it, a node's attribute row,
+        # class or position changes: what a cached mask is valid for.
+        self.value_of: Dict[int, str] = {}
+        self.volume_rows: Dict[str, Dict[int, int]] = {}
+        self.device_rows: Dict[str, Dict[int, int]] = {}
+        self.attr_version = 0
+        self._host_feasibility = None
         self._alloc = self._allocate_arrays(self.capacity)
         self._dirty: set = set()
         self._device: Optional[DeviceArrays] = None
@@ -383,6 +395,17 @@ class NodeMatrix:
 
             enc = self._encoder = RequestEncoder(self)
         return enc
+
+    def host_feasibility(self):
+        """The matrix-wide ``HostFeasibility``: the masks of escaped
+        predicates, cached across evals as the encoder's compilations are
+        (a stack is built per eval)."""
+        hf = self._host_feasibility
+        if hf is None:
+            from ..scheduler.feasible_host import HostFeasibility
+
+            hf = self._host_feasibility = HostFeasibility(self)
+        return hf
 
     def shared_masks(self) -> Tuple[np.ndarray, np.ndarray]:
         """(all-False, all-True) read-only (capacity,) bool masks — select
@@ -460,6 +483,7 @@ class NodeMatrix:
             }
             self.node_of = {r: nid for nid, r in self.row_of.items()}
             self._free = [int(mapping[r]) for r in self._free]
+            self._relocate_index(mapping)
             self._dirty = {int(mapping[r]) for r in self._dirty}
             self._sharded_dirty = {
                 int(mapping[r]) for r in self._sharded_dirty
@@ -482,6 +506,7 @@ class NodeMatrix:
                 new[k][: self.capacity] = arr
         self._alloc = new
         self.capacity = new_cap
+        self.attr_version += 1
         self._device_valid = False
         self._sharded_valid = False
 
@@ -612,6 +637,7 @@ class NodeMatrix:
                     new[k][dst] = arr[src]
             self._alloc = new
             self.capacity = new_cap
+            self._relocate_index(mapping)
             self.shard_count = n
             self.row_of = new_row_of
             self.node_of = {r: nid for nid, r in new_row_of.items()}
@@ -713,6 +739,9 @@ class NodeMatrix:
             self._next_row = 0
             self.class_ids.clear()
             self.class_repr.clear()
+            self.volume_rows.clear()
+            self.device_rows.clear()
+            self.attr_version += 1
             self._alloc = self._allocate_arrays(self.capacity)
             self._dirty.clear()
             self._device_valid = False
@@ -748,9 +777,11 @@ class NodeMatrix:
             slot = self.attrs.register(name)
             if slot is None:
                 continue
-            hash_row[slot] = stable_hash(str(value))
+            hash_row[slot] = h = stable_hash(str(value))
+            self.value_of[h] = str(value)
             num_row[slot] = numeric_value(str(value))
             ver_row[slot] = version_value(str(value))
+        changed = not np.array_equal(a["attr_hash"][row], hash_row)
         a["attr_hash"][row] = hash_row
         a["attr_num"][row] = num_row
         a["attr_ver"][row] = ver_row
@@ -761,7 +792,15 @@ class NodeMatrix:
             cid = len(self.class_ids)
             self.class_ids[key] = cid
             self.class_repr[cid] = node.id
+        changed |= int(a["class_id"][row]) != cid
         a["class_id"][row] = cid
+        changed |= self._index_row(
+            row, node.host_volumes,
+            {k: len(v) for k, v in node.resources.devices.items()},
+        )
+        # A status or eligibility update re-enters here with the same
+        # facts: the cached masks stay.
+        self.attr_version += changed
 
         dev_row = np.zeros((self.devices.slots,), np.int32)
         for name, instances in node.resources.devices.items():
@@ -778,6 +817,35 @@ class NodeMatrix:
 
         self._mark_dirty_locked(row)
         return row
+
+    def _index_row(self, row: int, volumes, devices: Dict[str, int]) -> bool:
+        """Enter what node ``row`` exposes into ``volume_rows`` /
+        ``device_rows`` (nothing = the row was freed); whether it changed."""
+        changed = False
+        for index, have in (
+            (self.volume_rows, dict.fromkeys(volumes, 1)),
+            (self.device_rows, {k: n for k, n in devices.items() if n}),
+        ):
+            for name, rows in index.items():
+                if name not in have and rows.pop(row, None) is not None:
+                    changed = True
+            for name, n in have.items():
+                rows = index.setdefault(name, {})
+                if rows.get(row) != n:
+                    rows[row] = n
+                    changed = True
+        return changed
+
+    def _relocate_index(self, mapping: np.ndarray) -> None:
+        """``volume_rows`` / ``device_rows`` after rows moved (a growth
+        under sharding, a re-layout): old row -> ``mapping[row]``."""
+        for index in (self.volume_rows, self.device_rows):
+            for name, rows in index.items():
+                index[name] = {
+                    int(mapping[r]): n for r, n in rows.items()
+                    if mapping[r] >= 0
+                }
+        self.attr_version += 1
 
     def set_eligibility(self, node_id: str, eligible: bool) -> None:
         with self._host_lock:
@@ -796,6 +864,8 @@ class NodeMatrix:
         if row is None:
             return
         del self.node_of[row]
+        self._index_row(row, (), {})
+        self.attr_version += 1
         # Re-seat the computed-class representative if this node held it:
         # escaped-constraint checks are evaluated against the representative
         # (stack._class_eligibility), so a stale id would skip them.

@@ -281,7 +281,11 @@ def check_packed_launch(monkeypatch, k, n_device_shards=1):
         assert x is st[field] and x.shape == (lanes, n)
     assert not hm[k:].any()
     assert orows.size >= OVERLAY_ROWS and (st["overlay_rows"] == -1).all()
-    small = (ce, sc, dr, dv, ls, orows, ovals, cv, flags) + tuple(reqs)
+    # (at ``dp_width`` 0 the distinct_property operands stay on the host)
+    handed = tuple(x for x in reqs if x is not None)
+    assert len(handed) == (
+        len(reqs) if coal._features.dp_width else kernels.DP_FIELDS)
+    small = (ce, sc, dr, dv, ls, orows, ovals, cv, flags) + handed
     assert all(isinstance(x, jax.Array) for x in small)
     for x, field in zip(small, SMALL):  # copied: the live entry donated them
         assert x.shape == st[field].shape and x.dtype == st[field].dtype

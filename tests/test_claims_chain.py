@@ -800,4 +800,11 @@ def test_the_benchmark_lists_the_metrics_wherever_evals_per_s_is_read():
         (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
         assert entry["layer"] == "coalescer"
         assert entry["moves"] == "evals_per_s" and "workloads" not in entry
-    assert bench["per_layer"][-1]["name"] == "chained_rows_per_launch"
+    # entries are appended, never inserted: the two stand together, after
+    # everything older (PR 44's four came after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("chained_launch_share")
+    assert names[at + 1] == "chained_rows_per_launch"
+    assert names[at + 2:] == [
+        "sched_feasibility_ms", "host_walk_nodes_per_eval",
+        "kernel_feasibility_share", "rules_place_batch_roofline"]

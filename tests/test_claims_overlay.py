@@ -622,4 +622,6 @@ def test_the_benchmark_lists_the_metric_in_all_four_cells():
     (entry,) = [m for m in bench["per_layer"]
                 if m["name"] == "overlay_rows_per_launch"]
     assert entry["layer"] == "coalescer" and entry["moves"] == "evals_per_s"
-    assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
+    # the four cells there were at PR 38; a cell a later PR adds enters
+    # a list-bound metric through a ``benchmark`` PR (PERF.md section 7)
+    assert entry["workloads"] == [w["name"] for w in bench["workloads"]][:4]
