@@ -420,6 +420,10 @@ def library_level(meter: CompileMeter, sizes, seed: int,
     stats = meter.since(mark)
     stats["wall_s"] = round(time.perf_counter() - t, 2)
     check(m.full_uploads == 1 and m.scatter_syncs == 3, "scatter: sync counts")
+    check(
+        m.scatter_operands_total == 3,
+        "scatter: a sync hands the device one packed host operand",
+    )
     check(stats["compiles"] <= 2, f"scatter compiled {stats['compiles']} > 2 buckets")
     results["row_scatter"] = stats
 
@@ -838,6 +842,7 @@ def live_level(meter: CompileMeter, sizes, seed: int) -> dict:
             "wedged_dispatches": coal.wedged_dispatches,
             "full_uploads": mx.full_uploads,
             "scatter_syncs": mx.scatter_syncs,
+            "scatter_operands_total": mx.scatter_operands_total,
             "rows_scattered_total": mx.rows_scattered_total,
             "upload_bytes_total": mx.upload_bytes_total,
             "plans_applied": srv.plan_applier.plans_applied,

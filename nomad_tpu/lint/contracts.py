@@ -225,13 +225,15 @@ def _unpack_packs(g: Grid) -> Tuple[Any, ...]:
 
 
 def scatter_operands(g: Grid) -> Tuple[Any, ...]:
-    """(device, idx, *row_data) for the dirty-row scatter; ``g.deltas``
-    is the (already pow2-padded) dirty-row count."""
-    arrays = _concrete_arrays(g.nodes)
-    k = g.deltas
-    idx = np.arange(k, dtype=np.int32) % g.nodes
-    row_data = tuple(np.asarray(f)[:k] for f in arrays)
-    return (arrays, idx) + row_data
+    """(device, pack) for the dirty-row scatter, the pack as a sync of the
+    server builds it (``NodeMatrix._pack_rows``: the rows' twelve fields
+    and their index in one buffer); ``g.deltas`` is the (already
+    pow2-padded) dirty-row count."""
+    from ..state.matrix import NodeMatrix
+
+    m = NodeMatrix(capacity=g.nodes)
+    rows = np.arange(g.deltas, dtype=np.int32) % g.nodes
+    return (m.sync_host(), m._pack_rows(rows))
 
 
 # ---------------------------------------------------------------------------
@@ -376,6 +378,21 @@ def table() -> Tuple[DeviceContract, ...]:
         return sharding.sharded_fused_place_batch(mesh, g.placements)
 
     scatter_grid = Grid(nodes=_N_A, batch=4, placements=1, deltas=4, live=4)
+    # Both dirty-row scatters, one chip's and the mesh's: one body
+    # (``matrix.scatter_packed``), one packed host operand.
+    scatter_contract: Dict[str, Any] = dict(
+        operands=scatter_operands,
+        static_kwargs=lambda g: {},
+        trace_grids=(scatter_grid, scatter_grid._replace(nodes=_N_B)),
+        out_budget=None,  # outputs ARE the device-resident matrix
+        node_axis_outputs_ok=True,
+        # launches in flight still read the old snapshot; the pack is a
+        # host buffer with nothing to give back
+        donated_args=(),
+        compile_grid=scatter_grid._replace(nodes=32),
+        sweep=pow2_rows_sweep,
+        max_compiles=2,  # pow2 buckets of 1..4 dirty rows: {2, 4}
+    )
     return (
         DeviceContract(
             name="fused_place_batch",
@@ -440,15 +457,17 @@ def table() -> Tuple[DeviceContract, ...]:
             name="make_row_scatter",
             path="nomad_tpu/state/matrix.py",
             build=lambda g: matrix.make_row_scatter(),
-            operands=scatter_operands,
-            static_kwargs=lambda g: {},
-            trace_grids=(scatter_grid, scatter_grid._replace(nodes=_N_B)),
-            out_budget=None,  # outputs ARE the device-resident matrix
-            node_axis_outputs_ok=True,
-            donated_args=(),  # in-flight dispatches still read the old snapshot
-            compile_grid=scatter_grid._replace(nodes=32),
-            sweep=pow2_rows_sweep,
-            max_compiles=2,  # pow2 buckets of 1..4 dirty rows: {2, 4}
+            **scatter_contract,
+        ),
+        DeviceContract(
+            name="make_sharded_row_scatter",
+            path="nomad_tpu/parallel/sharding.py",
+            # The (1, 1) mesh of ``build_sharded``: the out_shardings are
+            # in the lowering whatever the physical shard count.
+            build=lambda g: sharding.make_sharded_row_scatter(
+                sharding.make_mesh(1, batch=1)
+            ),
+            **scatter_contract,
         ),
     )
 

@@ -141,25 +141,39 @@ class SchedRequest(NamedTuple):
     dp_count: np.ndarray  # (DP, V) f32 — allocs held per value
 
 
-def packed_rows(lanes: int, specs):
-    """One ``(lanes, W)`` uint8 buffer and a ``(lanes,) + shape`` view of it
-    per ``(shape, dtype)`` of ``specs``: what a launch hands to jax as ONE
-    operand instead of one per field (a transfer costs the launching thread
-    by the device buffer, not by the byte: PERF.md section 6, PR 33).
-    Returns (buffer, views, layout); ``kernels.unpack_rows(buffer, layout)``
-    gives the fields back on the device.  Fields start on 4-byte bounds."""
-    layout, views, width = [], [], 0
+def packed_layout(specs):
+    """(layout, W) of a row that holds one field per ``(shape, dtype)`` of
+    ``specs``: each field's (offset, shape, dtype name, bytes), every field
+    bool or four bytes wide and starting on a 4-byte bound.  Both sides of a
+    transfer work it out from the shapes they see: the host to build the
+    buffer (``packed_rows``), a jitted program to take it apart
+    (``kernels.unpack_rows``)."""
+    layout, width = [], 0
     for shape, dtype in specs:
         dtype = np.dtype(dtype)
         assert dtype == bool or dtype.itemsize == 4, dtype
-        size = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+        size = math.prod(shape) * dtype.itemsize
         layout.append((width, tuple(shape), dtype.name, size))
         width += -(-size // 4) * 4
+    return tuple(layout), width
+
+
+def packed_rows(lanes: int, specs):
+    """One ``(lanes, W)`` uint8 buffer and a ``(lanes,) + shape`` view of it
+    per ``(shape, dtype)`` of ``specs``: what a call hands to jax as ONE
+    operand instead of one per field (a transfer costs the calling thread
+    by the device buffer, not by the byte: PERF.md section 6, PR 33).  A
+    launch's small lane operands cross this way (a row a lane), and so do a
+    matrix sync's dirty rows (a row a node: ``state/matrix.py``).
+    Returns (buffer, views, layout); ``kernels.unpack_rows(buffer, layout)``
+    gives the fields back on the device."""
+    layout, width = packed_layout(specs)
     buf = np.zeros((lanes, width), np.uint8)
-    for offset, shape, dtype, size in layout:
-        field = buf[:, offset:offset + size].view(dtype)
-        views.append(field.reshape((lanes,) + shape))
-    return buf, views, tuple(layout)
+    views = [
+        buf[:, offset:offset + size].view(dtype).reshape((lanes,) + shape)
+        for offset, shape, dtype, size in layout
+    ]
+    return buf, views, layout
 
 
 class RequestSlab:

@@ -55,7 +55,7 @@ from ..ops.kernels import (
     spread_values_at,
     unpack_lanes,
 )
-from ..state.matrix import DeviceArrays
+from ..state.matrix import DeviceArrays, scatter_packed
 
 # Hierarchical top-k width: each node shard contributes its k best rows to
 # the (shards, k) candidate table.  Any k >= 1 preserves exact argmax parity
@@ -199,27 +199,22 @@ def shard_matrix_arrays(mesh: Mesh, arrays: DeviceArrays) -> DeviceArrays:
 def make_sharded_row_scatter(mesh: Mesh):
     """Build the jitted dirty-row scatter into a mesh-RESIDENT matrix.
 
-    ``scatter(device, idx, *row_data) -> DeviceArrays`` updates rows
-    ``idx`` of the sharded snapshot with fresh host values; out_shardings
-    pins every output leaf to the same 'node' layout, so XLA routes each
-    row to the shard that owns it — the incremental alternative to
-    re-laying the full matrix through ``shard_matrix_arrays`` per dispatch
-    (state/matrix.py sync_sharded).  No donation: in-flight pipelined
-    dispatches may still be reading the previous snapshot's buffers.
+    ``scatter(device, pack) -> DeviceArrays`` is the one-chip scatter's
+    body (``state/matrix.py::scatter_packed``: one packed host operand
+    holding the dirty rows' twelve fields and their index) with
+    out_shardings pinning every output leaf to the same 'node' layout, so
+    XLA routes each row to the shard that owns it — the incremental
+    alternative to re-laying the full matrix through
+    ``shard_matrix_arrays`` per dispatch (state/matrix.py sync_sharded).
+    The operand has no sharding of its own, so it is replicated: one
+    buffer a device a sync (4 on ``(2, 2)``, where thirteen operands were
+    52).  No donation: in-flight pipelined dispatches may still be reading
+    the previous snapshot's buffers.
     """
     out_shardings = DeviceArrays(
         *(NamedSharding(mesh, spec) for spec in _ARRAYS_SPEC)
     )
-
-    def scat(d, i, *vals):
-        return DeviceArrays(
-            **{
-                f: getattr(d, f).at[i].set(v)
-                for f, v in zip(DeviceArrays._fields, vals)
-            }
-        )
-
-    return jax.jit(scat, out_shardings=out_shardings)
+    return jax.jit(scatter_packed, out_shardings=out_shardings)
 
 
 # ---------------------------------------------------------------------------

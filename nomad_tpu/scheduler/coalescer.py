@@ -899,7 +899,7 @@ class DeviceCoalescer:
 
         mx = self.matrix
         rows0, bytes0 = mx.rows_scattered_total, mx.upload_bytes_total
-        wait0 = mx.sync_lock_wait_total
+        wait0, operands0 = mx.sync_lock_wait_total, mx.scatter_operands_total
         arrays = sharded = None
         with self._state("coalescer.sync"):
             t_lock, device_wait = time.time(), 0.0
@@ -929,6 +929,10 @@ class DeviceCoalescer:
             trace.add_args(
                 rows=mx.rows_scattered_total - rows0,
                 bytes=mx.upload_bytes_total - bytes0,
+                # Host operands the scatter handed jax (a device buffer
+                # each, times the devices on a mesh): 1 a sync that
+                # scattered, 0 where nothing was dirty.
+                operands=mx.scatter_operands_total - operands0,
                 shards=self.mesh_shape()[1] if sharded is not None else 1,
                 # Blocked acquiring DEVICE_LOCK and the matrix's host lock
                 # (held by the applier's mutators): with the span's

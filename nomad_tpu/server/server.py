@@ -244,6 +244,10 @@ class Server:
         m.gauge_fn("nomad.matrix.full_uploads", lambda: mx.full_uploads)
         m.gauge_fn("nomad.matrix.scatter_syncs", lambda: mx.scatter_syncs)
         m.gauge_fn(
+            "nomad.matrix.scatter_operands_total",
+            lambda: mx.scatter_operands_total,
+        )
+        m.gauge_fn(
             "nomad.matrix.rows_scattered_total", lambda: mx.rows_scattered_total
         )
         m.gauge_fn(

@@ -255,38 +255,53 @@ def scatter_bucket(rows: int) -> int:
     return max(2, 1 << max(0, rows - 1).bit_length())
 
 
+def _row_specs(arrays):
+    """``packed_rows`` specs of one matrix row: every field of
+    ``DeviceArrays`` less its node axis, then the row's own index."""
+    return [(a.shape[1:], a.dtype) for a in arrays] + [((), np.int32)]
+
+
+def scatter_packed(d: "DeviceArrays", pack) -> "DeviceArrays":
+    """The body of both dirty-row scatters (this module's and the mesh's,
+    ``parallel/sharding.py``): take ``NodeMatrix._pack_rows``' buffer apart
+    (bit for bit what the host wrote) and write each field's rows at the
+    index that came with them.  The layout is worked out from the shapes of
+    ``d`` at trace time, as the host works it out from its mirror's."""
+    from ..ops.encode import packed_layout
+    from ..ops.kernels import unpack_rows
+
+    layout, width = packed_layout(_row_specs(d))
+    assert pack.shape[1] == width, (pack.shape, width)
+    *vals, i = unpack_rows(pack, layout)
+    return DeviceArrays(*(x.at[i].set(v) for x, v in zip(d, vals)))
+
+
 def make_row_scatter():
     """Build the jitted multi-field dirty-row scatter.
 
-    ``scatter(device, idx, *row_data) -> DeviceArrays`` writes rows
-    ``idx`` of every matrix field in ONE dispatch; numpy operands
-    transfer as part of that dispatch instead of one host→device
-    transfer per field.  This factory is the registered device entry
-    point for the scatter in ``lint/contracts.py`` (the jaxpr-level
+    ``scatter(device, pack) -> DeviceArrays`` writes the rows that
+    ``pack`` holds (``NodeMatrix._pack_rows``: twelve fields and their
+    index, ONE host operand, so one host->device buffer a sync where the
+    index and a numpy array a field were thirteen) into every matrix
+    field in one dispatch.  No donation: launches in flight still read the
+    previous snapshot's buffers.  This factory is the registered device
+    entry point for the scatter in ``lint/contracts.py`` (the jaxpr-level
     contract gate traces and sweeps it), so keep its signature stable;
     ``_scatter_rows`` below is the lazy process-wide instance the sync
     path actually calls.
     """
     import jax
 
-    def scat(d, i, *vals):
-        return DeviceArrays(
-            **{
-                f: getattr(d, f).at[i].set(v)
-                for f, v in zip(DeviceArrays._fields, vals)
-            }
-        )
-
-    return jax.jit(scat)
+    return jax.jit(scatter_packed)
 
 
-def _scatter_rows(device: "DeviceArrays", idx, *row_data) -> "DeviceArrays":
+def _scatter_rows(device: "DeviceArrays", pack) -> "DeviceArrays":
     """Jitted multi-field row scatter (lazy so importing nomad_tpu doesn't
     initialize a jax backend)."""
     global _SCATTER_FN
     if _SCATTER_FN is None:
         _SCATTER_FN = make_row_scatter()
-    return _SCATTER_FN(device, idx, *row_data)
+    return _SCATTER_FN(device, pack)
 
 
 class NodeMatrix:
@@ -338,6 +353,8 @@ class NodeMatrix:
         # syncs move O(dirty rows), not the whole matrix.
         self.full_uploads = 0
         self.scatter_syncs = 0
+        # Host operands the scatter syncs handed the device: one a sync.
+        self.scatter_operands_total = 0
         self.rows_scattered_total = 0
         self.upload_bytes_total = 0
         # Seconds the syncs spent blocked acquiring ``_host_lock`` (held by
@@ -1097,26 +1114,50 @@ class NodeMatrix:
                 self.scatter_syncs += 1
                 self.rows_scattered_total += len(rows)
                 return self._device
-            # Pad the row count to a pow2 bucket (repeating row 0 — the
-            # duplicate scatter writes identical data) so the jitted
-            # scatter compiles once per bucket; the numpy operands ride
-            # the dispatch instead of paying a dozen per-field transfers.
-            k = len(rows)
-            padded = scatter_bucket(k)
-            idx = np.full((padded,), rows[0], np.int32)
-            idx[:k] = rows
-            row_data = [self._alloc[f][idx] for f in DeviceArrays._fields]
+            pack = self._pack_rows(rows)
+        self._device = self._scatter(
+            _scatter_rows, self._device, pack, rows, self._dirty
+        )
+        return self._device
+
+    def _pack_rows(self, rows: np.ndarray) -> np.ndarray:
+        """Rows ``rows`` of every device field and ``rows`` itself as ONE
+        host buffer (``ops/encode.py::packed_rows``, a row a dirty row):
+        the scatter's only host operand, so a sync costs the calling
+        thread one device buffer (four on a mesh of four), not one per
+        field.  The row count is padded to a pow2 bucket so the jitted
+        scatter compiles once per bucket; the tail repeats the first row
+        (the duplicate writes carry identical data).  Call under the host
+        lock: the gather reads the mirror.  A fresh buffer every sync: jax
+        reads a numpy operand after the call returns."""
+        from ..ops.encode import packed_rows
+
+        fields = [self._alloc[f] for f in DeviceArrays._fields]
+        pack, views, _ = packed_rows(
+            scatter_bucket(len(rows)), _row_specs(fields)
+        )
+        idx = views[-1]
+        idx[:] = rows[0]
+        idx[: len(rows)] = rows
+        for src, view in zip(fields, views):
+            view[...] = src[idx]
+        return pack
+
+    def _scatter(self, scatter, device, pack, rows, dirty: set):
+        """``scatter(device, pack)`` with the sync's accounting; a scatter
+        that raises puts the drained ``rows`` back into ``dirty`` so a
+        later sync retries them."""
         try:
-            self._device = _scatter_rows(self._device, idx, *row_data)
+            device = scatter(device, pack)
         except BaseException:
-            # Put the drained rows back so a later sync retries them.
             with self._host_lock:
-                self._dirty.update(int(r) for r in rows)
+                dirty.update(int(r) for r in rows)
             raise
         self.scatter_syncs += 1
-        self.rows_scattered_total += k
-        self.upload_bytes_total += sum(a.nbytes for a in row_data)
-        return self._device
+        self.scatter_operands_total += 1
+        self.rows_scattered_total += len(rows)
+        self.upload_bytes_total += pack.nbytes
+        return device
 
     def invalidate(self) -> None:
         self._device_valid = False
@@ -1184,22 +1225,9 @@ class NodeMatrix:
             # scatter then issues one contiguous block per shard instead
             # of interleaved single-row transfers.
             rows.sort()
-            # Pow2 row-count buckets, as in _sync_locked, so the sharded
-            # scatter compiles once per bucket.
-            k = len(rows)
-            padded = scatter_bucket(k)
-            idx = np.full((padded,), rows[0], np.int32)
-            idx[:k] = rows
-            row_data = [self._alloc[f][idx] for f in DeviceArrays._fields]
-        try:
-            self._sharded_device = self._sharded_scatter(
-                self._sharded_device, idx, *row_data
-            )
-        except BaseException:
-            with self._host_lock:
-                self._sharded_dirty.update(int(r) for r in rows)
-            raise
-        self.scatter_syncs += 1
-        self.rows_scattered_total += k
-        self.upload_bytes_total += sum(a.nbytes for a in row_data)
+            pack = self._pack_rows(rows)
+        self._sharded_device = self._scatter(
+            self._sharded_scatter, self._sharded_device, pack, rows,
+            self._sharded_dirty,
+        )
         return self._sharded_device

@@ -338,3 +338,87 @@ def hold_gil(seconds: float) -> float:
     t0 = time.perf_counter()
     sum(range(int(seconds / per_item)))
     return time.perf_counter() - t0
+
+
+def dirty_hard_rows(matrix, rows):
+    """Write the values a transfer is most likely to bend into ``rows`` of
+    the matrix's host mirror, and mark them dirty for both device copies:
+    NaN payloads in ``attr_num`` / ``attr_ver`` (a float round trip may
+    quieten or canonicalise them), ``port_words`` with bit 31 set (a signed
+    detour would flip it), ``eligible`` toggled (so both truth values
+    cross), and a value of the row's own in every other field."""
+    import numpy as np
+
+    host = matrix.snapshot_host()
+    for r in (int(r) for r in rows):
+        payload = np.array([0x7FC01234 + r, 0xFFC0BEEF - r], np.uint32)
+        host["attr_num"][r, :2] = payload.view(np.float32)
+        host["attr_ver"][r, -2:] = payload[::-1].view(np.float32)
+        host["port_words"][r, [0, -1]] = (0x80000000 | r, 0xFFFFFFFF - r)
+        host["eligible"][r] = not host["eligible"][r]
+        host["used"][r] = (r + 0.25, r + 0.5, -0.0)
+        host["attr_hash"][r, 1] = -(r + 1)
+        host["class_id"][r] = r % 7
+        host["dev_used"][r, 0] = r + 3
+        host["prio_used"][r, -1] = (1e-38, r, 3e38)
+        host["dyn_used"][r] = 2**31 - 1 - r
+        matrix._dirty.add(r)
+        matrix._sharded_dirty.add(r)
+
+
+def host_mirror(matrix):
+    """The matrix's host arrays as a ``DeviceArrays`` (views, no copy):
+    what a device copy must equal after a sync."""
+    from nomad_tpu.state.matrix import DeviceArrays
+
+    return DeviceArrays(
+        **{f: matrix._alloc[f] for f in DeviceArrays._fields})
+
+
+def assert_bits_equal(got, want, what=""):
+    """Every field of two ``DeviceArrays`` (device or numpy) byte for
+    byte: ``assert_array_equal`` would call two NaNs of different payload
+    equal and -0.0 equal to 0.0."""
+    import numpy as np
+
+    for f, a, b in zip(type(want)._fields, got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, (what, f)
+        assert a.tobytes() == b.tobytes(), f"{what}: field {f} differs"
+
+
+def check_sync_span(n_device_shards=1):
+    """A launch's ``coalescer.sync`` span says how many host operands the
+    dirty-row scatter handed jax: 1 when rows were dirty (their twelve
+    fields and the index in one pack), 0 when none were; the device copy
+    the launch read is the host mirror bit for bit.  Uses the file's wide
+    coalescer (``check_packed_launch``'s), which has launched once."""
+    from nomad_tpu import trace
+
+    if n_device_shards not in _WIDE:
+        _WIDE[n_device_shards] = wide_coalescer(n_device_shards)
+        launch_lanes(_WIDE[n_device_shards], 1)
+    coal = _WIDE[n_device_shards]
+    m = coal.matrix
+    claimed = sorted(m.node_of)
+    rows = [claimed[0], claimed[1], claimed[-1]]  # both ends of the node axis
+
+    def sync_args():
+        trace.clear()
+        launch_lanes(coal, 2)
+        (rec,) = [r for r in trace.dump() if r["name"] == "coalescer.sync"]
+        return rec["args"]
+
+    dirty_hard_rows(m, rows)
+    operands0, bytes0 = m.scatter_operands_total, m.upload_bytes_total
+    args = sync_args()
+    assert args["operands"] == 1 and args["rows"] == len(rows)
+    assert m.scatter_operands_total == operands0 + 1
+    assert args["bytes"] == m.upload_bytes_total - bytes0 == (
+        m._pack_rows(rows).nbytes)
+    assert args["shards"] == coal.mesh_shape()[1]
+    dev = m._device if n_device_shards == 1 else m._sharded_device
+    assert_bits_equal(dev, host_mirror(m), f"{n_device_shards} device(s)")
+    clean = sync_args()
+    assert (clean["operands"], clean["rows"], clean["bytes"]) == (0, 0, 0)
+    return coal

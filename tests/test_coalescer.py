@@ -190,6 +190,11 @@ def test_a_launch_hands_over_two_packs_and_the_node_axis_operands(
     assert coal.mesh_shape() == (1, 1)
 
 
+def test_a_sync_span_counts_the_one_operand_the_scatter_hands_over():
+    coal = helpers.check_sync_span()
+    assert coal.mesh_shape() == (1, 1)
+
+
 def test_a_lane_live_in_one_launch_and_dead_in_the_next_leaves_no_trace(
     monkeypatch,
 ):

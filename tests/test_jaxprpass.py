@@ -304,6 +304,37 @@ def test_j105_unpacking_program_compiles_once_for_every_fill():
     assert contracts.occupancy_sweep(entry, c) == 0
 
 
+@pytest.mark.parametrize(
+    "name", ["make_row_scatter", "make_sharded_row_scatter"]
+)
+def test_row_scatter_takes_one_packed_operand_and_a_program_a_bucket(name):
+    """Both dirty-row scatters are registered, take the matrix and ONE
+    packed uint8 operand (the rows' twelve fields and their index, as a
+    sync of the server builds it), donate nothing (launches in flight read
+    the old snapshot), and 1..4 dirty rows cost the two pow2 buckets
+    {2, 4}, then nothing."""
+    from nomad_tpu.state.matrix import DeviceArrays, scatter_bucket
+
+    c = contracts.get(name)
+    assert c.sweep is contracts.pow2_rows_sweep and c.max_compiles == 2
+    assert c.donated_args == () and c.donated_kwargs == ()
+    assert c.out_budget is None and c.node_axis_outputs_ok
+    g = c.compile_grid
+    for k in range(1, g.batch + 1):
+        device, pack = c.operands(g._replace(deltas=scatter_bucket(k)))
+        assert isinstance(device, DeviceArrays)
+        assert pack.dtype == np.uint8 and pack.ndim == 2
+        assert pack.shape[0] == scatter_bucket(k)
+    assert jaxprpass.check_contract(c) == []
+    entry = c.build(g)
+    assert contracts.pow2_rows_sweep(entry, c) <= 2
+    assert contracts.pow2_rows_sweep(entry, c) == 0
+    out = entry(*c.operands(g))
+    assert all(
+        np.asarray(x).shape[0] == g.nodes for x in out
+    ), "the scatter returns the resident matrix"
+
+
 def test_contract_table_names_every_registered_entry():
     names = {c.name for c in contracts.table()}
     assert names == {
@@ -312,4 +343,5 @@ def test_contract_table_names_every_registered_entry():
         "unpack_lanes",
         "sharded_fused_place_batch",
         "make_row_scatter",
+        "make_sharded_row_scatter",
     }

@@ -1,8 +1,11 @@
 """Tests for the device-resident NodeMatrix encoding."""
 
 import numpy as np
+import pytest
 
 from nomad_tpu.state import NodeMatrix, priority_bucket, stable_hash, numeric_value
+from nomad_tpu.state import matrix as matrix_mod
+from nomad_tpu.state.matrix import DeviceArrays, scatter_bucket
 from nomad_tpu.structs import (
     Allocation,
     DriverInfo,
@@ -12,6 +15,7 @@ from nomad_tpu.structs import (
     NodeResources,
     Resources,
 )
+from helpers import assert_bits_equal, dirty_hard_rows, host_mirror
 
 
 def make_node(**kw):
@@ -137,3 +141,84 @@ class TestNodeMatrix:
         row = m.upsert_node(node)
         slot = m.devices.lookup("nvidia/gpu")
         assert m.snapshot_host()["dev_total"][row, slot] == 2
+
+
+class TestPackedScatter:
+    """A sync hands the device ONE host operand: the dirty rows' twelve
+    fields and their index in one packed buffer (ROADMAP S1b1)."""
+
+    @staticmethod
+    def _synced(n=40):
+        m = NodeMatrix(capacity=64)
+        nodes = [make_node() for _ in range(n)]
+        for node in nodes:
+            m.upsert_node(node)
+        return m, m.sync()
+
+    @pytest.mark.parametrize("k", [1, 2, 3, 33])
+    def test_bit_for_bit_against_a_per_field_set(self, k):
+        m, before = self._synced()
+        rows = np.arange(5, 5 + k, dtype=np.int32)[::-1].copy()
+        dirty_hard_rows(m, rows)
+        pack = m._pack_rows(rows)
+        bucket = scatter_bucket(k)
+        assert pack.dtype == np.uint8 and pack.shape[0] == bucket
+        # The index rides in the pack's last field; the tail of a padded
+        # bucket repeats the first row.
+        idx = pack[:, -4:].copy().view(np.int32)[:, 0]
+        assert idx[:k].tolist() == rows.tolist()
+        assert idx[k:].tolist() == [int(rows[0])] * (bucket - k)
+        got = matrix_mod.make_row_scatter()(before, pack)
+        want = DeviceArrays(*(
+            x.at[idx].set(m._alloc[f][idx])
+            for f, x in zip(DeviceArrays._fields, before)
+        ))
+        assert_bits_equal(got, want, f"{k} rows against .at[idx].set")
+        assert_bits_equal(got, host_mirror(m), f"{k} rows against the mirror")
+        # ... and the snapshot it was handed is left as it was: launches
+        # in flight still read it (no donation).
+        assert np.asarray(before.eligible).tobytes() != (
+            m._alloc["eligible"].tobytes())
+
+    def test_a_sync_hands_over_exactly_one_operand(self, monkeypatch):
+        m, _ = self._synced()
+        calls = []
+        real = matrix_mod._scatter_rows
+        monkeypatch.setattr(
+            matrix_mod, "_scatter_rows",
+            lambda *operands: calls.append(operands) or real(*operands),
+        )
+        bytes0 = m.upload_bytes_total
+        for n, k in enumerate([1, 2, 3, 33], start=1):
+            dirty_hard_rows(m, range(k))
+            dev = m.sync()
+            assert_bits_equal(dev, host_mirror(m), f"sync of {k} rows")
+            assert (m.scatter_syncs, m.scatter_operands_total) == (n, n)
+            device, pack = calls[-1]  # the matrix and ONE host buffer
+            assert isinstance(pack, np.ndarray) and pack.dtype == np.uint8
+            assert m.upload_bytes_total - bytes0 == pack.nbytes
+            bytes0 = m.upload_bytes_total
+        assert m.rows_scattered_total == 1 + 2 + 3 + 33
+        # A clean sync hands over nothing.
+        m.sync()
+        assert (m.scatter_syncs, m.scatter_operands_total) == (4, 4)
+        assert len(calls) == 4
+
+    def test_a_scatter_that_raises_puts_the_drained_rows_back(
+            self, monkeypatch):
+        m, before = self._synced()
+        dirty_hard_rows(m, [3, 9, 17])
+
+        def boom(device, pack):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(matrix_mod, "_scatter_rows", boom)
+        with pytest.raises(RuntimeError, match="device lost"):
+            m.sync()
+        assert m._dirty == {3, 9, 17}
+        assert m._device is before
+        assert (m.scatter_syncs, m.scatter_operands_total,
+                m.rows_scattered_total) == (0, 0, 0)
+        monkeypatch.undo()
+        assert_bits_equal(m.sync(), host_mirror(m), "the retry")
+        assert m._dirty == set() and m.scatter_operands_total == 1

@@ -17,7 +17,15 @@ from nomad_tpu.ops import kernels
 from nomad_tpu.ops.encode import RequestEncoder
 from nomad_tpu.state.matrix import NodeMatrix
 
-from helpers import LAUNCH_FILLS, check_packed_launch, lane_operands
+from helpers import (
+    LAUNCH_FILLS,
+    assert_bits_equal,
+    check_packed_launch,
+    check_sync_span,
+    dirty_hard_rows,
+    host_mirror,
+    lane_operands,
+)
 
 
 def _cluster(n_nodes=32, capacity=64, seed=0):
@@ -265,6 +273,14 @@ class TestPackedLaunch:
         want = NamedSharding(coal._mesh, P("batch"))
         assert all(x.sharding.is_equivalent_to(want, x.ndim) for x in small)
 
+    @pytest.mark.parametrize("devices,mesh_shape", [(2, (1, 2)), (4, (2, 2))])
+    def test_a_sync_span_counts_the_one_operand_the_scatter_hands_over(
+        self, eight_devices, devices, mesh_shape,
+    ):
+        coal = check_sync_span(devices)
+        assert coal.mesh_shape() == mesh_shape
+        assert coal.matrix.shard_count == mesh_shape[1]
+
 
 class TestTopkHostBytes:
     def test_host_fetch_is_node_count_independent(self, monkeypatch):
@@ -419,6 +435,69 @@ def _region_agent(shards):
                                         SEED % 2 ** 32)
     srv.matrix.set_usage(rows, used0.copy(), prio0)
     return agent, used0
+
+
+class TestShardedPackedScatter:
+    """``sync_sharded`` hands the mesh ONE packed host operand a sync (a
+    buffer a device, where thirteen operands were thirteen a device), and
+    the resident mirror stays the host's bit for bit."""
+
+    @staticmethod
+    def _resident(mesh_devices=8, node_shards=4):
+        from nomad_tpu.parallel.sharding import make_mesh
+
+        m = NodeMatrix(capacity=64)
+        m.set_shard_count(node_shards)
+        for _ in range(44):
+            m.upsert_node(mock.node())
+        mesh = make_mesh(mesh_devices, batch=mesh_devices // node_shards)
+        m.sync_sharded(mesh)
+        return m, mesh
+
+    @pytest.mark.parametrize("k", [1, 2, 3, 33])
+    def test_mirror_equals_the_host_bit_for_bit(self, eight_devices, k):
+        m, mesh = self._resident()
+        # Claims balance over the home shards, so the first rows of each
+        # block are live: walk the blocks round robin.
+        blk = m.capacity // m.shard_count
+        rows = [(i % 4) * blk + i // 4 for i in range(k)]
+        assert all(r in m.node_of for r in rows)
+        if k > 1:
+            assert len({m.home_shard(r) for r in rows}) == min(k, 4)
+        for n in range(1, 3):  # twice: a scatter onto a scattered mirror
+            dirty_hard_rows(m, rows)
+            dev = m.sync_sharded(mesh)
+            assert_bits_equal(dev, host_mirror(m), f"{k} rows, sync {n}")
+            assert (m.scatter_syncs, m.scatter_operands_total) == (n, n)
+            assert dev.used.sharding.spec[0] == "node"
+        assert m.full_uploads == 1
+        assert m.rows_scattered_total == 2 * k
+
+    def test_one_operand_a_sync_and_a_failed_scatter_retries(
+            self, eight_devices):
+        m, mesh = self._resident()
+        before, real, calls = m._sharded_device, m._sharded_scatter, []
+
+        def spy(*operands):
+            calls.append(operands)
+            if len(calls) == 1:
+                raise RuntimeError("device lost")
+            return real(*operands)
+
+        m._sharded_scatter = spy
+        dirty_hard_rows(m, [1, 17, 34])
+        with pytest.raises(RuntimeError, match="device lost"):
+            m.sync_sharded(mesh)
+        assert m._sharded_dirty == {1, 17, 34}
+        assert m._sharded_device is before
+        assert (m.scatter_syncs, m.scatter_operands_total) == (0, 0)
+        assert_bits_equal(m.sync_sharded(mesh), host_mirror(m), "the retry")
+        assert (m.scatter_syncs, m.scatter_operands_total) == (1, 1)
+        for device, pack in calls:  # the matrix and ONE host buffer
+            assert isinstance(pack, np.ndarray) and pack.dtype == np.uint8
+            assert pack.shape[0] == 4  # three rows in the bucket of four
+        # The one-chip copy keeps a dirty set of its own.
+        assert m._dirty >= {1, 17, 34}
 
 
 class TestShardedRegionServed:
