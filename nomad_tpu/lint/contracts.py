@@ -203,7 +203,9 @@ def fused_operands(g: Grid) -> Tuple[Any, ...]:
         np.zeros((b, MAX_SPREADS, MAX_SPREAD_VALUES), np.float32),
         np.zeros((b, n), bool),  # penalties
         _concrete_reqs(b),
-        np.ones((b, 1), bool),  # class_eligs
+        # class_eligs: live, (b, pow2_bucket(n_classes)): a width of the
+        # cluster's class count, whatever its node count or the lanes.
+        np.ones((b, 1), bool),
         np.ones((b, n), bool),  # host_masks
         lane_steps,
     )

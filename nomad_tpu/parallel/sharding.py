@@ -263,7 +263,9 @@ def _fused_place_batch_local(
     The distinct_property stage (``Features.dp_width`` > 0) carries its
     counts per node on each shard's slice; the picked node's property
     values ride the spread stage's broadcast, and "a node the limit alone
-    excluded scored higher" is one more ``pmax`` over 'node' a step.
+    excluded scored higher" is one more ``pmax`` over 'node' a step: what
+    the stage adds across shards is under the scope ``rules_exchange``
+    (inside ``update`` and ``update/broadcast``, where those ops were).
 
     The in-flight claims overlay (``overlay``: global rows, split over
     'batch' like the deltas; None = empty) is gathered with them and each
@@ -383,9 +385,11 @@ def _fused_place_batch_local(
             # The picked node's spread and property values, from its owner.
             nvals = spread_values_at(arrays, req_step, lwin)
             if features.dp_width:
-                nvals = jnp.concatenate([
-                    nvals, distinct_property_values_at(arrays, req_step, lwin)
-                ])
+                with jax.named_scope("rules_exchange"):
+                    nvals = jnp.concatenate([
+                        nvals,
+                        distinct_property_values_at(arrays, req_step, lwin),
+                    ])
             nvals = jnp.where(owner, nvals, 0)
             with jax.named_scope("broadcast"):
                 nvals = jax.lax.psum(nvals, "node")
@@ -421,7 +425,9 @@ def _fused_place_batch_local(
             grow, final, binp, pre,
         ) + tuple(jnp.where(active, c, 0) for c in counts)
         if features.dp_width:
-            with jax.named_scope("broadcast"):
+            with jax.named_scope("broadcast"), jax.named_scope(
+                "rules_exchange"
+            ):
                 blocked = jax.lax.pmax(res.dp_blocked_best, "node")
             out += (ok & (blocked > final),)
         return (u2, tg2, s_hash2, s_counts2, dp_cnt), out

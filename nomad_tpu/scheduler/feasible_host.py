@@ -148,7 +148,9 @@ class HostFeasibility:
     of ``check_constraint_value`` (one per distinct value on first sight);
     ``walked_nodes`` — nodes visited one by one in Python by the stack's
     fallback walk (``GenericStack._walk``): 0 where every attribute has a
-    column."""
+    column; ``walked_classes`` — computed classes visited one by one in
+    Python by a select (``GenericStack._class_eligibility``'s fallback over
+    the classes' representatives): 0 likewise."""
 
     MAX_ENTRIES = 256
 
@@ -156,6 +158,7 @@ class HostFeasibility:
         self.matrix = matrix
         self.predicates_evaluated = 0
         self.walked_nodes = 0
+        self.walked_classes = 0
         self._masks: Dict[tuple, Tuple[int, np.ndarray]] = {}
         # predicate -> (value ids seen, sorted; their verdicts)
         self._verdicts: Dict[tuple, Tuple[np.ndarray, np.ndarray]] = {}

@@ -157,8 +157,10 @@ class GenericStack:
         self.job: Optional[Job] = None
         # Eligibility telemetry consumed by blocked-eval creation
         # (reference: EvalEligibility, context.go:190; fills the eval's
-        # ClassEligibility / EscapedComputedClass fields).
-        self.class_eligibility: Dict[str, bool] = {}
+        # ClassEligibility / EscapedComputedClass fields).  A select keeps
+        # the verdicts as it computed them, one bool a class id; the dict
+        # by class key is built where an eval blocks (``class_eligibility``).
+        self._class_verdicts = np.zeros((0,), bool)
         self.escaped_computed_class = False
         # Alloc ids this pass is replacing or stopping — the ONLY live
         # volume claims a new placement may look through (set_replaced).
@@ -175,13 +177,30 @@ class GenericStack:
 
     def _record_eligibility(self, class_elig: np.ndarray, host_mask,
                             compiled: CompiledTaskGroup) -> None:
-        for key, cid in self.matrix.class_ids.items():
-            if cid < len(class_elig):
-                self.class_eligibility[key] = bool(class_elig[cid])
+        """Keep one select's verdicts for the classes the matrix knew then.
+        A later select (another group of the job) overwrites the classes it
+        saw and leaves the rest: no class is visited in Python here."""
+        n = min(len(self.matrix.class_ids), len(class_elig))
+        seen = self._class_verdicts
+        if n >= len(seen):
+            self._class_verdicts = class_elig[:n].copy()
+        else:
+            seen[:n] = class_elig[:n]
         if host_mask is not None or compiled.distinct_props:
             # Per-node (class-unhashable) checks were in play — the eval
             # escapes class caching and must retry on any capacity change.
             self.escaped_computed_class = True
+
+    @property
+    def class_eligibility(self) -> Dict[str, bool]:
+        """Class key -> the last verdict a select of this eval gave it
+        (the reference's ``EvalEligibility`` record), built on demand: only
+        an eval that blocks reads it.  ``matrix.class_ids`` is append-only
+        and in id order, so its first entries are the classes the selects
+        saw."""
+        seen = self._class_verdicts.tolist()
+        # (list(dict) is one call: a registration cannot grow it midway)
+        return dict(zip(list(self.matrix.class_ids), seen))
 
     # -- proposed-state assembly -------------------------------------------
 
@@ -296,7 +315,7 @@ class GenericStack:
         by class id.  Each constraint is evaluated once per distinct value
         of its attribute's column and broadcast (``HostFeasibility``); the
         representatives are visited one by one only where an attribute has
-        no column."""
+        no column, and counted (``nomad.sched.class_walk_total``)."""
         n_classes = max(1, len(self.matrix.class_ids))
         pad = _pow2_bucket(n_classes)
         escaped = [
@@ -313,7 +332,9 @@ class GenericStack:
         if elig is not None:
             return elig
         elig = np.ones((pad,), bool)
-        for cid, rep_node_id in list(self.matrix.class_repr.items()):
+        reprs = list(self.matrix.class_repr.items())
+        hf.walked_classes += len(reprs)
+        for cid, rep_node_id in reprs:
             node = self.ctx.snapshot.node_by_id(rep_node_id)
             if node is None:
                 continue
@@ -327,8 +348,9 @@ class GenericStack:
                      compiled: CompiledTaskGroup):
         """(class eligibility, host mask or None) of what the kernels do
         not evaluate, under the span ``sched.feasibility`` (tags: host-mask
-        terms ``escaped``, computed ``classes``, predicate ``values``
-        evaluated in Python: 0 once the masks are cached)."""
+        terms ``escaped``, computed ``classes``, the class operand's width
+        ``class_pad``, predicate ``values`` evaluated in Python: 0 once the
+        masks are cached)."""
         hf = self.matrix.host_feasibility()
         values0 = hf.predicates_evaluated
         with trace.span("sched.feasibility", cpu=True):
@@ -336,6 +358,7 @@ class GenericStack:
             host_mask = self._host_mask(job, tg, compiled)
             trace.add_args(
                 classes=len(self.matrix.class_ids),
+                class_pad=len(class_elig),
                 values=hf.predicates_evaluated - values0,
             )
         self._record_eligibility(class_elig, host_mask, compiled)

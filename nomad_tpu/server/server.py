@@ -285,8 +285,8 @@ class Server:
         # the scan and the picks it moved to another node; and the host's
         # side of feasibility: predicate evaluations in Python (one per
         # distinct value of an attribute's column, on first sight) and
-        # nodes walked one by one (0 where every attribute has a column:
-        # the regression alarm).
+        # nodes, and computed classes, walked one by one (0 where every
+        # attribute has a column: the regression alarms).
         m.gauge_fn(
             "nomad.kernel.distinct_property_lanes_total",
             lambda: c.distinct_property_lanes,
@@ -298,6 +298,10 @@ class Server:
         m.gauge_fn(
             "nomad.sched.host_walk_nodes_total",
             lambda: mx.host_feasibility().walked_nodes,
+        )
+        m.gauge_fn(
+            "nomad.sched.class_walk_total",
+            lambda: mx.host_feasibility().walked_classes,
         )
         m.gauge_fn(
             "nomad.sched.escaped_predicates_total",

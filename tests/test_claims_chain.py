@@ -801,10 +801,13 @@ def test_the_benchmark_lists_the_metrics_wherever_evals_per_s_is_read():
         assert entry["layer"] == "coalescer"
         assert entry["moves"] == "evals_per_s" and "workloads" not in entry
     # entries are appended, never inserted: the two stand together, after
-    # everything older (PR 44's four came after them)
+    # everything older (PR 44's four came after them, PR 46's three after
+    # those)
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index("chained_launch_share")
     assert names[at + 1] == "chained_rows_per_launch"
     assert names[at + 2:] == [
         "sched_feasibility_ms", "host_walk_nodes_per_eval",
-        "kernel_feasibility_share", "rules_place_batch_roofline"]
+        "kernel_feasibility_share", "rules_place_batch_roofline",
+        "sharded_rules_place_batch_roofline", "rules_exchange_share",
+        "class_walk_per_eval"]

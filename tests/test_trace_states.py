@@ -243,29 +243,44 @@ SCOPES = ("feasibility", "binpack", "affinity_spread", "preemption",
           "place_scan/update", "verify_scan", "pack")
 
 
+def _unwrapped(names):
+    """Scope paths with the wrappers transformations put around a scope
+    (``vmap(place_scan)``) taken off."""
+    return {re.sub(r"\b\w+\(([\w/]+)\)", r"\1", n) for n in names}
+
+
 @functools.lru_cache(maxsize=None)
-def _op_names(entry: str, variant: str):
-    """``op_name`` of every instruction of the compiled entry point, with
-    the wrappers transformations put around a scope (``vmap(place_scan)``)
-    taken off."""
+def _lowered(entry: str, variant: str):
     from nomad_tpu.lint.contracts import Grid, fused_operands
     from nomad_tpu.ops import kernels
     from nomad_tpu.parallel import sharding
 
     feats = kernels.FULL_FEATURES if variant == "full" else kernels.Features(
-        c_width=2, a_width=0, s_width=0, preempt=False, ports=False)
+        c_width=2, a_width=0, s_width=0, preempt=False, ports=False,
+        dp_width=0 if variant == "plain" else kernels.FULL_FEATURES.dp_width)
     g = Grid(nodes=64, batch=4, placements=16, deltas=4, live=3,
              features=feats)
     if entry == "fused_place_batch":
-        lowered = kernels.fused_place_batch.lower(
+        return kernels.fused_place_batch.lower(
             *fused_operands(g), n_placements=g.placements, features=feats)
-    else:
-        fn = sharding.sharded_fused_place_batch(
-            sharding.make_mesh(4), g.placements)
-        lowered = fn.lower(*fused_operands(g), features=feats)
-    text = lowered.compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
-    return {re.sub(r"\b\w+\(([\w/]+)\)", r"\1", n) for n in names}
+    fn = sharding.sharded_fused_place_batch(
+        sharding.make_mesh(4), g.placements)
+    return fn.lower(*fused_operands(g), features=feats)
+
+
+@functools.lru_cache(maxsize=None)
+def _op_names(entry: str, variant: str):
+    """``op_name`` of every instruction of the compiled entry point."""
+    text = _lowered(entry, variant).compile().as_text()
+    return _unwrapped(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _traced_names(entry: str, variant: str):
+    """Name stacks of the program as traced, before XLA merges ops (it
+    combines a step's small all-reduces into one, under one of their
+    names)."""
+    text = _lowered(entry, variant).as_text(debug_info=True)
+    return _unwrapped(re.findall(r'loc\("([^"]*)"', text))
 
 
 @pytest.mark.parametrize("scope", SCOPES)
@@ -304,6 +319,25 @@ def test_exchange_scope_in_the_sharded_entry_alone(eight_devices, scope):
     # (a scope is never an op_name's last part: that is the primitive, and
     # one of JAX's is called ``gather``)
     assert not any(re.search(r"(^|/)%s/" % last, n) for n in one_chip)
+
+
+def test_rules_exchange_scope_where_a_distinct_property_is_compiled_in(
+        eight_devices):
+    """What the distinct_property stage adds across shards (its values on
+    the spread stage's broadcast, one ``pmax`` a step) is under
+    ``rules_exchange``, inside the scopes those ops were under; a program
+    at ``dp_width`` 0, and the one-chip entry, have no such scope."""
+    pat = re.compile(r"(^|/)rules_exchange/")
+    wide = _op_names("sharded_fused_place_batch", "full")
+    assert any(re.search(r"place_scan/(.*/)?update/(.*/)?rules_exchange/", n)
+               for n in wide), sorted(wide)[:20]
+    traced = _traced_names("sharded_fused_place_batch", "full")
+    assert any(re.search(r"broadcast/rules_exchange/pmax$", n)
+               for n in traced), sorted(n for n in traced if pat.search(n))
+    for entry, variant in (("sharded_fused_place_batch", "plain"),
+                           ("fused_place_batch", "full")):
+        assert not any(pat.search(n) for n in _op_names(entry, variant))
+        assert not any(pat.search(n) for n in _traced_names(entry, variant))
 
 
 # ----------------------------------------------------------------------
