@@ -186,9 +186,11 @@ def _check_predicate(hash_T, numver_T, slot, op, want_hash, want_num):
     """
     nattrs = hash_T.shape[0]
     safe_slot = jnp.maximum(slot, 0)
-    h = hash_T[safe_slot]  # (N,) contiguous
+    h = lax.dynamic_index_in_dim(hash_T, safe_slot, 0, False)  # (N,)
     is_ver = op >= OP_VER_EQ
-    v = numver_T[safe_slot + jnp.where(is_ver, nattrs, 0)]  # (N,) contiguous
+    v = lax.dynamic_index_in_dim(
+        numver_T, safe_slot + jnp.where(is_ver, nattrs, 0), 0, False
+    )  # (N,) contiguous
     present = h != 0
 
     # Scalar op-class selectors (broadcast against the (N,) vectors).
@@ -221,6 +223,35 @@ def _tables(arrays):
     return hash_T, numver_T
 
 
+def _attr_rows(table_T, slots, width: int):
+    """(width, N): row ``slots[i]`` (row 0 for an empty slot, -1) of a
+    transposed attribute table for the first ``width`` (static) slots, one
+    contiguous dynamic slice a slot.  A ``table_T[slots]`` (or a ``vmap``
+    over the slots) is a gather, which the TPU compiler runs as a serial
+    loop with one strided read-modify-write a turn (PERF.md section 5,
+    PR 47)."""
+    if width == 0:  # no lane carries the stage: nothing of it is read
+        return jnp.zeros((0,) + table_T.shape[1:], table_T.dtype)
+    return jnp.stack([
+        lax.dynamic_index_in_dim(
+            table_T, jnp.maximum(slots[i], 0), 0, keepdims=False
+        )
+        for i in range(width)
+    ])
+
+
+def _check_predicates(arrays, slot, op, want_hash, want_num, width: int):
+    """(width, N) bool: ``_check_predicate`` of a request's first ``width``
+    (static) predicate slots, constraints or affinities."""
+    hash_T, numver_T = _tables(arrays)
+    return jnp.stack([
+        _check_predicate(
+            hash_T, numver_T, slot[i], op[i], want_hash[i], want_num[i]
+        )
+        for i in range(width)
+    ])
+
+
 def constraint_mask(
     arrays, req: SchedRequest, c_width: int = MAX_CONSTRAINTS
 ) -> jnp.ndarray:
@@ -232,15 +263,8 @@ def constraint_mask(
     n = arrays.attr_hash.shape[0]
     if c_width == 0:
         return jnp.ones((n,), bool)
-    hash_T, numver_T = _tables(arrays)
-    check = jax.vmap(
-        lambda s, o, h, n_: _check_predicate(hash_T, numver_T, s, o, h, n_)
-    )
-    per_constraint = check(
-        req.c_slot[:c_width],
-        req.c_op[:c_width],
-        req.c_hash[:c_width],
-        req.c_num[:c_width],
+    per_constraint = _check_predicates(
+        arrays, req.c_slot, req.c_op, req.c_hash, req.c_num, c_width
     )  # (c_width, N)
     return jnp.all(per_constraint, axis=0)
 
@@ -303,27 +327,33 @@ def feasibility_mask(arrays, req: SchedRequest,
     mask &= device_mask(arrays, req)
     mask &= port_mask(arrays, req, features.ports)
     if class_elig is not None:
-        cid = jnp.maximum(arrays.class_id, 0)
-        mask &= jnp.where(arrays.class_id < 0, False, class_elig[cid])
+        mask &= class_mask(arrays, class_elig)
     if host_mask is not None:
         mask &= host_mask
     return mask
 
 
-def _distinct_property_columns(arrays, req: SchedRequest, dp_width: int):
-    """((W,) bool active, (W, N) i32 value id of every node) of the
-    request's first ``dp_width`` distinct_property slots: the attribute's
-    hash column, 0 where a node lacks it."""
-    slot = req.dp_slot[:dp_width]
-    return slot >= 0, arrays.attr_hash.T[jnp.maximum(slot, 0)]
+def class_mask(arrays, class_elig):
+    """(N,) bool — the node's computed class is eligible: ``class_elig``
+    ((num_classes,) bool) gathered per node by its class id."""
+    cid = jnp.maximum(arrays.class_id, 0)
+    return jnp.where(arrays.class_id < 0, False, class_elig[cid])
 
 
-def distinct_property_counts(arrays, req: SchedRequest, dp_width: int):
+def distinct_property_columns(arrays, req: SchedRequest, dp_width: int):
+    """(W, N) i32 — every node's value id of the request's first
+    ``dp_width`` distinct_property slots: the attribute's hash column, 0
+    where a node lacks it.  The same in every step of a scan
+    (``lane_invariants``)."""
+    return _attr_rows(arrays.attr_hash.T, req.dp_slot, dp_width)
+
+
+def distinct_property_counts(col, req: SchedRequest, dp_width: int):
     """(W, N) f32 — the scan's first carry of the distinct_property stage:
     for every node, the allocs the job already holds on nodes that share
     its value of the property (``req.dp_value_hash`` / ``req.dp_count``,
-    seeded by the stack from the live and proposed allocations)."""
-    _, col = _distinct_property_columns(arrays, req, dp_width)
+    seeded by the stack from the live and proposed allocations).  ``col``:
+    ``distinct_property_columns``."""
     value_hash = req.dp_value_hash[:dp_width]  # (W, V)
     vmatch = (col[:, :, None] == value_hash[:, None, :]) & (
         value_hash[:, None, :] != 0
@@ -333,13 +363,13 @@ def distinct_property_counts(arrays, req: SchedRequest, dp_width: int):
     )
 
 
-def distinct_property_mask(arrays, req: SchedRequest, dp_cnt, dp_width: int):
+def distinct_property_mask(col, req: SchedRequest, dp_cnt, dp_width: int):
     """(N,) bool — the nodes every distinct_property limit still admits
     (DistinctPropertyIterator, feasible.go:604-700): the node has the
     property, and its value holds fewer allocs of the job than the limit.
     ``dp_cnt`` (W, N) changes from pick to pick (``distinct_property_pick``):
     this is the feasibility term that is NOT loop-invariant."""
-    active, col = _distinct_property_columns(arrays, req, dp_width)
+    active = req.dp_slot[:dp_width] >= 0
     full = (col == 0) | (dp_cnt >= req.dp_limit[:dp_width, None])
     return ~jnp.any(active[:, None] & full, axis=0)
 
@@ -350,11 +380,11 @@ def distinct_property_values_at(arrays, req: SchedRequest, row):
     return arrays.attr_hash[row, jnp.maximum(req.dp_slot, 0)]
 
 
-def distinct_property_pick(arrays, req: SchedRequest, dp_cnt, values,
+def distinct_property_pick(col, req: SchedRequest, dp_cnt, values,
                            dp_width: int):
     """``dp_cnt`` after a pick on a node whose property values are
     ``values`` ((DP,) i32): one more alloc on every node that shares one."""
-    active, col = _distinct_property_columns(arrays, req, dp_width)
+    active = req.dp_slot[:dp_width] >= 0
     v = values[:dp_width, None]
     return dp_cnt + ((col == v) & (v != 0) & active[:, None])
 
@@ -371,9 +401,10 @@ def system_feasible(arrays, used0, req: SchedRequest, class_elig, host_mask):
     mask = feasibility_mask(arrays, req, class_elig, host_mask)
     # A system job places one alloc a node: the limit as the seeds alone
     # read it (the host re-checks what it takes, system.py).
+    col = distinct_property_columns(arrays, req, MAX_DISTINCT_PROPS)
     mask &= distinct_property_mask(
-        arrays, req,
-        distinct_property_counts(arrays, req, MAX_DISTINCT_PROPS),
+        col, req,
+        distinct_property_counts(col, req, MAX_DISTINCT_PROPS),
         MAX_DISTINCT_PROPS,
     )
     fits, _, _ = fit_and_binpack(arrays, used0, req)
@@ -445,14 +476,10 @@ def affinity_score(arrays, req: SchedRequest, a_width: int = MAX_AFFINITIES):
     if a_width == 0:
         zeros = jnp.zeros((n,), jnp.float32)
         return zeros, jnp.zeros((n,), bool)
-    hash_T, numver_T = _tables(arrays)
-    check = jax.vmap(
-        lambda s, o, h, n_: _check_predicate(hash_T, numver_T, s, o, h, n_)
-    )
     a_slot = req.a_slot[:a_width]
     a_weight = req.a_weight[:a_width]
-    matches = check(
-        a_slot, req.a_op[:a_width], req.a_hash[:a_width], req.a_num[:a_width]
+    matches = _check_predicates(
+        arrays, req.a_slot, req.a_op, req.a_hash, req.a_num, a_width
     )  # (a_width, N)
     active = (a_slot >= 0)[:, None]  # (a_width, 1)
     matched = matches & active
@@ -464,23 +491,32 @@ def affinity_score(arrays, req: SchedRequest, a_width: int = MAX_AFFINITIES):
 
 
 @jax.named_scope("affinity_spread")
-def spread_score(arrays, req: SchedRequest, spread_counts,
+def spread_columns(arrays, req: SchedRequest, s_width: int = MAX_SPREADS):
+    """(s_width, N) i32 — every node's value of each spread stanza's
+    attribute (its hash, 0 = unset).  The same in every step of a scan
+    (``lane_invariants``); what a step matches against the value table and
+    the counts it carries (``spread_score``)."""
+    return _attr_rows(arrays.attr_hash.T, req.s_slot, s_width)
+
+
+@jax.named_scope("affinity_spread")
+def spread_score(nvalues, req: SchedRequest, spread_counts,
                  s_width: int = MAX_SPREADS):
     """SpreadIterator (spread.go:110-257).
 
+    ``nvalues`` (s_width, N) i32 — ``spread_columns``.
     ``spread_counts`` (S, V) f32 — usage count per known attribute value
     (existing + proposed allocs of this TG), aligned with req.s_value_hash.
     ``s_width`` (static) bounds the stanza loop to the batch occupancy.
     Returns (score (N,), appended (N,)).
     """
-    n = arrays.attr_hash.shape[0]
+    n = nvalues.shape[1]
     if s_width == 0:
         return jnp.zeros((n,), jnp.float32), jnp.zeros((n,), bool)
-    hash_T = arrays.attr_hash.T  # batch-invariant, CSE'd with _tables
 
-    def one_stanza(slot, weight, even, value_hash, desired, implicit, counts):
+    def one_stanza(slot, nvalue, weight, even, value_hash, desired, implicit,
+                   counts):
         active = slot >= 0
-        nvalue = hash_T[jnp.maximum(slot, 0)]  # (N,) contiguous
         node_has = nvalue != 0
 
         # match node value against the known-values table
@@ -536,6 +572,7 @@ def spread_score(arrays, req: SchedRequest, spread_counts,
 
     per_stanza = jax.vmap(one_stanza)(
         req.s_slot[:s_width],
+        nvalues,
         req.s_weight[:s_width],
         req.s_even[:s_width],
         req.s_value_hash[:s_width],
@@ -617,6 +654,81 @@ class ScoreResult(NamedTuple):
     dp_blocked_best: Optional[jnp.ndarray] = None
 
 
+class LaneInvariants(NamedTuple):
+    """What every placement step of a lane's scan shares: a function of
+    the matrix snapshot and the lane's request alone, never of the scan's
+    carry (``lane_invariants``)."""
+
+    feasible: jnp.ndarray  # (N,) bool: ``feasibility_mask``, all of it
+    affinity: tuple  # ``affinity_score``: (score (N,) f32, appended (N,) bool)
+    spread_values: jnp.ndarray  # (s_width, N) i32: ``spread_columns``
+    dp_values: jnp.ndarray  # (dp_width, N) i32: ``distinct_property_columns``
+    # ``preemption_state``: (freeable (N, 3), score (N,), usable (N,));
+    # None with preemption off.
+    preemption: Optional[tuple]
+
+
+def lane_invariants(arrays, req: SchedRequest, class_elig, host_mask,
+                    features: Features = FULL_FEATURES) -> LaneInvariants:
+    """The terms of ``score_nodes`` that no pick changes, computed once a
+    launch, before the placement scan and inside the same program (PR 47):
+    the static feasibility mask, the affinity term, the attribute columns
+    the spread and distinct_property stages match a step's carry against,
+    and the preemption tables' rows.  Each under the scope its work always
+    had, so a profile attributes it as before."""
+    with jax.named_scope("feasibility"), jax.named_scope("distinct_property"):
+        dp_values = distinct_property_columns(arrays, req, features.dp_width)
+    return LaneInvariants(
+        feasible=feasibility_mask(arrays, req, class_elig, host_mask, features),
+        affinity=affinity_score(arrays, req, features.a_width),
+        spread_values=spread_columns(arrays, req, features.s_width),
+        dp_values=dp_values,
+        preemption=preemption_state(arrays, req) if features.preempt else None,
+    )
+
+
+def launch_invariants(arrays, reqs, class_eligs, host_masks,
+                      features: Features, n_lanes, vary=lambda x: x):
+    """``lane_invariants`` of a launch's first ``n_lanes`` lanes (traced:
+    the last live lane + 1), stacked on a leading lane axis; the lanes past
+    them are dead, nothing reads theirs, and they keep zeros.
+
+    One loop over those lanes, not a ``vmap`` over all of them: in a lane's
+    turn every attribute column it reads is a contiguous row slice that
+    fuses into the arithmetic that reads it, where the vmapped read is a
+    gather, which the TPU compiler runs as a serial loop of its own with a
+    strided write a turn, for every lane the launch is padded to (64, of
+    which a closed loop fills 6-8: PERF.md section 5, PR 47).  ``vary``: the
+    sharded program's cast of the buffers to the mesh axes a lane's terms
+    vary over."""
+    lanes = class_eligs.shape[0]
+    # The class table's gather is the one read that is better vmapped: the
+    # class ids are every lane's, so one gather fetches all lanes' verdicts
+    # of a node, where a lane alone gathers 51,200 scalars (0.38 ms a lane
+    # on a v5e: PERF.md section 6, PR 47).
+    with jax.named_scope("feasibility"):
+        masks = host_masks & jax.vmap(
+            lambda ce: class_mask(arrays, ce)
+        )(class_eligs)
+
+    def one(b):
+        req, mask = jax.tree_util.tree_map(
+            lambda x: lax.dynamic_index_in_dim(x, b, 0, keepdims=False),
+            (reqs, masks),
+        )
+        return lane_invariants(arrays, req, None, mask, features)
+
+    shapes, tree = jax.tree_util.tree_flatten(jax.eval_shape(one, 0))
+    with jax.named_scope("lane_invariants"):
+        _, outs = scan_steps(
+            lambda carry, b: (carry, jax.tree_util.tree_leaves(one(b))),
+            (),
+            [vary(jnp.zeros((lanes,) + s.shape, s.dtype)) for s in shapes],
+            n_lanes,
+        )
+    return jax.tree_util.tree_unflatten(tree, outs)
+
+
 def score_nodes(
     arrays,
     used,
@@ -630,8 +742,33 @@ def score_nodes(
     node_axis: Optional[str] = None,
     dp_cnt=None,
 ) -> ScoreResult:
-    """The full ranking pipeline as one fused program (GenericStack.Select,
-    stack.go:117-179, minus the sampling the TPU design makes unnecessary).
+    """The full ranking pipeline in one call (GenericStack.Select,
+    stack.go:117-179, minus the sampling the TPU design makes unnecessary):
+    ``rank_nodes`` on ``lane_invariants``.  A scan computes the invariants
+    once and ranks every step against them."""
+    inv = lane_invariants(arrays, req, class_elig, host_mask, features)
+    return rank_nodes(
+        arrays, inv, used, tg_count, spread_counts, penalty_mask, req,
+        features, node_axis, dp_cnt,
+    )
+
+
+def rank_nodes(
+    arrays,
+    inv: LaneInvariants,
+    used,
+    tg_count,
+    spread_counts,
+    penalty_mask,
+    req: SchedRequest,
+    features: Features = FULL_FEATURES,
+    node_axis: Optional[str] = None,
+    dp_cnt=None,
+) -> ScoreResult:
+    """One step's ranking from the lane's invariants (``inv``) and what the
+    scan carries: proposed usage, the job's allocs per node, the spread
+    stage's value table (``req.s_value_hash``) and counts, the
+    distinct_property stage's counts.
 
     ``features`` (static) bounds every sub-pass to the dispatch's batch
     occupancy — padded constraint/affinity/spread slots, unused preemption
@@ -662,10 +799,9 @@ def score_nodes(
     of a preempting pick carries, beside the ranked mean in SCORE, the sum
     of the two estimated terms in BINPACK and the number of terms of the
     mean in PREEMPT (0.0 = no eviction): the host swaps the two terms."""
-    feas = feasibility_mask(arrays, req, class_elig, host_mask, features)
     # distinct_hosts: one proposed alloc of this job+TG per node, enforced
     # in-scan via tg_count so multi-placement batches can't stack a node.
-    feas &= ~(req.distinct_hosts & (tg_count > 0))
+    feas = inv.feasible & ~(req.distinct_hosts & (tg_count > 0))
     # distinct_property: at most ``limit`` allocs of the job a value, by the
     # per-node counts the scan carries (``dp_cnt`` (W, N); None = the seeds).
     dp_ok = None
@@ -675,16 +811,16 @@ def score_nodes(
         ):
             if dp_cnt is None:
                 dp_cnt = distinct_property_counts(
-                    arrays, req, features.dp_width
+                    inv.dp_values, req, features.dp_width
                 )
             dp_ok = distinct_property_mask(
-                arrays, req, dp_cnt, features.dp_width
+                inv.dp_values, req, dp_cnt, features.dp_width
             )
             feas_open, feas = feas, feas & dp_ok
     fits, binpack, exhausted = fit_and_binpack(arrays, used, req)
 
     if features.preempt:
-        freeable, pre_score, pre_usable = preemption_state(arrays, req)
+        freeable, pre_score, pre_usable = inv.preemption
         with jax.named_scope("preemption"):
             util = used + req.ask[None, :]
             deficit = jnp.maximum(util - arrays.totals, 0.0)
@@ -708,8 +844,8 @@ def score_nodes(
 
     aa_score, aa_app = anti_affinity_score(tg_count, req)
     pen_score, pen_app = penalty_score(penalty_mask)
-    aff_score, aff_app = affinity_score(arrays, req, features.a_width)
-    spr_score, spr_app = spread_score(arrays, req, spread_counts,
+    aff_score, aff_app = inv.affinity
+    spr_score, spr_app = spread_score(inv.spread_values, req, spread_counts,
                                       features.s_width)
 
     total = binpack + aa_score + pen_score + aff_score + spr_score + pre_component
@@ -821,16 +957,17 @@ def scan_steps(step, init, outs, trip):
     return lax.fori_loop(0, trip, body, (init, tuple(outs)))
 
 
-def _score_step(arrays, req: SchedRequest, carry, penalty_mask, class_elig,
-                host_mask, features: Features):
-    """One placement step's scores from the scan's carry: (the request as
-    this step reads it, its ``ScoreResult``, the three node counts)."""
+def _score_step(arrays, inv: LaneInvariants, req: SchedRequest, carry,
+                penalty_mask, features: Features):
+    """One placement step's scores from the lane's invariants and the
+    scan's carry: (the request as this step reads it, its ``ScoreResult``,
+    the three node counts)."""
     used, tg_cnt, s_hash, s_counts, dp_cnt = carry
     req_step = req._replace(s_value_hash=s_hash)
     with jax.named_scope("score"):
-        res = score_nodes(
-            arrays, used, tg_cnt, s_counts, penalty_mask, req_step,
-            class_elig, host_mask, features, dp_cnt=dp_cnt,
+        res = rank_nodes(
+            arrays, inv, used, tg_cnt, s_counts, penalty_mask, req_step,
+            features, dp_cnt=dp_cnt,
         )
     with jax.named_scope("pick"):
         counts = (
@@ -841,8 +978,8 @@ def _score_step(arrays, req: SchedRequest, carry, penalty_mask, class_elig,
     return req_step, res, counts
 
 
-def scan_carry(arrays, req: SchedRequest, used0, tg_count, spread_counts,
-               features: Features):
+def scan_carry(inv: LaneInvariants, req: SchedRequest, used0, tg_count,
+               spread_counts, features: Features):
     """A request's carry at the scan's first step: proposed usage, the
     job's allocs per node, the spread stage's value table and counts, the
     distinct_property stage's counts per node ((dp_width, N))."""
@@ -852,12 +989,14 @@ def scan_carry(arrays, req: SchedRequest, used0, tg_count, spread_counts,
         dp_cnt = jnp.zeros((0,) + tg_count.shape, jnp.float32)
         return used0, tg_count, req.s_value_hash, spread_counts, dp_cnt
     with jax.named_scope("feasibility"), jax.named_scope("distinct_property"):
-        dp_cnt = distinct_property_counts(arrays, req, features.dp_width)
+        dp_cnt = distinct_property_counts(
+            inv.dp_values, req, features.dp_width
+        )
     return used0, tg_count, req.s_value_hash, spread_counts, dp_cnt
 
 
-def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
-                 counts, row, ok, dp_width: int = 0):
+def _commit_step(arrays, inv: LaneInvariants, req_step: SchedRequest, carry,
+                 res: ScoreResult, counts, row, ok, dp_width: int = 0):
     """Charge a step's pick (``row``; nothing where ``ok`` is false) to the
     scan's carry; returns (carry, the step's seven output columns, and at
     ``dp_width`` > 0 an eighth: a node a distinct_property limit alone
@@ -874,7 +1013,7 @@ def _commit_step(arrays, req_step: SchedRequest, carry, res: ScoreResult,
         s_counts2 = jnp.where(ok, new_counts, s_counts)
         if dp_width:
             dp_cnt = jnp.where(ok, distinct_property_pick(
-                arrays, req_step, dp_cnt,
+                inv.dp_values, req_step, dp_cnt,
                 distinct_property_values_at(arrays, req_step, safe_row),
                 dp_width,
             ), dp_cnt)
@@ -913,17 +1052,19 @@ def _place_scan(
 
     def step(carry, _):
         req_step, res, counts = _score_step(
-            arrays, req, carry, penalty_mask, class_elig, host_mask, features
+            arrays, inv, req, carry, penalty_mask, features
         )
         with jax.named_scope("pick"):
             row = jnp.argmax(res.final).astype(jnp.int32)
             ok = res.final[row] > NEG_INF / 2
             row = jnp.where(ok, row, -1)
         return _commit_step(
-            arrays, req_step, carry, res, counts, row, ok, features.dp_width
+            arrays, inv, req_step, carry, res, counts, row, ok,
+            features.dp_width,
         )
 
-    init = scan_carry(arrays, req, used0, tg_count, spread_counts, features)
+    inv = lane_invariants(arrays, req, class_elig, host_mask, features)
+    init = scan_carry(inv, req, used0, tg_count, spread_counts, features)
     with jax.named_scope("place_scan"):
         (used_after, tg_after, *_), outs = lax.scan(
             step, init, None, length=n_placements
@@ -1326,25 +1467,25 @@ def _fused_place_batch_impl(
         add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
         return used.at[jnp.maximum(drows, 0)].add(add)
 
-    def score(carry, pen, req, ce, hm):
+    def score(inv, carry, pen, req):
         req_step, res, counts = _score_step(
-            arrays, req, carry, pen, ce, hm, features
+            arrays, inv, req, carry, pen, features
         )
         with jax.named_scope("pick"):
             own = jnp.argmax(res.final).astype(jnp.int32)
         return req_step, res, counts, own, res.final[own] > NEG_INF / 2
 
-    def commit(carry, req_step, res, counts, row, active):
+    def commit(inv, carry, req_step, res, counts, row, active):
         counts = tuple(jnp.where(active, c, 0) for c in counts)
         return _commit_step(
-            arrays, req_step, carry, res, counts, row, row >= 0,
+            arrays, inv, req_step, carry, res, counts, row, row >= 0,
             features.dp_width,
         )
 
     def step(state, i):
         carry, claims = state
         req_step, res, counts, own, own_ok = jax.vmap(score)(
-            carry, penalties, reqs, class_eligs, host_masks
+            invs, carry, penalties, reqs
         )
         # A lane that asked for fewer steps than the launch runs takes no
         # placement here: no usage charged, inert row.
@@ -1378,17 +1519,21 @@ def _fused_place_batch_impl(
                 (claims, jnp.full((lanes,), -1, jnp.int32)),
             )
         carry, out = jax.vmap(commit)(
-            carry, req_step, res, counts, rows, active
+            invs, carry, req_step, res, counts, rows, active
         )
         return (carry, claims), (
             out[:7] + ((rows >= 0) & (rows != own),) + out[7:]
         )
 
+    # What the steps of a lane all share, once a launch (PR 47).
+    invs = launch_invariants(
+        arrays, reqs, class_eligs, host_masks, features, last_lane
+    )
     init = jax.vmap(
-        lambda req, drows, dvals, tg, sc: scan_carry(
-            arrays, req, lane_used0(drows, dvals), tg, sc, features
+        lambda inv, req, drows, dvals, tg, sc: scan_carry(
+            inv, req, lane_used0(drows, dvals), tg, sc, features
         )
-    )(reqs, delta_rows, delta_vals, tg_counts, spread_counts)
+    )(invs, reqs, delta_rows, delta_vals, tg_counts, spread_counts)
     with jax.named_scope("place_scan"):
         _, outs = scan_steps(
             step, (init, claims_image(claimed, delta_rows, delta_vals, live)),

@@ -48,10 +48,11 @@ from ..ops.kernels import (
     distinct_property_values_at,
     fused_trip_counts,
     inert_lane_outputs,
+    launch_invariants,
     pack_fused_lanes,
+    rank_nodes,
     scan_carry,
     scan_steps,
-    score_nodes,
     spread_values_at,
     unpack_lanes,
 )
@@ -336,12 +337,12 @@ def _fused_place_batch_local(
             jnp.where((mine & valid)[..., None], dvals, 0.0).reshape(-1, 3)
         )
 
-    def score(carry, pen, req, ce, hm):
+    def score(inv, carry, pen, req):
         u, tg_cnt, s_hash, s_counts, dp_cnt = carry
         req_step = req._replace(s_value_hash=s_hash)
         with jax.named_scope("score"):
-            res = score_nodes(
-                arrays, u, tg_cnt, s_counts, pen, req_step, ce, hm,
+            res = rank_nodes(
+                arrays, inv, u, tg_cnt, s_counts, pen, req_step,
                 features=features, node_axis="node", dp_cnt=dp_cnt,
             )
         # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
@@ -374,7 +375,7 @@ def _fused_place_batch_local(
                 )
         return req_step, res, counts, own
 
-    def commit(carry, req_step, res, counts, grow, active):
+    def commit(inv, carry, req_step, res, counts, grow, active):
         u, tg_cnt, s_hash, s_counts, dp_cnt = carry
         ok = grow >= 0
         owner, lwin = local_rows(grow)
@@ -401,7 +402,7 @@ def _fused_place_batch_local(
             s_counts2 = jnp.where(ok, new_counts, s_counts)
             if features.dp_width:
                 dp_cnt = jnp.where(ok, distinct_property_pick(
-                    arrays, req_step, dp_cnt, nvals[n_spreads:],
+                    inv.dp_values, req_step, dp_cnt, nvals[n_spreads:],
                     features.dp_width,
                 ), dp_cnt)
 
@@ -435,7 +436,7 @@ def _fused_place_batch_local(
     def step(state, i):
         carry, claims = state
         req_step, res, counts, own = jax.vmap(score)(
-            carry, penalties, reqs, class_eligs, host_masks
+            invs, carry, penalties, reqs
         )
         active = i < lane_steps  # (b_local,)
         own = jnp.where(active, own, -1)
@@ -480,18 +481,25 @@ def _fused_place_batch_local(
             )
         rows = jax.lax.dynamic_slice_in_dim(g_rows, b_first, b_local)
         carry, out = jax.vmap(commit)(
-            carry, req_step, res, counts, rows, active
+            invs, carry, req_step, res, counts, rows, active
         )
         return (carry, claims), (
             out[:7] + ((rows >= 0) & (rows != own), g_rows) + out[7:]
         )
 
+    # What the steps of a lane all share, once a launch, for this shard's
+    # rows and the live lanes among its own.
+    invs = launch_invariants(
+        arrays, reqs, class_eligs, host_masks, features,
+        jnp.clip(last_lane - b_first, 0, b_local),
+        lambda x: vary(x, ("batch", "node")),
+    )
     init = jax.vmap(
-        lambda req, drows, dvals, tg, sc: scan_carry(
-            arrays, req, add_deltas(used, drows, dvals, drows >= 0), tg, sc,
+        lambda inv, req, drows, dvals, tg, sc: scan_carry(
+            inv, req, add_deltas(used, drows, dvals, drows >= 0), tg, sc,
             features,
         )
-    )(reqs, delta_rows, delta_vals, tg_counts, spread_counts)
+    )(invs, reqs, delta_rows, delta_vals, tg_counts, spread_counts)
     # Shared usage as the claims and the verify see it; the scores do not.
     claimed = vary(used)
     with jax.named_scope("overlay"):

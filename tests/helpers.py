@@ -92,7 +92,8 @@ def lane_operands(matrix, requests, deltas=None, penalties=None,
     return drows, dvals, tg, sc, pen, slab.batch(), ce, hm
 
 
-def solo_reference(arrays, operands, n_placements, lanes=None):
+def solo_reference(arrays, operands, n_placements, lanes=None,
+                   features=None):
     """Each lane (or each of ``lanes``) of ``operands`` (``lane_operands``'
     tuple) alone through ``place_task_group`` (the static scan over the
     dense proposed usage), packed (B, P, 7) in the ``PACKED_*`` column
@@ -105,6 +106,7 @@ def solo_reference(arrays, operands, n_placements, lanes=None):
     from nomad_tpu.ops import kernels
 
     drows, dvals, tg, sc, pen, reqs, ce, hm = operands
+    features = features or kernels.FULL_FEATURES
     out = []
     for i in (range(len(drows)) if lanes is None else lanes):
         live = drows[i] >= 0
@@ -112,7 +114,7 @@ def solo_reference(arrays, operands, n_placements, lanes=None):
         r = kernels.place_task_group(
             arrays, jax.tree_util.tree_map(lambda x: x[i], reqs), used0,
             jnp.asarray(tg[i]), jnp.asarray(sc[i]), jnp.asarray(pen[i]),
-            jnp.asarray(ce[i]), jnp.asarray(hm[i]), n_placements,
+            jnp.asarray(ce[i]), jnp.asarray(hm[i]), n_placements, features,
         )
         out.append(np.stack([
             np.asarray(c, np.float32) for c in (
@@ -422,3 +424,125 @@ def check_sync_span(n_device_shards=1):
     clean = sync_args()
     assert (clean["operands"], clean["rows"], clean["bytes"]) == (0, 0, 0)
     return coal
+
+
+def random_launch(seed, nodes, lanes, features, live=None, steps=4):
+    """Randomised operands of one fused launch, every stage engaged as far
+    as ``features`` goes: ``(arrays, used, delta_rows, delta_vals,
+    tg_counts, spread_counts, penalties, reqs, class_eligs, host_masks,
+    lane_steps)`` in ``fused_place_batch``'s order, numpy throughout.
+    Attribute values come from a vocabulary of five (0 = unset), so
+    constraints, affinities, spreads and limits all hit and miss; asks are
+    a tenth of a node, so lanes contend for the emptier nodes."""
+    import numpy as np
+
+    from nomad_tpu.ops import encode as e
+    from nomad_tpu.ops.kernels import device_request
+    from nomad_tpu.state import matrix as mx
+
+    rng = np.random.default_rng(seed)
+    f32, i32 = np.float32, np.int32
+    n, b = nodes, lanes
+    vocab = lambda *shape: rng.choice(
+        6, shape, p=[.05, .19, .19, .19, .19, .19]).astype(i32)
+    small = lambda *shape: rng.integers(0, 4, shape).astype(f32)
+    totals = np.tile(np.array([[4000.0, 8192.0, 1000.0]], f32), (n, 1))
+    prio = (rng.random((n, mx.PRIORITY_BUCKETS, 3)) < 0.1) * totals[:, None] / 8
+    num = small(n, mx.ATTR_SLOTS)
+    num[rng.random(num.shape) < 0.05] = np.nan
+    arrays = mx.DeviceArrays(
+        totals=totals,
+        used=(totals * rng.choice([0.2, 0.5, 0.95], (n, 1))).astype(f32),
+        eligible=rng.random(n) < 0.9,
+        attr_hash=vocab(n, mx.ATTR_SLOTS),
+        attr_num=num,
+        attr_ver=small(n, mx.ATTR_SLOTS),
+        class_id=rng.choice(5, n, p=[.04, .24, .24, .24, .24]).astype(i32) - 1,
+        dev_total=rng.integers(0, 3, (n, mx.DEVICE_SLOTS)).astype(i32),
+        dev_used=rng.integers(0, 2, (n, mx.DEVICE_SLOTS)).astype(i32),
+        prio_used=prio.astype(f32),
+        port_words=rng.integers(0, 2 ** 32, (n, mx.PORT_WORDS), np.uint32),
+        dyn_used=rng.integers(0, 100, n).astype(i32),
+    )
+
+    def slots(width, cap, holes=False):
+        """(b, cap) slots: the first ``width`` mostly live, the rest -1."""
+        s = rng.integers(0, mx.ATTR_SLOTS, (b, cap)).astype(i32)
+        dead = np.arange(cap)[None, :] >= rng.integers(
+            1, width + 1, (b, 1)) if width else np.ones((b, cap), bool)
+        if holes and width:  # spread slots are positional
+            dead = (np.arange(cap)[None, :] >= width) | (
+                rng.random((b, cap)) < 0.3)
+        return np.where(dead, -1, s).astype(i32)
+
+    def ops(cap):
+        """Mostly the ops most nodes pass, so that lanes place."""
+        p = np.array([3, 35, 3, 6, 3, 6, 35, 1, 1, 1, 3, 1, 3], float)
+        return rng.choice(13, (b, cap), p=p / p.sum()).astype(i32)
+
+    f = features
+    s_hash = vocab(b, e.MAX_SPREADS, e.MAX_SPREAD_VALUES)
+    s_hash[:, :, 4:] = 0  # room for a value the scan sees first
+    desired = small(b, e.MAX_SPREADS, e.MAX_SPREAD_VALUES) + 1
+    desired[rng.random(desired.shape) < 0.4] = np.nan
+    implicit = small(b, e.MAX_SPREADS)
+    implicit[rng.random(implicit.shape) < 0.5] = np.nan
+    dp_hash = vocab(b, e.MAX_DISTINCT_PROPS, e.MAX_DISTINCT_VALUES)
+    dp_hash[:, :, 3:] = 0
+    reqs = e.SchedRequest(
+        ask=np.tile(np.array([[400.0, 800.0, 100.0]], f32), (b, 1)),
+        c_slot=slots(f.c_width, e.MAX_CONSTRAINTS),
+        c_op=ops(e.MAX_CONSTRAINTS),
+        c_hash=vocab(b, e.MAX_CONSTRAINTS),
+        c_num=small(b, e.MAX_CONSTRAINTS),
+        dc_hash=np.where(
+            rng.random((b, 1)) < 0.5, -1,
+            rng.integers(1, 6, (b, e.MAX_DATACENTERS))).astype(i32),
+        dev_ask=(rng.random((b, mx.DEVICE_SLOTS)) < 0.03).astype(i32),
+        algorithm=rng.integers(0, 2, b).astype(i32),
+        desired_count=rng.integers(1, 9, b).astype(f32),
+        a_slot=slots(f.a_width, e.MAX_AFFINITIES),
+        a_op=ops(e.MAX_AFFINITIES),
+        a_hash=vocab(b, e.MAX_AFFINITIES),
+        a_num=small(b, e.MAX_AFFINITIES),
+        a_weight=rng.integers(-100, 101, (b, e.MAX_AFFINITIES)).astype(f32),
+        s_slot=slots(f.s_width, e.MAX_SPREADS, holes=True),
+        s_weight=rng.integers(1, 101, (b, e.MAX_SPREADS)).astype(f32),
+        s_even=rng.random((b, e.MAX_SPREADS)) < 0.5,
+        s_value_hash=s_hash,
+        s_desired=desired,
+        s_implicit=implicit,
+        s_sum_weights=rng.integers(50, 201, b).astype(f32),
+        preempt_bucket=(
+            rng.integers(-1, mx.PRIORITY_BUCKETS + 1, b) if f.preempt
+            else np.full(b, -1)).astype(i32),
+        distinct_hosts=rng.random(b) < 0.5,
+        p_static=np.where(
+            f.ports & (rng.random((b, e.MAX_STATIC_PORTS)) < 0.2),
+            rng.integers(0, 32 * mx.PORT_WORDS, (b, e.MAX_STATIC_PORTS)), -1
+        ).astype(i32),
+        p_dyn=(rng.integers(0, 3, b) * f.ports).astype(i32),
+        dp_slot=slots(f.dp_width, e.MAX_DISTINCT_PROPS, holes=True),
+        dp_limit=rng.integers(1, 4, (b, e.MAX_DISTINCT_PROPS)).astype(f32),
+        dp_value_hash=dp_hash,
+        dp_count=small(b, e.MAX_DISTINCT_PROPS, e.MAX_DISTINCT_VALUES),
+    )
+    k = 4
+    delta_rows = np.where(
+        rng.random((b, k)) < 0.5, rng.integers(0, n, (b, k)), -1).astype(i32)
+    lane_steps = np.zeros(b, i32)
+    lane_steps[: b if live is None else live] = rng.integers(
+        1, steps + 1, b if live is None else live)
+    return (
+        arrays,
+        arrays.used,
+        delta_rows,
+        (rng.random((b, k, 3)) * 200).astype(f32),
+        (rng.random((b, n)) < 0.1).astype(i32),  # tg_counts
+        small(b, e.MAX_SPREADS, e.MAX_SPREAD_VALUES),  # spread_counts
+        rng.random((b, n)) < 0.05,  # penalties
+        device_request(reqs, f.dp_width),
+        rng.random((b, 4)) < 0.8,  # class_eligs
+        rng.random((b, n)) < 0.9,  # host_masks
+        lane_steps,
+    )

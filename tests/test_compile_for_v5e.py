@@ -14,91 +14,62 @@ skips these tests without touching any other.
 
 from __future__ import annotations
 
+import os
 import re
+import sys
 
-import numpy as np
 import pytest
 
-LANES, ROWS, SCAN, CLASS_PAD = 64, 102_400, 16, 8192
+sys.path.insert(0, os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
+import aot_ops  # noqa: E402
+
 HBM_BYTES = 16 * 1024 ** 3
+# ``temp_size_in_bytes`` a device of the same compile at the parent of PR 47
+# (580cdd5; ``tools/aot_ops.py`` run on that tree), where every placement
+# step read the constraint columns anew through two 52 MB turn buffers.
+PARENT_TEMP = {"wide-1": 583_243_264, "wide-2": 557_364_224,
+               "plain": 561_742_336}
+ROOM = 64 * 1024 ** 2
 
 
 @pytest.fixture(scope="module")
 def mesh():
     import jax
-    from jax.experimental import topologies
-    from jax.sharding import Mesh
 
     try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
+        mesh = aot_ops.described_mesh()
     except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # Such a compile is written to the persistent cache and cannot be read
     # back without a chip: keep it out.
+    from jax.experimental.compilation_cache import compilation_cache
+
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "node"))
+    compilation_cache.reset_cache()  # (whether it is used is decided once)
+    yield mesh
     jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
-def _compiled(mesh, feats):
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from nomad_tpu.lint.contracts import Grid, fused_operands
-    from nomad_tpu.ops import kernels
-    from nomad_tpu.parallel import sharding
-    from nomad_tpu.scheduler.claims import CHAIN_DEPTH
-    from nomad_tpu.scheduler.coalescer import MAX_DELTA_ROWS
-
-    def spec(shape, dtype, p):
-        return jax.ShapeDtypeStruct(
-            tuple(shape), dtype, sharding=NamedSharding(mesh, p))
-
-    # Field shapes off a small grid; the node axis at the region's size.
-    small = fused_operands(Grid(
-        nodes=8, batch=LANES, placements=SCAN, deltas=MAX_DELTA_ROWS,
-        live=LANES, features=feats))
-    arrays = type(small[0])(*(
-        spec((ROWS,) + np.shape(x)[1:], np.asarray(x).dtype, p)
-        for x, p in zip(small[0], sharding._ARRAYS_SPEC)))
-    reqs = kernels.device_request(small[7], feats.dp_width)
-    reqs = type(reqs)(*(
-        None if f is None else spec(np.shape(f), np.asarray(f).dtype, p)
-        for f, p in zip(reqs, sharding._REQS_SPEC)))
-    lanes, f32, i32 = P("batch", None, None), np.float32, np.int32
-    k = MAX_DELTA_ROWS
-    args = (
-        arrays, spec((ROWS, 3), f32, P("node", None)),
-        spec((LANES, k), i32, P("batch", None)), spec((LANES, k, 3), f32, lanes),
-        spec((LANES, ROWS), i32, P("batch", "node")),
-        spec(np.shape(small[5]), f32, lanes),
-        spec((LANES, ROWS), bool, P("batch", "node")), reqs,
-        spec((LANES, CLASS_PAD), bool, P("batch", None)),
-        spec((LANES, ROWS), bool, P("batch", "node")),
-        spec((LANES,), i32, P("batch")),
-    )
-    overlay = (spec((LANES, 64), i32, P("batch", None)),
-               spec((LANES, 64, 3), f32, lanes))
-    chain = (spec((CHAIN_DEPTH, LANES, k + SCAN, 4), f32,
-                  P(None, "batch", None, None)),
-             spec((LANES, 1 + CHAIN_DEPTH), bool, P("batch", None)),
-             spec((LANES, k, 3), f32, lanes))
-    fn = sharding.sharded_fused_place_batch(mesh, SCAN)
-    return fn.lower(
-        *args, features=feats, overlay=overlay, chain=chain).compile()
-
-
-def _features(**widths):
-    from nomad_tpu.ops import kernels
-
-    return kernels.Features(preempt=False, ports=False, **widths)
+def _holds_the_invariants_outside_the_loop(compiled, parent_temp):
+    """PR 47: what a lane's steps share is computed before the placement
+    loop: no serial column read (a ``while`` of the feasibility stage) in
+    its body, and no more scratch memory than the parent took."""
+    text = compiled.as_text()
+    assert aot_ops.loops_under(text, "place_scan/while"), "no placement loop"
+    in_body = aot_ops.loops_under(
+        text, "place_scan/while/body", "/feasibility/")
+    assert not in_body, in_body
+    assert "feasibility" in text  # ... the stage is still in the program
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= parent_temp + ROOM, (temp, parent_temp)
 
 
 @pytest.mark.parametrize("dp_width", [1, 2])
 def test_the_wide_sharded_variant_compiles_for_four_chips(mesh, dp_width):
-    compiled = _compiled(mesh, _features(
+    compiled = aot_ops.compile_sharded(mesh, aot_ops.features(
         c_width=8, a_width=2, s_width=2, dp_width=dp_width))
     mem = compiled.memory_analysis()
     # a chip's half of the matrix is resident beside it (244 MB a snapshot)
@@ -110,9 +81,33 @@ def test_the_wide_sharded_variant_compiles_for_four_chips(mesh, dp_width):
     # other pmax)
     assert any(n.endswith("rules_exchange/pmax") for n in under), under
     assert any(n.endswith("rules_exchange/gather") for n in under), under
+    _holds_the_invariants_outside_the_loop(
+        compiled, PARENT_TEMP[f"wide-{dp_width}"])
 
 
 def test_the_plain_sharded_variant_has_no_rules_exchange(mesh):
-    compiled = _compiled(mesh, _features(
-        c_width=4, a_width=1, s_width=1, dp_width=0))
+    compiled = aot_ops.compile_sharded(mesh, aot_ops.features(
+        **aot_ops.VARIANTS["plain"]))
     assert "rules_exchange" not in compiled.as_text()
+    _holds_the_invariants_outside_the_loop(compiled, PARENT_TEMP["plain"])
+
+
+def test_the_op_table_names_a_loop_by_its_scope():
+    """``tools/aot_ops.py``'s table on a line of the parent's text (the
+    ledger's ``while.120`` of PR 46): no compile."""
+    text = """
+%wide.body (p: s32[]) -> s32[] {
+  %dynamic-update-slice.46 = s32[256,1,51200]{2,0,1:T(8,128)} dynamic-update-slice(%a, %b, %c)
+}
+
+ENTRY %main.62_spmd (p: s32[]) -> s32[] {
+  %fusion.1 = s32[8]{0:T(128)} fusion(%p), kind=kLoop, calls=%fused_computation.1
+  %while.120 = (s32[]{:T(128)}, s32[32,51200]{1,0:T(8,128)S(1)}) while(%tuple.331), condition=%wide.cond, body=%wide.body, backend_config={"known_trip_count":{"n":"256"}}, metadata={op_name="jit(entry)/shard_map/place_scan/while/body/vmap(score)/feasibility/vmap()/gather" stack_frame_id=94}
+}
+"""
+    rows = {r[0]: r for r in aot_ops.op_table(text)}
+    assert rows["while.120"][1:3] == ("main.62_spmd", "256")
+    assert rows["while.120"][3].endswith("feasibility/vmap()/gather")
+    assert rows["dynamic-update-slice.46"][1] == "wide.body (body of while.120)"
+    assert [r[0] for r in aot_ops.loops_under(
+        text, "place_scan/while/body", "/feasibility/")] == ["while.120"]
