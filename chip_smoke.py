@@ -831,6 +831,7 @@ def live_level(meter: CompileMeter, sizes, seed: int) -> dict:
             "n_device_shards": n_shards,
             "dispatches": coal.dispatches,
             "fused_dispatches": coal.fused_dispatches,
+            "device_calls": coal.device_calls,
             "fused_lanes": coal.fused_lanes,
             "scan_steps_total": coal.scan_steps_total,
             "coalesced_requests": coal.coalesced_requests,
@@ -856,6 +857,11 @@ def live_level(meter: CompileMeter, sizes, seed: int) -> dict:
         check(counters["heartbeats_missed"] == 0, "nodes missed heartbeats")
         check(coal.fused_dispatches > 0, "no fused dispatch happened")
         check(coal.dispatches == coal.fused_dispatches, "staged dispatches ran")
+        check(
+            coal.device_calls == coal.fused_dispatches,
+            f"{coal.device_calls} jitted calls for "
+            f"{coal.fused_dispatches} launches: a launch is one call",
+        )
         # The server shards over every visible accelerator on its own (a
         # CPU backend, the rehearsal's, stays on one device by design).
         n_dev = len(jax.devices())

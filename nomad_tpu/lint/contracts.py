@@ -211,9 +211,13 @@ def fused_operands(g: Grid) -> Tuple[Any, ...]:
     )
 
 
-def _unpack_packs(g: Grid) -> Tuple[Any, ...]:
+def _unpack_packs(g: Grid, class_pad: int = 1) -> Tuple[Any, ...]:
     """((request pack, lane pack), their layouts) as a launch of the server
-    hands them to ``unpack_lanes``: ``RequestSlab``'s and ``_staging``'s."""
+    hands them to its one program: ``RequestSlab``'s and ``_staging``'s own
+    buffers (so ``MAX_DELTA_ROWS`` delta rows a lane whatever ``g.deltas``),
+    every lane a valid request, the first ``g.live`` asking for ``g.steps``
+    placements; the class operand ``class_pad`` wide (a width of the
+    cluster's class count, whatever its node count or the lanes)."""
     import jax
 
     from ..ops.encode import MAX_SPREAD_VALUES, MAX_SPREADS
@@ -221,9 +225,36 @@ def _unpack_packs(g: Grid) -> Tuple[Any, ...]:
     from ..state.matrix import NodeMatrix
 
     coal = DeviceCoalescer(NodeMatrix(capacity=g.nodes), max_lanes=g.batch)
-    st, slab = coal._staging(g.nodes, 1, (MAX_SPREADS, MAX_SPREAD_VALUES))
+    st, slab = coal._staging(
+        g.nodes, class_pad, (MAX_SPREADS, MAX_SPREAD_VALUES))
     slab.fill(0, jax.tree_util.tree_map(lambda f: f[0], _concrete_reqs(1)))
+    st["lane_steps"][: g.live] = g.steps or g.placements
     return (slab.pack, st["pack"]), (slab.layout, st["layout"])
+
+
+def _live_carry(g: Grid) -> Any:
+    """The carry a live launch is handed: blocks of padding, a lane's block
+    as long as the staging slot's delta rows and the scan."""
+    from ..scheduler.claims import empty_carry
+    from ..scheduler.coalescer import MAX_DELTA_ROWS
+
+    return empty_carry(g.batch, MAX_DELTA_ROWS + g.placements)
+
+
+def packed_operands(g: Grid) -> Tuple[Any, ...]:
+    """The eight operands of a live launch (``fused_place_batch_live`` and
+    its mesh twin): the matrix, ``used``, the server's two packs, the three
+    node-axis lane buffers and the carry of the launch before."""
+    n, b = g.nodes, g.batch
+    return (
+        _concrete_arrays(n),
+        np.zeros((n, 3), np.float32),  # used
+        *_unpack_packs(g)[0],
+        np.zeros((b, n), np.int32),  # tg_counts
+        np.zeros((b, n), bool),  # penalties
+        np.ones((b, n), bool),  # host_masks
+        _live_carry(g),
+    )
 
 
 def scatter_operands(g: Grid) -> Tuple[Any, ...]:
@@ -365,6 +396,9 @@ def table() -> Tuple[DeviceContract, ...]:
     def carry_bytes(g: Grid) -> int:
         return int(chain(g)[0].nbytes)
 
+    def live_carry_bytes(g: Grid) -> int:
+        return int(_live_carry(g).nbytes)
+
     fused_kwargs = lambda g: {
         "n_placements": g.placements, "features": g.features,
         "overlay": overlay(g), "chain": chain(g),
@@ -378,6 +412,10 @@ def table() -> Tuple[DeviceContract, ...]:
         # physical shard count, so the contract holds wherever it runs.
         mesh = sharding.make_mesh(1, batch=1)
         return sharding.sharded_fused_place_batch(mesh, g.placements)
+
+    def build_sharded_live(g: Grid) -> Callable[..., Any]:
+        mesh = sharding.make_mesh(1, batch=1)
+        return sharding.sharded_fused_place_batch_live(mesh, g.placements)
 
     scatter_grid = Grid(nodes=_N_A, batch=4, placements=1, deltas=4, live=4)
     # Both dirty-row scatters, one chip's and the mesh's: one body
@@ -412,31 +450,23 @@ def table() -> Tuple[DeviceContract, ...]:
             name="fused_place_batch_live",
             path="nomad_tpu/ops/kernels.py",
             build=lambda g: kernels.fused_place_batch_live,
-            operands=fused_operands,
-            static_kwargs=fused_kwargs,
+            operands=packed_operands,
+            static_kwargs=lambda g: {
+                "layouts": _unpack_packs(g)[1],
+                "n_placements": g.placements, "features": g.features,
+            },
             trace_grids=trace_grids,
             out_budget=_fused_budget,
-            resident_out=carry_bytes,
-            donated_args=tuple(range(2, 11)),  # per-dispatch lane operands
-            # ... the overlay, and the chain: a carry has one reader
-            donated_kwargs=("overlay", "chain"),
+            resident_out=live_carry_bytes,
+            # The node-axis lane buffers, and the carry: it has one reader.
+            # The packs are views of a staging slot, read until the launch
+            # resolves.
+            donated_args=(4, 5, 6, 7),
             expect_alias=True,  # carry in -> carry out, in place
             compile_grid=compile_grid,
             sweep=lane_steps_sweep,
-            # occupancy and step counts are runtime data: ONE compile
-            max_compiles=1,
-        ),
-        DeviceContract(
-            name="unpack_lanes",
-            path="nomad_tpu/ops/kernels.py",
-            build=lambda g: kernels.unpack_lanes,
-            operands=lambda g: _unpack_packs(g)[0],
-            static_kwargs=lambda g: {"layouts": _unpack_packs(g)[1]},
-            trace_grids=trace_grids[:2],
-            out_budget=None,  # feeds the placement program; never fetched
-            donated_args=(),  # views of a staging slot, read until resolved
-            compile_grid=compile_grid,
-            sweep=occupancy_sweep,  # the packs' shapes do not know the fill
+            # occupancy and step counts are runtime data, and the packs'
+            # shapes do not know the fill: ONE compile
             max_compiles=1,
         ),
         DeviceContract(
@@ -451,9 +481,25 @@ def table() -> Tuple[DeviceContract, ...]:
             trace_grids=trace_grids,
             out_budget=_fused_budget,
             resident_out=carry_bytes,  # split over 'batch', never fetched
+            donated_args=(),  # tests, the smoke and the tools reuse inputs
+        ),
+        DeviceContract(
+            name="sharded_fused_place_batch_live",
+            path="nomad_tpu/parallel/sharding.py",
+            build=build_sharded_live,
+            operands=packed_operands,
+            static_kwargs=lambda g: {
+                "layouts": _unpack_packs(g)[1], "features": g.features,
+            },
+            trace_grids=trace_grids,
+            out_budget=_fused_budget,
+            resident_out=live_carry_bytes,
             # matrix stays shared with in-flight dispatches; the carry is a
             # few hundred KB a device, not worth a donation of its own
             donated_args=(),
+            compile_grid=compile_grid,
+            sweep=lane_steps_sweep,
+            max_compiles=1,
         ),
         DeviceContract(
             name="make_row_scatter",

@@ -66,7 +66,8 @@ SCAN_FILES = (
 DEVICE_PRODUCER_PREFIXES = ("kernels.", "jnp.", "jax.numpy.")
 DEVICE_PRODUCER_EXACT = {"jax.device_put"}
 DEVICE_PRODUCER_NAMES = {
-    "fused_place_batch_live", "sharded_fused_place_batch", "_sharded_fused_fn",
+    "fused_place_batch_live", "sharded_fused_place_batch",
+    "sharded_fused_place_batch_live", "_sharded_fused_fn",
 }
 
 # Sinks that force a device→host sync.
@@ -84,16 +85,17 @@ STACKING_CALL_NAMES = {
 }
 # Static params of the fused entry points (mirrors ops/kernels.py); a
 # batch-derived value here keys a fresh compile per occupancy.
-FUSED_STATIC_PARAMS = ("n_placements", "features")
+FUSED_STATIC_PARAMS = ("n_placements", "features", "layouts")
 
 # J005: the node-sharded dispatch builders — a function calling any of
 # these (or the fused entries above) is "on the fused/sharded path" and
 # must never fetch node-axis-shaped arrays to host.  ``_sharded_fused_fn``
-# is the coalescer's bound callable built by ``sharded_fused_place_batch``
-# — the production dispatch site invokes the entry through it, so the
-# bound name counts as an entry too.
+# is the coalescer's bound callable built by
+# ``sharded_fused_place_batch_live`` — the production dispatch site invokes
+# the entry through it, so the bound name counts as an entry too.
 SHARDED_ENTRY_NAMES = {
     "sharded_fused_place_batch",
+    "sharded_fused_place_batch_live",
     "_sharded_fused_fn",
 }
 # Node-axis-shaped leaves: every DeviceArrays field (state/matrix.py) plus

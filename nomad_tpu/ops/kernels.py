@@ -106,11 +106,11 @@ class Features(NamedTuple):
 
 FULL_FEATURES = Features()
 
-# The request's distinct_property operands, its last fields.  A launch at
-# ``dp_width`` 0 leaves them on the host (None in the request it hands the
-# placement program): a device buffer costs the launching thread by the
-# buffer, not by the byte (PERF.md section 6, PR 33), and four more a
-# launch showed on four chips (PR 44).
+# The request's distinct_property operands, its last fields.  At
+# ``dp_width`` 0 the placement program takes the request without them (None:
+# no stage reads them, and a caller that holds the request as device arrays
+# hands over four buffers fewer; a launch of the server hands over the
+# request slab's pack whole, and what is not read costs it nothing).
 DP_FIELDS = SchedRequest._fields.index("dp_slot")
 assert SchedRequest._fields[DP_FIELDS:] == (
     "dp_slot", "dp_limit", "dp_value_hash", "dp_count"
@@ -1600,22 +1600,6 @@ fused_place_batch = functools.partial(
     jax.jit, static_argnames=("n_placements", "features")
 )(_fused_place_batch_impl)
 
-# Live entry: per-dispatch lane operands (argnums 2..10, including the lane
-# step counts, the claims overlay and the chain) are DONATED, so XLA reuses
-# their freshly-transferred device buffers as scratch instead of holding them
-# live alongside the outputs; the carry a launch is handed is consumed by
-# that launch alone, and the carry it hands on takes its buffer.
-# ``arrays``/``used`` stay shared with in-flight pipelined dispatches and
-# are never donated.  Kept apart from ``fused_place_batch`` because callers
-# of the un-donated entry (tests, the smoke) reuse their inputs across calls.
-fused_place_batch_live = functools.partial(
-    jax.jit,
-    static_argnames=("n_placements", "features"),
-    donate_argnums=tuple(range(2, 11)),
-    donate_argnames=("overlay", "chain"),
-)(_fused_place_batch_impl)
-
-
 def unpack_rows(buf, layout):
     """The fields of ``encode.packed_rows``' ``(lanes, W)`` uint8 buffer,
     on the device: bit for bit what the host wrote into its views."""
@@ -1632,13 +1616,73 @@ def unpack_rows(buf, layout):
     return fields
 
 
-@functools.partial(jax.jit, static_argnames=("layouts",))
-def unpack_lanes(*packs, layouts):
-    """Every small lane operand of a launch (the request slab's fields; the
-    class eligibility, spread counts, deltas and step counts) from the two
-    packed buffers they are handed over in.  A program of its own (module
-    ``jit_unpack_lanes``): the placement program keeps its operands."""
-    return tuple(unpack_rows(p, lay) for p, lay in zip(packs, layouts))
+# The fields of a launch's lane pack, in its layout's order: every small
+# lane operand that is no part of the request (the coalescer's staging slot
+# builds the pack from these names).
+LANE_FIELDS = (
+    "class_elig", "spread_counts", "delta_rows", "delta_vals", "lane_steps",
+    "overlay_rows", "overlay_vals", "claim_vals", "chain_flags",
+)
+
+
+def unpack_launch(request_pack, lane_pack, layouts, dp_width: int):
+    """What a launch's two packed buffers hold, on the device: the request
+    (``RequestSlab.pack``; ``device_request`` leaves the distinct_property
+    fields out at ``dp_width`` 0) and the lane pack's fields by name
+    (``LANE_FIELDS``).  Slices and bitcasts at a placement program's entry:
+    the packs are the program's operands, and no field is a buffer of its
+    own on the launching thread."""
+    request_layout, lane_layout = layouts
+    with jax.named_scope("unpack"):
+        reqs = device_request(
+            unpack_rows(request_pack, request_layout), dp_width
+        )
+        lane = dict(zip(LANE_FIELDS, unpack_rows(lane_pack, lane_layout)))
+    return reqs, lane
+
+
+def place_launch(place, arrays, used, reqs, lane, tg_counts, penalties,
+                 host_masks, carry, **static):
+    """``place`` (a placement program's body: ``fused_place_batch``'s
+    signature) on a launch's operands as ``unpack_launch`` gives them: the
+    overlay, the chain's flags and ``claim_vals`` are the lane pack's."""
+    return place(
+        arrays, used, lane["delta_rows"], lane["delta_vals"], tg_counts,
+        lane["spread_counts"], penalties, reqs, lane["class_elig"],
+        host_masks, lane["lane_steps"],
+        overlay=(lane["overlay_rows"], lane["overlay_vals"]),
+        chain=(carry, lane["chain_flags"], lane["claim_vals"]),
+        **static,
+    )
+
+
+# Live entry: what a launch of the server hands jax, in ONE call — the
+# resident matrix, the two packs every small lane operand is a view of
+# (unpacked here, at the program's entry), the three node-axis lane buffers
+# and the carry.  The node-axis buffers are DONATED, so XLA reuses their
+# freshly-transferred device buffers as scratch instead of holding them live
+# alongside the outputs; the carry a launch is handed is consumed by that
+# launch alone, and the carry it hands on takes its buffer.  The packs are
+# views of a staging slot read until the launch resolves, and
+# ``arrays``/``used`` stay shared with in-flight pipelined dispatches: never
+# donated.  Kept apart from ``fused_place_batch`` because callers of the
+# un-donated entry (tests, the smoke) reuse their inputs across calls.
+@functools.partial(
+    jax.jit,
+    static_argnames=("layouts", "n_placements", "features"),
+    donate_argnames=("tg_counts", "penalties", "host_masks", "carry"),
+)
+def fused_place_batch_live(arrays, used, request_pack, lane_pack, tg_counts,
+                           penalties, host_masks, carry, *, layouts,
+                           n_placements: int, features: Features):
+    reqs, lane = unpack_launch(
+        request_pack, lane_pack, layouts, features.dp_width
+    )
+    return place_launch(
+        _fused_place_batch_impl, arrays, used, reqs, lane, tg_counts,
+        penalties, host_masks, carry,
+        n_placements=n_placements, features=features,
+    )
 
 
 # ---------------------------------------------------------------------------

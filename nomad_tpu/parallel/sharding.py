@@ -50,11 +50,12 @@ from ..ops.kernels import (
     inert_lane_outputs,
     launch_invariants,
     pack_fused_lanes,
+    place_launch,
     rank_nodes,
     scan_carry,
     scan_steps,
     spread_values_at,
-    unpack_lanes,
+    unpack_launch,
 )
 from ..state.matrix import DeviceArrays, scatter_packed
 
@@ -575,16 +576,10 @@ def _fused_place_batch_local(
     return packed, chained_carry(own, carry)
 
 
-def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
-    """Build the jitted SPMD twin of ``kernels.fused_place_batch``.
-
-    Same signature (``features`` keyword-static) and packed
-    (B, P, FUSED_PACKED_WIDTH) result as the single-device fused kernel —
-    the dispatch coalescer launches it when dispatches span a mesh
-    (scheduler/coalescer.py ``_resolve_sharding``).  Placement AND
-    verify-column parity with the unsharded kernel is exact (tie-breaks
-    included) — tests/test_parallel.py asserts it across shard counts.
-    """
+def _shard_mapped(mesh: Mesh, n_placements: int):
+    """``_fused_place_batch_local`` over ``mesh``, ``fused_place_batch``'s
+    signature: the body of both jitted entries below (each a function
+    ``entry``: module ``jit_entry`` in a profile)."""
 
     def entry(
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
@@ -629,7 +624,52 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
             chain,
         )
 
-    return jax.jit(entry, static_argnames=("features",))
+    return entry
+
+
+def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
+    """Build the jitted SPMD twin of ``kernels.fused_place_batch``.
+
+    Same signature (``features`` keyword-static) and packed
+    (B, P, FUSED_PACKED_WIDTH) result as the single-device fused kernel:
+    every operand its own, as tests, the smoke and the tools hand them over
+    (a launch of the server goes through ``sharded_fused_place_batch_live``).
+    Placement AND verify-column parity with the unsharded kernel is exact
+    (tie-breaks included) — tests/test_parallel.py asserts it across shard
+    counts.
+    """
+    return jax.jit(
+        _shard_mapped(mesh, n_placements), static_argnames=("features",)
+    )
+
+
+def sharded_fused_place_batch_live(mesh: Mesh, n_placements: int):
+    """Build the jitted SPMD twin of ``kernels.fused_place_batch_live``: what
+    the dispatch coalescer launches when dispatches span a mesh
+    (scheduler/coalescer.py ``_resolve_sharding``), in ONE call.  The two
+    packs are held to a split over ``batch`` alone (the compiler lays the
+    operands out so: ``compiled.input_shardings``) and unpacked at the entry
+    (``kernels.unpack_launch``): a lane's row is on the batch shard that
+    holds the lane, the ``shard_map`` asks the same split of every field,
+    so no field crosses a chip.  The node-axis lane buffers and the carry
+    are the placement program's operands as they always were.
+    """
+    place = _shard_mapped(mesh, n_placements)
+    lanes = NamedSharding(mesh, P("batch"))
+
+    def entry(arrays, used, request_pack, lane_pack, tg_counts, penalties,
+              host_masks, carry, *, layouts, features):
+        reqs, lane = unpack_launch(
+            jax.lax.with_sharding_constraint(request_pack, lanes),
+            jax.lax.with_sharding_constraint(lane_pack, lanes),
+            layouts, features.dp_width,
+        )
+        return place_launch(
+            place, arrays, used, reqs, lane, tg_counts, penalties,
+            host_masks, carry, features=features,
+        )
+
+    return jax.jit(entry, static_argnames=("layouts", "features"))
 
 
 def shard_carry(mesh: Mesh, carry):
@@ -639,21 +679,3 @@ def shard_carry(mesh: Mesh, carry):
     return jax.device_put(
         carry, NamedSharding(mesh, P(None, "batch", None, None))
     )
-
-
-def sharded_unpack_lanes(mesh: Mesh):
-    """``kernels.unpack_lanes`` over the mesh: the packed buffers in and
-    every field out split over ``batch`` alone, as the placement program's
-    ``in_specs`` ask of its small lane operands."""
-    lanes = NamedSharding(mesh, P("batch"))
-
-    @functools.lru_cache(maxsize=None)
-    def program(layouts):
-        def unpack_lanes_sharded(*packs):
-            return unpack_lanes.__wrapped__(*packs, layouts=layouts)
-
-        return jax.jit(
-            unpack_lanes_sharded, in_shardings=lanes, out_shardings=lanes
-        )
-
-    return lambda *packs, layouts: program(layouts)(*packs)

@@ -35,10 +35,12 @@ every dispatch uses the SAME static shapes — ``max_lanes`` lanes (short
 batches padded by memset of the preallocated staging buffers) and a
 ``PLACEMENT_CHUNK``-long output (callers take the first rows they asked
 for) — so one executable per ``Features`` variant serves every batch
-size; a recompile costs seconds.  The 30 small lane operands cross as two
-packed buffers that a program of its own gives back on the device
-(``kernels.unpack_lanes``): a launch costs its thread by the device buffer,
-not by the byte.  What the shapes do not fix is the work:
+size; a recompile costs seconds.  A launch is ONE jitted call that hands
+jax the resident matrix, two packed buffers, the three node-axis lane
+buffers and the carry: the 30-odd small lane operands are views of the two
+packs on the host and the placement program unpacks them at its entry
+(``kernels.unpack_launch``), since a launch costs its thread by the call and
+by the buffer, not by the byte.  What the shapes do not fix is the work:
 the fused kernel's two loops take their trip counts from the staged
 ``lane_steps`` operand (each live lane's ``n_live``, 0 for a dead lane),
 so a launch scores all nodes once per placement its widest lane asked
@@ -268,9 +270,11 @@ class DeviceCoalescer:
         # traffic staged per batched dispatch.
         self.solo_ops = 0
         self.operand_bytes_total = 0
-        # The mesh's unpacking program, built with _sharded_fused_fn, and
-        # the (program, layouts) the last launch unpacked with.
-        self._sharded_unpack = self._unpack_variant = None
+        # Jitted calls the launches made (one each: the placement program
+        # unpacks the two packs itself), and the (route, layouts) of the
+        # last launch's program.
+        self.device_calls = 0
+        self._unpack_variant = None
         # Batched-launch accounting: launches and live lanes
         # (launches-per-eval = fused_dispatches / fused_lanes),
         # verify-column conflicts (placements an earlier lane's plan will
@@ -692,18 +696,16 @@ class DeviceCoalescer:
                 make_mesh,
                 mesh_layout,
                 node_shard_count,
-                sharded_fused_place_batch,
-                sharded_unpack_lanes,
+                sharded_fused_place_batch_live,
             )
 
             batch, _node = mesh_layout(
                 self.n_device_shards, int(self.matrix.capacity)
             )
             self._mesh = make_mesh(self.n_device_shards, batch=batch)
-            self._sharded_fused_fn = sharded_fused_place_batch(
+            self._sharded_fused_fn = sharded_fused_place_batch_live(
                 self._mesh, self.scan_length
             )
-            self._sharded_unpack = sharded_unpack_lanes(self._mesh)
             node_shards = node_shard_count(self._mesh)
             # Home rows to their mesh shard so claims balance across the
             # node axis and growth never migrates a row between shards.
@@ -861,7 +863,8 @@ class DeviceCoalescer:
         ):
             lanes = self.max_lanes
             # The small lane operands are views of one buffer, handed to
-            # jax as one operand (kernels.unpack_lanes gives them back).
+            # jax as one operand (kernels.unpack_launch gives them back,
+            # inside the placement program).
             # The claims overlay is one flat list to the program; it rides
             # the pack as a few rows a lane (no device buffer of its own).
             # So do the chain's flags (kernels.chain_flags: the lane holds
@@ -881,10 +884,7 @@ class DeviceCoalescer:
                 "tg_count": np.zeros((lanes, n), np.int32),
                 "penalty": np.zeros((lanes, n), bool),
                 "pack": pack, "layout": layout,
-                **dict(zip(("class_elig", "spread_counts", "delta_rows",
-                            "delta_vals", "lane_steps", "overlay_rows",
-                            "overlay_vals", "claim_vals", "chain_flags"),
-                           small)),
+                **dict(zip(kernels.LANE_FIELDS, small)),
             }
             st["class_elig"][:] = True
             st["delta_rows"][:] = -1
@@ -1240,19 +1240,16 @@ class DeviceCoalescer:
         if self.feature_recompiles != variants:
             state = "coalescer.trace_variant"
             args["features"] = str(tuple(feats))
-        unpack = kernels.unpack_lanes if n_shards == 1 else self._sharded_unpack
-        # At ``dp_width`` 0 the distinct_property operands stay on the host.
-        layouts = (
-            slab.layout if feats.dp_width
-            else slab.layout[: kernels.DP_FIELDS]
-        ), st["layout"]
-        if self._unpack_variant != (unpack, layouts):
-            self._unpack_variant = unpack, layouts
+        # The carry goes from call to call on the device, and by route: the
+        # second output of the launch before is this launch's operand.
+        route = "device" if n_shards == 1 else self._mesh
+        layouts = slab.layout, st["layout"]
+        if self._unpack_variant != (route, layouts):
+            self._unpack_variant = route, layouts
             state = "coalescer.trace_variant"
+        calls0 = self.device_calls
         with self._state(state, **args):
-            # The carry goes from call to call on the device: the second
-            # output of the launch before is this launch's operand.
-            carry = self._carry_in("device" if n_shards == 1 else self._mesh)
+            carry = self._carry_in(route)
             # The claims overlay and the chain's live flags go into the
             # pack last, immediately before the call that hands the pack
             # over.
@@ -1266,29 +1263,34 @@ class DeviceCoalescer:
                 flat = np.zeros((orows.size, 3), np.float32)
                 flat[: len(rows)] = vals
                 ovals[:] = flat.reshape(ovals.shape)
-            # The 30 small lane operands cross as two buffers, not 30: the
-            # placement program takes them as device arrays.
-            reqs, (ce, sc, dr, dv, ls, orows, ovals, cv, flags) = unpack(
-                slab.pack, st["pack"], layouts=layouts
+            # ONE jitted call a launch.  It takes the resident matrix
+            # (shared with in-flight dispatches, never donated), the two
+            # packs the 30-odd small lane operands are views of (unpacked
+            # at the program's entry; views of this slot, read until the
+            # launch resolves), the three node-axis lane buffers and the
+            # carry.
+            resident = arrays if n_shards == 1 else sharded
+            operands = (
+                resident, resident.used, slab.pack, st["pack"], tg, pen, hm,
+                carry,
             )
-            reqs = kernels.device_request(reqs, feats.dp_width)
-            chain = carry, flags, cv
             if n_shards > 1:
                 packed, carry = self._sharded_fused_fn(
-                    sharded, sharded.used, dr, dv, tg, sc, pen, reqs, ce,
-                    hm, ls, features=feats, overlay=(orows, ovals),
-                    chain=chain,
+                    *operands, layouts=layouts, features=feats
                 )
             else:
-                # The live entry donates the per-dispatch lane operands
-                # (their device buffers become XLA scratch); `arrays`/`used`
-                # stay live — they are matrix-resident and shared with
-                # in-flight dispatches.
                 packed, carry = kernels.fused_place_batch_live(
-                    arrays, arrays.used, dr, dv, tg, sc, pen, reqs, ce, hm,
-                    ls, n_placements=self.scan_length,
-                    features=feats, overlay=(orows, ovals), chain=chain,
+                    *operands, layouts=layouts,
+                    n_placements=self.scan_length, features=feats,
                 )
+            self.device_calls += 1
+            # Jitted calls this launch made, and the host and device
+            # buffers it handed jax (the matrix's fields one each; times the
+            # devices on a mesh).
+            trace.add_args(
+                calls=self.device_calls - calls0,
+                operands=len(resident) + len(operands) - 1,
+            )
         self._chain(batch, version, carry)
         return packed, version
 

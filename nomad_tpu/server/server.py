@@ -257,6 +257,13 @@ class Server:
         # compile-cache hit/miss, and host→device operand traffic.
         m.gauge_fn("nomad.kernel.launches", lambda: c.dispatches, path="batched")
         m.gauge_fn("nomad.kernel.launches", lambda: c.solo_ops, path="solo")
+        # Jitted calls the batched launches made: one each (the placement
+        # program unpacks its packed operands itself), so this over
+        # launches{path=fused} reads 1.0 on the device and mesh routes (the
+        # numpy twin makes none).
+        m.gauge_fn(
+            "nomad.coalescer.device_calls_total", lambda: c.device_calls
+        )
         # Fused megakernel accounting: one launch serves every coalesced
         # lane (launches an eval = launches{path=fused} / fused_lanes), plus
         # the cross-lane AllocsFit verify verdicts, the picks the in-launch

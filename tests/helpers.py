@@ -157,22 +157,20 @@ def fill_frontier(matrix, nodes, picks, ask_cpu, ask_mem, seed=0):
 # 16 workers), past a batch shard of (2, 2) and up to every lane.
 LAUNCH_FILLS = (1, 2, 8, 9, 16, 33, 57, 64)
 NODE_AXIS = ("tg_count", "penalty", "host_mask")
-SMALL = ("class_elig", "spread_counts", "delta_rows", "delta_vals",
-         "lane_steps", "overlay_rows", "overlay_vals", "claim_vals",
-         "chain_flags")
 
 
-def launch_lanes(coal, k, seed=0, classes=2):
-    """One batch of ``k`` lanes straight through ``coal._dispatch`` (no
-    threads, so the launch holds exactly ``k``): lanes whose node-axis
-    operands differ (host masks with holes, job counts, penalties, seeded
-    per lane, the same whatever the launch's width), asks that differ.
-    Returns the fetched packed result, every lane of it."""
+def lane_batch(coal, k, seed=0, classes=2, rules=False):
+    """``k`` lanes for ``coal._dispatch``: lanes whose node-axis operands
+    differ (host masks with holes, job counts, penalties, seeded per lane,
+    the same whatever the launch's width), asks that differ; with ``rules``
+    every other lane's job carries two ``distinct_property`` limits
+    (``dp_width`` 2: the matrix's nodes need ``meta.rack`` / ``meta.zone``)."""
     import numpy as np
 
     from nomad_tpu import mock
     from nomad_tpu.ops.encode import RequestEncoder
     from nomad_tpu.scheduler.coalescer import MAX_DELTA_ROWS, _Pending
+    from nomad_tpu.structs.types import Constraint, Op
 
     m, n = coal.matrix, int(coal.matrix.capacity)
     enc = RequestEncoder(m)
@@ -181,6 +179,11 @@ def launch_lanes(coal, k, seed=0, classes=2):
         rng = np.random.default_rng(1000 * seed + i)
         job = mock.job()
         job.task_groups[0].tasks[0].resources.cpu = 100 + 10 * (i % 7)
+        if rules and i % 2 == 0:
+            job.task_groups[0].constraints = [
+                Constraint(l_target=f"${{meta.{prop}}}", r_target=limit,
+                           operand=Op.DISTINCT_PROPERTY.value)
+                for prop, limit in (("rack", "2"), ("zone", "9"))]
         req = enc.compile(job, job.task_groups[0]).request
         batch.append(_Pending(
             request=req,
@@ -193,37 +196,51 @@ def launch_lanes(coal, k, seed=0, classes=2):
             host_mask=rng.random(n) < 0.7,
             n_live=1 + i % 3,
         ))
-    packed, _version = coal._dispatch(batch)
+    return batch
+
+
+def launch_lanes(coal, k, degraded=False, **lanes):
+    """One batch of ``k`` lanes (``lane_batch``) straight through
+    ``coal._dispatch`` (no threads, so the launch holds exactly ``k``).
+    Returns the fetched packed result, every lane of it."""
+    import numpy as np
+
+    packed, _version = coal._dispatch(
+        lane_batch(coal, k, **lanes), degraded=degraded)
     return np.asarray(packed)
 
 
 def spy_on_launch(monkeypatch, coal):
-    """Record what the next launches hand jax: ``packs`` gets the unpacking
-    program's operands, ``placed`` the placement program's (positional,
-    then the claims overlay's two and the chain's three)."""
+    """Record the jitted calls of the next launches: ``(operands, static)``
+    of each call of the placement program (one device's or, where the
+    coalescer has built it, the mesh's)."""
     from nomad_tpu.ops import kernels
 
-    packs, placed = [], []
+    calls = []
 
-    def spy(fn, seen):
+    def spy(fn):
         def call(*operands, **static):
-            seen.append(operands + tuple(static.get("overlay", ()))
-                        + tuple(static.get("chain", ())))
+            calls.append((operands, static))
             return fn(*operands, **static)
         return call
 
     monkeypatch.setattr(
-        kernels, "fused_place_batch_live",
-        spy(kernels.fused_place_batch_live, placed))
-    if coal._sharded_fused_fn is None:
+        kernels, "fused_place_batch_live", spy(kernels.fused_place_batch_live))
+    if coal._sharded_fused_fn is not None:
         monkeypatch.setattr(
-            kernels, "unpack_lanes", spy(kernels.unpack_lanes, packs))
-    else:
-        monkeypatch.setattr(
-            coal, "_sharded_fused_fn", spy(coal._sharded_fused_fn, placed))
-        monkeypatch.setattr(
-            coal, "_sharded_unpack", spy(coal._sharded_unpack, packs))
-    return packs, placed
+            coal, "_sharded_fused_fn", spy(coal._sharded_fused_fn))
+    return calls
+
+
+def collectives(hlo_text):
+    """How many ops of each collective kind a compiled program's text holds
+    (an asynchronous pair counts once, at its start)."""
+    import re
+    from collections import Counter
+
+    return Counter(re.findall(
+        r" (all-gather|all-reduce|all-to-all|collective-permute|"
+        r"collective-broadcast|reduce-scatter)(?:-start)?\(", hlo_text))
 
 
 def wide_coalescer(n_device_shards=1, nodes=40, capacity=64, lanes=64):
@@ -232,82 +249,108 @@ def wide_coalescer(n_device_shards=1, nodes=40, capacity=64, lanes=64):
     from nomad_tpu.state import NodeMatrix
 
     m = NodeMatrix(capacity=capacity)
-    for _ in range(nodes):
-        m.upsert_node(mock.node())
+    for i in range(nodes):
+        node = mock.node()
+        node.meta = {"rack": f"r{i % 8}", "zone": f"z{i % 2}"}
+        m.upsert_node(node)
     return DeviceCoalescer(
         m, max_lanes=lanes, linger_s=0.0, pipeline_depth=1,
         n_device_shards=n_device_shards,
     )
 
 
-_WIDE = {}  # devices -> a 64-lane coalescer that has launched once
+_WIDE = {}  # (devices, rules) -> a 64-lane coalescer that has launched once
 
 
-def check_packed_launch(monkeypatch, k, n_device_shards=1):
-    """A launch of ``k`` lanes hands jax five buffers: the node-axis
-    operands at full width (the slot's buffers, dead lanes all-False) and
-    the two packs every small operand is a view of; the placement program
-    takes the small ones as device arrays; the byte counter says so; and
-    every lane reads bit for bit what the placement program gives on the
-    numpy operands as the slot holds them (the route before the packs).
-    The coalescer is kept for the whole file: a mesh compiles its
-    placement program per coalescer.  Returns it and the small operands
-    the placement program was launched on."""
+def launched_coalescer(n_device_shards=1, rules=False):
+    """The file's wide coalescer for that route, kept for the whole file (a
+    mesh compiles its placement program per coalescer): it has launched
+    once, at ``dp_width`` 2 where ``rules``."""
+    key = n_device_shards, rules
+    if key not in _WIDE:
+        _WIDE[key] = wide_coalescer(n_device_shards)
+        launch_lanes(_WIDE[key], 1, rules=rules)
+    return _WIDE[key]
+
+
+def check_packed_launch(monkeypatch, k, n_device_shards=1, rules=False):
+    """A launch of ``k`` lanes is ONE jitted call.  Its operands are the
+    resident matrix, the two packs every small lane operand is a view of
+    (the slot's own buffers, numpy: nothing unpacked them on the way), the
+    three node-axis buffers at full width (the slot's, dead lanes
+    all-False) and the carry the launch before handed on; the byte counter
+    and the call counter say so; and every lane reads bit for bit what the
+    plain placement program gives on the numpy operands as the slot holds
+    them.  Returns the coalescer and the call's (operands, static)."""
     import jax
     import numpy as np
 
     from nomad_tpu.ops import kernels
     from nomad_tpu.scheduler.claims import OVERLAY_ROWS
 
-    if n_device_shards not in _WIDE:
-        _WIDE[n_device_shards] = wide_coalescer(n_device_shards)
-        launch_lanes(_WIDE[n_device_shards], 1)
-    coal = _WIDE[n_device_shards]
+    coal = launched_coalescer(n_device_shards, rules)
+    assert coal._features.dp_width == (2 if rules else 0)
     n, lanes = int(coal.matrix.capacity), coal.max_lanes
-    packs, placed = spy_on_launch(monkeypatch, coal)
-    bytes0 = coal.operand_bytes_total
-    got = launch_lanes(coal, k, seed=1)
+    calls = spy_on_launch(monkeypatch, coal)
+    bytes0, calls0, carry0 = (
+        coal.operand_bytes_total, coal.device_calls, coal._carry)
+    got = launch_lanes(coal, k, seed=1, rules=rules)
 
     st, slab = coal._stage[0], coal._req_slabs[0]
-    assert all(np.shares_memory(st[f], st["pack"]) for f in SMALL)
+    small = kernels.LANE_FIELDS
+    assert all(np.shares_memory(st[f], st["pack"]) for f in small)
     assert all(np.shares_memory(f, slab.pack) for f in slab.batch())
-    fields = sum(st[f].nbytes for f in SMALL)  # each padded to 4 bytes a lane
-    assert fields <= st["pack"].nbytes <= fields + lanes * 4 * len(SMALL)
-    ((req_pack, lane_pack),) = packs
-    assert req_pack is slab.pack and lane_pack is st["pack"]
+    fields = sum(st[f].nbytes for f in small)  # each padded to 4 bytes a lane
+    assert fields <= st["pack"].nbytes <= fields + lanes * 4 * len(small)
     assert coal.operand_bytes_total - bytes0 == (
         lanes * n * (1 + 4 + 1) + st["pack"].nbytes + slab.pack.nbytes)
-    ((_arrays, _used, dr, dv, tg, sc, pen, reqs, ce, hm, ls, orows,
-      ovals, _carry, flags, cv),) = placed
+    ((operands, static),) = calls
+    assert coal.device_calls == calls0 + 1
+    resident, used, req_pack, lane_pack, tg, pen, hm, carry = operands
+    mx = coal.matrix
+    assert resident is (
+        mx._device if n_device_shards == 1 else mx._sharded_device)
+    assert used is resident.used
+    assert req_pack is slab.pack and lane_pack is st["pack"]
     for x, field in zip((tg, pen, hm), NODE_AXIS):
         assert x is st[field] and x.shape == (lanes, n)
     assert not hm[k:].any()
-    assert orows.size >= OVERLAY_ROWS and (st["overlay_rows"] == -1).all()
-    # (at ``dp_width`` 0 the distinct_property operands stay on the host)
-    handed = tuple(x for x in reqs if x is not None)
-    assert len(handed) == (
-        len(reqs) if coal._features.dp_width else kernels.DP_FIELDS)
-    small = (ce, sc, dr, dv, ls, orows, ovals, cv, flags) + handed
-    assert all(isinstance(x, jax.Array) for x in small)
-    for x, field in zip(small, SMALL):  # copied: the live entry donated them
-        assert x.shape == st[field].shape and x.dtype == st[field].dtype
+    assert carry is carry0 and isinstance(carry, jax.Array)
+    assert static["layouts"] == (slab.layout, st["layout"])
+    assert static["features"] == coal._features
+    assert st["overlay_rows"].size >= OVERLAY_ROWS
+    assert (st["overlay_rows"] == -1).all()
 
-    operands = [st[f].copy() for f in (
+    reqs = kernels.device_request(
+        [f.copy() for f in slab.batch()], coal._features.dp_width)
+    plain = [st[f].copy() for f in (
         "delta_rows", "delta_vals", "tg_count", "spread_counts", "penalty")]
-    operands += [type(reqs)(*(f.copy() for f in slab.batch()))]
-    operands += [st[f].copy() for f in ("class_elig", "host_mask", "lane_steps")]
+    plain += [reqs] + [st[f].copy() for f in (
+        "class_elig", "host_mask", "lane_steps")]
     if n_device_shards == 1:
-        arrays = coal.matrix.sync()
         want = kernels.fused_place_batch(
-            arrays, arrays.used, *operands,
+            resident, used, *plain,
             n_placements=coal.scan_length, features=coal._features)
     else:
-        arrays = coal.matrix.sync_sharded(coal._mesh)
-        want = coal._sharded_fused_fn(
-            arrays, arrays.used, *operands, features=coal._features)
+        want = plain_mesh_program(coal)(
+            resident, used, *plain, features=coal._features)
     assert (got[:k, 0, kernels.PACKED_ROW] >= 0).any()
     np.testing.assert_array_equal(got, np.asarray(want))
-    return coal, small
+    return coal, (operands, static)
+
+
+_PLAIN = {}  # (mesh, scan length) -> the mesh's plain placement program
+
+
+def plain_mesh_program(coal):
+    """``sharded_fused_place_batch`` on the coalescer's mesh (every operand
+    its own): what the packed entry is held to."""
+    from nomad_tpu.parallel.sharding import sharded_fused_place_batch
+
+    key = coal._mesh, coal.scan_length
+    if key not in _PLAIN:
+        _PLAIN[key] = sharded_fused_place_batch(*key)
+    return _PLAIN[key]
 
 
 _COMPILES = []
@@ -397,10 +440,7 @@ def check_sync_span(n_device_shards=1):
     coalescer (``check_packed_launch``'s), which has launched once."""
     from nomad_tpu import trace
 
-    if n_device_shards not in _WIDE:
-        _WIDE[n_device_shards] = wide_coalescer(n_device_shards)
-        launch_lanes(_WIDE[n_device_shards], 1)
-    coal = _WIDE[n_device_shards]
+    coal = launched_coalescer(n_device_shards)
     m = coal.matrix
     claimed = sorted(m.node_of)
     rows = [claimed[0], claimed[1], claimed[-1]]  # both ends of the node axis
@@ -423,6 +463,34 @@ def check_sync_span(n_device_shards=1):
     assert_bits_equal(dev, host_mirror(m), f"{n_device_shards} device(s)")
     clean = sync_args()
     assert (clean["operands"], clean["rows"], clean["bytes"]) == (0, 0, 0)
+    return coal
+
+
+def check_enqueue_span(n_device_shards=1):
+    """A launch's ``coalescer.enqueue`` span says what it handed jax: ONE
+    jitted call, and its operands buffer by buffer (the resident matrix's
+    twelve fields and ``used``, the two packs, the three node-axis buffers,
+    the carry: 19 whatever the lanes' requests hold); the counter the
+    server exports as ``nomad.coalescer.device_calls_total`` grows by one a
+    launch, as the launches' own counter does.  The numpy twin's route makes
+    no jitted call and says nothing.  Uses the file's wide coalescer."""
+    from nomad_tpu import trace
+
+    coal = launched_coalescer(n_device_shards)
+    calls0, launches0 = coal.device_calls, coal.fused_dispatches
+    for k in (1, 9, 64):
+        trace.clear()
+        launch_lanes(coal, k)
+        (rec,) = [r for r in trace.dump() if r["name"] == "coalescer.enqueue"]
+        assert rec["args"]["calls"] == 1 and rec["args"]["lanes"] == k
+        assert rec["args"]["operands"] == 12 + 1 + 2 + 3 + 1
+    assert coal.device_calls - calls0 == coal.fused_dispatches - launches0 == 3
+    twin = wide_coalescer(n_device_shards, nodes=10, capacity=16, lanes=8)
+    trace.clear()
+    launch_lanes(twin, 2, degraded=True, classes=16)  # an open breaker's route
+    (rec,) = [r for r in trace.dump() if r["name"] == "coalescer.enqueue"]
+    assert "calls" not in rec["args"]
+    assert (twin.device_calls, twin.fused_dispatches) == (0, 1)
     return coal
 
 

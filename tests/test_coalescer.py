@@ -178,20 +178,27 @@ def test_every_route_returns_the_verify_column(route, eight_devices):
 
 
 # ---------------------------------------------------------------------------
-# The small lane operands cross as two packed buffers (kernels.unpack_lanes)
+# A launch is one jitted call: the small lane operands cross as two packed
+# buffers that the placement program unpacks itself (kernels.unpack_launch)
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("rules", [False, True], ids=["dp0", "dp2"])
 @pytest.mark.parametrize("k", helpers.LAUNCH_FILLS)
 def test_a_launch_hands_over_two_packs_and_the_node_axis_operands(
-    monkeypatch, k,
+    monkeypatch, k, rules,
 ):
-    coal, _small = helpers.check_packed_launch(monkeypatch, k)
+    coal, _call = helpers.check_packed_launch(monkeypatch, k, rules=rules)
     assert coal.mesh_shape() == (1, 1)
 
 
 def test_a_sync_span_counts_the_one_operand_the_scatter_hands_over():
     coal = helpers.check_sync_span()
+    assert coal.mesh_shape() == (1, 1)
+
+
+def test_an_enqueue_span_counts_the_one_call_and_its_operands():
+    coal = helpers.check_enqueue_span()
     assert coal.mesh_shape() == (1, 1)
 
 
@@ -204,10 +211,13 @@ def test_a_lane_live_in_one_launch_and_dead_in_the_next_leaves_no_trace(
 
     coal = helpers.wide_coalescer()
     helpers.launch_lanes(coal, 16)
-    assert coal._stage[0]["host_mask"][3:16].any()
-    _packs, placed = helpers.spy_on_launch(monkeypatch, coal)
+    st = coal._stage[0]
+    assert st["host_mask"][3:16].any()
+    calls = helpers.spy_on_launch(monkeypatch, coal)
     got = helpers.launch_lanes(coal, 3, seed=2)
-    hm, ls = placed[0][9], np.asarray(placed[0][10])
+    ((operands, _static),) = calls
+    hm, ls = operands[6], st["lane_steps"]
+    assert hm is st["host_mask"] and np.shares_memory(ls, operands[3])
     assert hm[:3].any() and not hm[3:].any()
     assert ls[:3].all() and not ls[3:].any()
     assert (got[3:, :, kernels.PACKED_ROW] == -1).all()
@@ -216,10 +226,10 @@ def test_a_lane_live_in_one_launch_and_dead_in_the_next_leaves_no_trace(
 
 def test_no_fill_compiles_and_a_new_layout_is_named_a_variant(monkeypatch):
     """After the first launch at a node width, fills 1..max_lanes compile
-    nothing, of either program; after a matrix growth the same holds.  A
-    launch whose packs have another layout than the last one's (here: the
-    class-eligibility width doubles) compiles a variant of the unpacking
-    program and says so (``coalescer.trace_variant``); the next does not."""
+    nothing; after a matrix growth the same holds.  A launch whose packs
+    have another layout than the last one's (here: the class-eligibility
+    width doubles) compiles a variant of the one program a launch calls
+    and says so (``coalescer.trace_variant``); the next does not."""
     from nomad_tpu.ops import kernels
 
     coal = helpers.wide_coalescer(nodes=10, capacity=16, lanes=8)
@@ -227,6 +237,7 @@ def test_no_fill_compiles_and_a_new_layout_is_named_a_variant(monkeypatch):
     state = coal._state
     monkeypatch.setattr(
         coal, "_state", lambda name, **a: states.append(name) or state(name, **a))
+    program = kernels.fused_place_batch_live
 
     def launch_states(k, **kw):
         del states[:]
@@ -235,20 +246,23 @@ def test_no_fill_compiles_and_a_new_layout_is_named_a_variant(monkeypatch):
 
     for n in (16, 32):
         assert int(coal.matrix.capacity) == n
-        first = launch_states(1)
-        assert first[-1] == (
+        assert launch_states(1)[-1] == (
             "coalescer.trace_variant" if n == 16 else "coalescer.enqueue")
-        before = helpers.backend_compiles(), kernels.unpack_lanes._cache_size()
+        # (the second launch is handed a carry that lives on the device,
+        # the first one of numpy: an entry of the jit cache each, one
+        # executable)
+        assert launch_states(1)[-1] == "coalescer.enqueue"
+        before = helpers.backend_compiles(), program._cache_size()
         for k in range(1, 9):
             assert launch_states(k)[-1] == "coalescer.enqueue"
-        assert (helpers.backend_compiles(),
-                kernels.unpack_lanes._cache_size()) == before
+        assert (helpers.backend_compiles(), program._cache_size()) == before
         for _ in range(10):  # past the capacity: the matrix grows
             coal.matrix.upsert_node(mock.node())
-    size = kernels.unpack_lanes._cache_size()
+    size = program._cache_size()
     assert launch_states(2, classes=4)[-1] == "coalescer.trace_variant"
-    assert kernels.unpack_lanes._cache_size() == size + 1
+    assert program._cache_size() == size + 1
     assert launch_states(2, classes=4)[-1] == "coalescer.enqueue"
+    assert coal.device_calls == coal.fused_dispatches
 
 
 @pytest.mark.parametrize("devices", [1, 4])
@@ -260,7 +274,6 @@ def test_packed_lane_operands_unpack_bit_for_bit(eight_devices, devices):
     from nomad_tpu.ops import kernels
     from nomad_tpu.ops.encode import packed_rows
     from nomad_tpu.parallel import make_mesh
-    from nomad_tpu.parallel.sharding import sharded_unpack_lanes
 
     specs = [((3,), bool), ((2, 5), np.float32), ((7,), np.int32),
              ((), np.int32), ((), bool), ((4, 3), np.float32), ((1,), bool)]
@@ -278,11 +291,20 @@ def test_packed_lane_operands_unpack_bit_for_bit(eight_devices, devices):
             v[...] = rng.integers(
                 -2**31, 2**31, v.shape, np.int64).astype(np.int32).view(dtype)
     views[1][0, 0, 0] = -0.0
-    if devices == 1:
-        unpack = kernels.unpack_lanes
-    else:
-        unpack = sharded_unpack_lanes(make_mesh(devices, batch=2))
-    fields, again = unpack(buf, buf.copy(), layouts=(layout, layout))
+    import jax
+
+    shardings = {}
+    if devices > 1:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        lanes_over_batch = NamedSharding(
+            make_mesh(devices, batch=2), P("batch"))
+        shardings = dict(
+            in_shardings=lanes_over_batch, out_shardings=lanes_over_batch)
+    unpack = jax.jit(
+        lambda *packs: [kernels.unpack_rows(p, layout) for p in packs],
+        **shardings)
+    fields, again = unpack(buf, buf.copy())
     for got, twin, want in zip(fields, again, views):
         assert got.shape == want.shape and got.dtype == want.dtype
         assert np.asarray(got).tobytes() == np.asarray(twin).tobytes() \

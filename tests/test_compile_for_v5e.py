@@ -1,8 +1,10 @@
 """The node-sharded placement program compiled for a v5e 2x2 that is
 described, not attached (PR 46): the wide ``Features`` variant the cell
 ``c2m-100k-rules.rules-backlog-x4`` launches, at its timed sizes (64 lanes x
-102,400 rows on a ``(2, 2)`` mesh, a class operand of 8,192 a lane, the
-overlay and the chain as the coalescer hands them over).  What the chip's
+102,400 rows on a ``(2, 2)`` mesh, a class operand of 8,192 a lane), as the
+one call a launch of the server makes (PR 48: the two packs, the three
+node-axis buffers and the carry; the overlay and the chain's flags ride the
+lane pack), and the one-chip cells' entry beside it.  What the chip's
 compiler would refuse (a variant that does not partition, a program that
 does not fit a chip's memory) it refuses here, at no chip time; nothing
 runs, so this says nothing of results or times.
@@ -90,6 +92,49 @@ def test_the_plain_sharded_variant_has_no_rules_exchange(mesh):
         **aot_ops.VARIANTS["plain"]))
     assert "rules_exchange" not in compiled.as_text()
     _holds_the_invariants_outside_the_loop(compiled, PARENT_TEMP["plain"])
+
+
+def test_the_packed_sharded_entry_moves_nothing_between_chips(mesh):
+    """A launch is one call of module ``jit_entry`` (the name the four-chip
+    cells' ``placement_programs`` find it by): the compiler lays both packs
+    out over ``batch`` alone, and the program holds the collectives of the
+    placement program that took every operand as its own (each laid out as
+    the ``shard_map`` asks) and no other: unpacking at the entry moves
+    nothing between chips."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from helpers import collectives
+
+    feats = aot_ops.features(**aot_ops.VARIANTS["plain"])
+    live = aot_ops.compile_sharded(mesh, feats)
+    text = live.as_text()
+    assert re.match(r"HloModule jit_entry\b", text), text[:80]
+    lanes = NamedSharding(mesh, P("batch"))
+    _arrays, _used, *packs = live.input_shardings[0][:4]
+    assert len(packs) == 2
+    assert all(s.is_equivalent_to(lanes, 2) for s in packs), packs
+    plain = aot_ops.compile_sharded_plain(mesh, feats).as_text()
+    assert re.match(r"HloModule jit_entry\b", plain), plain[:80]
+    assert collectives(text) == collectives(plain)
+    assert sum(collectives(plain).values()) > 10
+
+
+@pytest.mark.parametrize("variant", sorted(aot_ops.VARIANTS))
+def test_the_packed_one_chip_entry_compiles_for_a_v5e(mesh, variant):
+    """The one-chip cells' one call a launch, at 64 lanes x 10,240 rows:
+    module ``jit_fused_place_batch_live`` (their ``placement_programs``
+    look for ``fused_place_batch``), nothing that crosses a chip, well
+    inside a chip's memory beside the resident matrix."""
+    from helpers import collectives
+
+    compiled = aot_ops.compile_one_chip(
+        mesh.devices.flat[0], aot_ops.features(**aot_ops.VARIANTS[variant]))
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit_fused_place_batch_live\b", text), text[:80]
+    assert not collectives(text)
+    assert aot_ops.loops_under(text, "place_scan/while"), "no placement loop"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES // 8
 
 
 def test_the_op_table_names_a_loop_by_its_scope():

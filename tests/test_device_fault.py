@@ -322,7 +322,7 @@ class TestPipelineWedge:
 
         def compiling_launch(*_operands, **_static):
             time.sleep(0.4)  # 8x the deadline, 5x the wedge bound
-            return Launched(), _static["chain"][0]  # packed, the carry
+            return Launched(), _operands[-1]  # packed, the carry
 
         monkeypatch.setattr(
             kernels, "fused_place_batch_live", compiling_launch

@@ -287,21 +287,34 @@ def test_j105_one_compile_serves_all_step_counts():
     assert contracts._cache_size(entry) == before
 
 
-def test_j105_unpacking_program_compiles_once_for_every_fill():
-    """The unpacking program's operands are the server's own packs
-    (``RequestSlab``'s and ``_staging``'s layouts), whose shapes do not
-    know the fill: fills 1..64 after the first launch cost no compile."""
+@pytest.mark.parametrize(
+    "name", ["fused_place_batch_live", "sharded_fused_place_batch_live"]
+)
+def test_j105_the_packed_entries_compile_once_for_every_fill(name):
+    """A live entry's operands are the server's own packs (``RequestSlab``'s
+    and ``_staging``'s layouts), whose shapes do not know the fill, the
+    three node-axis buffers and the carry; the packs are not donated (views
+    of a staging slot, read until the launch resolves); fills 1..64 and
+    every step count after the first launch cost no compile."""
     from nomad_tpu.ops.encode import SchedRequest
+    from nomad_tpu.ops.kernels import LANE_FIELDS
 
-    c = contracts.get("unpack_lanes")
-    assert c.sweep is contracts.occupancy_sweep and c.max_compiles == 1
-    packs, layouts = contracts._unpack_packs(c.compile_grid)
-    assert [len(lay) for lay in layouts] == [len(SchedRequest._fields), 9]
-    assert all(p.dtype == np.uint8 and p.shape[0] == c.compile_grid.batch
-               for p in packs)
-    entry = c.build(c.compile_grid)
-    assert contracts.occupancy_sweep(entry, c) <= 1
-    assert contracts.occupancy_sweep(entry, c) == 0
+    c = contracts.get(name)
+    assert c.sweep is contracts.lane_steps_sweep and c.max_compiles == 1
+    assert not {2, 3} & set(c.donated_args) and c.donated_kwargs == ()
+    g = c.compile_grid
+    packs, layouts = contracts._unpack_packs(g._replace(live=3, steps=2))
+    assert [len(lay) for lay in layouts] == [
+        len(SchedRequest._fields), len(LANE_FIELDS)]
+    assert all(p.dtype == np.uint8 and p.shape[0] == g.batch for p in packs)
+    operands = c.operands(g)
+    assert len(operands) == 8 and all(
+        a.tobytes() == b.tobytes()
+        for a, b in zip(operands[2:4], contracts._unpack_packs(g)[0]))
+    assert c.static_kwargs(g)["layouts"] == layouts
+    entry = c.build(g)
+    assert contracts.lane_steps_sweep(entry, c) <= 1
+    assert contracts.lane_steps_sweep(entry, c) == 0
 
 
 @pytest.mark.parametrize(
@@ -340,8 +353,8 @@ def test_contract_table_names_every_registered_entry():
     assert names == {
         "fused_place_batch",
         "fused_place_batch_live",
-        "unpack_lanes",
         "sharded_fused_place_batch",
+        "sharded_fused_place_batch_live",
         "make_row_scatter",
         "make_sharded_row_scatter",
     }

@@ -91,13 +91,16 @@ def _walk(jaxpr, tables, stack=(), in_loop=False):
             )
 
 
+FUSED = ("fused_place_batch", "fused_place_batch_live",
+         "sharded_fused_place_batch", "sharded_fused_place_batch_live")
+
+
 @pytest.fixture(scope="module")
 def traced():
     """{(entry, variant): the equations of the entry traced at the lint
-    grid} for the three fused entries, wide and plain."""
+    grid} for the four fused entries, wide and plain."""
     out = {}
-    for name in ("fused_place_batch", "fused_place_batch_live",
-                 "sharded_fused_place_batch"):
+    for name in FUSED:
         c = contracts.get(name)
         for variant in ("wide", "plain"):
             g = c.trace_grids[0]._replace(features=FEATURES[variant])
@@ -111,9 +114,7 @@ def traced():
     return out
 
 
-ENTRIES = [(e, v) for e in ("fused_place_batch", "fused_place_batch_live",
-                            "sharded_fused_place_batch")
-           for v in ("wide", "plain")]
+ENTRIES = [(e, v) for e in FUSED for v in ("wide", "plain")]
 
 
 @pytest.mark.parametrize("entry,variant", ENTRIES)

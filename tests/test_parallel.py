@@ -20,11 +20,14 @@ from nomad_tpu.state.matrix import NodeMatrix
 from helpers import (
     LAUNCH_FILLS,
     assert_bits_equal,
+    check_enqueue_span,
     check_packed_launch,
     check_sync_span,
+    collectives,
     dirty_hard_rows,
     host_mirror,
     lane_operands,
+    plain_mesh_program,
 )
 
 
@@ -255,31 +258,68 @@ class TestShardedFusedParity:
 
 
 class TestPackedLaunch:
-    """On a mesh the two packs are unpacked by ``sharded_unpack_lanes`` and
-    the sharded placement program takes every small lane operand as a
-    device array split over ``batch`` alone, as its ``in_specs`` ask."""
+    """On a mesh a launch is ONE jitted call too: the sharded placement
+    program takes the two packs split over ``batch`` alone and unpacks them
+    at its entry, every field held to the split its ``in_specs`` ask."""
 
+    MESHES = [(2, (1, 2)), (4, (2, 2))]
+
+    @pytest.mark.parametrize("rules", [False, True], ids=["dp0", "dp2"])
     @pytest.mark.parametrize("k", LAUNCH_FILLS)
-    @pytest.mark.parametrize("devices,mesh_shape", [(2, (1, 2)), (4, (2, 2))])
+    @pytest.mark.parametrize("devices,mesh_shape", MESHES)
     def test_a_launch_hands_over_two_packs_and_the_node_axis_operands(
-        self, eight_devices, monkeypatch, devices, mesh_shape, k,
+        self, eight_devices, monkeypatch, devices, mesh_shape, k, rules,
     ):
         """tests/test_coalescer.py's check on the layouts the server gives
         two and four devices (under (2, 2) a batch shard holds 32 lanes)."""
+        coal, _call = check_packed_launch(monkeypatch, k, devices, rules)
+        assert coal.mesh_shape() == mesh_shape
+
+    @pytest.mark.parametrize("devices,mesh_shape", MESHES)
+    def test_the_packs_arrive_split_over_batch_and_no_field_crosses_a_chip(
+        self, eight_devices, monkeypatch, devices, mesh_shape,
+    ):
+        """The compiled program's own word: both packs are laid out over
+        ``batch`` alone, and it holds the collectives of the plain placement
+        program (every operand its own, each laid out as ``in_specs`` say)
+        and no other: unpacking at the entry moves nothing between chips."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        coal, small = check_packed_launch(monkeypatch, k, devices)
+        coal, (operands, static) = check_packed_launch(
+            monkeypatch, 9, devices)
         assert coal.mesh_shape() == mesh_shape
-        want = NamedSharding(coal._mesh, P("batch"))
-        assert all(x.sharding.is_equivalent_to(want, x.ndim) for x in small)
+        monkeypatch.undo()  # (the spy has no ``lower``)
+        operands = operands[:7] + (np.asarray(coal._carry),)
+        live = coal._sharded_fused_fn.lower(*operands, **static).compile()
+        lanes = NamedSharding(coal._mesh, P("batch"))
+        _arrays, _used, *packs = live.input_shardings[0][:4]
+        assert len(packs) == 2
+        assert all(s.is_equivalent_to(lanes, 2) for s in packs), packs
 
-    @pytest.mark.parametrize("devices,mesh_shape", [(2, (1, 2)), (4, (2, 2))])
+        st, slab = coal._stage[0], coal._req_slabs[0]
+        plain = kernels.place_launch(
+            plain_mesh_program(coal).lower, *operands[:2],
+            kernels.device_request(slab.batch(), static["features"].dp_width),
+            {f: st[f] for f in kernels.LANE_FIELDS}, *operands[4:],
+            features=static["features"],
+        ).compile()
+        assert collectives(live.as_text()) == collectives(plain.as_text())
+        assert sum(collectives(plain.as_text()).values()) > 0
+
+    @pytest.mark.parametrize("devices,mesh_shape", MESHES)
     def test_a_sync_span_counts_the_one_operand_the_scatter_hands_over(
         self, eight_devices, devices, mesh_shape,
     ):
         coal = check_sync_span(devices)
         assert coal.mesh_shape() == mesh_shape
         assert coal.matrix.shard_count == mesh_shape[1]
+
+    @pytest.mark.parametrize("devices,mesh_shape", MESHES)
+    def test_an_enqueue_span_counts_the_one_call_and_its_operands(
+        self, eight_devices, devices, mesh_shape,
+    ):
+        coal = check_enqueue_span(devices)
+        assert coal.mesh_shape() == mesh_shape
 
 
 class TestTopkHostBytes:

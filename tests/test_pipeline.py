@@ -209,12 +209,10 @@ class TestStagingReuse:
                 out[:, :, kernels.PACKED_ROW] = -1.0
                 return out
 
-        def stand_in(arrays, used, dr, dv, tg, sc, pen, reqs, ce, hm, ls,
-                     **_static):
-            launches.append(
-                InFlight([dr, dv, tg, sc, pen, ce, hm, ls, *reqs])
-            )
-            return launches[-1], _static["chain"][0]  # packed, the carry
+        def stand_in(arrays, used, request_pack, lane_pack, tg, pen, hm,
+                     carry, **_static):
+            launches.append(InFlight([request_pack, lane_pack, tg, pen, hm]))
+            return launches[-1], carry  # packed, the carry
 
         monkeypatch.setattr(kernels, "fused_place_batch_live", stand_in)
         inputs = []

@@ -62,55 +62,100 @@ def described_mesh():
     return Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "node"))
 
 
-def compile_sharded(mesh, feats):
-    """``sharded_fused_place_batch`` lowered and compiled for ``mesh`` at
-    the four-chip cells' sizes, from shapes alone."""
+def launch_specs(feats, rows, class_pad, sharding_of):
+    """What a launch of the server hands its one program, from shapes alone:
+    ``(operands, static)`` of the live entries (``kernels.
+    fused_place_batch_live`` / ``sharding.sharded_fused_place_batch_live``)
+    at 64 lanes x ``rows`` nodes, the packs and their layouts the server's
+    own (``lint/contracts.py``: ``RequestSlab``'s and ``_staging``'s).
+    ``sharding_of(spec)`` lays an operand out, ``spec`` its
+    ``PartitionSpec`` on a ('batch', 'node') mesh, None for the two packs
+    (their split is the program's to ask)."""
     import jax
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    from nomad_tpu.lint.contracts import Grid, fused_operands
+    from nomad_tpu.lint import contracts
+    from nomad_tpu.parallel import sharding
+
+    g = contracts.Grid(nodes=8, batch=LANES, placements=SCAN, deltas=0,
+                       live=LANES, features=feats)
+    arrays, used, _rp, _lp, tg, pen, hm, carry = contracts.packed_operands(g)
+    packs, layouts = contracts._unpack_packs(g, class_pad)
+
+    def struct(x, spec, shape=None):
+        return jax.ShapeDtypeStruct(
+            shape or np.shape(x), np.asarray(x).dtype,
+            sharding=sharding_of(spec))
+
+    def node_rows(x, spec):
+        return struct(x, spec, (rows,) + np.shape(x)[1:])
+
+    operands = (
+        type(arrays)(*map(node_rows, arrays, sharding._ARRAYS_SPEC)),
+        node_rows(used, P("node", None)),
+        *(struct(p, None) for p in packs),
+        *(struct(x, P("batch", "node"), (LANES, rows)) for x in (tg, pen, hm)),
+        struct(carry, P(None, "batch", None, None)),
+    )
+    return operands, {"layouts": layouts, "features": feats}
+
+
+def _on(mesh):
+    from jax.sharding import NamedSharding
+
+    return lambda spec: None if spec is None else NamedSharding(mesh, spec)
+
+
+def compile_sharded(mesh, feats):
+    """``sharded_fused_place_batch_live`` (the one call a launch of the
+    four-chip cells makes) lowered and compiled for ``mesh`` at their
+    sizes, from shapes alone."""
+    from nomad_tpu.parallel import sharding
+
+    operands, static = launch_specs(feats, ROWS, CLASS_PAD, _on(mesh))
+    fn = sharding.sharded_fused_place_batch_live(mesh, SCAN)
+    return fn.lower(*operands, **static).compile()
+
+
+def compile_sharded_plain(mesh, feats):
+    """``sharded_fused_place_batch`` (every operand its own, each laid out
+    as the ``shard_map`` asks: the placement program as it was before a
+    launch handed it the packs) at the same sizes: what the live entry's
+    collectives are held to."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
     from nomad_tpu.ops import kernels
     from nomad_tpu.parallel import sharding
-    from nomad_tpu.scheduler.claims import CHAIN_DEPTH
-    from nomad_tpu.scheduler.coalescer import MAX_DELTA_ROWS
 
-    def spec(shape, dtype, p):
-        return jax.ShapeDtypeStruct(
-            tuple(shape), dtype, sharding=NamedSharding(mesh, p))
-
-    # Field shapes off a small grid; the node axis at the region's size.
-    small = fused_operands(Grid(
-        nodes=8, batch=LANES, placements=SCAN, deltas=MAX_DELTA_ROWS,
-        live=LANES, features=feats))
-    arrays = type(small[0])(*(
-        spec((ROWS,) + np.shape(x)[1:], np.asarray(x).dtype, p)
-        for x, p in zip(small[0], sharding._ARRAYS_SPEC)))
-    reqs = kernels.device_request(small[7], feats.dp_width)
-    reqs = type(reqs)(*(
-        None if f is None else spec(np.shape(f), np.asarray(f).dtype, p)
-        for f, p in zip(reqs, sharding._REQS_SPEC)))
-    lanes, f32, i32 = P("batch", None, None), np.float32, np.int32
-    k = MAX_DELTA_ROWS
-    args = (
-        arrays, spec((ROWS, 3), f32, P("node", None)),
-        spec((LANES, k), i32, P("batch", None)), spec((LANES, k, 3), f32, lanes),
-        spec((LANES, ROWS), i32, P("batch", "node")),
-        spec(np.shape(small[5]), f32, lanes),
-        spec((LANES, ROWS), bool, P("batch", "node")), reqs,
-        spec((LANES, CLASS_PAD), bool, P("batch", None)),
-        spec((LANES, ROWS), bool, P("batch", "node")),
-        spec((LANES,), i32, P("batch")),
-    )
-    overlay = (spec((LANES, 64), i32, P("batch", None)),
-               spec((LANES, 64, 3), f32, lanes))
-    chain = (spec((CHAIN_DEPTH, LANES, k + SCAN, 4), f32,
-                  P(None, "batch", None, None)),
-             spec((LANES, 1 + CHAIN_DEPTH), bool, P("batch", None)),
-             spec((LANES, k, 3), f32, lanes))
+    (arrays, used, request_pack, lane_pack, *rest), static = launch_specs(
+        feats, ROWS, CLASS_PAD, _on(mesh))
+    reqs, lane = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=_on(mesh)(P("batch"))),
+        jax.eval_shape(
+            lambda *packs: kernels.unpack_launch(
+                *packs, static["layouts"], feats.dp_width),
+            request_pack, lane_pack))
     fn = sharding.sharded_fused_place_batch(mesh, SCAN)
-    return fn.lower(
-        *args, features=feats, overlay=overlay, chain=chain).compile()
+    return kernels.place_launch(
+        fn.lower, arrays, used, reqs, lane, *rest, features=feats).compile()
+
+
+def compile_one_chip(device, feats, rows=10_240, class_pad=512):
+    """``kernels.fused_place_batch_live`` (the one call a launch of the
+    one-chip cells makes) lowered and compiled for ``device`` at their
+    sizes (64 lanes x 10,240 rows; ``c2m-10k-rules`` has 480 computed
+    classes), from shapes alone."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nomad_tpu.ops import kernels
+
+    operands, static = launch_specs(
+        feats, rows, class_pad, lambda _spec: SingleDeviceSharding(device))
+    return kernels.fused_place_batch_live.lower(
+        *operands, n_placements=SCAN, **static).compile()
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
