@@ -90,9 +90,8 @@ class DeviceContract:
     # J103: entry is ALLOWED to emit node-axis-shaped outputs across the
     # mesh boundary (the scatter returns the resident matrix itself).
     node_axis_outputs_ok: bool = False
-    # J103: shapes exempt from the boundary check — the declared
-    # (shards, k) candidate table of a hierarchical top-k, if a node
-    # count ever collides with it.
+    # J103: shapes exempt from the boundary check, if a node count ever
+    # collides with a shape a program declares it may move.
     boundary_exempt_shapes: Tuple[Tuple[int, ...], ...] = ()
     # J104: require an explicit input_output_alias in the compiled HLO.
     # The fused kernel's donated lane operands are scratch-reusable but

@@ -45,7 +45,8 @@ This pass enforces both lexically over ``ops/``, ``parallel/``,
   (parallel/sharding.py) is that only the packed (B, P, 8) winner block
   ever crosses the device→host boundary; an (…, N) fetch reintroduces
   O(nodes) host traffic per dispatch and scales with cluster size —
-  exactly what hierarchical top-k exists to prevent.
+  exactly what the election across shards (``kernels.elect``) exists to
+  prevent.
 """
 
 from __future__ import annotations
@@ -370,8 +371,8 @@ def _check_function(
                     f"fused/sharded call site — only the packed "
                     f"(B, P, 8) winner block may cross the device->host "
                     f"boundary; an (..., N) fetch is O(nodes) host "
-                    f"traffic per dispatch (see parallel/sharding.py "
-                    f"hierarchical top-k)",
+                    f"traffic per dispatch (see ops/kernels.py "
+                    f"elect)",
                 ))
                 continue
 

@@ -196,8 +196,8 @@ def _check_traced(c: Any, g: Any, closed: Any, emit: Callable[[str, str], None])
                             "J103",
                             f"collective '{name}' moves a node-axis value "
                             f"of shape {shape} (N={marker}) across the mesh "
-                            f"at grid {g!r} — only the declared (shards, k) "
-                            "candidate table may cross",
+                            f"at grid {g!r} — only scalars and lane-sized "
+                            "values may cross (kernels.elect)",
                         )
         elif name == "shard_map" and not c.node_axis_outputs_ok:
             for var in eqn.outvars:

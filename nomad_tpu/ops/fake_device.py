@@ -821,7 +821,7 @@ def fused_place_batch(arrays, used, delta_rows: List[np.ndarray],
 
     ``overlay`` = (rows, vals), -1 padded, any leading shape: the
     in-flight claims of the launches before this one
-    (``kernels.overlay_usage``), added to the usage under the claims image
+    (``claimed`` in the kernel), added to the usage under the claims image
     and the verify pass and to no score; None = empty.
 
     ``chain`` = (carry (D, Bc, K + P, 4) f32 with Bc >= B, live (D,) bool,
@@ -943,11 +943,11 @@ def sharded_fused_place_batch(arrays, used, delta_rows, delta_vals,
                               overlay=None, chain=None):
     """Twin of parallel.sharding.sharded_fused_place_batch for host-only CI.
 
-    The sharded kernel's hierarchical top-k election (per-shard stable
-    top-k → cross-shard pmax/pmin of the (shards, k) candidate table,
-    shard-major row-minor tie-break) provably reproduces the dense argmax
+    The placement body's election across shards (``kernels.elect``: each
+    shard's arg-max → the cluster's best score by pmax → the lowest row
+    that holds it by pmin) provably reproduces the dense argmax
     row-for-row, and its owner-veto verify reproduces the sequential
-    cross-lane AllocsFit scan (PARITY.md "Hierarchical top-k") — so the
+    cross-lane AllocsFit scan (PARITY.md "The election") — so the
     bit-compatible numpy reference IS the dense twin, run after validating
     the shard partition the mesh would impose.
     """

@@ -22,6 +22,7 @@ golden tests against the scalar oracle in structs.funcs):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple, Optional
 
@@ -154,6 +155,116 @@ def features_of(reqs: SchedRequest) -> Features:
         ),
         dp_width=_slot_width(reqs.dp_slot, MAX_DISTINCT_PROPS),
     )
+
+
+# ---------------------------------------------------------------------------
+# The topology seam
+# ---------------------------------------------------------------------------
+
+
+class Topology:
+    """What the topology a placement program runs on decides, and nothing
+    else: which rows and lanes this shard holds, how a reduction over its
+    nodes becomes one over the cluster's, how a lane's value reaches every
+    shard.  The placement step is written once against these methods
+    (``_fused_place_batch_impl`` and what it calls) and never asks which
+    instance it holds.
+
+    This class is the one-device instance (``ONE_DEVICE``): the device
+    holds every row and lane, so each method returns its argument and the
+    traced program holds no collective, gather or cast
+    (tests/test_topology_seam.py).  The mesh's (``parallel/sharding.py::
+    MESH``) overrides each with the collective it stands for.  Whoever
+    builds a jitted entry binds one of the two (``fused_place_batch``,
+    ``place_task_group`` here, ``_shard_mapped`` there): it is no operand,
+    no static argument and no option.
+    """
+
+    def shard(self, n_local: int, b_local: int):
+        """(global row of this shard's first row, the launch's lane of its
+        first lane), for a shard of ``n_local`` rows and ``b_local`` lanes."""
+        return 0, 0
+
+    def vary(self, x, nodes: bool = False):
+        """``x`` typed as differing from shard to shard over the lanes' axis
+        (and the nodes'): a loop carry that starts as a constant."""
+        return x
+
+    def all_lanes(self, x, axis: int = 0):
+        """``x`` of this shard's lanes -> of all the launch's lanes."""
+        return x
+
+    def max(self, x, lanes: bool = False):
+        """The largest ``x`` of any node shard (with ``lanes``: any shard)."""
+        return x
+
+    def min(self, x, lanes: bool = False):
+        """The smallest ``x`` of any node shard (with ``lanes``: any shard)."""
+        return x
+
+    def sum(self, x):
+        """``x`` summed over the node shards: a count of nodes, or a value
+        only a row's owner holds (the others offer zeros)."""
+        return x
+
+    def any(self, flag):
+        """``flag`` (bool) holds on some node shard."""
+        return flag
+
+    def all(self, flags):
+        """``flags`` (bool) hold on every node shard: each can veto."""
+        return flags
+
+    def exchange(self, name: str):
+        """The scope a profile files an exchange between shards under
+        (``gather``, ``elect``, ``count``, ``broadcast``,
+        ``rules_exchange``): a mesh's alone, no scope on one device."""
+        return contextlib.nullcontext()
+
+
+ONE_DEVICE = Topology()
+
+# No row: what a shard that does not hold the best score offers an election.
+_NO_ROW = 2 ** 30
+
+
+def local_rows(rows, row_offset, n_local: int):
+    """(global rows (...,)) -> (held by this shard, index among its
+    ``n_local`` rows, in range whether held or not); a launch's lanes among
+    a shard's own are told apart the same way."""
+    local = rows - row_offset
+    return (rows >= 0) & (local >= 0) & (local < n_local), jnp.clip(
+        local, 0, n_local - 1
+    )
+
+
+def add_claims(image, rows, vals, row_offset):
+    """``image`` (n_local, 3) with ``vals`` (..., 3) added on those of the
+    global ``rows`` (...,) this shard holds (a padding row, -1, is held by
+    none)."""
+    mine, safe = local_rows(rows, row_offset, image.shape[0])
+    return image.at[safe.reshape(-1)].add(
+        jnp.where(mine[..., None], vals, 0.0).reshape(-1, 3)
+    )
+
+
+def elect(topo: Topology, scores, row_offset, lanes: bool = False):
+    """The election every pick is: (the cluster's best of ``scores``, the
+    lowest global row that holds it).  ``scores`` (n_local,) are this
+    shard's, already masked (NEG_INF = not a candidate).  ``jnp.argmax`` is
+    the lowest local index of the shard's own maximum; ``topo.max`` elects
+    the winning score and ``topo.min`` the lowest row among the shards that
+    hold it, so ties break to the lowest global row whatever the layout:
+    one device's arg-max bit for bit (PARITY.md "The election").  With
+    ``lanes`` the election spans the batch shards too (a lane's scores live
+    on one of them; the others offer NEG_INF).  Nothing wider than a scalar
+    crosses a shard."""
+    idx = jnp.argmax(scores).astype(jnp.int32)
+    with topo.exchange("elect"):
+        best = topo.max(scores[idx], lanes)
+    row = jnp.where(scores[idx] == best, row_offset + idx, _NO_ROW)
+    with topo.exchange("elect"):
+        return best, topo.min(row, lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -375,8 +486,8 @@ def distinct_property_mask(col, req: SchedRequest, dp_cnt, dp_width: int):
 
 
 def distinct_property_values_at(arrays, req: SchedRequest, row):
-    """Per-slot property value of node ``row`` ((DP,) i32), split out like
-    ``spread_values_at`` for the node-sharded step."""
+    """Per-slot property value of node ``row`` ((DP,) i32), as
+    ``spread_values_at``."""
     return arrays.attr_hash[row, jnp.maximum(req.dp_slot, 0)]
 
 
@@ -687,20 +798,19 @@ def lane_invariants(arrays, req: SchedRequest, class_elig, host_mask,
     )
 
 
-def launch_invariants(arrays, reqs, class_eligs, host_masks,
-                      features: Features, n_lanes, vary=lambda x: x):
+def launch_invariants(topo: Topology, arrays, reqs, class_eligs, host_masks,
+                      features: Features, n_lanes):
     """``lane_invariants`` of a launch's first ``n_lanes`` lanes (traced:
-    the last live lane + 1), stacked on a leading lane axis; the lanes past
-    them are dead, nothing reads theirs, and they keep zeros.
+    the last live lane + 1 among the shard's own), stacked on a leading lane
+    axis; the lanes past them are dead, nothing reads theirs, and they keep
+    zeros.
 
     One loop over those lanes, not a ``vmap`` over all of them: in a lane's
     turn every attribute column it reads is a contiguous row slice that
     fuses into the arithmetic that reads it, where the vmapped read is a
     gather, which the TPU compiler runs as a serial loop of its own with a
     strided write a turn, for every lane the launch is padded to (64, of
-    which a closed loop fills 6-8: PERF.md section 5, PR 47).  ``vary``: the
-    sharded program's cast of the buffers to the mesh axes a lane's terms
-    vary over."""
+    which a closed loop fills 6-8: PERF.md section 5, PR 47)."""
     lanes = class_eligs.shape[0]
     # The class table's gather is the one read that is better vmapped: the
     # class ids are every lane's, so one gather fetches all lanes' verdicts
@@ -723,7 +833,8 @@ def launch_invariants(arrays, reqs, class_eligs, host_masks,
         _, outs = scan_steps(
             lambda carry, b: (carry, jax.tree_util.tree_leaves(one(b))),
             (),
-            [vary(jnp.zeros((lanes,) + s.shape, s.dtype)) for s in shapes],
+            [topo.vary(jnp.zeros((lanes,) + s.shape, s.dtype), nodes=True)
+             for s in shapes],
             n_lanes,
         )
     return jax.tree_util.tree_unflatten(tree, outs)
@@ -739,21 +850,21 @@ def score_nodes(
     class_elig,
     host_mask,
     features: Features = FULL_FEATURES,
-    node_axis: Optional[str] = None,
     dp_cnt=None,
 ) -> ScoreResult:
     """The full ranking pipeline in one call (GenericStack.Select,
     stack.go:117-179, minus the sampling the TPU design makes unnecessary):
-    ``rank_nodes`` on ``lane_invariants``.  A scan computes the invariants
-    once and ranks every step against them."""
+    ``rank_nodes`` on ``lane_invariants``, on one device.  A scan computes
+    the invariants once and ranks every step against them."""
     inv = lane_invariants(arrays, req, class_elig, host_mask, features)
     return rank_nodes(
-        arrays, inv, used, tg_count, spread_counts, penalty_mask, req,
-        features, node_axis, dp_cnt,
+        ONE_DEVICE, arrays, inv, used, tg_count, spread_counts, penalty_mask,
+        req, features, dp_cnt,
     )
 
 
 def rank_nodes(
+    topo: Topology,
     arrays,
     inv: LaneInvariants,
     used,
@@ -762,7 +873,6 @@ def rank_nodes(
     penalty_mask,
     req: SchedRequest,
     features: Features = FULL_FEATURES,
-    node_axis: Optional[str] = None,
     dp_cnt=None,
 ) -> ScoreResult:
     """One step's ranking from the lane's invariants (``inv``) and what the
@@ -779,11 +889,10 @@ def rank_nodes(
     (generic_sched.go:773-792: select without preemption, and only when no
     option was found select again with it).  Two tiers in ONE arg-max: a
     node that needs an eviction is in this step's arg-max only if NO
-    feasible node fits without one (one more reduction a step;
-    ``node_axis`` names the mesh axis the nodes are sharded over, so that
-    "no node" is said of the whole cluster).  Among preempting nodes the
-    rank is Nomad's mean with two of its terms ESTIMATED, because the
-    victims are chosen on the host, after the launch, for the one node
+    feasible node fits without one (one more reduction a step, and
+    ``topo.any`` says "no node" of the whole cluster).  Among preempting
+    nodes the rank is Nomad's mean with two of its terms ESTIMATED, because
+    the victims are chosen on the host, after the launch, for the one node
     picked (scheduler/preemption.py):
 
     * binpack: ScoreFit of the utilisation after the LEAST eviction the
@@ -827,10 +936,7 @@ def rank_nodes(
             can_preempt = (
                 ~fits & jnp.all(deficit <= freeable, axis=1) & pre_usable
             )
-            any_fit = jnp.any(feas & fits)
-            if node_axis is not None:
-                any_fit = lax.pmax(any_fit.astype(jnp.int32), node_axis) > 0
-            needs_preempt = can_preempt & ~any_fit
+            needs_preempt = can_preempt & ~topo.any(jnp.any(feas & fits))
             fits_all = fits | needs_preempt
             evicted = jnp.minimum(freeable, deficit)
             binpack = jnp.where(
@@ -901,9 +1007,8 @@ class PlacementResult(NamedTuple):
 
 
 def spread_values_at(arrays, req: SchedRequest, row):
-    """Per-stanza attribute hash of node ``row`` ((S,) i32) — split out so
-    the node-sharded step can compute it on the winning row's owner shard
-    and broadcast (parallel/sharding.py)."""
+    """Per-stanza attribute hash of node ``row`` ((S,) i32), a local row:
+    the shard that holds the picked node reads it (``_commit_step``)."""
     return arrays.attr_hash[row, jnp.maximum(req.s_slot, 0)]
 
 
@@ -929,19 +1034,10 @@ def apply_spread_values(spread_counts, req: SchedRequest, nvalues):
     )
 
 
-def _update_spread_counts(spread_counts, req: SchedRequest, arrays, row):
-    """After placing on ``row``, bump the count of that node's attribute
-    value per stanza."""
-    return apply_spread_values(
-        spread_counts, req, spread_values_at(arrays, req, row)
-    )
-
-
 def scan_steps(step, init, outs, trip):
     """``lax.scan(step, init, jnp.arange(P))`` cut off after ``trip`` steps
     (traced i32 scalar): step ``i``'s outputs land at index ``i`` of the
-    ``outs`` buffers, the rows never reached keep what ``outs`` held.
-    Shared with the node-sharded step (parallel/sharding.py)."""
+    ``outs`` buffers, the rows never reached keep what ``outs`` held."""
 
     def body(i, state):
         carry, bufs = state
@@ -957,25 +1053,32 @@ def scan_steps(step, init, outs, trip):
     return lax.fori_loop(0, trip, body, (init, tuple(outs)))
 
 
-def _score_step(arrays, inv: LaneInvariants, req: SchedRequest, carry,
-                penalty_mask, features: Features):
-    """One placement step's scores from the lane's invariants and the
-    scan's carry: (the request as this step reads it, its ``ScoreResult``,
-    the three node counts)."""
+def _score_step(topo: Topology, arrays, inv: LaneInvariants,
+                req: SchedRequest, carry, penalty_mask, features: Features,
+                row_offset):
+    """The first half of a placement step, for one lane on one shard: its
+    scores from the lane's invariants and the scan's carry, and its own
+    pick.  Returns (the request as this step reads it, its ``ScoreResult``,
+    the three node counts, the elected global row: -1 where no node of the
+    cluster is feasible and fits)."""
     used, tg_cnt, s_hash, s_counts, dp_cnt = carry
     req_step = req._replace(s_value_hash=s_hash)
     with jax.named_scope("score"):
         res = rank_nodes(
-            arrays, inv, used, tg_cnt, s_counts, penalty_mask, req_step,
-            features, dp_cnt=dp_cnt,
+            topo, arrays, inv, used, tg_cnt, s_counts, penalty_mask,
+            req_step, features, dp_cnt,
         )
     with jax.named_scope("pick"):
+        best, own = elect(topo, res.final, row_offset)
+        own = jnp.where(best > NEG_INF / 2, own, -1)
         counts = (
-            jnp.sum(res.feasible).astype(jnp.int32),
-            jnp.sum(~res.feasible & arrays.eligible).astype(jnp.int32),
-            jnp.sum(res.feasible & ~res.fits).astype(jnp.int32),
+            jnp.sum(res.feasible.astype(jnp.int32)),
+            jnp.sum((~res.feasible & arrays.eligible).astype(jnp.int32)),
+            jnp.sum((res.feasible & ~res.fits).astype(jnp.int32)),
         )
-    return req_step, res, counts
+        with topo.exchange("count"):
+            counts = tuple(topo.sum(c) for c in counts)
+    return req_step, res, counts, own
 
 
 def scan_carry(inv: LaneInvariants, req: SchedRequest, used0, tg_count,
@@ -995,40 +1098,62 @@ def scan_carry(inv: LaneInvariants, req: SchedRequest, used0, tg_count,
     return used0, tg_count, req.s_value_hash, spread_counts, dp_cnt
 
 
-def _commit_step(arrays, inv: LaneInvariants, req_step: SchedRequest, carry,
-                 res: ScoreResult, counts, row, ok, dp_width: int = 0):
-    """Charge a step's pick (``row``; nothing where ``ok`` is false) to the
-    scan's carry; returns (carry, the step's seven output columns, and at
-    ``dp_width`` > 0 an eighth: a node a distinct_property limit alone
-    excluded scored higher than the node taken)."""
+def _commit_step(topo: Topology, arrays, inv: LaneInvariants, carry,
+                 req_step: SchedRequest, res: ScoreResult, counts, row,
+                 features: Features, row_offset):
+    """The second half: charge a step's pick (``row``, global; -1 =
+    nothing) to the scan's carry on the shard that holds the row, and read
+    what the output and the rule stages need of that node from its owner.
+    Returns (carry, the step's seven output columns, and at ``dp_width`` >
+    0 an eighth: a node a distinct_property limit alone excluded scored
+    higher than the node taken)."""
     used, tg_cnt, s_hash, s_counts, dp_cnt = carry
+    ok = row >= 0
+    owner, lrow = local_rows(row, row_offset, used.shape[0])
     with jax.named_scope("update"):
-        safe_row = jnp.maximum(row, 0)
-        used2 = jnp.where(ok, used.at[safe_row].add(req_step.ask), used)
-        tg2 = jnp.where(ok, tg_cnt.at[safe_row].add(1), tg_cnt)
-        new_hash, new_counts = _update_spread_counts(
-            s_counts, req_step, arrays, safe_row
+        used2 = jnp.where(owner, used.at[lrow].add(req_step.ask), used)
+        tg2 = jnp.where(owner, tg_cnt.at[lrow].add(1), tg_cnt)
+
+        # The picked node's spread and property values, from its owner.
+        nvals = spread_values_at(arrays, req_step, lrow)
+        if features.dp_width:
+            with topo.exchange("rules_exchange"):
+                nvals = jnp.concatenate([
+                    nvals,
+                    distinct_property_values_at(arrays, req_step, lrow),
+                ])
+        nvals = jnp.where(owner, nvals, 0)
+        with topo.exchange("broadcast"):
+            nvals = topo.sum(nvals)
+        n_spreads = req_step.s_slot.shape[0]
+        new_hash, new_counts = apply_spread_values(
+            s_counts, req_step, nvals[:n_spreads]
         )
         s_hash2 = jnp.where(ok, new_hash, s_hash)
         s_counts2 = jnp.where(ok, new_counts, s_counts)
-        if dp_width:
+        if features.dp_width:
             dp_cnt = jnp.where(ok, distinct_property_pick(
-                inv.dp_values, req_step, dp_cnt,
-                distinct_property_values_at(arrays, req_step, safe_row),
-                dp_width,
+                inv.dp_values, req_step, dp_cnt, nvals[n_spreads:],
+                features.dp_width,
             ), dp_cnt)
 
-    preempted = ok & res.needs_preempt[safe_row]
-    if res.pre_terms is not None:
-        preempted = jnp.where(preempted, res.pre_terms[safe_row], 0.0)
-    out = (
-        row,
-        jnp.where(ok, res.final[safe_row], 0.0),
-        jnp.where(ok, res.binpack[safe_row], 0.0),
-        preempted,
-    ) + counts
-    if dp_width:
-        out += (ok & (res.dp_blocked_best > res.final[safe_row]),)
+        own_score = jnp.where(
+            owner, jnp.stack([res.final[lrow], res.binpack[lrow]]), 0.0
+        )
+        own_pre = owner & res.needs_preempt[lrow]
+        if res.pre_terms is not None:  # the count of the mean's terms, no flag
+            own_pre = jnp.where(own_pre, res.pre_terms[lrow], 0.0)
+        with topo.exchange("broadcast"):
+            final, binpack = topo.sum(own_score)
+            preempted = (
+                topo.any(own_pre) if res.pre_terms is None
+                else topo.max(own_pre)
+            )
+    out = (row, final, binpack, preempted) + counts
+    if features.dp_width:
+        with topo.exchange("broadcast"), topo.exchange("rules_exchange"):
+            blocked = topo.max(res.dp_blocked_best)
+        out += (ok & (blocked > final),)
     return (used2, tg2, s_hash2, s_counts2, dp_cnt), out
 
 
@@ -1046,21 +1171,17 @@ def _place_scan(
 ) -> PlacementResult:
     """Traceable core of the solo placement scan (``place_task_group``): a
     static ``lax.scan`` of ``n_placements`` steps, each the arg-max of the
-    request's own scores.  The batched program runs the same two halves of
-    a step (``_score_step``, ``_commit_step``) with the lanes' picks
-    resolved between them (``_fused_place_batch_impl``)."""
+    request's own scores, on one device.  The batched program runs the same
+    two halves of a step (``_score_step``, ``_commit_step``) with the
+    lanes' picks resolved between them (``_fused_place_batch_impl``)."""
 
     def step(carry, _):
-        req_step, res, counts = _score_step(
-            arrays, inv, req, carry, penalty_mask, features
+        req_step, res, counts, row = _score_step(
+            ONE_DEVICE, arrays, inv, req, carry, penalty_mask, features, 0
         )
-        with jax.named_scope("pick"):
-            row = jnp.argmax(res.final).astype(jnp.int32)
-            ok = res.final[row] > NEG_INF / 2
-            row = jnp.where(ok, row, -1)
         return _commit_step(
-            arrays, inv, req_step, carry, res, counts, row, ok,
-            features.dp_width,
+            ONE_DEVICE, arrays, inv, carry, req_step, res, counts, row,
+            features, 0,
         )
 
     inv = lane_invariants(arrays, req, class_elig, host_mask, features)
@@ -1169,40 +1290,6 @@ def fused_trip_counts(lane_steps, n_placements: int):
     return trip, last_lane
 
 
-def add_claims(used, rows, vals):
-    """``used`` with ``vals`` (..., 3) added on ``rows`` (...,); the caller
-    has zeroed the vals of what does not count (padding rows read 0)."""
-    return used.at[jnp.maximum(rows, 0).reshape(-1)].add(vals.reshape(-1, 3))
-
-
-def overlay_usage(used, overlay, chain=None):
-    """``used`` plus the in-flight claims: the usage of plans the launches
-    before this one picked and the applier has not decided yet.  Two
-    sources, each pick in exactly one of them (``scheduler/claims.py``):
-    ``overlay`` = (rows (..., K) i32 with -1 padding, vals (..., K, 3)
-    f32), the coalescer's ``ClaimsLedger`` (launches whose result is on the
-    host), and ``chain`` = (carry, flags, ...), the blocks the launches
-    before this one wrote on the device (``claims_block``), of which the
-    live ones are the launches whose result is NOT on the host yet.  None
-    or all padding / no live block = ``used`` bit for bit.  It stands under
-    the claims image and the verify pass and under nothing else: no score
-    ever reads it."""
-    if overlay is None and chain is None:
-        return used
-    with jax.named_scope("overlay"):
-        if overlay is not None:
-            rows, vals = overlay
-            used = add_claims(
-                used, rows, jnp.where((rows >= 0)[..., None], vals, 0.0)
-            )
-        if chain is not None:
-            carry, flags = chain[:2]
-            used = add_claims(used, *carried_claims(
-                carry, chain_flags(flags, carry.shape[0])[1]
-            ))
-        return used
-
-
 def chain_flags(flags, depth: int):
     """The chain's flags as they ride the packed lane buffer ((B, 1 + w)
     bool): column 0 says whether the lane holds claims (an eval stands
@@ -1260,28 +1347,6 @@ def chained_carry(own, carry):
     return jnp.concatenate([own[None], carry[:-1]], axis=0)
 
 
-def claims_image(used, delta_rows, delta_vals, live):
-    """What is claimed before any lane of the launch places: the shared
-    usage (with the in-flight overlay under it: ``overlay_usage``) plus
-    every live lane's in-flight deltas ((N, 3); the resolution adds each
-    pick's ask to it as the lanes take their turns).  With one live lane
-    and an empty overlay it is, bit for bit, that lane's own ``used0``."""
-    valid = (delta_rows >= 0) & live[:, None]  # (B, K)
-    return add_claims(
-        used, delta_rows, jnp.where(valid[:, :, None], delta_vals, 0.0)
-    )
-
-
-def resolved_pick(final, room, own):
-    """A lane's pick under the launch's claims: the arg-max of its own
-    ``final`` over the rows with ``room``; its unresolved arg-max ``own``
-    where no feasible row has room (never an empty slot: the re-verify
-    then reads 0.0 and the applier decides)."""
-    masked = jnp.where(room, final, NEG_INF)
-    alt = jnp.argmax(masked).astype(jnp.int32)
-    return jnp.where(masked[alt] > NEG_INF / 2, alt, own)
-
-
 @jax.named_scope("pack")
 def pack_fused_lanes(
     rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
@@ -1289,10 +1354,7 @@ def pack_fused_lanes(
 ):
     """Stack per-lane placement outputs into the fused (B, P, 8) layout with
     dead-lane masking: row/-1, VERIFIED/-1.0, zeros elsewhere.  VERIFIED
-    reads 2.0 where the placement fits and the lane re-picked.  Shared by
-    the single-device fused kernel and the shard_map local body
-    (parallel/sharding.py) so the two paths cannot drift column-wise —
-    tests/test_parallel.py asserts bitwise parity across them.
+    reads 2.0 where the placement fits and the lane re-picked.
 
     ``dp_moved`` (``Features.dp_width`` > 0): the FILTERED column, a whole
     number of nodes, carries + 0.5 where a distinct_property limit moved the
@@ -1321,20 +1383,22 @@ def pack_fused_lanes(
     )  # (B, P, FUSED_PACKED_WIDTH)
 
 
-def inert_lane_outputs(lanes: int, n_placements: int,
-                       preempt: bool = False, dp: bool = False) -> tuple:
+def inert_lane_outputs(lanes: int, all_lanes: int, n_placements: int,
+                       preempt: bool, dp: bool) -> tuple:
     """The stacked outputs of a launch in which no step ran, step-major
-    ((P, B): a step's outputs of all lanes land at one index): row -1,
-    zero scores, flags and node counts (what a failed-or-never-asked
-    placement reads, and what the numpy twin fills its tail rows with),
-    and the re-pick flag as an eighth buffer.  With ``preempt`` (the
-    launch's ``Features``) the PREEMPT buffer holds a count, not a flag
-    (``score_nodes``); with ``dp`` (``Features.dp_width`` > 0) a ninth
-    buffer holds the distinct_property stage's flag (``_commit_step``)."""
+    ((P, B): a step's outputs of all lanes land at one index), in the
+    order a step of the batched program writes them: row -1 (of every lane
+    of the launch, ``all_lanes``: each shard's verify replays them all; the
+    other buffers are of this shard's ``lanes``), zero scores, flags and
+    node counts (what a failed-or-never-asked placement reads, and what the
+    numpy twin fills its tail rows with), the re-pick flag.  With
+    ``preempt`` the PREEMPT buffer holds a count, not a flag
+    (``score_nodes``); with ``dp`` (``Features.dp_width`` > 0) a last buffer
+    holds the distinct_property stage's flag."""
     shape = (n_placements, lanes)
     moved = (jnp.zeros(shape, bool),) if dp else ()
     return (
-        jnp.full(shape, -1, jnp.int32),
+        jnp.full((n_placements, all_lanes), -1, jnp.int32),
         jnp.zeros(shape, jnp.float32),
         jnp.zeros(shape, jnp.float32),
         jnp.zeros(shape, jnp.float32 if preempt else bool),
@@ -1346,6 +1410,7 @@ def inert_lane_outputs(lanes: int, n_placements: int,
 
 
 def _fused_place_batch_impl(
+    topo: Topology,
     arrays,
     used,
     delta_rows,
@@ -1357,15 +1422,21 @@ def _fused_place_batch_impl(
     class_eligs,
     host_masks,
     lane_steps,
-    n_placements: int,
-    features: Features = FULL_FEATURES,
     overlay=None,
     chain=None,
+    *,
+    n_placements: int,
+    features: Features = FULL_FEATURES,
 ):
     """The mega-batched ranking megakernel: B eval pipelines — feasibility →
     binpack → spread/affinity → preemption evict-state → placement scan —
     with the lanes' picks resolved in lane order inside every placement
-    step, PLUS the ``AllocsFit`` plan re-verify, in ONE launch.
+    step, PLUS the ``AllocsFit`` plan re-verify, in ONE launch.  This is the
+    program of one shard, written once: ``topo`` (``Topology``) says what
+    the shard holds and how it hears of the others.  On one device it holds
+    everything and hears nothing (``fused_place_batch``,
+    ``fused_place_batch_live``); under ``shard_map`` the same lines are the
+    mesh's program (``parallel/sharding.py``; "Across shards", below).
 
     Per-request args lead with a B axis.  ``delta_rows``/``delta_vals``
     ((B, K) i32 / (B, K, 3) f32, row -1 = padding) carry each request's
@@ -1386,7 +1457,7 @@ def _fused_place_batch_impl(
     * **In-launch pick resolution.**  Every lane scores the nodes against
       its own proposed usage, exactly as alone.  Then, within a step, the
       live lanes take their picks in lane order against one image of the
-      launch's claims (``claims_image``: the shared usage with the
+      launch's claims (``claims0``: the shared usage with the
       in-flight overlay under it, every live lane's in-flight deltas,
       every pick so far): a lane takes the arg-max of its own scores over
       the nodes where that image still has room for its ask, and adds its
@@ -1428,7 +1499,7 @@ def _fused_place_batch_impl(
       two launches named the same nodes.  The coalescer's ``ClaimsLedger``
       hands each launch those undecided picks, and they are added to the
       usage under the claims image and under the verify pass
-      (``overlay_usage``) and to NOTHING else: every lane still scores the
+      (``claimed``) and to NOTHING else: every lane still scores the
       nodes against its own proposed usage, so the overlay only decides
       which nodes have ``room`` (and are ``unclaimed``, for a node taken
       by preempting).  A lane passes over a node because lanes of this
@@ -1449,146 +1520,204 @@ def _fused_place_batch_impl(
       live = that launch's result is not on the host yet, decided by the
       host in one step with its read of the ledger, so a pick is in the
       overlay or in a live block and never in both.  Live blocks enter
-      exactly where the overlay enters (``overlay_usage``) and nowhere
+      exactly where the overlay enters (``claimed``) and nowhere
       else.  The second output is ``chained_carry``: this launch's block,
       then the carried ones shifted by one.  With no live block the packed
       output is bit for bit what it is without the operand.
+    * **Across shards.**  A shard holds ``n_local`` rows of the node axis
+      (every (N, ...) operand is its slice; ``delta_rows``, the overlay,
+      the carry and the output name rows globally, and ``local_rows`` says
+      which a shard holds) and ``b_local`` of the launch's lanes.  It
+      scores its own lanes on its own rows with no communication; what
+      crosses a shard is a scalar or a lane-sized vector, never a score
+      vector, each under a scope of its own:
+
+      - ``gather``: asks, in-flight deltas, step counts, the overlay and
+        the carried blocks of ALL lanes, once a launch (the walk and the
+        verify visit every lane on every shard, each against its slice of
+        the claims; so the trip counts are taken over the whole batch, one
+        number on every shard), and every lane's unresolved pick, once a
+        step;
+      - ``elect``: a pick (``elect``): the best score, then the lowest row
+        that holds it.  A lane's own pick is elected over the node shards;
+        a turn of the walk over the batch shards too, which is how every
+        shard learns every lane's winner for the verify;
+      - ``count``: the three node counts, summed;
+      - ``broadcast``: what the output and the spread stage need of the
+        picked node (its two scores, its eviction count, its attribute
+        values), from the one shard that holds it;
+      - ``rules_exchange`` (``Features.dp_width`` > 0): the picked node's
+        property values riding the spread stage's broadcast, and "a node
+        the limit alone excluded scored higher", one more maximum a step.
+
+      Beside those: "no feasible node fits without an eviction" is said of
+      the cluster (``rank_nodes``), and each row's owner alone decides its
+      verify verdict.  Every shard writes its own lanes' part of the claims
+      block: the carry costs no collective.  The five scopes are the mesh's
+      (``topo.exchange``): the one-device program has none of them.
 
     Returns (B, n_placements, FUSED_PACKED_WIDTH) f32 — one fetch; with a
     ``chain``, that and the carry for the next launch (never fetched).
     """
-    live = lane_steps > 0  # (B,)
-    trip, last_lane = fused_trip_counts(lane_steps, n_placements)
-    lanes = lane_steps.shape[0]
-    # Shared usage as the claims and the verify see it; the scores do not.
-    claimed = overlay_usage(used, overlay, chain)
-
-    def lane_used0(drows, dvals):
-        add = jnp.where((drows >= 0)[:, None], dvals, 0.0)
-        return used.at[jnp.maximum(drows, 0)].add(add)
-
-    def score(inv, carry, pen, req):
-        req_step, res, counts = _score_step(
-            arrays, inv, req, carry, pen, features
-        )
-        with jax.named_scope("pick"):
-            own = jnp.argmax(res.final).astype(jnp.int32)
-        return req_step, res, counts, own, res.final[own] > NEG_INF / 2
-
-    def commit(inv, carry, req_step, res, counts, row, active):
-        counts = tuple(jnp.where(active, c, 0) for c in counts)
-        return _commit_step(
-            arrays, inv, req_step, carry, res, counts, row, row >= 0,
-            features.dp_width,
-        )
+    n_local = used.shape[0]
+    b_local = lane_steps.shape[0]
+    row_offset, b_first = topo.shard(n_local, b_local)
+    live = lane_steps > 0  # (b_local,)
+    # What every shard needs of every lane, gathered once (the winners
+    # themselves reach every shard through the resolution's elections).
+    with jax.named_scope("verify_scan"), topo.exchange("gather"):
+        g_steps = topo.all_lanes(lane_steps)  # (B,)
+        g_ask = topo.all_lanes(reqs.ask)  # (B, 3)
+        g_drows = topo.all_lanes(delta_rows)  # (B, K)
+        g_dvals = topo.all_lanes(delta_vals)
+        if overlay is not None:
+            g_orows, g_ovals = (topo.all_lanes(o) for o in overlay)
+        if chain is not None:
+            carry, flags, claim_vals = chain
+            g_carry = topo.all_lanes(carry, axis=1)
+            g_flags = topo.all_lanes(flags)
+    g_live = g_steps > 0  # (B,)
+    lanes = g_steps.shape[0]
+    trip, last_lane = fused_trip_counts(g_steps, n_placements)
 
     def step(state, i):
         carry, claims = state
-        req_step, res, counts, own, own_ok = jax.vmap(score)(
-            invs, carry, penalties, reqs
-        )
+        req_step, res, counts, own = jax.vmap(
+            lambda inv, carry, pen, req: _score_step(
+                topo, arrays, inv, req, carry, pen, features, row_offset
+            )
+        )(invs, carry, penalties, reqs)
         # A lane that asked for fewer steps than the launch runs takes no
         # placement here: no usage charged, inert row.
-        active = i < lane_steps  # (B,)
-
-        def take(b, picked):
-            claims, rows = picked
-            ok = own_ok[b] & active[b]
-            ask = reqs.ask[b]
-            room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
-            # A node this lane may only take by preempting is not masked
-            # for want of room under the claims (eviction frees it at
-            # apply time), but by a claim that already over-fills it: an
-            # earlier lane of this launch took it by preempting, the host
-            # would choose the same victims for both, and the applier
-            # would reject the second.
-            unclaimed = jnp.all(claims <= arrays.totals, axis=1)
-            row = resolved_pick(
-                res.final[b], room | (res.needs_preempt[b] & unclaimed),
-                own[b],
-            )
-            row = jnp.where(ok, row, -1)
-            return (
-                claims.at[jnp.maximum(row, 0)].add(jnp.where(ok, ask, 0.0)),
-                lax.dynamic_update_index_in_dim(rows, row, b, 0),
-            )
-
+        active = i < lane_steps  # (b_local,)
+        own = jnp.where(active, own, -1)
+        counts = tuple(jnp.where(active, c, 0) for c in counts)
         with jax.named_scope("pick"), jax.named_scope("resolve"):
-            claims, rows = lax.fori_loop(
+            # Every lane's unresolved pick on every shard: the walk's
+            # fallback, and what says whether the lane places at all.
+            with topo.exchange("gather"):
+                g_own = topo.all_lanes(own)  # (B,)
+
+            def take(b, picked):
+                claims, rows = picked
+                # Whether this lane is mine, and which of mine it is.
+                holds, bl = local_rows(b, b_first, b_local)
+                ask = g_ask[b]
+                room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
+                # A node taken only by preempting is masked by a claim that
+                # already over-fills it, not for want of room (docstring).
+                unclaimed = jnp.all(claims <= arrays.totals, axis=1)
+                best, row = elect(topo, jnp.where(
+                    (room | (res.needs_preempt[bl] & unclaimed)) & holds,
+                    res.final[bl], NEG_INF,
+                ), row_offset, lanes=True)
+                # Its unresolved pick where no feasible row has room (never
+                # an empty slot: the re-verify then reads 0.0 and the
+                # applier decides).
+                row = jnp.where(best > NEG_INF / 2, row, g_own[b])
+                row = jnp.where(g_own[b] >= 0, row, -1)
+                mine, safe = local_rows(row, row_offset, n_local)
+                return (
+                    claims.at[safe].add(jnp.where(mine, ask, 0.0)),
+                    lax.dynamic_update_index_in_dim(rows, row, b, 0),
+                )
+
+            claims, g_rows = lax.fori_loop(
                 0, last_lane, take,
-                (claims, jnp.full((lanes,), -1, jnp.int32)),
+                (claims, topo.vary(jnp.full((lanes,), -1, jnp.int32))),
             )
-        carry, out = jax.vmap(commit)(
-            invs, carry, req_step, res, counts, rows, active
-        )
+        rows = lax.dynamic_slice_in_dim(g_rows, b_first, b_local)
+        carry, out = jax.vmap(
+            lambda inv, carry, req_step, res, counts, row: _commit_step(
+                topo, arrays, inv, carry, req_step, res, counts, row,
+                features, row_offset,
+            )
+        )(invs, carry, req_step, res, counts, rows)
         return (carry, claims), (
-            out[:7] + ((rows >= 0) & (rows != own),) + out[7:]
+            (g_rows,) + out[1:7] + ((rows >= 0) & (rows != own),) + out[7:]
         )
 
-    # What the steps of a lane all share, once a launch (PR 47).
+    # What the steps of a lane all share, once a launch (PR 47), for this
+    # shard's rows and the live lanes among its own.
     invs = launch_invariants(
-        arrays, reqs, class_eligs, host_masks, features, last_lane
+        topo, arrays, reqs, class_eligs, host_masks, features,
+        jnp.clip(last_lane - b_first, 0, b_local),
     )
     init = jax.vmap(
         lambda inv, req, drows, dvals, tg, sc: scan_carry(
-            inv, req, lane_used0(drows, dvals), tg, sc, features
+            inv, req, add_claims(used, drows, dvals, row_offset), tg, sc,
+            features,
         )
     )(invs, reqs, delta_rows, delta_vals, tg_counts, spread_counts)
+    # Shared usage as the claims and the verify see it; the scores do not.
+    claimed = topo.vary(used)
+    with jax.named_scope("overlay"):
+        if overlay is not None:
+            claimed = add_claims(claimed, g_orows, g_ovals, row_offset)
+        if chain is not None:
+            claimed = add_claims(claimed, *carried_claims(
+                g_carry, chain_flags(g_flags, carry.shape[0])[1]
+            ), row_offset)
+    # What is claimed before any lane places: every live lane's in-flight
+    # deltas on top (with one live lane and nothing in flight, bit for bit
+    # that lane's own proposed usage).
+    claims0 = add_claims(
+        claimed, jnp.where(g_live[:, None], g_drows, -1), g_dvals, row_offset
+    )
+    bufs = inert_lane_outputs(
+        b_local, lanes, n_placements, features.preempt, bool(features.dp_width)
+    )
     with jax.named_scope("place_scan"):
         _, outs = scan_steps(
-            step, (init, claims_image(claimed, delta_rows, delta_vals, live)),
-            inert_lane_outputs(
-                lanes, n_placements, features.preempt, bool(features.dp_width)
-            ),
-            trip,
+            step, (init, claims0), tuple(topo.vary(o) for o in bufs), trip
         )
-    rows, scores, binpack, preempted, n_eval, n_filt, n_exh, repicked = (
+    g_rows, scores, binpack, preempted, n_eval, n_filt, n_exh, repicked = (
         o.T for o in outs[:8]
-    )  # each (B, P)
+    )  # each (b_local, P); g_rows (B, P): every lane's rows on every shard
+    rows = lax.dynamic_slice_in_dim(g_rows, b_first, b_local)
     dp_moved = outs[8].T if features.dp_width else None
 
-    # Sequential cross-lane AllocsFit: a loop over lanes carrying the
-    # cumulative proposed usage. Each lane first applies its own in-flight
-    # deltas, then commits its placements one by one, checking
-    # used ≤ totals on every touched row (funcs.go:97-160 AllocsFit, in
-    # plan-apply order). Work per lane is O(trip) row updates on an (N, 3)
-    # carry; lanes past the last live one and slots past the launch's
-    # largest count are never visited and read "fits".
+    # Sequential cross-lane AllocsFit: a loop over ALL lanes, in resolve
+    # order, carrying the cumulative proposed usage of this shard's rows.
+    # Each lane first applies its own in-flight deltas, then commits its
+    # placements one by one, checking used <= totals on every touched row
+    # (funcs.go:97-160 AllocsFit, in plan-apply order); a row this shard
+    # does not hold fits vacuously, its owner decides (``topo.all``).  Lanes
+    # past the last live one and slots past the launch's largest count are
+    # never visited and read "fits".
     def lane_step(b, state):
-        cum_used, verified = state
-        l_drows, l_live = delta_rows[b], live[b]
-        l_rows, l_ask = rows[b], reqs.ask[b]
-        dadd = jnp.where(
-            ((l_drows >= 0) & l_live)[:, None], delta_vals[b], 0.0
+        cum_used, fits_all = state
+        l_rows, l_ask, l_live = g_rows[b], g_ask[b], g_live[b]
+        base = add_claims(
+            cum_used, jnp.where(l_live, g_drows[b], -1), g_dvals[b], row_offset
         )
-        base = cum_used.at[jnp.maximum(l_drows, 0)].add(dadd)
 
         def p_step(u, p):
-            row = l_rows[p]
-            ok_row = (row >= 0) & l_live
-            safe_r = jnp.maximum(row, 0)
-            u2 = u.at[safe_r].add(jnp.where(ok_row, l_ask, 0.0))
-            fit = jnp.all(u2[safe_r] <= arrays.totals[safe_r]) | ~ok_row
+            mine, safe = local_rows(l_rows[p], row_offset, n_local)
+            mine &= l_live
+            u2 = u.at[safe].add(jnp.where(mine, l_ask, 0.0))
+            fit = jnp.all(u2[safe] <= arrays.totals[safe]) | ~mine
             return u2, (fit,)
 
-        after, (fits,) = scan_steps(p_step, base, (verified[b],), trip)
+        after, (fits,) = scan_steps(p_step, base, (fits_all[b],), trip)
         return (
             jnp.where(l_live, after, cum_used),
-            lax.dynamic_update_index_in_dim(verified, fits, b, 0),
+            lax.dynamic_update_index_in_dim(fits_all, fits, b, 0),
         )
 
     with jax.named_scope("verify_scan"):
-        _, verified = lax.fori_loop(
-            0, last_lane, lane_step, (claimed, jnp.ones(rows.shape, bool))
+        _, fits_all = lax.fori_loop(
+            0, last_lane, lane_step,
+            (claimed, topo.vary(jnp.ones(g_rows.shape, bool), nodes=True)),
         )  # (B, P) bool
-
+        verified = topo.all(fits_all)
     packed = pack_fused_lanes(
-        rows, scores, binpack, preempted, n_eval, n_filt, n_exh, verified,
+        rows, scores, binpack, preempted, n_eval, n_filt, n_exh,
+        lax.dynamic_slice_in_dim(verified, b_first, b_local),
         repicked, live, dp_moved,
     )
     if chain is None:
         return packed
-    carry, flags, claim_vals = chain
     own = claims_block(
         delta_rows, claim_vals, rows, preempted, reqs.ask,
         live & chain_flags(flags, carry.shape[0])[0],
@@ -1596,9 +1725,14 @@ def _fused_place_batch_impl(
     return packed, chained_carry(own, carry)
 
 
-fused_place_batch = functools.partial(
-    jax.jit, static_argnames=("n_placements", "features")
-)(_fused_place_batch_impl)
+# The body bound to one device, under the body's own name (a profile's module
+# is ``jit_`` + that).
+_place_on_one_device = functools.partial(_fused_place_batch_impl, ONE_DEVICE)
+_place_on_one_device.__name__ = _fused_place_batch_impl.__name__
+
+fused_place_batch = jax.jit(
+    _place_on_one_device, static_argnames=("n_placements", "features")
+)
 
 def unpack_rows(buf, layout):
     """The fields of ``encode.packed_rows``' ``(lanes, W)`` uint8 buffer,
@@ -1679,9 +1813,8 @@ def fused_place_batch_live(arrays, used, request_pack, lane_pack, tg_counts,
         request_pack, lane_pack, layouts, features.dp_width
     )
     return place_launch(
-        _fused_place_batch_impl, arrays, used, reqs, lane, tg_counts,
-        penalties, host_masks, carry,
-        n_placements=n_placements, features=features,
+        _place_on_one_device, arrays, used, reqs, lane, tg_counts, penalties,
+        host_masks, carry, n_placements=n_placements, features=features,
     )
 
 

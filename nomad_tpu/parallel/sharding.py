@@ -1,4 +1,14 @@
-"""The batched placement program over a ``jax.sharding.Mesh``.
+"""The mesh the batched placement program runs over, and the mesh's side
+of the seam it is written against.
+
+The program itself is ``ops/kernels.py::_fused_place_batch_impl``: one
+placement step, written once against ``kernels.Topology``.  This module
+holds what is a mesh's alone: the layout of the devices (``mesh_layout``,
+``make_mesh``), the split of the operands over it (the partition specs,
+``shard_matrix_arrays``, ``make_sharded_row_scatter``, ``shard_carry``),
+the mesh's instance of the seam (``MESH``: every collective of the program)
+and the ``shard_map`` that runs the body on every shard (``_shard_mapped``,
+behind the two jitted entries).
 
 Mesh axes and their roles (the sharding design the scaling-book recipe
 produces for this workload):
@@ -7,21 +17,14 @@ produces for this workload):
   dims in an ML model. Every (N, ...) array in ``DeviceArrays`` plus the
   usage matrix splits along it. Feasibility/scoring is row-parallel, so each
   shard scores its own nodes with zero communication; only the final
-  *argmax* crosses shards (a ``pmax``/``pmin`` pair over ICI — the analog
-  of a ring-attention score reduction).
+  *argmax* crosses shards (``kernels.elect``: a ``pmax``/``pmin`` pair over
+  ICI — the analog of a ring-attention score reduction).
 - ``batch`` — independent evaluations, sharded like data-parallel batches.
   Each batch shard scores its own lanes; the in-launch pick resolution
   and the cross-lane verify replay ALL lanes in resolve order on every
   replica: asks, in-flight deltas and step counts are ``all_gather``ed over
   the batch axis once a launch, and each lane's winner reaches every shard
   through the election of its turn (a ``pmax`` / ``pmin`` over both axes).
-
-Reference behaviors preserved: the step scores all nodes per eval (replacing
-stack.go:78-91's candidate sampling), applies proposed usage like
-BinPackIterator's proposed-alloc accounting (rank.go:210-323), and leaves
-conflict resolution ACROSS launches to the serialized plan applier
-(plan_apply.go:49-69) — batched picks are optimistic by design; only the
-lanes of one launch resolve their picks among themselves.
 """
 
 from __future__ import annotations
@@ -38,38 +41,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.encode import SchedRequest
 from ..ops.kernels import (
     FULL_FEATURES,
-    NEG_INF,
-    apply_spread_values,
-    carried_claims,
-    chain_flags,
-    chained_carry,
-    claims_block,
-    distinct_property_pick,
-    distinct_property_values_at,
-    fused_trip_counts,
-    inert_lane_outputs,
-    launch_invariants,
-    pack_fused_lanes,
+    Topology,
+    _fused_place_batch_impl,
     place_launch,
-    rank_nodes,
-    scan_carry,
-    scan_steps,
-    spread_values_at,
     unpack_launch,
 )
 from ..state.matrix import DeviceArrays, scatter_packed
-
-# Hierarchical top-k width: each node shard contributes its k best rows to
-# the (shards, k) candidate table.  Any k >= 1 preserves exact argmax parity
-# (the global winner is always some shard's per-shard maximum, and
-# jax.lax.top_k is stable so the lowest-index occurrence of that maximum is
-# always in the table); PARITY.md "Hierarchical top-k" documents the
-# tie-break proof.  k = 1 is the fast path: XLA lowers top_k with k > 1
-# inside the shard_map scan to a full sort of the (n_local,) scores —
-# measured 2x end-to-end on the 100K-node sweep — while k = 1 stays the
-# single-pass max+argmax.  Widen only for a future multi-winner selection
-# that actually consumes the extra rows.
-TOPK_K = 1
 
 
 def mesh_layout(n_devices: int, node_capacity: int) -> Tuple[int, int]:
@@ -220,366 +197,57 @@ def make_sharded_row_scatter(mesh: Mesh):
 
 
 # ---------------------------------------------------------------------------
-# Sharded fused megakernel (hierarchical top-k + sharded AllocsFit verify):
-# the live multi-chip path
+# The placement program on the mesh: the live multi-chip path
 # ---------------------------------------------------------------------------
 
 
-def _fused_place_batch_local(
-    arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
-    penalties, reqs, class_eligs, host_masks, lane_steps, overlay, chain,
-    n_placements, features,
-):
-    """Per-shard body of ``kernels.fused_place_batch`` under a
-    ('batch', 'node') mesh — the full megakernel (ranking scan with the
-    lanes' picks resolved in lane order inside every step + cross-lane
-    AllocsFit re-verify) with the node axis partitioned.
+class _MeshTopology(Topology):
+    """``kernels.Topology`` for a shard of a ('batch', 'node') mesh, inside
+    ``shard_map``: every collective the placement program holds, and the
+    scope a profile files each exchange under."""
 
-    Ranking is a hierarchical top-k: each shard scores only its local node
-    slice and contributes its ``k = min(TOPK_K, n_local)`` best rows via one
-    ``all_gather`` over ICI, producing a tiny (shards, k) candidate table
-    replicated on every shard.  The global winner is the table's max score,
-    ties broken to the LOWEST global row — ``jax.lax.top_k`` is stable
-    (lower index first on ties), so the per-shard maximum's lowest local
-    occurrence is always in the table and the min-over-ties selection
-    reproduces the single-device ``jnp.argmax`` bit-for-bit (PARITY.md
-    "Hierarchical top-k").  No (B, N) score tensor ever exists globally:
-    per-shard intermediates are (n_local,) and everything crossing the
-    interconnect or reaching the host is O(B · P) or (shards, k).
+    def shard(self, n_local, b_local):
+        return (
+            jax.lax.axis_index("node") * n_local,
+            jax.lax.axis_index("batch") * b_local,
+        )
 
-    The resolution walks ALL B lanes in lane order on every shard, against
-    this shard's slice of the launch's claims image (the in-flight overlay
-    under it, as on one device; every batch replica
-    holds the same slice and makes the same updates).  A lane's scores
-    live on one batch shard, so each turn elects the lane's best row with
-    room across the whole mesh (a ``pmax`` and a ``pmin`` over both axes;
-    the other batch shards offer nothing) — the winners every shard needs
-    for the verify are thereby already everywhere, and only asks, deltas
-    and step counts are gathered over 'batch', once, up front.
-
-    The cross-lane verify scans all B lanes against the LOCAL (n_local, 3)
-    usage slice with non-owned rows vacuously fitting, and combines
-    verdicts with a single ``pmin`` over the node axis — each row's owner
-    alone decides.
-
-    The distinct_property stage (``Features.dp_width`` > 0) carries its
-    counts per node on each shard's slice; the picked node's property
-    values ride the spread stage's broadcast, and "a node the limit alone
-    excluded scored higher" is one more ``pmax`` over 'node' a step: what
-    the stage adds across shards is under the scope ``rules_exchange``
-    (inside ``update`` and ``update/broadcast``, where those ops were).
-
-    The in-flight claims overlay (``overlay``: global rows, split over
-    'batch' like the deltas; None = empty) is gathered with them and each
-    node shard adds the rows it holds to its slice of the usage under the
-    claims image and the verify pass, as ``kernels.overlay_usage`` does on
-    one device: no score reads it.
-
-    The claims chained on the device (``chain`` = (carry, flags,
-    claim_vals), ``kernels._fused_place_batch_impl``; None = the program
-    without it) are split over 'batch' like the lanes they are of: the
-    carried blocks are gathered with the overlay and each node shard adds
-    the live ones' rows it holds, exactly where the overlay enters; every
-    batch shard writes its own lanes' part of this launch's block
-    (``claims_block``: the winners are on every shard), so the carry
-    leaves as it came, one buffer split over 'batch', and no collective is
-    spent on it.
-
-    Both loops run as many iterations as the launch's live lanes asked for
-    (``lane_steps``, as in the single-device kernel).  Every step holds
-    collectives and the walk and the verify visit all B lanes on every
-    shard, so the trip counts are taken over the WHOLE batch: one number
-    on every shard.
-    """
-    n_local = used.shape[0]
-    shard = jax.lax.axis_index("node")
-    row_offset = shard * n_local
-    big = jnp.int32(2 ** 30)
-    k = min(TOPK_K, n_local)
-    live = lane_steps > 0  # (b_local,)
-    b_local = lane_steps.shape[0]
-    b_first = jax.lax.axis_index("batch") * b_local
-    # What every shard needs of every lane, gathered once (the winners
-    # themselves reach every shard through the resolution's elections).
-    with jax.named_scope("verify_scan"), jax.named_scope("gather"):
-        g_steps = jax.lax.all_gather(lane_steps, "batch", tiled=True)  # (B,)
-        g_ask = jax.lax.all_gather(reqs.ask, "batch", tiled=True)  # (B, 3)
-        g_drows = jax.lax.all_gather(delta_rows, "batch", tiled=True)  # (B, K)
-        g_dvals = jax.lax.all_gather(delta_vals, "batch", tiled=True)
-        if overlay is not None:
-            g_orows, g_ovals = (
-                jax.lax.all_gather(o, "batch", tiled=True) for o in overlay
-            )
-        if chain is not None:
-            carry, flags, claim_vals = chain
-            g_carry = jax.lax.all_gather(carry, "batch", axis=1, tiled=True)
-            g_flags = jax.lax.all_gather(flags, "batch", tiled=True)
-    g_live = g_steps > 0  # (B,)
-    lanes = g_steps.shape[0]
-    trip, last_lane = fused_trip_counts(g_steps, n_placements)
-
-    def vary(x, axes=("batch",)):
+    def vary(self, x, nodes=False):
         # shard_map's varying-axes check wants a loop carry typed the same
-        # going in as coming out: buffers that start as constants (or as
-        # this shard's slice) and take per-lane values are cast to vary
-        # over those axes up front — a typing formality.
+        # going in as coming out: a typing formality, no data moves.
+        axes = ("batch", "node") if nodes else ("batch",)
         return jax.lax.pcast(x, axes, to="varying")
 
-    def local_rows(rows):
-        """(global rows (...,)) -> (owned by this shard, local index)."""
-        local = rows - row_offset
-        return (rows >= 0) & (local >= 0) & (local < n_local), jnp.clip(
-            local, 0, n_local - 1
-        )
+    def all_lanes(self, x, axis=0):
+        return jax.lax.all_gather(x, "batch", axis=axis, tiled=True)
 
-    def add_deltas(image, drows, dvals, valid):
-        mine, safe = local_rows(drows)
-        return image.at[safe.reshape(-1)].add(
-            jnp.where((mine & valid)[..., None], dvals, 0.0).reshape(-1, 3)
-        )
+    def max(self, x, lanes=False):
+        return jax.lax.pmax(x, ("batch", "node") if lanes else "node")
 
-    def score(inv, carry, pen, req):
-        u, tg_cnt, s_hash, s_counts, dp_cnt = carry
-        req_step = req._replace(s_value_hash=s_hash)
-        with jax.named_scope("score"):
-            res = rank_nodes(
-                arrays, inv, u, tg_cnt, s_counts, pen, req_step,
-                features=features, node_axis="node", dp_cnt=dp_cnt,
-            )
-        # Hierarchical top-k: (n_local,) -> per-shard (k,) candidates,
-        # then a cross-shard reduce of the implicit (shards, k) table —
-        # pmax elects the winning score, pmin the lowest owning row.
-        with jax.named_scope("pick"):
-            vals, idxs = jax.lax.top_k(res.final, k)
-            with jax.named_scope("elect"):
-                best = jax.lax.pmax(vals[0], "node")
-            cand = jnp.where(
-                vals == best, row_offset + idxs.astype(jnp.int32), big
-            )
-            # lowest row on ties
-            with jax.named_scope("elect"):
-                own = jax.lax.pmin(jnp.min(cand), "node")
-            own = jnp.where(best > NEG_INF / 2, own, -1)
+    def min(self, x, lanes=False):
+        return jax.lax.pmin(x, ("batch", "node") if lanes else "node")
 
-            n_feasible = jnp.sum(res.feasible.astype(jnp.int32))
-            n_filtered = jnp.sum(
-                (~res.feasible & arrays.eligible).astype(jnp.int32)
-            )
-            n_exhausted = jnp.sum(
-                (res.feasible & ~res.fits).astype(jnp.int32)
-            )
-            with jax.named_scope("count"):
-                counts = (
-                    jax.lax.psum(n_feasible, "node"),
-                    jax.lax.psum(n_filtered, "node"),
-                    jax.lax.psum(n_exhausted, "node"),
-                )
-        return req_step, res, counts, own
+    def sum(self, x):
+        return jax.lax.psum(x, "node")
 
-    def commit(inv, carry, req_step, res, counts, grow, active):
-        u, tg_cnt, s_hash, s_counts, dp_cnt = carry
-        ok = grow >= 0
-        owner, lwin = local_rows(grow)
-        with jax.named_scope("update"):
-            u2 = jnp.where(owner, u.at[lwin].add(req_step.ask), u)
-            tg2 = jnp.where(owner, tg_cnt.at[lwin].add(1), tg_cnt)
+    def any(self, flag):
+        return jax.lax.pmax(flag.astype(jnp.int32), "node") > 0
 
-            # The picked node's spread and property values, from its owner.
-            nvals = spread_values_at(arrays, req_step, lwin)
-            if features.dp_width:
-                with jax.named_scope("rules_exchange"):
-                    nvals = jnp.concatenate([
-                        nvals,
-                        distinct_property_values_at(arrays, req_step, lwin),
-                    ])
-            nvals = jnp.where(owner, nvals, 0)
-            with jax.named_scope("broadcast"):
-                nvals = jax.lax.psum(nvals, "node")
-            n_spreads = req_step.s_slot.shape[0]
-            new_hash, new_counts = apply_spread_values(
-                s_counts, req_step, nvals[:n_spreads]
-            )
-            s_hash2 = jnp.where(ok, new_hash, s_hash)
-            s_counts2 = jnp.where(ok, new_counts, s_counts)
-            if features.dp_width:
-                dp_cnt = jnp.where(ok, distinct_property_pick(
-                    inv.dp_values, req_step, dp_cnt, nvals[n_spreads:],
-                    features.dp_width,
-                ), dp_cnt)
+    def all(self, flags):
+        return jax.lax.pmin(flags.astype(jnp.int32), "node").astype(bool)
 
-            own_score = jnp.where(
-                owner, jnp.stack([res.final[lwin], res.binpack[lwin]]), 0.0
-            )
-            if res.pre_terms is None:
-                own_pre = jnp.where(
-                    owner, res.needs_preempt[lwin], False
-                ).astype(jnp.int32)
-            else:  # the count of the mean's terms, not a flag
-                own_pre = jnp.where(
-                    owner & res.needs_preempt[lwin], res.pre_terms[lwin], 0.0
-                )
-            with jax.named_scope("broadcast"):
-                final, binp = jax.lax.psum(own_score, "node")
-                pre = jax.lax.pmax(own_pre, "node")
-            if res.pre_terms is None:
-                pre = pre.astype(bool)
-        out = (
-            grow, final, binp, pre,
-        ) + tuple(jnp.where(active, c, 0) for c in counts)
-        if features.dp_width:
-            with jax.named_scope("broadcast"), jax.named_scope(
-                "rules_exchange"
-            ):
-                blocked = jax.lax.pmax(res.dp_blocked_best, "node")
-            out += (ok & (blocked > final),)
-        return (u2, tg2, s_hash2, s_counts2, dp_cnt), out
+    def exchange(self, name):
+        return jax.named_scope(name)
 
-    def step(state, i):
-        carry, claims = state
-        req_step, res, counts, own = jax.vmap(score)(
-            invs, carry, penalties, reqs
-        )
-        active = i < lane_steps  # (b_local,)
-        own = jnp.where(active, own, -1)
-        with jax.named_scope("pick"), jax.named_scope("resolve"):
-            # Every lane's unresolved pick on every shard: the walk's
-            # fallback, and what says whether the lane places at all.
-            with jax.named_scope("gather"):
-                g_own = jax.lax.all_gather(own, "batch", tiled=True)  # (B,)
 
-            def take(b, picked):
-                claims, rows = picked
-                bl = b - b_first  # this lane among mine, if it is mine
-                holds = (bl >= 0) & (bl < b_local)
-                bl = jnp.clip(bl, 0, b_local - 1)
-                ok = g_own[b] >= 0
-                ask = g_ask[b]
-                room = jnp.all(claims + ask[None, :] <= arrays.totals, axis=1)
-                unclaimed = jnp.all(claims <= arrays.totals, axis=1)
-                masked = jnp.where(
-                    (room | (res.needs_preempt[bl] & unclaimed)) & holds,
-                    res.final[bl], NEG_INF,
-                )
-                idx = jnp.argmax(masked).astype(jnp.int32)
-                with jax.named_scope("elect"):
-                    best = jax.lax.pmax(masked[idx], ("batch", "node"))
-                cand = jnp.where(
-                    (masked[idx] == best) & holds, row_offset + idx, big
-                )
-                with jax.named_scope("elect"):
-                    alt = jax.lax.pmin(cand, ("batch", "node"))
-                row = jnp.where(best > NEG_INF / 2, alt, g_own[b])
-                row = jnp.where(ok, row, -1)
-                mine, safe = local_rows(row)
-                return (
-                    claims.at[safe].add(jnp.where(mine, ask, 0.0)),
-                    jax.lax.dynamic_update_index_in_dim(rows, row, b, 0),
-                )
-
-            claims, g_rows = jax.lax.fori_loop(
-                0, last_lane, take,
-                (claims, vary(jnp.full((lanes,), -1, jnp.int32))),
-            )
-        rows = jax.lax.dynamic_slice_in_dim(g_rows, b_first, b_local)
-        carry, out = jax.vmap(commit)(
-            invs, carry, req_step, res, counts, rows, active
-        )
-        return (carry, claims), (
-            out[:7] + ((rows >= 0) & (rows != own), g_rows) + out[7:]
-        )
-
-    # What the steps of a lane all share, once a launch, for this shard's
-    # rows and the live lanes among its own.
-    invs = launch_invariants(
-        arrays, reqs, class_eligs, host_masks, features,
-        jnp.clip(last_lane - b_first, 0, b_local),
-        lambda x: vary(x, ("batch", "node")),
-    )
-    init = jax.vmap(
-        lambda inv, req, drows, dvals, tg, sc: scan_carry(
-            inv, req, add_deltas(used, drows, dvals, drows >= 0), tg, sc,
-            features,
-        )
-    )(invs, reqs, delta_rows, delta_vals, tg_counts, spread_counts)
-    # Shared usage as the claims and the verify see it; the scores do not.
-    claimed = vary(used)
-    with jax.named_scope("overlay"):
-        if overlay is not None:
-            claimed = add_deltas(claimed, g_orows, g_ovals, g_orows >= 0)
-        if chain is not None:
-            c_rows, c_vals = carried_claims(
-                g_carry, chain_flags(g_flags, carry.shape[0])[1]
-            )
-            claimed = add_deltas(claimed, c_rows, c_vals, c_rows >= 0)
-    claims0 = add_deltas(claimed, g_drows, g_dvals, g_live[:, None])
-    inert = inert_lane_outputs(
-        b_local, n_placements, features.preempt, bool(features.dp_width)
-    )
-    bufs = tuple(
-        vary(o)
-        for o in inert[:8]
-        + (jnp.full((n_placements, lanes), -1, jnp.int32),) + inert[8:]
-    )
-    with jax.named_scope("place_scan"):
-        _, outs = scan_steps(step, (init, claims0), bufs, trip)
-    rows, scores, binpack, pre, ne, nf, nx, repicked, g_rows = (
-        o.T for o in outs[:9]
-    )  # each (b_local, P); g_rows (B, P): every lane's rows on every shard
-    dp_moved = outs[9].T if features.dp_width else None
-
-    # Cross-lane AllocsFit re-verify, sharded: each node shard replays all
-    # B lanes in resolve order against its local (n_local, 3) usage slice;
-    # rows it does not own fit vacuously, and one pmin over 'node' lets
-    # each row's owner veto.
-    def lane_step(b, state):
-        cum_used, fits_all = state
-        l_rows, l_ask, l_live = g_rows[b], g_ask[b], g_live[b]
-        base = add_deltas(cum_used, g_drows[b], g_dvals[b], l_live)
-
-        def p_step(u, p):
-            p_mine, p_safe = local_rows(l_rows[p])
-            p_mine &= l_live
-            u2 = u.at[p_safe].add(jnp.where(p_mine, l_ask, 0.0))
-            fit = jnp.all(u2[p_safe] <= arrays.totals[p_safe]) | ~p_mine
-            return u2, (fit,)
-
-        after, (fits,) = scan_steps(p_step, base, (fits_all[b],), trip)
-        return (
-            jnp.where(l_live, after, cum_used),
-            jax.lax.dynamic_update_index_in_dim(fits_all, fits, b, 0),
-        )
-
-    # The carry starts as this shard's usage slice (varying over 'node'
-    # only) but accumulates lane data gathered over 'batch' (every batch
-    # replica holds the same values).  Lanes past the last live one and
-    # slots past the largest count are never visited and read "fits".
-    with jax.named_scope("verify_scan"):
-        _, fits_all = jax.lax.fori_loop(
-            0, last_lane, lane_step,
-            (claimed, vary(jnp.ones(g_rows.shape, bool), ("batch", "node"))),
-        )  # (B, P) bool, identical on every node shard only after the pmin:
-        verified = jax.lax.pmin(fits_all.astype(jnp.int32), "node")  # (B, P)
-
-    v_local = jax.lax.dynamic_slice_in_dim(
-        verified, b_first, b_local, axis=0
-    )  # (b_local, P)
-    packed = pack_fused_lanes(
-        rows, scores, binpack, pre, ne, nf, nx, v_local, repicked, live,
-        dp_moved,
-    )
-    if chain is None:
-        return packed
-    own = claims_block(
-        delta_rows, claim_vals, rows, pre, reqs.ask,
-        live & chain_flags(flags, carry.shape[0])[0],
-    )
-    return packed, chained_carry(own, carry)
+MESH = _MeshTopology()
 
 
 def _shard_mapped(mesh: Mesh, n_placements: int):
-    """``_fused_place_batch_local`` over ``mesh``, ``fused_place_batch``'s
-    signature: the body of both jitted entries below (each a function
-    ``entry``: module ``jit_entry`` in a profile)."""
+    """The placement program's one body (``kernels._fused_place_batch_impl``)
+    on every shard of ``mesh``, bound to the mesh's side of the seam;
+    ``fused_place_batch``'s signature: the body of both jitted entries
+    below (each a function ``entry``: module ``jit_entry`` in a profile)."""
 
     def entry(
         arrays, used, delta_rows, delta_vals, tg_counts, spread_counts,
@@ -589,7 +257,8 @@ def _shard_mapped(mesh: Mesh, n_placements: int):
         lanes = P("batch", None, None)
         fn = shard_map(
             functools.partial(
-                _fused_place_batch_local,
+                _fused_place_batch_impl,
+                MESH,
                 n_placements=n_placements,
                 features=features,
             ),
@@ -628,15 +297,11 @@ def _shard_mapped(mesh: Mesh, n_placements: int):
 
 
 def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
-    """Build the jitted SPMD twin of ``kernels.fused_place_batch``.
-
-    Same signature (``features`` keyword-static) and packed
-    (B, P, FUSED_PACKED_WIDTH) result as the single-device fused kernel:
-    every operand its own, as tests, the smoke and the tools hand them over
-    (a launch of the server goes through ``sharded_fused_place_batch_live``).
-    Placement AND verify-column parity with the unsharded kernel is exact
-    (tie-breaks included) — tests/test_parallel.py asserts it across shard
-    counts.
+    """Build ``kernels.fused_place_batch`` for ``mesh``: same signature
+    (``features`` keyword-static), same packed (B, P, FUSED_PACKED_WIDTH)
+    result bit for bit (tests/test_parallel.py, across shard counts), every
+    operand its own, as tests, the smoke and the tools hand them over (a
+    launch of the server goes through ``sharded_fused_place_batch_live``).
     """
     return jax.jit(
         _shard_mapped(mesh, n_placements), static_argnames=("features",)
@@ -644,7 +309,7 @@ def sharded_fused_place_batch(mesh: Mesh, n_placements: int):
 
 
 def sharded_fused_place_batch_live(mesh: Mesh, n_placements: int):
-    """Build the jitted SPMD twin of ``kernels.fused_place_batch_live``: what
+    """Build ``kernels.fused_place_batch_live`` for ``mesh``: what
     the dispatch coalescer launches when dispatches span a mesh
     (scheduler/coalescer.py ``_resolve_sharding``), in ONE call.  The two
     packs are held to a split over ``batch`` alone (the compiler lays the

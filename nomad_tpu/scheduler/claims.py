@@ -1,7 +1,7 @@
 """In-flight claims ledger — what launches in flight tell one another.
 
 A launch scores and resolves against the committed matrix plus the claims
-of its own lanes (``ops/kernels.py::claims_image``).  The picks of the
+of its own lanes (``claims0`` in ``ops/kernels.py``'s placement body).  The picks of the
 launch before it, whose plans are still being built, queued or applied, are
 in neither: two launches in flight named the same nodes and the applier
 refused the second plan (a third of a closed loop's plans, PERF.md section

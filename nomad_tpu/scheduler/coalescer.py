@@ -8,8 +8,9 @@ serialize behind one another, and the batched kernel would sit unused.
 This module makes the batched kernel THE live path: workers enqueue
 compiled placement requests and block on a future; a dispatch thread
 drains the queue, stacks up to ``max_lanes`` requests, and issues ONE
-``ops.kernels.fused_place_batch_live`` dispatch (its node-sharded twin
-when dispatches span a mesh) whose packed result costs ONE fetch.
+``ops.kernels.fused_place_batch_live`` dispatch (the same placement body
+over a mesh, ``sharded_fused_place_batch_live``, when dispatches span one)
+whose packed result costs ONE fetch.
 
 A dispatch thread that performed that fetch itself (``np.asarray`` blocks
 until the device is done) would keep exactly one dispatch in flight, so
@@ -217,8 +218,8 @@ class DeviceCoalescer:
         self.scan_length = scan_length or PLACEMENT_CHUNK
         self.linger_s = linger_s
         self.pipeline_depth = pipeline_depth or PIPELINE_DEPTH
-        # Multi-chip: when >1, dispatches go through the SPMD twin of the
-        # fused kernel (parallel/sharding.py sharded_fused_place_batch)
+        # Multi-chip: when >1, dispatches run the fused kernel's one body
+        # (parallel/sharding.py sharded_fused_place_batch_live)
         # over a ('batch', 'node') mesh — the live server path the dryrun
         # certifies.  None = auto: all visible devices on real
         # accelerators, single-device on CPU (the virtual 8-CPU rig is a
@@ -1221,8 +1222,8 @@ class DeviceCoalescer:
         # The jitted call: the fused megakernel covers feasibility → binpack
         # → spread/affinity → evict-set → the cross-lane AllocsFit
         # re-verify column in one launch (node-sharded: each mesh shard
-        # scores only its local node slice, the winner comes from the
-        # hierarchical top-k reduce, and the packed (B, P, 8) fetch is the
+        # scores only its local node slice, the winner comes from an
+        # election across shards, and the packed (B, P, 8) fetch is the
         # sole device→host traffic).  A launch that widened the features
         # ratchet, or whose packs have another layout than the last one's (a
         # class-count pow2 crossing, a new request-field shape), traces,

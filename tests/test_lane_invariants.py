@@ -166,8 +166,8 @@ def _step_anew(arrays, req, carry, penalty, class_elig, host_mask, features):
     inv = kernels.lane_invariants(
         arrays, req_step, class_elig, host_mask, features)
     return kernels._commit_step(
-        arrays, inv, req_step, carry, res, counts, jnp.where(ok, row, -1),
-        ok, features.dp_width)
+        kernels.ONE_DEVICE, arrays, inv, carry, req_step, res, counts,
+        jnp.where(ok, row, -1), features, 0)
 
 
 def _scan_anew(ops, lane, n_placements, features):
