@@ -206,6 +206,32 @@ def score_gaps(samples):
     return score_gap, rank_gap
 
 
+def gap_notes(samples, numbers, limits):
+    """What to print where ``score_gap`` or ``rank_gap`` is over its limit:
+    the worst sample of each (``net_check`` prints the same)."""
+    notes = []
+    if samples and not numbers["score_gap"] <= limits["score_gap"]:
+        def gap(s):
+            g = check._sample_gaps(s)[0]
+            return max(g, spread_gap(s)) if s["spread"] else g
+        worst = max(samples, key=gap)
+        notes.append("score_gap: worst sample " + str({
+            k: worst.get(k) for k in (
+                "job_id", "shape", "alloc", "row", "ask", "count",
+                "siblings", "collisions_max", "affinity", "binpack",
+                "final", "spread_final")}))
+    if samples and not numbers["rank_gap"] <= limits["rank_gap"]:
+        worst = max(samples, key=lambda s: (
+            s["floor"] - s["final"] if s["floor"] is not None
+            and s["final"] is not None and not s["spread"]
+            else float("-inf")))
+        notes.append("rank_gap: worst sample " + str({
+            k: worst.get(k) for k in (
+                "job_id", "shape", "alloc", "row", "count", "binpack",
+                "final", "floor")}))
+    return notes
+
+
 def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
     cluster, n = cfg["cluster"], cfg["nodes"]
     row_of = {check.node_id(i): i for i in range(n)}
@@ -307,24 +333,6 @@ def decide(get, cfg, traffic, records, used0, seed, dump=None, state=None):
             notes.append("overcommitted_nodes: " + "; ".join(
                 f"row {int(r)} used {used_end[r].tolist()} of {totals.tolist()}"
                 for r in over))
-        if samples and not numbers["score_gap"] <= LIMITS["score_gap"]:
-            def gap(s):
-                g = check._sample_gaps(s)[0]
-                return max(g, spread_gap(s)) if s["spread"] else g
-            worst = max(samples, key=gap)
-            notes.append("score_gap: worst sample " + str({
-                k: worst.get(k) for k in (
-                    "job_id", "shape", "alloc", "row", "ask", "count",
-                    "siblings", "collisions_max", "affinity", "binpack",
-                    "final", "spread_final")}))
-        if samples and not numbers["rank_gap"] <= LIMITS["rank_gap"]:
-            worst = max(samples, key=lambda s: (
-                s["floor"] - s["final"] if s["floor"] is not None
-                and s["final"] is not None and not s["spread"]
-                else float("-inf")))
-            notes.append("rank_gap: worst sample " + str({
-                k: worst.get(k) for k in (
-                    "job_id", "shape", "alloc", "row", "count", "binpack",
-                    "final", "floor")}))
+        notes.extend(gap_notes(samples, numbers, LIMITS))
         lines.extend(f"check: over its limit: {n}" for n in notes[:8])
     return correct, numbers, lines

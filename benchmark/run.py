@@ -64,7 +64,9 @@ HEARTBEAT_TICK_S = 0.1    # the simulated clients renew in small batches
 MAX_WARMUP_PASSES = 4
 SCATTER_BUCKETS = 11      # dirty-row scatter compiles per pow2 bucket: 1..1024
 TRACE_RING = 1 << 17      # program span records kept per thread when traced
-TRACE_SECONDS = 2.0       # the profiler traces the window's last 2 s (/ chips)
+TRACE_SECONDS = 2.0       # the profiler traces the window's last 2 s, or ...
+TRACE_COLLECTIONS = 3.0   # ... this many of the heap's longest full collection
+TRACE_LEAD_S = 0.5        # the profiler is armed this long before its slice
 
 
 def log(msg: str) -> None:
@@ -239,6 +241,19 @@ class GcPauses:
                 self.pauses.append((self._t, info["generation"], took))
 
 
+def slice_seconds(seconds, collections) -> float:
+    """How much of the window's end the device trace covers.  A full
+    collection of the server's heap stops every thread, the launching one
+    too, and a slice that lies inside one holds no launch (PR 49 was lost
+    to a 0.5 s slice under a 1.0 s collection of the 100,000-node heap).
+    So the slice outlasts the longest collection seen so far (the set-up's
+    own, and those of the window up to now; each is longer than the last)
+    three times over, on any number of chips, and launches stand on either
+    side of one that falls inside it."""
+    longest = max(collections, default=0.0)
+    return min(seconds / 2, max(TRACE_SECONDS, TRACE_COLLECTIONS * longest))
+
+
 class CompileCounter:
     """Backend compiles, from jax.monitoring."""
 
@@ -410,11 +425,16 @@ def run(args) -> dict:
             # The device trace covers the window's last seconds: a whole
             # window of a 12 ms kernel is millions of events.  It is
             # stopped after the window, so writing it out disturbs nothing.
-            length = min(max(0.5, TRACE_SECONDS / len(devices)), seconds / 2)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1
-            time.sleep(max(0.0, t0 + seconds - length - 0.5 - time.time()))
+            while True:
+                length = slice_seconds(seconds, [setup["gc_s"]] + [
+                    s for t, _g, s in gc_pauses.pauses if t >= t0])
+                wait = t0 + seconds - length - TRACE_LEAD_S - time.time()
+                if wait <= 0:
+                    break
+                time.sleep(min(wait, 0.25))
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
             traced["marker_wall"] = time.time()
             with jax.profiler.TraceAnnotation("bench.marker"):
@@ -494,14 +514,13 @@ def run(args) -> dict:
             "correct": correct, "attempted": len(attempted),
             "failed": len(failed), "metrics": {}, "device": device,
         }
-        moved = None
+        moved = reduced = None
         if not tracing:
             kind = "end_to_end"
             values = e2e
         else:
             kind = "per_layer"
             moved = {m["name"] for m in metrics_of(bench, cell, "end_to_end")}
-            reduced = None
             if platform != "cpu":
                 import trace_reduce
 
@@ -515,6 +534,9 @@ def run(args) -> dict:
                     spans=spans)
                 if reduced:
                     log(f"trace: programs {reduced['modules']}")
+                    # What kernel_ms_per_launch is a mean of.
+                    log(f"trace: launches {reduced['launches']:g} in a "
+                        f"slice of {reduced['window_s']:.2f}s")
                 lines = {}
                 for e in events:
                     lines[(e[0], e[1])] = lines.get((e[0], e[1]), 0) + 1
@@ -529,6 +551,10 @@ def run(args) -> dict:
                     "idle_gaps": reduced["idle_gaps"],
                 }
             host = srv.matrix.snapshot_host()
+            resident = sum(host[f].nbytes for f in DeviceArrays._fields)
+            bitmap = host["port_words"].nbytes
+            log(f"trace: the resident matrix is {resident} bytes, {bitmap} "
+                f"of them the port bitmap")
             ctx = {
                 "loop": reply["loop"], "attempted": attempted,
                 "client": reply, "m0": m0, "m1": m1, "spans": spans,
@@ -537,8 +563,11 @@ def run(args) -> dict:
                 "compiles_in_window": compiles_in_window,
                 "memory_peak_bytes": peak,
                 "device_kind": devices[0].device_kind,
-                "matrix_bytes": float(sum(
-                    host[f].nbytes for f in DeviceArrays._fields)),
+                # What a launch has to read of the resident matrix: every
+                # field but the port bitmap (4 KB a node), of which a launch
+                # gathers a few words a node and lane, and only where its
+                # traffic asks for ports (roofline_net.py counts those).
+                "matrix_bytes": float(resident - bitmap),
             }
             values = {}
             for m in metrics_of(bench, cell, kind, moved):
@@ -557,6 +586,8 @@ def run(args) -> dict:
         print("detail: " + json.dumps({
             "causes": causes, "numbers": numbers, "setup": setup, "e2e": e2e,
             "compiles_in_window": compiles_in_window,
+            # Launches of the placement program in the traced slice, a chip.
+            "launches": reduced["launches"] if reduced else None,
             "evals_ended": reply["evals_ended"],
             "evals_failed": reply["evals_failed"],
             "eval_failed_causes": reply["eval_failed_causes"],

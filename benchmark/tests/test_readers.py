@@ -239,7 +239,10 @@ TEN = ("launch_host_ms", "matrix_sync_ms", "coalescer_idle_share",
        "gc_pause_share", "plan_rejected_share", "plan_partial_share",
        "kernel_scan_share", "kernel_verify_share", "scan_steps_per_launch",
        "deck_used_share")
-LISTED = [(m, NEW_CELL) for m in TEN + ("setup_variant_trace_s",)]
+# PR 51: the two rules cells enter the same eleven lists.
+RULES_CELLS = ("c2m-10k-rules.rules-backlog", "c2m-100k-rules.rules-backlog-x4")
+LISTED = [(m, cell) for cell in (NEW_CELL,) + RULES_CELLS
+          for m in TEN + ("setup_variant_trace_s",)]
 
 
 def _bench():
@@ -277,13 +280,28 @@ def test_benchmark_json_lists_the_cell_where_pr_43_says():
     per_layer = {m["name"]: m for m in _bench()["per_layer"]}
     for name, cell in LISTED:
         assert cell in per_layer[name]["workloads"], (name, cell)
+    # PR 51: the four-chip rules cell also where its control and the
+    # one-chip rules cell stand; a list only ever grows at its end, and
+    # ``overlay_rows_per_launch`` keeps the four cells tier-1 pins.
+    for name in ("collective_share", "cross_shard_share",
+                 "kernel_feasibility_share", "sched_feasibility_ms",
+                 "host_walk_nodes_per_eval"):
+        assert per_layer[name]["workloads"][-1] == RULES_CELLS[1], name
+    cells = [w["name"] for w in _bench()["workloads"]]
+    assert per_layer["overlay_rows_per_launch"]["workloads"] == cells[:4]
+    for m in per_layer.values():
+        assert set(m.get("workloads", [])) <= set(cells), m["name"]
     # ... and took no cell away from any list.
     for name in TEN[:-1]:
         assert set(per_layer[name]["workloads"]) >= {
             "c2m-10k.steady", "c2m-10k.backlog", "c2m-100k.backlog-x4"}
 
 
-@pytest.mark.parametrize("name,cell", LISTED)
+# The recorded trace below holds the one-chip program (``fused_place_batch``);
+# a mesh cell's stage shares look for ``jit_entry`` (test_sharded_readers.py).
+@pytest.mark.parametrize("name,cell", [
+    (m, c) for m, c in LISTED
+    if not (c == RULES_CELLS[1] and m.startswith("kernel_"))])
 def test_list_bound_reader_reads_a_number_in_a_cell_it_now_lists(
         run, xplane, monkeypatch, name, cell):
     monkeypatch.setattr(stage_reduce, "TRACE_DIR",

@@ -66,18 +66,85 @@ def _digest(name, seed, seconds=50):
     # backlog-x4 deals backlog's mix and as many blocks (41)
     ("backlog-x4", ["4b2a222a08b12aeb", "0e35a2a754e91e60"]),
     ("tiers-backlog", ["0f01b3959f660c85", "38f9281dd1cd4ab9"]),
+    # taken from the parent tree of PR 51 (7026899), before ``job_payload``
+    # learned ``disk_mb``, ``networks`` and ``devices``
+    ("rules-backlog", ["f72d8149d8038aff", "672bb7e4abd5a69b"]),
+    ("rules-backlog-x4", ["693520a2e45eb74e", "7407092a25d21114"]),
 ])
 def test_the_files_that_were_there_give_the_operations_they_gave(name, want):
     """Digests taken from the parent tree (259c845) before ``traffic.py``
-    learned the kinds: a file without the new keys gives, bit for bit, what
-    it gave."""
+    learned the kinds (PR 43), and held again when a shape's body became
+    data (PR 51): a file without the new keys gives, bit for bit, what it
+    gave."""
     assert [_digest(name, s) for s in (7, 2 ** 31 + 4300)] == want
     t = traffic.load(name)
     assert all("kind" not in o for o in traffic.schedule(t, 7, 2))
     payload = traffic.job_payload(t, traffic.schedule(t, 7, 2)[0])
     assert list(payload) == ["id", "name", "namespace", "type", "priority",
                              "datacenters", "task_groups"]
-    assert list(payload["task_groups"][0])[:2] == ["name", "count"]
+    assert list(payload["task_groups"][0]) == [
+        "name", "count", "constraints", "affinities", "spreads", "tasks"]
+    assert list(payload["task_groups"][0]["tasks"][0]["resources"]) == [
+        "cpu", "memory_mb"]
+
+
+# -- the job body as data (PR 51) ----------------------------------------------------
+
+OP = {"namespace": "default", "width": 3, "type": "service", "priority": 50,
+      "shape": 0, "job_id": "op-000000"}
+
+
+def _body(**keys):
+    t = traffic.load("backlog")
+    plain = traffic.job_payload(t, OP)
+    t["shapes"][0] = dict(t["shapes"][0], **keys)
+    return plain, traffic.job_payload(t, OP)
+
+
+@pytest.mark.parametrize("keys,where,want", [
+    ({"disk_mb": 300}, "group", {"ephemeral_disk": {"size_mb": 300}}),
+    ({"disk_mb": 0}, "group", {"ephemeral_disk": {"size_mb": 0}}),
+    ({"networks": [{"reserved_ports": [80, 443], "dynamic_ports": ["db"]}]},
+     "group",
+     {"networks": [{"reserved_ports": [80, 443], "dynamic_ports": ["db"]}]}),
+    ({"networks": [{"dynamic_ports": ["http", "metrics"]}]}, "group",
+     {"networks": [{"reserved_ports": [],
+                    "dynamic_ports": ["http", "metrics"]}]}),
+    ({"devices": [{"name": "nvidia/gpu", "count": 2}]}, "resources",
+     {"devices": [{"name": "nvidia/gpu", "count": 2}]}),
+    ({"devices": [{"name": "nvidia/gpu"}]}, "resources",
+     {"devices": [{"name": "nvidia/gpu", "count": 1}]}),
+])
+def test_a_shape_may_carry_disk_networks_and_devices(keys, where, want):
+    """Each key lands where the server's decoder reads it (the group's
+    ``ephemeral_disk`` and ``networks``, the task's ``resources.devices``)
+    and changes nothing else of the body."""
+    plain, body = _body(**keys)
+    group = body["task_groups"][0]
+    got = group if where == "group" else group["tasks"][0]["resources"]
+    for k, v in want.items():
+        assert got.pop(k) == v
+    assert body == plain
+
+
+def test_net_backlog_is_backlogs_loop_with_a_port_a_disk_or_a_device_a_shape():
+    base, t = traffic.load("backlog"), traffic.load("net-backlog")
+    differ = {k for k in set(base) | set(t) if base.get(k) != t.get(k)}
+    assert differ == {"shapes", "why", "name"}
+    assert [s["name"] for s in t["shapes"]] == [f"n{i}" for i in range(8)]
+    assert all(s.get("networks") or s.get("devices") or s["disk_mb"]
+               for s in t["shapes"])
+    n0 = traffic.job_payload(t, dict(OP, shape=0))["task_groups"][0]
+    # the ``nomad job init`` example job: cpu 500, memory 256, port "db",
+    # ephemeral_disk 300
+    assert n0["tasks"][0]["resources"] == {"cpu": 500, "memory_mb": 256}
+    assert n0["networks"] == [{"reserved_ports": [], "dynamic_ports": ["db"]}]
+    assert n0["ephemeral_disk"] == {"size_mb": 300}
+    counts = collections.Counter(
+        o["shape"] for o in traffic.schedule(t, 7, 50)[:512])
+    assert set(counts.values()) == {64}  # equal shares, block by block
+    # one job a type, shape and extreme width: the warm-up meets every body
+    assert len(traffic.warmup_ops(t)) == 2 * 8 * 2
 
 
 def _mix(**keys):

@@ -13,8 +13,10 @@ what lets runs on different seeds be compared.
 
 Whether an operation registers a new job or a resident one AGAIN, unchanged,
 is data too (README.md, "What one operation is"): ``register_again_fraction``
-and ``resident_jobs``.  A file without these keys gives the operations it
-gave before there were any.
+and ``resident_jobs``.  So is what a job's body asks for beyond cpu and
+memory: a shape's ``disk_mb``, ``networks`` and ``devices`` (``job_payload``).
+A file without these keys gives the operations and bodies it gave before
+there were any.
 """
 
 from __future__ import annotations
@@ -215,9 +217,42 @@ def warmup_ops(t: Dict) -> List[Dict]:
 
 def job_payload(t: Dict, op: Dict, prefix: str = "") -> Dict:
     """The job as PUT to /v1/jobs (the server's snake_case wire form): one
-    task group ``g`` of ``width`` instances of the op's shape."""
+    task group ``g`` of ``width`` instances of the op's shape.
+
+    A shape may carry three optional keys beside ``cpu`` and ``memory_mb``,
+    each in the API's wire form (README.md, "Adding things"): ``disk_mb``
+    (the group's ``ephemeral_disk`` size), ``networks`` (the group's
+    ``network`` stanzas: ``reserved_ports`` and ``dynamic_ports`` labels,
+    where ``nomad job init``'s example job has its one) and ``devices`` (the
+    task's ``device`` asks: ``name``, ``count``).  A shape without them
+    gives, byte for byte, the body it gave before there were any."""
     s = t["shapes"][op["shape"]]
     jid = prefix + op["job_id"]
+    resources = {"cpu": s["cpu"], "memory_mb": s["memory_mb"]}
+    if "devices" in s:
+        resources["devices"] = [
+            {"name": d["name"], "count": d.get("count", 1)}
+            for d in s["devices"]]
+    group = {
+        "name": "g",
+        "count": op["width"],
+        "constraints": [dict(c) for c in s.get("constraints", [])],
+        "affinities": [dict(a) for a in s.get("affinities", [])],
+        "spreads": [dict(x) for x in s.get("spreads", [])],
+        "tasks": [{
+            "name": "t",
+            "driver": "mock",
+            "config": {"run_for": 0},
+            "resources": resources,
+        }],
+    }
+    if "disk_mb" in s:
+        group["ephemeral_disk"] = {"size_mb": s["disk_mb"]}
+    if "networks" in s:
+        group["networks"] = [
+            {"reserved_ports": list(n.get("reserved_ports", [])),
+             "dynamic_ports": list(n.get("dynamic_ports", []))}
+            for n in s["networks"]]
     return {
         "id": jid,
         "name": jid,
@@ -225,17 +260,5 @@ def job_payload(t: Dict, op: Dict, prefix: str = "") -> Dict:
         "type": op["type"],
         "priority": op["priority"],
         "datacenters": list(s["datacenters"]),
-        "task_groups": [{
-            "name": "g",
-            "count": op["width"],
-            "constraints": [dict(c) for c in s.get("constraints", [])],
-            "affinities": [dict(a) for a in s.get("affinities", [])],
-            "spreads": [dict(x) for x in s.get("spreads", [])],
-            "tasks": [{
-                "name": "t",
-                "driver": "mock",
-                "config": {"run_for": 0},
-                "resources": {"cpu": s["cpu"], "memory_mb": s["memory_mb"]},
-            }],
-        }],
+        "task_groups": [group],
     }
